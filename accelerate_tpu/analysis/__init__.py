@@ -53,7 +53,6 @@ from .program import (  # noqa: F401
 )
 from .contracts import (  # noqa: F401
     contract_for,
-    lowering_flavor,
     serving_program_contracts,
     shard_map_contracts,
 )
@@ -79,7 +78,6 @@ __all__ = [
     "collective_counts",
     "find_host_transfers",
     "contract_for",
-    "lowering_flavor",
     "serving_program_contracts",
     "shard_map_contracts",
     "PAIRING_TABLE",

@@ -1,13 +1,8 @@
 """The repo's declared collective contracts, in ONE table.
 
-Exact collective-permute pins depend on which shard_map lowering the
-running jax ships: the modern top-level `jax.shard_map` CSEs the rotation
-permutes inside scan bodies, the 0.4.x experimental lowering duplicates
-them across the unrolled+transposed bodies (counts measured on jax
-0.4.37). Before this module those pins lived as scattered
-`has_native_shard_map()` branches in tests/test_compiled_contracts.py;
-now every per-version number is one row here and the version probe is
-resolved exactly once, in `lowering_flavor()`.
+Exact collective-permute pins are those of the installed jax's
+`jax.shard_map` lowering (jax 0.9.0), which CSEs the rotation permutes
+inside scan bodies: one row per program here.
 
 The structural clauses (`forbid`/`require`) are lowering-independent and
 are what actually sets each mode's performance class — a ring that
@@ -19,7 +14,6 @@ from __future__ import annotations
 from .program import CANONICAL_COLLECTIVES, CollectiveContract
 
 __all__ = [
-    "lowering_flavor",
     "contract_for",
     "shard_map_contracts",
     "serving_program_contracts",
@@ -27,38 +21,26 @@ __all__ = [
 ]
 
 
-def lowering_flavor() -> str:
-    """"native" (top-level `jax.shard_map`) or "experimental" (0.4.x
-    `jax.experimental.shard_map`). The ONE place the probe is consulted."""
-    from ..utils.imports import has_native_shard_map
-
-    return "native" if has_native_shard_map() else "experimental"
-
-
-# program name -> {flavor: exact pins} + lowering-independent structure.
+# program name -> exact pins + lowering-independent structure.
 # Pins guard against silent rewrites (a doubled rotation, a CSE
 # regression); structure guards against degeneration (gather-the-world).
 _SHARD_MAP_TABLE: dict[str, dict] = {
     # one rotation = one permute per rotated buffer (K and V) in the scan
-    # body; the experimental lowering carries the pair fourfold across its
-    # unrolled bodies
+    # body
     "ring_attention.forward": dict(
-        pins={"native": {"collective-permute": 2},
-              "experimental": {"collective-permute": 8}},
+        pins={"collective-permute": 2},
         forbid=("all-gather", "all-to-all"),
     ),
     # fwd K/V + bwd recompute + dK/dV return rings
     "ring_attention.backward": dict(
-        pins={"native": {"collective-permute": 8},
-              "experimental": {"collective-permute": 28}},
+        pins={"collective-permute": 8},
         forbid=("all-gather",),
     ),
     # GPipe/1F1B: one fwd shift + one bwd shift in the loop bodies;
     # activations/params never gather across the stage axis, grads
     # all-reduce
     "pipeline.step": dict(
-        pins={"native": {"collective-permute": 2},
-              "experimental": {"collective-permute": 6}},
+        pins={"collective-permute": 2},
         forbid=("all-gather", "all-to-all"),
         require=("all-reduce",),
     ),
@@ -73,15 +55,13 @@ _SHARD_MAP_TABLE: dict[str, dict] = {
 }
 
 
-def shard_map_contracts(flavor: str | None = None) -> dict[str, CollectiveContract]:
-    """Every shard_map program contract for one lowering flavor."""
-    flavor = flavor or lowering_flavor()
+def shard_map_contracts() -> dict[str, CollectiveContract]:
+    """Every shard_map program contract."""
     out: dict[str, CollectiveContract] = {}
     for name, row in _SHARD_MAP_TABLE.items():
-        pins = row.get("pins", {})
         out[name] = CollectiveContract(
             name=name,
-            exact=pins.get(flavor, {}),
+            exact=row.get("pins", {}),
             at_least=row.get("at_least", {}),
             require=row.get("require", ()),
             forbid=row.get("forbid", ()),
@@ -89,9 +69,9 @@ def shard_map_contracts(flavor: str | None = None) -> dict[str, CollectiveContra
     return out
 
 
-def contract_for(name: str, flavor: str | None = None) -> CollectiveContract:
-    """Resolve one named contract for the running (or given) lowering."""
-    contracts = shard_map_contracts(flavor)
+def contract_for(name: str) -> CollectiveContract:
+    """Resolve one named contract."""
+    contracts = shard_map_contracts()
     if name not in contracts:
         raise KeyError(
             f"no contract named {name!r}; known: {sorted(contracts)}")

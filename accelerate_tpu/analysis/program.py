@@ -42,19 +42,27 @@ CANONICAL_COLLECTIVES = (
     "all-to-all",
 )
 
-# jaxpr primitive -> canonical (psum2 is the shard_map-body spelling of
-# psum on jax 0.4.x; pmin/pmax lower to all-reduce too)
+# jaxpr primitive -> canonical, by the names jax 0.9.0 gives them
+# (`jax._src.lax.parallel`). Under `shard_map(check_vma=True)`, the
+# default, `psum` traces as `psum_invariant` and an all-gather whose
+# result is replicated as `all_gather_invariant`; with the check off the
+# plain names appear. pmin/pmax lower to all-reduce too. `pshuffle` is a
+# python wrapper over ppermute and `psum_scatter` traces as
+# `reduce_scatter`, so neither needs a row.
 _PRIM_TO_CANONICAL = {
     "psum": "all-reduce",
-    "psum2": "all-reduce",
+    "psum_invariant": "all-reduce",
+    "unreduced_psum": "all-reduce",
     "pmin": "all-reduce",
     "pmax": "all-reduce",
     "all_gather": "all-gather",
-    "psum_scatter": "reduce-scatter",
+    "all_gather_invariant": "all-gather",
+    "all_gather_reduced": "all-gather",
     "reduce_scatter": "reduce-scatter",
+    "unreduced_reduce_scatter": "reduce-scatter",
     "ppermute": "collective-permute",
-    "pshuffle": "collective-permute",
     "all_to_all": "all-to-all",
+    "ragged_all_to_all": "all-to-all",
 }
 
 # one regex covers optimized HLO (`all-reduce`), StableHLO

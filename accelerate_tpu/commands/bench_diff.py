@@ -117,7 +117,7 @@ def load_row(path: str) -> dict:
     if not isinstance(data, dict):
         raise MalformedRow(f"{path}: bench row must be a JSON object")
     if "parsed" in data and isinstance(data["parsed"], dict):
-        data = data["parsed"]  # BENCH_r* capture wrapper
+        data = data["parsed"]  # the driver's capture wrapper
     validate_row(data, path)
     return data
 

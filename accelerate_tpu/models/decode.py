@@ -12,7 +12,7 @@ Design (TPU-first):
   compiled program (no per-position retracing).
 - the whole decode loop is ONE compiled program (`lax.scan` over steps with
   (last_token, caches) as carry) — a single dispatch for all tokens instead
-  of a host round-trip per token, which dominates on remote/tunneled devices.
+  of a host round-trip per token, which dominates on remote devices.
 - each family keeps its own `forward(config, params, ids, positions=...,
   kv_caches=...) -> (logits, new_caches)`; `build_generate` turns that
   uniform signature into a compiled prefill + fused-decode pair, cached per

@@ -123,8 +123,9 @@ def select_attention_backend(
     """Resolve 'auto' to a concrete attention backend.
 
     The einsum path materializes [B,H,S,S] f32 scores in HBM and is
-    bandwidth-bound from ~1k context; the pallas flash kernel measures
-    >=2x faster from s=1024 on v5e (benchmarks/sweep_attn.py). Decode
+    bandwidth-bound from ~1k context, so from s=1024 the pallas flash
+    kernel is chosen (the crossover is not measured on the current
+    code; benchmarks/sweep_attn.py is the sweep to rerun). Decode
     (kv_cache) keeps the mask-capable einsum path. Pure so the selection
     is contract-testable without TPU hardware
     (tests/test_compiled_contracts.py)."""
@@ -241,10 +242,15 @@ def _attention(config: LlamaConfig, layer: dict, x, cos, sin, positions, mask,
             if backend == "flash" and (
                 mask is None or getattr(mask, "ndim", 0) == 2
             ):
-                from ..ops.flash_attention import flash_attention
+                # a Mosaic kernel cannot be partitioned by GSPMD: under
+                # a mesh it runs per shard (batch x heads) in shard_map
+                from ..ops.flash_attention import flash_attention_on_mesh
+                from ..state import PartialState
 
-                out = flash_attention(q, k, v, causal=True, mask=mask,
-                                      window=window)
+                mesh = (PartialState().mesh if PartialState._shared_state
+                        else None)
+                out = flash_attention_on_mesh(q, k, v, mesh, causal=True,
+                                              mask=mask, window=window)
             else:
                 out = dot_product_attention(q, k, v, mask=mask, causal=True,
                                             window=window)

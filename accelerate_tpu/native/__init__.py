@@ -6,18 +6,21 @@ data_loader.py:518-559); this package owns that machinery natively:
 `token_loader.cpp` memory-maps tokenized corpora and assembles shuffled,
 host-sharded batches on producer threads behind a C ABI.
 
-The shared library builds on demand with g++ (cached beside the source);
-`TokenCorpusLoader` transparently falls back to a NumPy implementation with
-IDENTICAL semantics (same permutation, sharding, wraparound) when no
-toolchain is available, so behavior never depends on the build.
+The shared library builds on demand with g++ from the TRACKED source,
+into the git-ignored `_native/_build/`, under a name that carries the
+source's hash: an artifact that some other tree or toolchain left on disk
+is never picked up for being newer. `TokenCorpusLoader` transparently falls
+back to a NumPy implementation with IDENTICAL semantics (same permutation,
+sharding, wraparound) when no toolchain is available, so behavior never
+depends on the build; `loader.implementation` says which one runs.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 
 import numpy as np
@@ -33,25 +36,17 @@ _build_error: str | None = None
 
 
 def _build_dir() -> str:
-    override = os.environ.get("ACCELERATE_TPU_NATIVE_CACHE")
-    candidates = [override] if override else [
-        os.path.join(_SRC_DIR, "_build"),  # read-only installs fall through
-        os.path.join(tempfile.gettempdir(), f"accelerate_tpu_native_{os.getuid()}"),
-    ]
-    for d in candidates:
-        try:
-            os.makedirs(d, mode=0o700, exist_ok=True)
-            st = os.stat(d)
-            # refuse dirs we don't own or that others can write: a planted
-            # .so in a predictable shared path would be dlopened into the
-            # training process
-            if st.st_uid != os.getuid() or (st.st_mode & 0o022):
-                continue
-            if os.access(d, os.W_OK):
-                return d
-        except OSError:
-            continue
-    raise OSError(f"no safe writable native build dir among {candidates}")
+    # inside the checkout only (a read-only tree uses the NumPy loader)
+    d = os.environ.get("ACCELERATE_TPU_NATIVE_CACHE") or os.path.join(
+        _SRC_DIR, "_build")
+    os.makedirs(d, mode=0o700, exist_ok=True)
+    st = os.stat(d)
+    # refuse a dir we don't own or that others can write: a planted .so in
+    # a predictable shared path would be dlopened into the training process
+    if (st.st_uid != os.getuid() or (st.st_mode & 0o022)
+            or not os.access(d, os.W_OK)):
+        raise OSError(f"no safe writable native build dir at {d}")
+    return d
 
 
 def _load_library():
@@ -61,10 +56,10 @@ def _load_library():
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            so_path = os.path.join(_build_dir(), "libatl.so")
-            if not os.path.exists(so_path) or (
-                os.path.getmtime(so_path) < os.path.getmtime(_SRC)
-            ):
+            with open(_SRC, "rb") as f:
+                src_hash = hashlib.sha256(f.read()).hexdigest()[:16]
+            so_path = os.path.join(_build_dir(), f"libatl-{src_hash}.so")
+            if not os.path.exists(so_path):
                 # unique temp output + atomic rename: N launcher workers can
                 # race this build without anyone dlopening a half-written .so
                 tmp_out = f"{so_path}.{os.getpid()}.tmp"
@@ -255,6 +250,11 @@ class TokenCorpusLoader:
         else:
             self.remainder = -1
             self.tail_layout = None
+
+    @property
+    def implementation(self) -> str:
+        """"native" (the C++ core built from token_loader.cpp) or "numpy"."""
+        return "native" if self._loader is not None else "numpy"
 
     def set_epoch(self, epoch: int) -> None:
         self.epoch = int(epoch)

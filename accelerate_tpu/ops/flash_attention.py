@@ -16,7 +16,13 @@ kernel (q innermost) recompute the probability blocks on the fly, so both
 directions are O(S) memory — no S x S score matrix anywhere.
 
 On non-TPU backends the kernel runs in pallas interpret mode (slow, for
-tests); prefer `dot_product_attention` there.
+tests; decided and recorded in `ops/kernel_mode.py`); prefer
+`dot_product_attention` there.
+
+A Mosaic kernel is opaque to GSPMD ("Mosaic kernels cannot be
+automatically partitioned"): under a mesh of more than one device call
+`flash_attention_on_mesh`, which runs the kernel per shard inside
+`jax.shard_map` over the batch and head axes.
 """
 
 from __future__ import annotations
@@ -29,8 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# `TPUCompilerParams` was renamed `CompilerParams` in newer jax; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import kernel_mode
 
 NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width; scalar-per-row state is kept 2D
@@ -191,7 +196,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -336,7 +341,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
         in_specs=dq_in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -368,7 +373,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -461,9 +466,9 @@ def flash_attention(
     skipped entirely, so long-context windowed attention costs
     O(S * window), not O(S^2). Requires causal=True.
 
-    Default blocks come from the v5e sweep (benchmarks/sweep_attn.py):
-    big blocks amortize pallas grid overhead — 512x1024 wins to ~2k context,
-    1024x1024 from 4k up (96.7 TF/s vs einsum's 18.2 at s=4096)."""
+    Default blocks (512x1024 to ~2k context, 1024x1024 from 4k up) were
+    picked by an earlier sweep (benchmarks/sweep_attn.py) whose numbers
+    are not measured on the current code."""
     b, sq, h, d = q.shape
     if window is not None:
         if not causal:
@@ -490,8 +495,6 @@ def flash_attention(
         block_q = 1024 if sq >= 4096 else 512
     if block_k is None:
         block_k = 1024
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     # clamp blocks to the sequence, rounded down to a power of two (>= 16 for
     # Mosaic sublane tiling): an unaligned block (e.g. 300 rows after a plain
     # min()) fails Mosaic lowering on real TPUs even though interpret-mode
@@ -547,6 +550,9 @@ def flash_attention(
             block_k = min(block_k, sk & -sk)
             if block_q < 16 or block_k < 16:
                 return _fallback()
+    # only here is the kernel really about to run (the einsum fallbacks
+    # above returned already), so only here is the mode decided/recorded
+    interpret = kernel_mode.resolve_interpret("flash_attention", interpret)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -563,3 +569,52 @@ def flash_attention(
     else:
         out = _flash(qf, kf, vf, causal, block_q, block_k, interpret, window)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+
+def flash_attention_on_mesh(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mesh,
+    causal: bool = False,
+    mask: jax.Array | None = None,
+    window: int | None = None,
+) -> jax.Array:
+    """`flash_attention` under a device mesh: the kernel runs per shard
+    inside `jax.shard_map`, batch split over the data-like axes
+    (`BATCH_AXES`) and heads over the tensor-parallel axis — the layout
+    the sharding planner already gives activations, so no resharding is
+    added around the call. An axis whose size does not divide its dim is
+    left out of the spec (that dim is then replicated over it: correct,
+    just not split). The sequence is never split here (ring/ulysses do
+    that). `mask` must be a [B, S_k] key-padding mask or None.
+
+    Called with a one-device mesh, or from inside an enclosing
+    `shard_map` (the pipeline stages: the mesh axes are already manual
+    and the arrays already per-shard), it is the bare kernel."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..utils.constants import AXIS_MODEL, BATCH_AXES
+
+    fn = functools.partial(flash_attention, causal=causal, window=window)
+    already_manual = bool(jax.sharding.get_abstract_mesh().manual_axes)
+    if mesh is None or mesh.size == 1 or already_manual:
+        return fn(q, k, v, mask=mask)
+    b, _, h, _ = q.shape
+    batch_axes, n = [], 1
+    for a in BATCH_AXES:
+        size = mesh.shape.get(a, 1)
+        if size > 1 and b % (n * size) == 0:
+            batch_axes.append(a)
+            n *= size
+    lead = tuple(batch_axes) if len(batch_axes) > 1 else (
+        batch_axes[0] if batch_axes else None)
+    tp = mesh.shape.get(AXIS_MODEL, 1)
+    heads = AXIS_MODEL if tp > 1 and h % tp == 0 else None
+    spec = P(lead, None, heads, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if mask is not None:
+        args, in_specs = args + (mask,), in_specs + (P(lead, None),)
+    return jax.shard_map(
+        lambda q, k, v, m=None: fn(q, k, v, mask=m), mesh=mesh,
+        in_specs=in_specs, out_specs=spec, check_vma=False)(*args)

@@ -16,21 +16,26 @@ mapping, request mix, and eviction history.
 
 Layout and semantics:
 
-- pool K/V: [num_pages + 1, page_size, Hkv, D] per layer (the serving
+- pool K/V: [num_pages + 1, Hkv, page_size, D] per layer (the serving
   pool minus its leading layer dim — the kernel is called inside the
-  family forward's `lax.scan` over layers). The last page is the
-  reserved trash page backing padded table entries.
+  family forward's `lax.scan` over layers). Heads sit OUTSIDE the page
+  rows so one head's page is a whole [page_size, D] tile: the TPU
+  compiler only takes blocks whose trailing two dims are whole array
+  dims or (8, 128) multiples. The last page is the reserved trash page
+  backing padded table entries.
 - page table: [slots, pages_per_slot] int32; lengths: [slots] int32.
 - q: one token per slot, GQA grouped as [slots, Hkv, group, D] — the
-  head-group broadcast happens in-kernel (each grid step dots the whole
-  q group against its kv head's page), so K/V are never `repeat_kv`'d.
+  head-group broadcast happens in-kernel (each grid step dots every kv
+  head's whole q group, padded to 8 sublanes, against that head's
+  page), so K/V are never `repeat_kv`'d.
 - the NEW token's K/V (this step's, position == length) are folded into
   the online softmax as a final single-key update instead of being
   written to the pool first: the kernel never writes, the engine
   scatters the one new row per slot afterwards (`paged_append_rows`).
 - int8 pools (`PagedKV.scales` set) dequantize per page INSIDE the
-  kernel — codes * per-row-per-head scales — so the HBM stream is the
-  int8 bytes, not a pre-dequantized bf16 copy.
+  kernel — per-row-per-head scales applied to the scores and the
+  probabilities, which is the same product as scaling the codes — so
+  the HBM stream is the int8 bytes, not a pre-dequantized bf16 copy.
 
 Masking matches `models/decode.cached_attention_mask` exactly: a slot's
 query (position == length) attends pool rows < length plus its own new
@@ -40,8 +45,11 @@ compute garbage that the engine discards via its `live` lane mask —
 same contract as the dense gather path.
 
 On non-TPU backends the kernel runs in pallas interpret mode (slow, for
-tests) — tier-1 proves exactness against `paged_decode_reference` and
-token-exactness against the dense-gather engine path on CPU.
+tests; decided and recorded in `ops/kernel_mode.py`) — tier-1 proves
+exactness against `paged_decode_reference` and token-exactness against
+the dense-gather engine path on CPU, `tests/test_chip_compile.py` that
+the chip's compiler takes it at real widths, and `chip_smoke.py` that
+it agrees with the reference on the chip.
 """
 
 from __future__ import annotations
@@ -55,11 +63,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# `TPUCompilerParams` was renamed `CompilerParams` in newer jax; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import kernel_mode
 
 NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width; scalar-per-group state is kept 2D
+_SUBLANES = 8  # f32 sublanes per vreg; the query group pads to this
 
 __all__ = [
     "PagedKV",
@@ -78,9 +86,9 @@ __all__ = [
 class PagedKV:
     """One pool buffer (K or V) as it threads through a family forward.
 
-    `data` is the [L, pages+1, page_size, Hkv, D] pool (or a per-layer
+    `data` is the [L, pages+1, Hkv, page_size, D] pool (or a per-layer
     slice of it — `lax.scan` over the leading dim slices both children
-    together); `scales` is the int8 mode's [L, pages+1, page_size, Hkv]
+    together); `scales` is the int8 mode's [L, pages+1, Hkv, page_size]
     per-row-per-head scale array, None for a bf16 pool. `compute_dtype`
     is the dtype attention math materializes K/V rows in (and the dtype
     of the new-token rows handed back for the engine to write); None
@@ -157,17 +165,24 @@ class PagedDecodeMeta:
 def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
                          pk_ref, pv_ref, *rest, sm_scale: float,
                          page_size: int, pages_per_slot: int,
-                         window: int | None, quantized: bool):
-    """Grid [slots, Hkv, pages_per_slot] (pages innermost/arbitrary):
-    each step folds one page of one slot's kv head into the online
-    softmax; the last step also folds the new token's K/V and finalizes.
-    `table_ref`/`lengths_ref` are scalar-prefetch SMEM refs — the same
-    values the BlockSpec index maps used to choose the page blocks."""
+                         num_kv_heads: int, window: int | None,
+                         quantized: bool):
+    """Grid [slots, pages_per_slot] (pages innermost/arbitrary): each
+    step folds one page of one slot — every kv head of it, in a static
+    loop — into the online softmax; the last step also folds the new
+    token's K/V and finalizes. `table_ref`/`lengths_ref` are
+    scalar-prefetch SMEM refs — the same values the BlockSpec index maps
+    used to choose the page blocks.
+
+    Every block's trailing two dims are whole array dims ([ps, D] page
+    tiles, [Gp, D] query groups, [1, D] new rows, [Hkv, ps] scales), the
+    one shape rule Mosaic holds a TPU block to; heads are indexed with
+    static leading indices only."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         (ks_ref, vs_ref), (o_ref, m_scr, l_scr, acc_scr) = (None, None), rest
-    s, j = pl.program_id(0), pl.program_id(2)
+    s, j = pl.program_id(0), pl.program_id(1)
     length = lengths_ref[s]
 
     @pl.when(j == 0)
@@ -176,14 +191,15 @@ def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def update(s_blk, v_blk):
-        """One online-softmax step: fold pre-scaled, pre-masked scores
-        s_blk [G, n] and values v_blk [n, D] into the running state.
-        Probabilities stay f32 through the PV dot — decode is
+    def update(h, s_blk, pv):
+        """One online-softmax step for kv head `h`: fold pre-scaled,
+        pre-masked scores s_blk [Gp, n] into the running state; `pv`
+        maps the probabilities [Gp, n] to their value sum [Gp, D].
+        Probabilities stay f32 through the PV product — decode is
         bandwidth-bound, not MXU-bound, and the dense reference path
         keeps f32 probabilities too."""
-        m_prev = m_scr[...][:, :1]
-        l_prev = l_scr[...][:, :1]
+        m_prev = m_scr[h][:, :1]
+        l_prev = l_scr[h][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
         p = jnp.exp(s_blk - m_new)
         # a fully-masked block keeps m_new at NEG_INF where exp(s - m)
@@ -191,10 +207,9 @@ def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
         p = jnp.where(s_blk <= NEG_INF / 2, 0.0, p)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v_blk.astype(jnp.float32), preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[h] = acc_scr[h] * alpha + pv(p)
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     # a page is live iff it holds at least one row below the slot's
     # length; dead pages (allocation slack, trash padding) compute
@@ -203,16 +218,6 @@ def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
 
     @pl.when(live)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32)           # [G, D]
-        k = pk_ref[0, :, 0, :]                        # [ps, D]
-        v = pv_ref[0, :, 0, :]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0].astype(
-                jnp.float32)[:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0].astype(
-                jnp.float32)[:, None]
-        s_blk = jnp.dot(q, k.T.astype(jnp.float32),
-                        preferred_element_type=jnp.float32) * sm_scale
         pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         keep = pos < length
@@ -220,86 +225,120 @@ def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
             # HF sliding-window convention: key visible iff q - key <
             # window; the query sits at position == length
             keep = keep & (pos > length - window)
-        s_blk = jnp.where(keep, s_blk, NEG_INF)
-        update(s_blk, v)
+        if quantized:
+            # per-row scales sit along the LANES of the [Gp, ps] score
+            # block, so dequantization is applied to scores and
+            # probabilities (q.(c*s) == (q.c)*s) instead of to the
+            # [ps, D] codes — no lane->sublane transpose of the scales
+            ks_all = ks_ref[0].astype(jnp.float32)        # [Hkv, ps]
+            vs_all = vs_ref[0].astype(jnp.float32)
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)           # [Gp, D]
+            k = pk_ref[0, h].astype(jnp.float32)          # [ps, D]
+            v = pv_ref[0, h].astype(jnp.float32)
+            # q @ k^T as an NT contraction (no in-kernel transpose)
+            s_blk = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if quantized:
+                s_blk = s_blk * ks_all[h:h + 1, :]
+                vs = vs_all[h:h + 1, :]
+            s_blk = jnp.where(keep, s_blk * sm_scale, NEG_INF)
+            if quantized:
+                update(h, s_blk, lambda p, v=v, vs=vs: jnp.dot(
+                    p * vs, v, preferred_element_type=jnp.float32))
+            else:
+                update(h, s_blk, lambda p, v=v: jnp.dot(
+                    p, v, preferred_element_type=jnp.float32))
 
     @pl.when(j == pages_per_slot - 1)
     def _tail():
         # the new token's K/V (position == length, always visible — its
-        # window distance is 0) folds as one more single-key update;
-        # then finalize. l > 0 always: this key contributes exp(0) when
-        # it is the running max.
-        q = q_ref[0, 0].astype(jnp.float32)
-        kn = kn_ref[0, 0].astype(jnp.float32)          # [D]
-        s_new = jnp.dot(q, kn[:, None],
-                        preferred_element_type=jnp.float32) * sm_scale
-        update(s_new, vn_ref[0, 0][None, :])
-        l = l_scr[...][:, :1]
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype)
+        # window distance is 0) folds as one more single-key update, on
+        # the VPU (a one-column matmul has no legal MXU shape); then
+        # finalize. l > 0 always: this key contributes exp(0) when it is
+        # the running max.
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)
+            kn = kn_ref[0, h].astype(jnp.float32)          # [1, D]
+            vn = vn_ref[0, h].astype(jnp.float32)
+            s_new = jnp.sum(q * kn, axis=-1, keepdims=True) * sm_scale
+            update(h, s_new, lambda p, vn=vn: p * vn)
+            l = l_scr[h][:, :1]
+            o_ref[0, h] = (acc_scr[h] / jnp.maximum(l, 1e-30)).astype(
+                o_ref.dtype)
 
 
 def _paged_attention_call(q4, kn, vn, pool_k, pool_v, k_scales, v_scales,
                           table, lengths, window: int | None,
                           interpret: bool):
-    """q4 [S, Hkv, G, D], kn/vn [S, Hkv, D], pool [N+1, ps, Hkv, D]
-    (+ scales [N+1, ps, Hkv] when quantized) -> out [S, Hkv, G, D]."""
+    """q4 [S, Hkv, G, D], kn/vn [S, Hkv, D], pool [N+1, Hkv, ps, D]
+    (+ scales [N+1, Hkv, ps] when quantized) -> out [S, Hkv, G, D]."""
     S, Hkv, G, D = q4.shape
     P = table.shape[1]
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     quantized = k_scales is not None
     sm_scale = 1.0 / math.sqrt(D)
+    # the query group is the sublane dim of every score block: pad it to
+    # a whole f32 sublane tile (zero rows attend uniformly and are
+    # sliced off) so G in {1, 4, 6} lowers like G = 8
+    Gp = -(-G // _SUBLANES) * _SUBLANES
+    if Gp != G:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    # [S, Hkv, 1, D]: a unit sublane dim makes the per-head new row a
+    # whole-trailing-dims block
+    kn, vn = kn[:, :, None, :], vn[:, :, None, :]
 
-    def page_map(s, h, j, table_ref, lengths_ref):
+    def page_map(s, j, table_ref, lengths_ref):
         # dead steps (page start >= length) re-target page 0 of the
         # slot's table: consecutive dead steps then revisit one block
         # instead of streaming allocation slack / trash padding
         j_live = jnp.where(j * ps < jnp.maximum(lengths_ref[s], 1), j, 0)
-        return table_ref[s * P + j_live], 0, h, 0
+        return table_ref[s * P + j_live], 0, 0, 0
 
-    def per_slot(s, h, j, table_ref, lengths_ref):
-        return (s, h, 0, 0)
-
-    def per_head_row(s, h, j, table_ref, lengths_ref):
-        return (s, h, 0)
+    def per_slot(s, j, table_ref, lengths_ref):
+        return (s, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), per_slot),
-        pl.BlockSpec((1, 1, D), per_head_row),
-        pl.BlockSpec((1, 1, D), per_head_row),
-        pl.BlockSpec((1, ps, 1, D), page_map),
-        pl.BlockSpec((1, ps, 1, D), page_map),
+        pl.BlockSpec((1, Hkv, Gp, D), per_slot),
+        pl.BlockSpec((1, Hkv, 1, D), per_slot),
+        pl.BlockSpec((1, Hkv, 1, D), per_slot),
+        pl.BlockSpec((1, Hkv, ps, D), page_map),
+        pl.BlockSpec((1, Hkv, ps, D), page_map),
     ]
     operands = [q4, kn, vn, pool_k, pool_v]
     if quantized:
-        scale_map = (lambda s, h, j, table_ref, lengths_ref:
-                     page_map(s, h, j, table_ref, lengths_ref)[:3])
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                     pl.BlockSpec((1, ps, 1), scale_map)]
+        scale_map = (lambda s, j, table_ref, lengths_ref:
+                     page_map(s, j, table_ref, lengths_ref)[:3])
+        in_specs += [pl.BlockSpec((1, Hkv, ps), scale_map),
+                     pl.BlockSpec((1, Hkv, ps), scale_map)]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, Hkv, P),
+        grid=(S, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), per_slot),
+        out_specs=pl.BlockSpec((1, Hkv, Gp, D), per_slot),
         scratch_shapes=[
-            pltpu.VMEM((G, _LANES), jnp.float32),
-            pltpu.VMEM((G, _LANES), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, page_size=ps,
-        pages_per_slot=P, window=window, quantized=quantized)
-    return pl.pallas_call(
+        pages_per_slot=P, num_kv_heads=Hkv, window=window,
+        quantized=quantized)
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, G, D), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, Gp, D), q4.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_decode_attention",
         interpret=interpret,
     )(table.reshape(-1), lengths, *operands)
+    return out[:, :, :G]
 
 
 # ---------------------------------------------------------------------------
@@ -336,8 +375,7 @@ def paged_decode_attention(
             f"page table covers {meta.table.shape[0]} slots, q has {S}")
     if window is not None and (window <= 0 or window >= meta.rows):
         window = None  # band wider than the cache reach: plain causal
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = kernel_mode.resolve_interpret("paged_decode_attention", interpret)
     G = H // Hkv
     row_dtype = pk.row_dtype
     # the fold must see exactly the bytes the engine will write, so a
@@ -369,16 +407,16 @@ def paged_decode_reference(
     S, _, H, D = q.shape
     Hkv = k_new.shape[2]
     G = H // Hkv
-    ps = pk.data.shape[1]
+    ps = pk.data.shape[2]
     R = meta.table.shape[1] * ps
     row_dtype = pk.row_dtype
 
     def dense(p: PagedKV):
-        pages = p.data[meta.table]                      # [S, P, ps, Hkv, D]
+        pages = p.data[meta.table]                      # [S, P, Hkv, ps, D]
         full = pages.astype(jnp.float32)
         if p.quantized:
             full = full * p.scales[meta.table].astype(jnp.float32)[..., None]
-        return full.reshape(S, R, Hkv, D)
+        return jnp.swapaxes(full, 2, 3).reshape(S, R, Hkv, D)
 
     k_all, v_all = dense(pk), dense(pv)
     k_row = k_new.astype(row_dtype)

@@ -28,9 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..utils.constants import AXIS_EXPERT
-from ..utils.imports import resolve_shard_map
 
-_shard_map = resolve_shard_map()
 
 
 class MoEFallbackWarning(UserWarning):
@@ -314,7 +312,7 @@ def expert_parallel_moe_a2a(
     out_specs = (P(axis_name), P()) if has_extras else P(axis_name)
     if expert_aux is not None:
         aux_spec = jax.tree_util.tree_map(lambda _: P(), expert_aux)
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name), expert_spec,
                       P(axis_name), P(axis_name), aux_spec),
@@ -322,14 +320,14 @@ def expert_parallel_moe_a2a(
             check_vma=False,
         )(x, router_logits, expert_params, topk[0], topk[1], expert_aux)
     if topk is not None:
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name), expert_spec,
                       P(axis_name), P(axis_name)),
             out_specs=out_specs,
             check_vma=False,
         )(x, router_logits, expert_params, topk[0], topk[1])
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), expert_spec),
         out_specs=out_specs,
@@ -398,20 +396,20 @@ def expert_parallel_moe(
     out_specs = (P(), P()) if has_extras else P()
     if expert_aux is not None:
         aux_spec = jax.tree_util.tree_map(lambda _: P(), expert_aux)
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(), P(), expert_spec, P(), P(), aux_spec),
             out_specs=out_specs,
             check_vma=False,
         )(x, router_logits, expert_params, tg, ti, expert_aux)
     if topk is not None:
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(), P(), expert_spec, P(), P()),
             out_specs=out_specs,
             check_vma=False,
         )(x, router_logits, expert_params, tg, ti)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), P(), expert_spec),
         out_specs=out_specs,

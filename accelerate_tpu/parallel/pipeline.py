@@ -40,9 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..utils.constants import AXIS_STAGE
-from ..utils.imports import resolve_shard_map
 
-_shard_map = resolve_shard_map()
 
 
 def stack_layers_into_stages(params: Any, num_stages: int) -> Any:
@@ -239,7 +237,7 @@ def pipeline_apply(
             _pipeline_local, stage_fn=stage_fn, axis_name=axis_name,
             num_stages=num_stages, num_micro=num_micro_batches,
         )
-    out = _shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(stage_spec, P()),
         out_specs=P(),
@@ -541,7 +539,7 @@ def pipeline_value_and_grad(
             _pipeline_1f1b_local, stage_fn=stage_fn, loss_fn=loss_fn,
             axis_name=axis_name, num_stages=num_stages, num_micro=M,
         )
-    loss, grads = _shard_map(
+    loss, grads = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(stage_spec, P(), P()),
         out_specs=(P(), stage_spec),

@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops import kernel_mode
 from ..utils.constants import AXIS_SEQ
-from ..utils.imports import resolve_shard_map
 from ..models.common import repeat_kv as _repeat_heads
 from ..ops.flash_attention import (
     _flash_backward,
@@ -41,7 +41,6 @@ from ..ops.flash_attention import (
     _pow2_floor,
 )
 
-_shard_map = resolve_shard_map()
 
 NEG_INF = -1e30
 
@@ -461,11 +460,11 @@ def ring_attention(
     axis_size = mesh.shape[axis_name]
     n_rep = q.shape[2] // k.shape[2]
     s_local = q.shape[1] // axis_size
-    interpret = jax.devices()[0].platform != "tpu"
     blk = _chunk_blocks(s_local)
     # the pallas ring kernel carries no cross-chunk band offsets: windowed
     # rings run the (exact) einsum fold
     use_kernel = blk >= 16 and s_local % blk == 0 and window is None
+    interpret = use_kernel and kernel_mode.resolve_interpret("ring_attention")
 
     seq_spec = P(None, axis_name, None, None)
     mask_spec = P(None, axis_name)
@@ -476,7 +475,7 @@ def ring_attention(
                 axis_size=axis_size, causal=causal, n_rep=n_rep,
                 interpret=interpret,
             )
-            return _shard_map(
+            return jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(seq_spec, seq_spec, seq_spec, mask_spec),
                 out_specs=seq_spec,
@@ -492,13 +491,13 @@ def ring_attention(
             axis_size=axis_size, causal=causal, n_rep=n_rep, window=window,
         )
         if mask is not None:
-            return _shard_map(
+            return jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(seq_spec, seq_spec, seq_spec, mask_spec),
                 out_specs=seq_spec,
                 check_vma=False,
             )(q, k, v, mask)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec),
         out_specs=seq_spec,

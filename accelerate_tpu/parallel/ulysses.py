@@ -22,9 +22,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from ..utils.constants import AXIS_SEQ
-from ..utils.imports import resolve_shard_map
 
-_shard_map = resolve_shard_map()
 
 
 def _ulysses_local(q, k, v, mask=None, *, axis_name: str, causal: bool,
@@ -128,13 +126,13 @@ def ulysses_attention(
     fn = partial(_ulysses_local, axis_name=axis_name, causal=causal,
                  n_rep=n_rep, window=window)
     if mask is not None:
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec, P(None, axis_name)),
             out_specs=seq_spec,
             check_vma=False,
         )(q, k, v, mask)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec),
         out_specs=seq_spec,

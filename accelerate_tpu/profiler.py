@@ -27,7 +27,7 @@ from typing import Any, Iterator
 import jax
 
 from .telemetry.registry import StreamingHistogram
-from .utils.constants import TPU_PEAK_FLOPS
+from .utils.constants import tpu_peak_flops
 
 
 @contextlib.contextmanager
@@ -75,13 +75,16 @@ def live_array_bytes() -> int:
 
 
 def peak_flops_per_chip(device=None) -> float:
-    """Peak bf16 FLOPs/s for this chip generation (public specs table)."""
+    """Peak bf16 FLOPs/s for this chip generation (public specs table).
+    Raises for a device that is not a TPU of a known generation: there is
+    no peak to divide by, and 0.0 or an assumed value would turn into a
+    made-up utilization downstream."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, flops in TPU_PEAK_FLOPS.items():
-        if key in kind:
-            return flops
-    return 0.0
+    if device.platform != "tpu":
+        raise ValueError(
+            f"peak_flops_per_chip: {device.platform!r} device has no entry "
+            "in the TPU peak table")
+    return tpu_peak_flops(getattr(device, "device_kind", ""))
 
 
 def causal_lm_train_flops(n_params: int, tokens: int,
@@ -362,10 +365,14 @@ class StepTimer:
 
     def mfu(self) -> float:
         """Model FLOPs utilization in [0,1] against chip peak * num_chips."""
-        peak = self.peak_flops if self.peak_flops is not None else peak_flops_per_chip()
-        chips = self.num_chips if self.num_chips is not None else jax.device_count()
-        if not peak or not self.flops_per_step or not self._step_hist.count:
+        if not self.flops_per_step or not self._step_hist.count:
             return 0.0
+        peak = self.peak_flops
+        if peak is None:
+            if jax.devices()[0].platform != "tpu":
+                return float("nan")  # no chip, no utilization to report
+            peak = peak_flops_per_chip()
+        chips = self.num_chips if self.num_chips is not None else jax.device_count()
         achieved = self.flops_per_step / self.mean_step_time
         return achieved / (peak * chips)
 

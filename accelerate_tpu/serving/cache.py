@@ -9,7 +9,9 @@ compiled program (the pjit/TPUv4 static-shapes rule: the program is
 compiled once, the *data* changes).
 
 `PagedKVCache` goes one step further: the physical buffer is a pool of
-fixed-size pages ([L, pages, page_size, H, D]) and each slot owns an
+fixed-size pages ([L, pages, H, page_size, D] — heads outside the page
+rows, so one head's page is a contiguous [page_size, D] tile the Pallas
+decode kernel can block on the chip) and each slot owns an
 ordered page table instead of a contiguous stripe. Two things fall out:
 
 - per-request memory is sized by the request (pages allocated at
@@ -176,7 +178,7 @@ jax.tree_util.register_pytree_node(SlotKVCache, _flatten, _unflatten)
 class PagedKVCache:
     """Paged KV pool with fixed-shape per-slot page tables.
 
-    k/v: [num_layers, num_pages + 1, page_size, num_kv_heads, head_dim] —
+    k/v: [num_layers, num_pages + 1, num_kv_heads, page_size, head_dim] —
     the last page is the reserved TRASH page backing padded page-table
     entries (idle lanes gather it, masked rows and dead writes land in
     it, and it is never allocated). lengths: [num_slots] int32, the
@@ -185,7 +187,7 @@ class PagedKVCache:
     through jit and donates; `page_size`/`pages_per_slot`/... are static.
 
     QUANTIZED mode (`create(kv_dtype="int8")`): k/v hold int8 codes and
-    `k_scale`/`v_scale` ([L, pages+1, page_size, H] bf16, one symmetric
+    `k_scale`/`v_scale` ([L, pages+1, H, page_size] bf16, one symmetric
     absmax scale per row per head — `ops/quant.py kv_quantize_rows`)
     ride alongside as extra pytree children. Halving the bytes per page
     doubles the pages — and therefore the concurrent users — a fixed
@@ -239,7 +241,7 @@ class PagedKVCache:
             raise ValueError(
                 f"num_pages({num_pages}) < pages_per_slot({pages_per_slot}):"
                 " a max-size request could never be admitted")
-        shape = (num_layers, num_pages + 1, page_size, num_kv_heads, head_dim)
+        shape = (num_layers, num_pages + 1, num_kv_heads, page_size, head_dim)
         scale_shape = shape[:-1]
         return cls(
             k=jnp.zeros(shape, jnp.int8 if quantized else dtype),
@@ -289,7 +291,7 @@ class PagedKVCache:
         quantized mode) and all layers — the unit behind the
         `serving_kv_bytes_in_use` gauge and the HBM math in
         docs/serving.md: pages a budget holds = budget / page_nbytes."""
-        L, _, ps, H, D = self.k.shape
+        L, _, H, ps, D = self.k.shape
         per = L * ps * H * D * self.k.dtype.itemsize
         if self.quantized:
             per += L * ps * H * self.k_scale.dtype.itemsize
@@ -305,14 +307,16 @@ class PagedKVCache:
 def _dense_pages(codes: jax.Array, scales: jax.Array | None,
                  idx: jax.Array, dtype) -> jax.Array:
     """Gather pool pages at `idx` (any int32 index shape) and materialize
-    them densely: a plain gather for a bf16 pool, gather + per-row
-    dequantization for an int8 pool."""
-    pages = codes[:, idx]
-    if scales is None:
-        return pages
-    from ..ops.quant import kv_dequantize_rows
+    them densely as [L, *idx, page_size, H, D]: a plain gather for a
+    bf16 pool, gather + per-row dequantization for an int8 pool."""
+    pages = codes[:, idx]                       # [L, *idx, H, ps, D]
+    if scales is not None:
+        from ..ops.quant import kv_dequantize_rows
 
-    return kv_dequantize_rows(pages, scales[:, idx], dtype)
+        pages = kv_dequantize_rows(pages, scales[:, idx], dtype)
+    # pool pages keep heads outside the rows; the dense views are
+    # row-major ([rows, H, D], `models/decode.py` layout)
+    return jnp.swapaxes(pages, -3, -2)
 
 
 def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
@@ -323,7 +327,7 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
     int8 pool. `table_row` ([pages_per_slot] int32) and `slot` are
     traced — one compiled program covers every slot and every page
     mapping."""
-    L, _, ps, H, D = cache.k.shape
+    L, _, H, ps, D = cache.k.shape
     P = cache.pages_per_slot
     ks = _dense_pages(cache.k, cache.k_scale, table_row,
                       cache.compute_dtype).reshape(L, 1, P * ps, H, D)
@@ -349,7 +353,7 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
     bit-identical however many sharers race (an int8 round-trip is NOT
     idempotent, so rewriting a shared page with "the same values" would
     actually drift them)."""
-    L, _, ps, H, D = cache.k.shape
+    L, _, H, ps, D = cache.k.shape
     R = cache.rows
     length = cache.lengths[slot]
     # rows never spill past the view: length <= max_len and pad_slack
@@ -366,27 +370,29 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
 def _scatter_rows(cache: PagedKVCache, pages: jax.Array, offs: jax.Array,
                   rows_k: jax.Array, rows_v: jax.Array,
                   new_lengths: jax.Array) -> PagedKVCache:
-    """Scatter row payloads [L, n, H, D] at (page, offset) pairs,
-    quantizing codes + per-row scales on an int8 pool. The shared tail
-    of every pool write path (prefill chunks, decode appends, both
-    engine attention modes)."""
+    """Scatter row payloads [L, *idx, H, D] at (page, offset) pairs
+    (`pages`/`offs` of index shape `idx`), quantizing codes + per-row
+    scales on an int8 pool. The shared tail of every pool write path
+    (prefill chunks, decode appends, both engine attention modes)."""
+
+    def put(pool, rows):
+        # the page and row indices straddle the pool's head axis, so
+        # the indexed result leads with the index dims: [*idx, L, H(, D)]
+        return pool.at[:, pages, :, offs].set(
+            jnp.moveaxis(rows, 0, pages.ndim).astype(pool.dtype))
+
     if not cache.quantized:
         return dataclasses.replace(
-            cache,
-            k=cache.k.at[:, pages, offs].set(rows_k.astype(cache.k.dtype)),
-            v=cache.v.at[:, pages, offs].set(rows_v.astype(cache.v.dtype)),
-            lengths=new_lengths,
-        )
+            cache, k=put(cache.k, rows_k), v=put(cache.v, rows_v),
+            lengths=new_lengths)
     from ..ops.quant import kv_quantize_rows
 
     ck, sk = kv_quantize_rows(rows_k)
     cv, sv = kv_quantize_rows(rows_v)
     return dataclasses.replace(
         cache,
-        k=cache.k.at[:, pages, offs].set(ck),
-        v=cache.v.at[:, pages, offs].set(cv),
-        k_scale=cache.k_scale.at[:, pages, offs].set(sk),
-        v_scale=cache.v_scale.at[:, pages, offs].set(sv),
+        k=put(cache.k, ck), v=put(cache.v, cv),
+        k_scale=put(cache.k_scale, sk), v_scale=put(cache.v_scale, sv),
         lengths=new_lengths,
     )
 
@@ -396,7 +402,7 @@ def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     (k [L, S, R, H, D], v [L, S, R, H, D]), dequantized to
     `compute_dtype` on an int8 pool. `table` is the full
     [S, pages_per_slot] int32 page table (traced)."""
-    L, _, ps, H, D = cache.k.shape
+    L, _, H, ps, D = cache.k.shape
     S = cache.num_slots
     P = cache.pages_per_slot
     ks = _dense_pages(cache.k, cache.k_scale, table,
@@ -423,7 +429,7 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
     the dense gather path extracts the row from the returned views
     (`paged_append_batch`), the Pallas kernel path hands the rows over
     directly."""
-    _, _, ps, _, _ = cache.k.shape
+    ps = cache.page_size
     row = cache.lengths                                  # [S] view row
     page = jnp.take_along_axis(table, (row // ps)[:, None], axis=1)[:, 0]
     off = row % ps
@@ -457,7 +463,7 @@ def paged_append_window(cache: PagedKVCache, table: jax.Array,
     at or past `length`, hence in a PRIVATE page (allocator invariant),
     so shared copy-on-write pages are untouched — the same write-safety
     argument as `paged_append_rows`, W rows at a time."""
-    _, _, ps, _, _ = cache.k.shape
+    ps = cache.page_size
     W = win_k.shape[2]
     rows = cache.lengths[:, None] + jnp.arange(W, dtype=jnp.int32)  # [S, W]
     valid = (jnp.arange(W, dtype=jnp.int32)[None, :] < counts[:, None]) \

@@ -83,8 +83,8 @@ def shard_params(params: Any, mesh: Mesh,
 def cache_state_shardings(cache, mesh: Mesh):
     """(cache_shardings, replicated) for an engine's pool + slot state.
 
-    The pool shards over the KV-heads dim (axis 3 of
-    [L, pages+1, page_size, H, D]) when H divides the model axis — each
+    The pool shards over the KV-heads dim (axis 2 of
+    [L, pages+1, H, page_size, D]) when H divides the model axis — each
     chip owns its heads' pages outright, page gathers/scatters stay
     chip-local, and pool HBM scales 1/N. When H doesn't divide (tiny-GQA
     models on a wide mesh) the pool falls back to sharding over the PAGE
@@ -99,19 +99,19 @@ def cache_state_shardings(cache, mesh: Mesh):
     leading dims).
 
     The specs deliberately omit trailing `None` entries
-    (`P(None, None, None, "model")`, not `...,"model", None)`): GSPMD
+    (`P(None, None, "model")`, not `...,"model", None)`): GSPMD
     normalizes specs that way in its output shardings, and the engine
     pins outputs to exactly these objects — a cosmetically different
     spelling of the same sharding would still be a different jit cache
     key on the next step's inputs."""
     n = mesh.shape[AXIS_MODEL]
     rep = NamedSharding(mesh, PartitionSpec())
-    num_heads = cache.k.shape[3]
+    num_heads = cache.k.shape[2]
     # one spec serves pool and scales in every branch: the sharded dim
-    # (heads = axis 3, pages = axis 1) sits at the same index in the 5-D
+    # (heads = axis 2, pages = axis 1) sits at the same index in the 5-D
     # pool and the 4-D scale array
     if num_heads % n == 0:
-        kv = NamedSharding(mesh, PartitionSpec(None, None, None, AXIS_MODEL))
+        kv = NamedSharding(mesh, PartitionSpec(None, None, AXIS_MODEL))
     elif cache.k.shape[1] % n == 0:
         kv = NamedSharding(mesh, PartitionSpec(None, AXIS_MODEL))
     else:

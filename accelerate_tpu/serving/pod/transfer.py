@@ -8,7 +8,7 @@ ship to a decode worker that owns the slot for the request's decode
 lifetime. This module is the device-side half of that hand-off:
 
 - `extract`: gather one slot's table row out of the pool into a dense
-  [L, pages_per_slot, page_size, H, D] block. FIXED shape — the block
+  [L, pages_per_slot, H, page_size, D] block. FIXED shape — the block
   always spans the full table row (trash-padded rows gather the trash
   page) so every extraction hits the same compiled program. The host then
   keeps only the `n_prompt_pages` that carry real prompt KV; on a real
@@ -56,7 +56,7 @@ class KVPageShipment:
     """One prompt's prefilled KV state, in transit prefill -> decode.
 
     `k_pages`/`v_pages` are the fixed-shape extracted block
-    ([L, pages_per_slot, page_size, H, D] host numpy); only the first
+    ([L, pages_per_slot, H, page_size, D] host numpy); only the first
     `n_prompt_pages` carry prompt KV (the rest rode along for shape
     stability and are dropped at install). `first_token` is the first
     generated token — sampled on the prefill worker from the final
@@ -79,7 +79,7 @@ class KVPageShipment:
     # with its tokens; None only for shipments from pre-logprob senders
     first_logprob: float | None = None
     # int8 pools ship their codes as-is plus the per-row-per-head scale
-    # blocks ([L, pages_per_slot, page_size, H]) — the wire carries half
+    # blocks ([L, pages_per_slot, H, page_size]) — the wire carries half
     # the bytes of a bf16 shipment; None on bf16 pools
     k_scales: np.ndarray | None = None
     v_scales: np.ndarray | None = None
@@ -140,7 +140,7 @@ class PageTransport:
             @jax.jit
             def extract(cache, rows):
                 # rows: [pages_per_slot] int32 (traced data — any mapping,
-                # one program); gathers [L, P, ps, H, D] per buffer
+                # one program); gathers [L, P, H, ps, D] per buffer
                 return cache.k[:, rows], cache.v[:, rows]
 
             @partial(jax.jit, donate_argnums=(0, 1),

@@ -276,11 +276,7 @@ def shard_pytree(tree: Any, plan: Any) -> Any:
 
     All array leaves go through ONE batched `jax.device_put` call rather
     than one call per leaf: the single entry into jaxlib's
-    batched_device_put is faster for large trees and sidesteps an
-    intermittent jaxlib 0.4.36 CPU-client segfault observed in tier-1
-    when hundreds of per-leaf device_put calls race the GC (the PR 6
-    known-flake class — per-leaf placement crashed ~1-in-2 on a loaded
-    box, batched has not reproduced)."""
+    batched_device_put is faster for large trees."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     plan_leaves = treedef.flatten_up_to(plan)
     idx = [i for i, x in enumerate(leaves) if hasattr(x, "shape")]

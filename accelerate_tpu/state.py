@@ -369,7 +369,11 @@ class AcceleratorState:
         self.mixed_precision = resolve_mixed_precision(mixed_precision)
         mesh_config = mesh_config or MeshConfig.from_env() or MeshConfig.data_parallel()
         self.mesh_config = mesh_config
-        self.mesh = mesh_config.build(self.partial_state.devices)
+        # an explicit MeshConfig.devices list (a sub-mesh of the host's
+        # chips) wins over "every device of the process"
+        self.mesh = mesh_config.build(
+            None if mesh_config.devices is not None
+            else self.partial_state.devices)
         self.partial_state.set_mesh(self.mesh)
 
     @property

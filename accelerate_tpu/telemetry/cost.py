@@ -58,9 +58,10 @@ __all__ = [
 
 COST_SAMPLE_EVERY_ENV = "ACCELERATE_TPU_COST_SAMPLE_EVERY"
 
-# Nominal peaks for backends without a public spec entry (the CPU smoke
-# path): roofline numbers stay non-null and comparable run-over-run;
-# `peaks_nominal` marks them as placeholders, not hardware claims.
+# Nominal peaks for NON-TPU backends only (the CPU rehearsals): roofline
+# arithmetic stays exercised there, and `peaks_nominal` marks the output
+# as placeholders, not hardware claims. An unknown TPU kind is an error
+# (`device_peaks`), never these.
 NOMINAL_PEAK_FLOPS = 1e12
 NOMINAL_PEAK_HBM_BYTES = 100e9
 
@@ -90,19 +91,25 @@ def resolve_sample_every(explicit: int | None = None,
 
 def device_peaks(device=None) -> tuple[float, float, bool]:
     """(peak_flops, peak_hbm_bytes_per_s, nominal) for this chip.
-    TPU generations resolve from the public spec tables; anything else
-    (CPU smoke, unknown accelerators) gets the NOMINAL placeholders with
-    nominal=True."""
+    TPU generations resolve from the public spec tables, and a TPU whose
+    `device_kind` is in neither table is an ERROR (a roofline against an
+    assumed peak is a made-up number). Only a non-TPU backend (the CPU
+    rehearsals) gets the NOMINAL placeholders, with nominal=True."""
     import jax
 
-    from ..utils.constants import TPU_PEAK_FLOPS
+    from ..utils.constants import tpu_peak_flops
 
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, flops in TPU_PEAK_FLOPS.items():
-        if key in kind:
-            return flops, TPU_PEAK_HBM_BYTES.get(key, NOMINAL_PEAK_HBM_BYTES), False
-    return NOMINAL_PEAK_FLOPS, NOMINAL_PEAK_HBM_BYTES, True
+    if device.platform != "tpu":
+        return NOMINAL_PEAK_FLOPS, NOMINAL_PEAK_HBM_BYTES, True
+    kind = getattr(device, "device_kind", "")
+    flops = tpu_peak_flops(kind)
+    for key, hbm in TPU_PEAK_HBM_BYTES.items():
+        if key in kind.lower():
+            return flops, hbm, False
+    raise ValueError(
+        f"no peak HBM bandwidth known for device kind {kind!r}; add it to "
+        "TPU_PEAK_HBM_BYTES with its source")
 
 
 def fence(tree: Any) -> None:

@@ -116,6 +116,20 @@ def are_the_same_tensors(tensor) -> bool:
     return bool(np.all(stacked == stacked[0:1]))
 
 
+def checkout_child_env(extra: dict | None = None) -> dict:
+    """os.environ + the checkout root on PYTHONPATH (+ `extra`): what any
+    child python needs to `import accelerate_tpu`, which is NOT
+    pip-installed on the machines this repo runs on."""
+    merged = dict(os.environ)
+    pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    merged["PYTHONPATH"] = os.pathsep.join(
+        p for p in [pkg_root, merged.get("PYTHONPATH", "")] if p
+    )
+    if extra:
+        merged.update(extra)
+    return merged
+
+
 def execute_subprocess(cmd: list[str], env: dict | None = None,
                        timeout: int | None = None) -> str:
     """Run a launch command, raise with captured output on failure
@@ -129,16 +143,7 @@ def execute_subprocess(cmd: list[str], env: dict | None = None,
     if timeout is None:
         timeout = int(os.environ.get("ACCELERATE_TPU_TEST_LAUNCH_TIMEOUT",
                                      "1200"))
-    merged = dict(os.environ)
-    # Child processes must import accelerate_tpu even when the package is not
-    # pip-installed (running from a source checkout): prepend the package's
-    # parent directory to PYTHONPATH.
-    pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    merged["PYTHONPATH"] = os.pathsep.join(
-        p for p in [pkg_root, merged.get("PYTHONPATH", "")] if p
-    )
-    if env:
-        merged.update(env)
+    merged = checkout_child_env(env)
     # own session: on timeout the WHOLE process group dies (SIGKILLing just
     # the launcher would skip its finally-block terminate and leak the
     # wedged worker ranks it spawned — still bound to the coordinator port)

@@ -82,3 +82,17 @@ TPU_PEAK_FLOPS = {
     "v5p": 459e12,
     "v6e": 918e12,
 }
+
+
+def tpu_peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of the TPU generation `device_kind` names (as
+    `jax.devices()[0].device_kind` reports it). A kind that is not in
+    `TPU_PEAK_FLOPS` is an error, never a default: a utilization computed
+    against an assumed peak is a made-up number."""
+    kind = device_kind.lower()
+    for key, flops in TPU_PEAK_FLOPS.items():
+        if key in kind:
+            return flops
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device_kind!r}; add it to "
+        f"TPU_PEAK_FLOPS (known: {sorted(TPU_PEAK_FLOPS)}) with its source")

@@ -73,10 +73,13 @@ def set_virtual_host_devices(n: int, env: dict | None = None) -> None:
 
 
 def force_cpu_platform() -> bool:
-    """Force JAX onto the host CPU platform, beating images whose PJRT plugin
-    pins the platform programmatically (jax.config wins over the JAX_PLATFORMS
-    env var). Returns False if a backend is already initialized — at that
-    point the platform can no longer change in this process."""
+    """Force JAX onto the host CPU platform from INSIDE a process that has
+    already imported jax (`Accelerator(cpu=True)`, the dry runs): the
+    JAX_PLATFORMS variable is read when jax is imported, so after that the
+    choice has to go through `jax.config`. A process that can set
+    JAX_PLATFORMS=cpu before importing jax needs nothing else. Returns
+    False if a backend is already initialized — at that point the platform
+    can no longer change in this process."""
     import jax
 
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -91,11 +94,32 @@ _compilation_cache_dir_applied: str | None = None
 
 
 def default_compilation_cache_dir() -> str:
-    """~/.cache/accelerate_tpu/compilation (XDG_CACHE_HOME honoured)."""
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
+    """`<checkout>/.jax_cache`: ONE fixed directory next to the package
+    (git-ignored). The path is part of every cache key, so it is never
+    built from a temporary name, a process id or the time — two
+    processes of one checkout always agree on it."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
+
+
+def _apply_cache_thresholds() -> None:
+    """Forward the threshold env overrides to the matching jax knobs."""
+    import jax
+
+    from .constants import (
+        ENV_COMPILATION_CACHE_MIN_COMPILE_SECS,
+        ENV_COMPILATION_CACHE_MIN_ENTRY_BYTES,
     )
-    return os.path.join(base, "accelerate_tpu", "compilation")
+
+    min_secs = os.environ.get(ENV_COMPILATION_CACHE_MIN_COMPILE_SECS)
+    if min_secs is not None:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", float(min_secs))
+    min_bytes = os.environ.get(ENV_COMPILATION_CACHE_MIN_ENTRY_BYTES)
+    if min_bytes is not None:
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", int(min_bytes))
 
 
 def configure_compilation_cache(
@@ -105,10 +129,14 @@ def configure_compilation_cache(
     executables instead of recompiling (minutes of XLA work at real model
     sizes; the dominant cost of a restart on TPU pods).
 
-    Resolution: explicit ``cache_dir`` arg > ``ACCELERATE_TPU_COMPILATION_CACHE``
-    env > a ``jax_compilation_cache_dir`` the user already configured (left
-    untouched) > the default user cache dir. A value of ``0``/``off``/
-    ``false``/``none`` (env or arg) disables. Threshold overrides
+    Resolution: explicit ``cache_dir`` arg (scoped caches of test
+    fixtures; ``off`` disables) > ``JAX_COMPILATION_CACHE_DIR`` (jax reads
+    it itself at import: the cache is kept THERE and this function points
+    jax at no other path) > ``ACCELERATE_TPU_COMPILATION_CACHE`` env (a
+    dir, or ``0``/``off``/``false``/``none`` to disable) > a
+    ``jax_compilation_cache_dir`` the user already configured (left
+    untouched) > `default_compilation_cache_dir()`, the fixed
+    ``<checkout>/.jax_cache``. Threshold overrides
     ``ACCELERATE_TPU_COMPILATION_CACHE_MIN_COMPILE_SECS`` / ``_MIN_ENTRY_BYTES``
     forward to the matching jax knobs (jax's defaults otherwise: entries
     cheaper than ~1 s of compile are not persisted).
@@ -119,14 +147,18 @@ def configure_compilation_cache(
     or None when disabled. Idempotent per resolved dir unless ``force``.
     """
     global _compilation_cache_dir_applied
-    from .constants import (
-        ENV_COMPILATION_CACHE,
-        ENV_COMPILATION_CACHE_MIN_COMPILE_SECS,
-        ENV_COMPILATION_CACHE_MIN_ENTRY_BYTES,
-    )
+    from .constants import ENV_COMPILATION_CACHE
 
     _OFF = {"0", "off", "false", "no", "none", "disabled"}
     if cache_dir is None:
+        placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+        if placed:
+            # placed from outside: jax already holds this dir (it reads
+            # the variable at import) and nothing here may move it
+            import jax
+
+            _apply_cache_thresholds()
+            return jax.config.jax_compilation_cache_dir or placed
         cache_dir = os.environ.get(ENV_COMPILATION_CACHE)
     if cache_dir is not None:
         cache_dir = cache_dir.strip()
@@ -150,36 +182,24 @@ def configure_compilation_cache(
             cache_dir = None
     import jax
 
-    def _apply_thresholds() -> None:
-        min_secs = os.environ.get(ENV_COMPILATION_CACHE_MIN_COMPILE_SECS)
-        if min_secs is not None:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", float(min_secs)
-            )
-        min_bytes = os.environ.get(ENV_COMPILATION_CACHE_MIN_ENTRY_BYTES)
-        if min_bytes is not None:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", int(min_bytes)
-            )
-
     if cache_dir is None:
         existing = jax.config.jax_compilation_cache_dir
         if existing:
             # user already configured jax directly: keep their dir, but the
             # threshold env overrides still apply
-            _apply_thresholds()
+            _apply_cache_thresholds()
             return existing
         cache_dir = default_compilation_cache_dir()
     cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
     if cache_dir == _compilation_cache_dir_applied and not force:
-        _apply_thresholds()
+        _apply_cache_thresholds()
         return cache_dir
     try:
         os.makedirs(cache_dir, exist_ok=True)
     except OSError:
         return None  # unwritable cache location (read-only HOME): skip
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    _apply_thresholds()
+    _apply_cache_thresholds()
     # jax checks cache usability once, at the first compile, and memoizes the
     # answer — a process that already compiled something (test suites, REPL
     # exploration before Accelerator()) would otherwise silently keep "no

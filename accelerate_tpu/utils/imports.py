@@ -93,42 +93,3 @@ def package_version(name: str) -> str | None:
         return importlib.metadata.version(name)
     except importlib.metadata.PackageNotFoundError:
         return None
-
-
-def has_native_shard_map() -> bool:
-    """True when `jax.shard_map` exists at the top level — the same probe
-    `resolve_shard_map` gates on. Beyond the API location, the two lines
-    lower shard_map bodies differently: the modern lowering CSEs the
-    rotation collectives so a ring/pipeline body carries exactly one
-    collective-permute per rotated buffer, while the 0.4.x experimental
-    lowering duplicates them across the unrolled/transposed bodies. The
-    compiled-program contract tests pin exact collective counts per
-    lowering via this predicate (the structure — no gathers — is asserted
-    unconditionally)."""
-    import jax
-
-    return getattr(jax, "shard_map", None) is not None
-
-
-def resolve_shard_map():
-    """`jax.shard_map` moved to the top level only in newer jax; older
-    runtimes ship it under jax.experimental with the replication-check kwarg
-    named `check_rep` instead of `check_vma`. One resolution point for every
-    shard_map call site (parallel/{ring_attention,ulysses,pipeline,moe}) —
-    call sites write the new-style API and run on both."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    import functools
-
-    from jax.experimental.shard_map import shard_map
-
-    @functools.wraps(shard_map)
-    def compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return shard_map(*args, **kwargs)
-
-    return compat

@@ -13,6 +13,12 @@ import jax
 import numpy as np
 import optax
 
+import os
+
+# run as `python benchmarks/<this>.py`: the package is not pip-installed,
+# so put the checkout root (not benchmarks/) on sys.path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 from accelerate_tpu import TrainState
 from accelerate_tpu.accelerator import Accelerator
 from accelerate_tpu.models import mixtral

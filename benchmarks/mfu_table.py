@@ -17,11 +17,17 @@ import jax
 import numpy as np
 import optax
 
+import os
+
+# run as `python benchmarks/<this>.py`: the package is not pip-installed,
+# so put the checkout root (not benchmarks/) on sys.path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 from accelerate_tpu import TrainState
 from accelerate_tpu.accelerator import Accelerator
 from accelerate_tpu.models import llama
 from accelerate_tpu.models.common import count_params
-from accelerate_tpu.utils.constants import TPU_PEAK_FLOPS
+from accelerate_tpu.utils.constants import tpu_peak_flops
 
 CONFIGS = {
     # name: (hidden, ffn, layers, heads, kv_heads, batch, seq, remat_policy,
@@ -86,8 +92,8 @@ def run(name: str, steps: int = 15) -> None:
     tok_s = batch * seq * steps / best
     attn = 12 * L * h * seq
     flops_tok = 6 * n_params + attn
-    device_kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
-    peak = next((v for k, v in TPU_PEAK_FLOPS.items() if k in device_kind), 197e12)
+    # an unknown device kind raises: no assumed peak, no made-up MFU
+    peak = tpu_peak_flops(jax.devices()[0].device_kind)
     mfu = flops_tok * tok_s / peak
     print(f"{name:5s}: {n_params/1e6:7.1f}M params  b={batch} s={seq}  "
           f"{tok_s:9.1f} tok/s  mfu={mfu:.4f}", flush=True)

@@ -208,10 +208,28 @@ def build_tiny_distributed_pod(family_name: str = "llama", pod_roles=(1, 1),
     import sys as _sys
     import time as _time
 
+    import jax
+
     import accelerate_tpu
     from accelerate_tpu.commands.pod import spawn_socket_workers
     from accelerate_tpu.serving.pod.distributed import (
         ChannelListener, DistributedPodConfig, DistributedPodRouter)
+
+    if jax.devices()[0].platform == "tpu":
+        # a chip belongs to one process at a time: this process holds the
+        # TPU already (it drives the load and builds engines), and every
+        # worker it would start builds an engine on the default device
+        # too — they would fail at start-up or hang. Nothing here pins a
+        # worker to a chip of its own, so however many chips the host
+        # has, N+M+1 processes cannot share them.
+        raise RuntimeError(
+            f"the socket pod starts {sum(pod_roles)} worker processes that "
+            "each need the accelerator while this process holds it "
+            f"({len(jax.devices())} TPU chip(s), one process per chip): not "
+            "runnable on one TPU host from one launcher. Run it on the CPU "
+            "(JAX_PLATFORMS=cpu), or start router and workers on hosts of "
+            "their own with `accelerate-tpu pod-router --no-spawn` / "
+            "`pod-worker`.")
     from accelerate_tpu.serving.pod.distributed.worker import (
         engine_config_from_spec)
 

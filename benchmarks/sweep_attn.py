@@ -12,6 +12,12 @@ import time
 import jax
 import jax.numpy as jnp
 
+import os
+
+# run as `python benchmarks/<this>.py`: the package is not pip-installed,
+# so put the checkout root (not benchmarks/) on sys.path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 from accelerate_tpu.models.common import dot_product_attention
 from accelerate_tpu.ops.flash_attention import flash_attention
 

@@ -13,11 +13,17 @@ import jax
 import numpy as np
 import optax
 
+import os
+
+# run as `python benchmarks/<this>.py`: the package is not pip-installed,
+# so put the checkout root (not benchmarks/) on sys.path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 from accelerate_tpu import TrainState
 from accelerate_tpu.accelerator import Accelerator
 from accelerate_tpu.models import llama
 from accelerate_tpu.models.common import count_params
-from accelerate_tpu.utils.constants import TPU_PEAK_FLOPS
+from accelerate_tpu.utils.constants import tpu_peak_flops
 
 
 def run(backend: str, remat: bool, policy: str, batch: int, seq: int = 2048,
@@ -55,8 +61,8 @@ def run(backend: str, remat: bool, policy: str, batch: int, seq: int = 2048,
     tok_s = batch * seq * steps / best
     attn_flops = 12 * cfg.num_hidden_layers * cfg.hidden_size * seq
     flops_per_token = 6 * n_params + attn_flops
-    device_kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
-    peak = next((v for k, v in TPU_PEAK_FLOPS.items() if k in device_kind), 197e12)
+    # an unknown device kind raises: no assumed peak, no made-up MFU
+    peak = tpu_peak_flops(jax.devices()[0].device_kind)
     mfu = flops_per_token * tok_s / peak
     print(f"{backend:7s} remat={remat!s:5s}/{policy:4s} b={batch:3d}: "
           f"{tok_s:9.1f} tok/s  mfu={mfu:.4f}", flush=True)

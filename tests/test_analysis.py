@@ -41,7 +41,6 @@ from accelerate_tpu.analysis import (
     save_baseline,
 )
 from accelerate_tpu.commands.accelerate_cli import main as cli_main
-from accelerate_tpu.utils.imports import resolve_shard_map
 
 TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(TESTS_DIR)
@@ -753,10 +752,13 @@ class TestConcurrencyPasses:
 
 def _psum_program():
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("i",))
-    sm = resolve_shard_map()
-    f = sm(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
            in_specs=P("i"), out_specs=P())
     return jax.jit(f), jnp.arange(8.0)
+
+
+def _ring(x):
+    return jax.lax.ppermute(x, "i", [(k, (k + 1) % 8) for k in range(8)])
 
 
 class TestCollectiveCounts:
@@ -764,6 +766,28 @@ class TestCollectiveCounts:
         fn, x = _psum_program()
         counts = collective_counts(jax.make_jaxpr(fn)(x))
         assert counts["all-reduce"] == 1
+
+    @pytest.mark.parametrize("check_vma", [True, False])
+    @pytest.mark.parametrize("body,in_spec,out_spec,expect", [
+        (lambda x: jax.lax.psum(x, "i"), P("i"), P(), "all-reduce"),
+        (lambda x: jax.lax.pmax(x, "i"), P("i"), P(), "all-reduce"),
+        (lambda x: jax.lax.all_gather(x, "i", tiled=True), P("i"), P("i"),
+         "all-gather"),
+        (lambda x: jax.lax.psum_scatter(x, "i", tiled=True), P(), P("i"),
+         "reduce-scatter"),
+        (_ring, P("i"), P("i"), "collective-permute"),
+    ], ids=["psum", "pmax", "all_gather", "psum_scatter", "ppermute"])
+    def test_jaxpr_counts_every_collective_under_both_vma_modes(
+            self, body, in_spec, out_spec, expect, check_vma):
+        """jax 0.9 renames the shard_map-body primitives when the
+        replication check is on (`psum` -> `psum_invariant`): the strict
+        jaxpr audit must count each collective under either spelling, not
+        silently read 0."""
+        mesh = Mesh(np.array(jax.devices()).reshape(8), ("i",))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                           out_specs=out_spec, check_vma=check_vma)
+        counts = collective_counts(jax.make_jaxpr(fn)(jnp.arange(64.0)))
+        assert counts[expect] == 1, counts
 
     def test_counts_from_lowered_stablehlo(self):
         fn, x = _psum_program()
@@ -812,12 +836,10 @@ class TestCollectiveContract:
         c = CollectiveContract(name="loose", require=("all-reduce",))
         assert c.check("all-reduce\ncollective-permute\n") == []
 
-    def test_contract_table_resolves_per_flavor(self):
-        native = contract_for("ring_attention.forward", flavor="native")
-        exp = contract_for("ring_attention.forward", flavor="experimental")
-        assert dict(native.exact)["collective-permute"] == 2
-        assert dict(exp.exact)["collective-permute"] == 8
-        assert "all-gather" in native.forbid and "all-gather" in exp.forbid
+    def test_contract_table_resolves_by_name(self):
+        ring = contract_for("ring_attention.forward")
+        assert dict(ring.exact)["collective-permute"] == 2
+        assert "all-gather" in ring.forbid
         with pytest.raises(KeyError):
             contract_for("no_such_program")
 

@@ -20,6 +20,7 @@ import sys
 import pytest
 
 from accelerate_tpu import checkpointing as ckpt
+from accelerate_tpu.test_utils import checkout_child_env
 
 _SCRIPT = os.path.join(os.path.dirname(__file__), "crash_resume_script.py")
 
@@ -33,8 +34,9 @@ def _load_script_module():
 
 
 def test_sigkill_mid_save_resumes_loss_curve_exact(tmp_path):
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "CRASH_DIR": str(tmp_path)}
+    # the child imports accelerate_tpu from this checkout (not installed)
+    env = checkout_child_env({"JAX_PLATFORMS": "cpu",
+                              "CRASH_DIR": str(tmp_path)})
     out = subprocess.run([sys.executable, _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == -signal.SIGKILL, (out.returncode, out.stderr)

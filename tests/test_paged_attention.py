@@ -27,7 +27,7 @@ def _setup(seed=0, S=3, P=4, ps=8, Hkv=2, G=3, D=16, num_pages=12,
     slot 0 mid-page length, slot 1 exactly at a page boundary, slot 2
     nearly empty with a trash-padded table row."""
     rng = np.random.default_rng(seed)
-    shape = (num_pages + 1, ps, Hkv, D)
+    shape = (num_pages + 1, Hkv, ps, D)
     pool_k = jnp.asarray(rng.normal(size=shape), dtype)
     pool_v = jnp.asarray(rng.normal(size=shape), dtype)
     table = np.full((S, P), num_pages, np.int32)  # trash-padded
@@ -103,7 +103,7 @@ def test_kernel_ignores_stale_rows_in_reused_pages():
     output: poisoning them with huge values changes nothing."""
     q, kn, vn, pk, pv, meta = _setup()
     out0, _ = paged_decode_attention(q, kn, vn, pk, pv, meta)
-    ps = pk.data.shape[1]
+    ps = pk.data.shape[2]
     poisoned_k, poisoned_v = np.asarray(pk.data).copy(), np.asarray(
         pv.data).copy()
     table, lengths = np.asarray(meta.table), np.asarray(meta.lengths)
@@ -111,8 +111,8 @@ def test_kernel_ignores_stale_rows_in_reused_pages():
         for j, page in enumerate(table[s]):
             for r in range(ps):
                 if j * ps + r >= lengths[s]:
-                    poisoned_k[page, r] = 900.0
-                    poisoned_v[page, r] = -900.0
+                    poisoned_k[page, :, r] = 900.0
+                    poisoned_v[page, :, r] = -900.0
     out1, _ = paged_decode_attention(
         q, kn, vn, PagedKV(jnp.asarray(poisoned_k)),
         PagedKV(jnp.asarray(poisoned_v)), meta)

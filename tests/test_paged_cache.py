@@ -244,7 +244,7 @@ def test_paged_cache_shapes_and_pytree():
                                 dtype=jnp.float32, page_size=8, pad_slack=4)
     # ceil((16+4)/8) = 3 pages/slot, default pool 9 pages + 1 trash
     assert cache.pages_per_slot == 3 and cache.num_pages == 9
-    assert cache.k.shape == (2, 10, 8, 4, 8)
+    assert cache.k.shape == (2, 10, 4, 8, 8)  # [L, pages+1, H, ps, D]
     assert cache.rows == 24 and cache.trash_page == 9
     leaves, treedef = jax.tree_util.tree_flatten(cache)
     assert len(leaves) == 3
@@ -506,10 +506,10 @@ def test_int8_write_then_view_roundtrips_and_leaves_other_rows_bitstable():
     cache = paged_write_slot(cache, table_row, jnp.int32(0), payload(2),
                              payload(2), jnp.int32(8), chunk)  # rows 5..12
     # rows 0..4 (written only by the first chunk) are bit-identical
-    np.testing.assert_array_equal(np.asarray(cache.k)[:, 0, :5],
-                                  codes_after_first[:, 0, :5])
-    np.testing.assert_array_equal(np.asarray(cache.k_scale)[:, 0, :5],
-                                  scales_after_first[:, 0, :5])
+    np.testing.assert_array_equal(np.asarray(cache.k)[:, 0, :, :5],
+                                  codes_after_first[:, 0, :, :5])
+    np.testing.assert_array_equal(np.asarray(cache.k_scale)[:, 0, :, :5],
+                                  scales_after_first[:, 0, :, :5])
     # and the dense view dequantizes to within the int8 error of the
     # payload on the real rows
     ks, _, length = paged_slot_view(cache, table_row, jnp.int32(0))
@@ -541,7 +541,7 @@ def test_int8_append_rows_quantizes_one_row_per_live_slot():
     # slot 0 row landed at page 0 offset 3, quantized
     from accelerate_tpu.ops.quant import kv_dequantize_rows
 
-    got = kv_dequantize_rows(out.k[0, 0, 3], out.k_scale[0, 0, 3],
+    got = kv_dequantize_rows(out.k[0, 0, :, 3], out.k_scale[0, 0, :, 3],
                              jnp.float32)
     want = np.asarray(row_k[0, 0], np.float32)
     absmax = np.abs(want).max(-1, keepdims=True)

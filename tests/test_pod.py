@@ -228,7 +228,7 @@ def test_cache_state_shardings_spec_shapes(gpt2_setup):
     mesh = tensor_mesh(2)
     cache_sh, rep = cache_state_shardings(eng.cache, mesh)
     assert cache_sh.k.spec == jax.sharding.PartitionSpec(
-        None, None, None, "model")
+        None, None, "model")
     assert rep.spec == jax.sharding.PartitionSpec()
     # non-dividing heads (gpt2-tiny has 4): a 3-device mesh replicates
     cache_sh3, _ = cache_state_shardings(eng.cache, tensor_mesh(3))

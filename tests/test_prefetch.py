@@ -366,6 +366,78 @@ class TestCompilationCache:
             env_mod._compilation_cache_dir_applied = None
 
 
+    def test_jax_env_var_places_the_cache_and_nothing_moves_it(
+            self, tmp_path, monkeypatch):
+        """Where JAX_COMPILATION_CACHE_DIR is set, the cache is kept there:
+        `configure_compilation_cache()` returns that directory and points
+        jax at NO other path — the repo's own variable no longer beats
+        it, and the in-checkout default is not applied."""
+        from accelerate_tpu.utils import environment as env_mod
+        from accelerate_tpu.utils.constants import ENV_COMPILATION_CACHE
+
+        placed = str(tmp_path / "placed-from-outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        monkeypatch.setenv(ENV_COMPILATION_CACHE, str(tmp_path / "ours"))
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append((name, value))
+            return real_update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        # jax reads the variable itself at import; model that state
+        prev_dir = jax.config.jax_compilation_cache_dir
+        real_update("jax_compilation_cache_dir", placed)
+        try:
+            assert env_mod.configure_compilation_cache() == placed
+            assert jax.config.jax_compilation_cache_dir == placed
+            assert [u for u in updates
+                    if u[0] == "jax_compilation_cache_dir"] == []
+            assert not os.path.exists(str(tmp_path / "ours"))
+        finally:
+            real_update("jax_compilation_cache_dir", prev_dir)
+            env_mod._compilation_cache_dir_applied = None
+
+    def test_default_is_one_fixed_directory_inside_the_checkout(
+            self, monkeypatch):
+        """Unset everywhere: `<checkout>/.jax_cache` — not under the
+        user's home, and not built from a temporary name, a pid or the
+        time (the path is part of every cache key)."""
+        from accelerate_tpu.utils import environment as env_mod
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.setenv("HOME", "/nonexistent-home")
+        monkeypatch.setenv("XDG_CACHE_HOME", "/nonexistent-xdg")
+        want = os.path.join(repo, ".jax_cache")
+        assert env_mod.default_compilation_cache_dir() == want
+        assert env_mod.default_compilation_cache_dir() == want  # stable
+        ignored = open(os.path.join(repo, ".gitignore")).read().split()
+        assert ".jax_cache/" in ignored
+
+    def test_two_processes_resolve_the_same_directory(self):
+        """Two fresh processes (different pids, different start times)
+        with no cache variable set configure the SAME directory."""
+        import subprocess
+        import sys
+
+        from accelerate_tpu.test_utils import checkout_child_env
+
+        code = ("from accelerate_tpu.utils.environment import "
+                "configure_compilation_cache as c; print(c())")
+        env = checkout_child_env({"JAX_PLATFORMS": "cpu"})
+        for var in ("JAX_COMPILATION_CACHE_DIR",
+                    "ACCELERATE_TPU_COMPILATION_CACHE"):
+            env.pop(var, None)
+        outs = [subprocess.run([sys.executable, "-c", code], env=env,
+                               capture_output=True, text=True, timeout=120)
+                for _ in range(2)]
+        paths = [o.stdout.strip().splitlines()[-1] for o in outs]
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert paths == [os.path.join(repo, ".jax_cache")] * 2, (
+            paths, outs[0].stderr[-500:])
+
+
 # ---------------------------------------------------------------------------
 # tier-1 collection guard
 # ---------------------------------------------------------------------------

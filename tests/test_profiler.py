@@ -91,8 +91,34 @@ def test_device_memory_stats_shape():
     assert isinstance(stats, dict)  # CPU backend may legitimately be empty
 
 
-def test_peak_flops_lookup_unknown_is_zero():
-    assert peak_flops_per_chip(jax.devices()[0]) >= 0.0
+def test_peak_flops_lookup_unknown_is_an_error():
+    """No default peak: a device that is not a known TPU generation
+    raises (it used to read 0.0, and bench.py assumed a v5e)."""
+    from accelerate_tpu.utils.constants import tpu_peak_flops
+
+    with pytest.raises(ValueError, match="no entry"):
+        peak_flops_per_chip(jax.devices()[0])  # the CPU test device
+    assert tpu_peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        tpu_peak_flops("TPU v99 imaginary")
+
+
+def test_device_peaks_unknown_tpu_kind_is_an_error():
+    """telemetry.cost.device_peaks: nominal placeholders for the CPU
+    rehearsal only; a TPU of an unknown kind raises."""
+    from accelerate_tpu.telemetry.cost import device_peaks
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = "TPU v99 imaginary"
+
+    class V5e(FakeTpu):
+        device_kind = "TPU v5 lite"
+
+    assert device_peaks(jax.devices()[0])[2] is True
+    assert device_peaks(V5e()) == (197e12, 0.82e12, False)
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        device_peaks(FakeTpu())
 
 
 def test_debug_mode_verifies_collectives(monkeypatch):

@@ -1182,7 +1182,7 @@ def test_telemetry_tests_are_tier1_collected():
 def test_telemetry_imports_without_jax_device_init():
     """`accelerate_tpu.telemetry` must be importable in collectors/CLI
     tools without initializing a jax backend (device init is expensive and
-    can hang on a dead TPU tunnel)."""
+    can hang at backend init)."""
     code = (
         "import accelerate_tpu.telemetry as t\n"
         "t.get_registry().counter('probe').inc()\n"
@@ -1191,7 +1191,9 @@ def test_telemetry_imports_without_jax_device_init():
         "assert not xla_bridge.backends_are_initialized(), "
         "'telemetry import initialized a jax backend'\n"
     )
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    from accelerate_tpu.test_utils import checkout_child_env
+
+    env = checkout_child_env({"JAX_PLATFORMS": "cpu"})
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
