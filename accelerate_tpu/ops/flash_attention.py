@@ -200,6 +200,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*operands)
     if save_residuals:
         return res[0], res[1]
@@ -345,6 +346,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_operands)
 
     # dK/dV pass: k blocks outer (parallel), q blocks inner (reduction)
@@ -377,6 +379,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, block_q: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*dkv_operands)
     return dq, dk, dv
 

@@ -69,6 +69,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.trace import span
+
 
 @dataclasses.dataclass(frozen=True)
 class SlotKVCache:
@@ -891,6 +893,15 @@ class PagedAllocator:
         None = insufficient pages even with eviction (keep queued) — and
         in that case NOTHING was evicted (evict_lru is all-or-nothing),
         so a too-big queue head can't strip the cache while it waits."""
+        with span("serving.kv.allocate") as sp:
+            evictions = self.evictions
+            alloc = self._allocate(request)
+            sp.set(pages=len(alloc.pages) if alloc else 0,
+                   reused_len=alloc.reused_len if alloc else 0,
+                   evicted=self.evictions - evictions)
+        return alloc
+
+    def _allocate(self, request) -> PageAllocation | None:
         if self.hold_admission is not None and self.hold_admission(request):
             return None
         path = (self.index.match(request.prompt)
@@ -1009,10 +1020,11 @@ class PagedAllocator:
         n_cached = len(alloc.nodes)
         if full <= n_cached:
             return n_cached
-        new_nodes = self.index.extend_path(req.prompt, alloc.pages,
-                                           n_cached, full)
-        self.index.acquire(new_nodes)
-        alloc.nodes.extend(new_nodes)
+        with span("serving.kv.release", pages=0, published=full - n_cached):
+            new_nodes = self.index.extend_path(req.prompt, alloc.pages,
+                                               n_cached, full)
+            self.index.acquire(new_nodes)
+            alloc.nodes.extend(new_nodes)
         return len(alloc.nodes)
 
     def release(self, slot, finished: bool) -> None:
@@ -1034,12 +1046,16 @@ class PagedAllocator:
         building the ATP2xx/sanitizer audit and pinned model-free in
         test_paged_cache."""
         alloc, req = slot.alloc, slot.request
-        self.index.release(alloc.nodes)
-        n_cached = len(alloc.nodes)
-        full = min(req.prompt_len, slot.prompt_done) // self.page_size \
-            if (finished and self.prefix_cache) else n_cached
-        spare = (self.index.insert(req.prompt, alloc.pages, full)
-                 if full > n_cached else [])
-        self.pool.release(spare + alloc.pages[full:])
-        if self.on_unmap is not None:
-            self.on_unmap(slot.index)
+        with span("serving.kv.release") as sp:
+            self.index.release(alloc.nodes)
+            n_cached = len(alloc.nodes)
+            full = min(req.prompt_len, slot.prompt_done) // self.page_size \
+                if (finished and self.prefix_cache) else n_cached
+            spare = (self.index.insert(req.prompt, alloc.pages, full)
+                     if full > n_cached else [])
+            freed = spare + alloc.pages[full:]
+            self.pool.release(freed)
+            if self.on_unmap is not None:
+                self.on_unmap(slot.index)
+            sp.set(pages=len(freed),
+                   published=max(full - n_cached, 0) - len(spare))

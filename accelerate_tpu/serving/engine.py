@@ -115,6 +115,15 @@ from .scheduler import Request, Scheduler, Slot, SlotState
 __all__ = ["Engine", "EngineConfig"]
 
 
+def _phase(name: str, **attrs):
+    """A leaf phase of the engine's host pass (docs/observability.md,
+    "Engine phases"). `trace=0`: a phase belongs to the engine's pass and
+    to no request, so it never joins the trace of a request-scoped span
+    open above it (`serving.submit` is joined to its request's) and stays
+    out of the per-trace index. The shared null span when tracing is off."""
+    return span(name, trace=0, **attrs)
+
+
 def prepare_request_tracing(req: Request, trace_id, trace_parent,
                             trace_sampled) -> None:
     """Install the request's trace identity at submit time — shared by
@@ -962,22 +971,28 @@ class Engine:
             tenant=tenant, slo_ttft_s=slo_ttft_s, parent_id=parent_id,
         )
         prepare_request_tracing(req, trace_id, trace_parent, trace_sampled)
-        # drain first, THEN capacity-check: a slot freed since the last
-        # step (or an expired entry still holding a queue position) must
-        # make room before this request is judged against max_queue — the
-        # queue bound covers genuinely *waiting* requests only
-        self._admit_pending()
-        self.scheduler.submit(req)
-        # pressure/displacement victims shed INSIDE submit have no other
-        # path into the metrics — drain them before reporting the newcomer
-        for victim in self.scheduler.drain_shed():
-            self._finalize_request(victim)
-        if req.done:
-            self._finalize_request(req)
-        else:
-            # eager admission: a free slot absorbs the request now, so
-            # TTFT doesn't wait for the next step() call
+        with self._request_span("serving.submit", req,
+                                prompt_len=req.prompt_len) as sp:
+            # drain first, THEN capacity-check: a slot freed since the last
+            # step (or an expired entry still holding a queue position) must
+            # make room before this request is judged against max_queue —
+            # the queue bound covers genuinely *waiting* requests only
             self._admit_pending()
+            self.scheduler.submit(req)
+            # pressure/displacement victims shed INSIDE submit have no other
+            # path into the metrics — drain them before reporting the
+            # newcomer
+            victims = self.scheduler.drain_shed()
+            for victim in victims:
+                self._finalize_request(victim)
+            if req.done:
+                self._finalize_request(req)
+            else:
+                # eager admission: a free slot absorbs the request now, so
+                # TTFT doesn't wait for the next step() call
+                self._admit_pending()
+            sp.set(admitted=req.admitted_at is not None,
+                   shed=len(victims) + int(req.done))
         return req
 
     def fork(
@@ -1090,7 +1105,13 @@ class Engine:
         if self.watchdog is not None:
             self.watchdog.tick()
         self._admit_pending()
-        action = self.scheduler.next_action()
+        # an idle engine records nothing: callers poll step() in a tight
+        # loop, and with no live slot there is nothing to schedule
+        action = None
+        if self.scheduler.live_slots:
+            with _phase("serving.schedule") as sp:
+                action = self.scheduler.next_action()
+                sp.set(action=action[0])
         if action is None:
             self.metrics.stopped_at = self._clock()
             if self._sanitize:
@@ -1101,19 +1122,20 @@ class Engine:
             self._run_prefill_chunk(action[1])
         else:
             self._run_decode(action[1])
-        self.metrics.stopped_at = self._clock()
-        # the EMA behind the scheduler's SLO / Retry-After estimates —
-        # host-side bookkeeping only, nothing traced
-        self.scheduler.note_step_time(self.metrics.stopped_at - t0)
-        self.metrics.observe_step(self.scheduler.live_slots,
-                                  self.engine_config.num_slots,
-                                  self.scheduler.queue_depth)
-        # keep the goodput gauge live for mid-run scrapes (a handful of
-        # host float ops — the device never sees it)
-        self._goodput()
-        self._maybe_log()
-        if self._sanitize:
-            self._sanity_check()
+        with _phase("serving.bookkeeping"):
+            self.metrics.stopped_at = self._clock()
+            # the EMA behind the scheduler's SLO / Retry-After estimates —
+            # host-side bookkeeping only, nothing traced
+            self.scheduler.note_step_time(self.metrics.stopped_at - t0)
+            self.metrics.observe_step(self.scheduler.live_slots,
+                                      self.engine_config.num_slots,
+                                      self.scheduler.queue_depth)
+            # keep the goodput gauge live for mid-run scrapes (a handful of
+            # host float ops — the device never sees it)
+            self._goodput()
+            self._maybe_log()
+            if self._sanitize:
+                self._sanity_check()
         return True
 
     def _sanity_check(self) -> None:
@@ -1157,12 +1179,20 @@ class Engine:
         queue into free slots. Observation goes through the scheduler's
         shed log — the one path that also covers victims shed inside
         submit() (queue-pressure and tier-displacement sheds)."""
-        now = self._clock()
-        self.scheduler.shed_expired(now)
-        for req in self.scheduler.drain_shed():
-            self._finalize_request(req)
-        for slot, req in self.scheduler.admissions(now):
-            self._run_admit(slot, req)
+        sched = self.scheduler
+        depth = sched.queue_depth
+        if not depth and not sched.shed_log:
+            return  # nothing waits: no work, and an idle engine records nothing
+        with _phase("serving.admit_pending", queue_depth=depth) as sp:
+            now = self._clock()
+            sched.shed_expired(now)
+            shed = sched.drain_shed()
+            for req in shed:
+                self._finalize_request(req)
+            admitted = sched.admissions(now)
+            for slot, req in admitted:
+                self._run_admit(slot, req)
+            sp.set(admitted=len(admitted), shed=len(shed))
 
     def _strict_audit(self, name: str, jitted, args: tuple) -> None:
         """Strict-mode program passes, once per program, at first use.
@@ -1504,14 +1534,15 @@ class Engine:
         advance over identical windows."""
         chunk = self.engine_config.prefill_chunk
         req = slot.request
-        start = slot.draft_done
-        real = min(chunk, upto - start)
-        ids = np.zeros((chunk,), np.int32)
-        ids[:real] = req.prompt[start:start + real]
-        args = (self._draft_params, self._draft_cache,
-                jnp.int32(slot.index), ids, jnp.int32(real))
-        self._strict_audit("draft_prefill", self._draft_prefill_p, args)
-        self._ensure_cost("draft_prefill", self._draft_prefill_p, args)
+        with _phase("serving.stage_inputs", h2d_bytes=4 * chunk + 8):
+            start = slot.draft_done
+            real = min(chunk, upto - start)
+            ids = np.zeros((chunk,), np.int32)
+            ids[:real] = req.prompt[start:start + real]
+            args = (self._draft_params, self._draft_cache,
+                    jnp.int32(slot.index), ids, jnp.int32(real))
+            self._strict_audit("draft_prefill", self._draft_prefill_p, args)
+            self._ensure_cost("draft_prefill", self._draft_prefill_p, args)
         with self.cost.maybe_sample(
                 "draft_prefill", fence_in=self._draft_cache) as sample:
             with self._request_span("serving.draft_prefill", req,
@@ -1535,15 +1566,18 @@ class Engine:
             # it) — a draft-sized catch-up chunk is neither
             self._run_draft_chunk(slot, slot.prompt_done)
             return
-        start = slot.prompt_done  # includes the reused prefix on a hit
-        real = min(chunk, req.prompt_len - start)
-        ids = np.zeros((chunk,), np.int32)
-        ids[:real] = req.prompt[start:start + real]
-        args = (self.params, self.cache, self._tokens, self._slot_keys,
-                self._temps, jnp.int32(slot.index),
-                self._table[slot.index], ids, jnp.int32(real))
-        self._strict_audit("prefill", self._prefill_p, args)
-        self._ensure_cost("prefill", self._prefill_p, args)
+        row = self._table[slot.index]
+        with _phase("serving.stage_inputs",
+                    h2d_bytes=row.nbytes + 4 * chunk + 8):
+            start = slot.prompt_done  # includes the reused prefix on a hit
+            real = min(chunk, req.prompt_len - start)
+            ids = np.zeros((chunk,), np.int32)
+            ids[:real] = req.prompt[start:start + real]
+            args = (self.params, self.cache, self._tokens, self._slot_keys,
+                    self._temps, jnp.int32(slot.index), row, ids,
+                    jnp.int32(real))
+            self._strict_audit("prefill", self._prefill_p, args)
+            self._ensure_cost("prefill", self._prefill_p, args)
         with self.cost.maybe_sample(
                 "prefill", fence_in=(self.cache, self._tokens)) as sample:
             with self._request_span("serving.prefill", req, slot=slot.index,
@@ -1551,58 +1585,68 @@ class Engine:
                     self.timer.dispatch():
                 self.cache, self._tokens, lp = self._prefill_p(*args)
             sample(self.cache)
-        self.metrics.note_prefill_chunk()
         if self._spec:
             # joint chunk: the draft processes the same window, so both
             # prompts complete on the same engine step
             self._run_draft_chunk(slot, start + real)
-        done = self.scheduler.note_prefill_chunk(slot, real)
-        if req.share_prompt:
-            # fork parent: every full prompt page this chunk completed
-            # becomes shareable NOW — forks queued behind us map it at
-            # admission instead of re-prefilling
-            self.allocator.publish_prompt(slot)
+        with _phase("serving.commit", tokens=0, finished=0):
+            self.metrics.note_prefill_chunk()
+            done = self.scheduler.note_prefill_chunk(slot, real)
+            if req.share_prompt:
+                # fork parent: every full prompt page this chunk completed
+                # becomes shareable NOW — forks queued behind us map it at
+                # admission instead of re-prefilling
+                self.allocator.publish_prompt(slot)
         if done:
             # the chunk that completed the prompt also produced the
             # request's first token — fetch it (TTFT is measured here).
             # Index on device first: only ONE element crosses to the host,
             # not the whole [S] token vector (self-lint ATP003 class).
-            tok = int(self._tokens[slot.index])
-            if self.scheduler.note_token(slot, tok, logprob=float(lp)):
-                self._finalize_request(req)
+            with _phase("serving.host_read", program="prefill"):
+                tok = int(self._tokens[slot.index])
+                lp = float(lp)
+            with _phase("serving.commit", tokens=1) as sp:
+                finished = self.scheduler.note_token(slot, tok, logprob=lp)
+                if finished:
+                    self._finalize_request(req)
+                sp.set(finished=int(finished))
 
     def _run_decode(self, slots: list[Slot]) -> None:
         if self._spec:
             self._run_spec_decode(slots)
             return
-        live = np.zeros((self.engine_config.num_slots,), bool)
-        for s in slots:
-            live[s.index] = True
-        args = (self.params, self.cache, self._tokens, self._slot_keys,
-                self._temps, live, self._table)
-        self._strict_audit("decode", self._decode_p, args)
-        # one decode step serves EVERY live slot, so the step span belongs
-        # to no single request: span LINKS carry each sampled request's
-        # trace id instead (bounded by num_slots)
-        links = [s.request.trace_id for s in slots
-                 if s.request is not None and s.request.trace_sampled]
-        self._ensure_cost("decode", self._decode_p, args)
+        num_slots = self.engine_config.num_slots
+        with _phase("serving.stage_inputs",
+                    h2d_bytes=self._table.nbytes + num_slots):
+            live = np.zeros((num_slots,), bool)
+            for s in slots:
+                live[s.index] = True
+            args = (self.params, self.cache, self._tokens, self._slot_keys,
+                    self._temps, live, self._table)
+            self._strict_audit("decode", self._decode_p, args)
+            links = self._step_links(slots)
+            self._ensure_cost("decode", self._decode_p, args)
         with self.cost.maybe_sample(
                 "decode", fence_in=(self.cache, self._tokens)) as sample:
-            with span("serving.decode", links=links or None), \
+            with span("serving.decode", links=links), \
                     self.timer.dispatch():
                 self.cache, self._tokens, lps = self._decode_p(*args)
             sample(self.cache)
-        toks = np.asarray(self._tokens)  # the per-step host read
-        lps = np.asarray(lps)
-        self.timer.tick(block_on=None)
-        self.metrics.note_decode_step(
-            "kernel" if self._use_paged_kernel else "dense")
-        for s in slots:
-            req = s.request
-            if self.scheduler.note_token(s, int(toks[s.index]),
-                                         logprob=float(lps[s.index])):
-                self._finalize_request(req)
+        with _phase("serving.host_read", program="decode"):
+            toks = np.asarray(self._tokens)  # the per-step host read
+            lps = np.asarray(lps)
+        with _phase("serving.commit", tokens=len(slots)) as sp:
+            self.timer.tick(block_on=None)
+            self.metrics.note_decode_step(
+                "kernel" if self._use_paged_kernel else "dense")
+            finished = 0
+            for s in slots:
+                req = s.request
+                if self.scheduler.note_token(s, int(toks[s.index]),
+                                             logprob=float(lps[s.index])):
+                    self._finalize_request(req)
+                    finished += 1
+            sp.set(finished=finished)
 
     def _run_spec_decode(self, slots: list[Slot]) -> None:
         """One speculative step for every decoding slot: draft K
@@ -1613,28 +1657,31 @@ class Engine:
         its valid rows are exactly the target's (inputs t0..d_{c-1}), so
         the two models stay position-synchronized without a catch-up."""
         K = self.engine_config.draft_k
-        live = np.zeros((self.engine_config.num_slots,), bool)
-        for s in slots:
-            live[s.index] = True
-        links = [s.request.trace_id for s in slots
-                 if s.request is not None and s.request.trace_sampled]
-        dargs = (self._draft_params, self._draft_cache, self._tokens,
-                 self._slot_keys, self._temps)
-        self._strict_audit("draft", self._draft_p, dargs)
-        self._ensure_cost("draft", self._draft_p, dargs)
+        num_slots = self.engine_config.num_slots
+        with _phase("serving.stage_inputs", h2d_bytes=0):
+            live = np.zeros((num_slots,), bool)
+            for s in slots:
+                live[s.index] = True
+            links = self._step_links(slots)
+            dargs = (self._draft_params, self._draft_cache, self._tokens,
+                     self._slot_keys, self._temps)
+            self._strict_audit("draft", self._draft_p, dargs)
+            self._ensure_cost("draft", self._draft_p, dargs)
         with self.cost.maybe_sample(
                 "draft", fence_in=self._draft_cache) as sample:
-            with span("serving.draft", links=links or None), \
+            with span("serving.draft", links=links), \
                     self.timer.dispatch():
                 d_toks, d_logits, new_dcache = self._draft_p(*dargs)
             sample(new_dcache)
-        vargs = (self.params, self.cache, self._tokens, self._slot_keys,
-                 self._temps, live, self._table, d_toks, d_logits)
-        self._strict_audit("verify", self._verify_p, vargs)
-        self._ensure_cost("verify", self._verify_p, vargs)
+        with _phase("serving.stage_inputs",
+                    h2d_bytes=self._table.nbytes + num_slots):
+            vargs = (self.params, self.cache, self._tokens, self._slot_keys,
+                     self._temps, live, self._table, d_toks, d_logits)
+            self._strict_audit("verify", self._verify_p, vargs)
+            self._ensure_cost("verify", self._verify_p, vargs)
         with self.cost.maybe_sample(
                 "verify", fence_in=(self.cache, self._tokens)) as sample:
-            with span("serving.verify", links=links or None), \
+            with span("serving.verify", links=links), \
                     self.timer.dispatch():
                 (self.cache, self._tokens, committed, counts, n_acc,
                  lps) = self._verify_p(*vargs)
@@ -1654,35 +1701,53 @@ class Engine:
         # masked or overwritten. jnp.where yields a FRESH buffer, so the
         # pool's lengths never alias into the draft cache (the next
         # donating dispatch must not see one buffer through two args).
-        restore = np.zeros((self.engine_config.num_slots,), np.int32)
-        for s in self.scheduler.slots:
-            if s.request is not None and not live[s.index]:
-                restore[s.index] = s.draft_done
-        self._draft_cache = dataclasses.replace(
-            new_dcache, lengths=jnp.where(jnp.asarray(live),
-                                          self.cache.lengths,
-                                          jnp.asarray(restore)))
-        toks = np.asarray(committed)   # [S, K] — the per-step host read
-        cnts = np.asarray(counts)
-        accs = np.asarray(n_acc)
-        lps = np.asarray(lps)
-        self.timer.tick(block_on=None)
-        self.metrics.note_decode_step("speculative")
-        for s in slots:
-            self.metrics.note_speculation(K, int(accs[s.index]))
-            req = s.request
-            for j in range(int(cnts[s.index])):
-                if self.scheduler.note_token(
-                        s, int(toks[s.index, j]),
-                        logprob=float(lps[s.index, j])):
-                    # retired mid-window (budget or EOS): the remaining
-                    # committed tokens are discarded — their rows sit
-                    # past the slot's final length in reserved private
-                    # pages and are never attended
-                    self._finalize_request(req)
-                    break
+        with _phase("serving.commit", tokens=0, finished=0):
+            restore = np.zeros((num_slots,), np.int32)
+            for s in self.scheduler.slots:
+                if s.request is not None and not live[s.index]:
+                    restore[s.index] = s.draft_done
+            self._draft_cache = dataclasses.replace(
+                new_dcache, lengths=jnp.where(jnp.asarray(live),
+                                              self.cache.lengths,
+                                              jnp.asarray(restore)))
+        with _phase("serving.host_read", program="verify"):
+            toks = np.asarray(committed)   # [S, K] — the per-step host read
+            cnts = np.asarray(counts)
+            accs = np.asarray(n_acc)
+            lps = np.asarray(lps)
+        with _phase("serving.commit") as sp:
+            self.timer.tick(block_on=None)
+            self.metrics.note_decode_step("speculative")
+            tokens = finished = 0
+            for s in slots:
+                self.metrics.note_speculation(K, int(accs[s.index]))
+                req = s.request
+                for j in range(int(cnts[s.index])):
+                    tokens += 1
+                    if self.scheduler.note_token(
+                            s, int(toks[s.index, j]),
+                            logprob=float(lps[s.index, j])):
+                        # retired mid-window (budget or EOS): the remaining
+                        # committed tokens are discarded — their rows sit
+                        # past the slot's final length in reserved private
+                        # pages and are never attended
+                        self._finalize_request(req)
+                        finished += 1
+                        break
+            sp.set(tokens=tokens, finished=finished)
 
     # -- request tracing -----------------------------------------------------
+
+    @staticmethod
+    def _step_links(slots: list[Slot]) -> list | None:
+        """One decode step serves EVERY live slot, so its dispatch span
+        belongs to no single request: span LINKS carry each sampled
+        request's trace id instead (bounded by num_slots). Built only
+        while tracing is on: the step pays nothing for it otherwise."""
+        if not tracing_enabled():
+            return None
+        return [s.request.trace_id for s in slots
+                if s.request is not None and s.request.trace_sampled] or None
 
     @staticmethod
     def _request_span(name: str, req: Request, **attrs):

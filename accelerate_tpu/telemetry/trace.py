@@ -3,11 +3,17 @@
 `span("name", **attrs)` wraps a region of host code; when tracing is
 enabled each span records (trace id, span id, parent id, thread, start,
 duration, attrs) into a bounded ring buffer — the *flight recorder* — and
-optionally enters `jax.profiler.TraceAnnotation` so the same names appear
-on XLA device traces captured by `profiler.profile()`. The recorder tail
-is what the stall watchdog dumps when a job goes silent, and
-`export_chrome_trace()` writes the whole ring as Perfetto-compatible
-`chrome://tracing` JSON.
+optionally enters `jax.profiler.TraceAnnotation`, so a LIVE span is also
+an event on the host plane of a `profiler.profile()` capture, on the same
+clock as the device's operations: that capture is where host spans and
+device slices line up. The recorder tail is what the stall watchdog dumps
+when a job goes silent, and `export_chrome_trace()` writes the whole ring
+as Perfetto-compatible `chrome://tracing` JSON — the place for
+retrospective spans (`record_span`), which no profiler capture holds. The
+ring is one timeline (live spans read `perf_counter_ns`, retrospective
+ones are given in `time.monotonic`/`perf_counter` seconds: one timebase,
+pinned in tests/test_telemetry.py), but its origin is the host clock's,
+not a capture's: the two files do not share a zero.
 
 Request-scoped tracing (ISSUE 8) builds on three additions:
 
@@ -79,8 +85,19 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
+
+# Events the flight recorder keeps by default. The serving engine records
+# six or seven phase spans an engine step and as many again per admission
+# (docs/observability.md): 140-200 events a second at 20-30 steps a second
+# (read on the chip, PERF.md), so 16,384 events hold over a minute of a
+# loaded engine — what the `/debug` request view and an incident bundle's
+# tail need. 4,096 (the default before the phase spans) held 20-30 s.
+DEFAULT_RING_SIZE = 16384
 
 
 class _State:
@@ -91,7 +108,7 @@ class _State:
     def __init__(self):
         self.enabled = False
         self.annotate = True
-        self.ring_size = 4096
+        self.ring_size = DEFAULT_RING_SIZE
         self.ring: deque = deque()
         # monotone count of every event ever appended — the cursor space
         # for `drain_spans` (a pod worker's heartbeat exporter)
@@ -262,6 +279,11 @@ class _Span:
         self.parent_id = parent
         self.links = links
 
+    def set(self, **attrs) -> None:
+        """Attach attributes known only once the work is done (how many
+        were admitted, what a lookup found). Call before the span exits."""
+        self.attrs.update(attrs)
+
     def __enter__(self):
         stack = _stack()
         if self.trace_id is None:
@@ -316,8 +338,13 @@ def span(name: str, trace=None, parent=None, links=None, **attrs):
     disabled; otherwise records to the flight recorder and mirrors the
     name onto the XLA trace timeline. `trace`/`parent` join the span to
     an explicit trace (request tracing) instead of the thread-local
-    stack; `links` attaches other trace ids (a span serving many
-    requests at once — e.g. one batched decode step — links them all)."""
+    stack; `trace=0` keeps a span out of every trace, whatever is open
+    above it, and out of the per-trace index (the serving engine's phase
+    spans: they belong to the engine's pass, not to the request whose
+    `submit` happens to run them). `links` attaches other trace ids (a
+    span serving many requests at once — e.g. one batched decode step —
+    links them all). The yielded span's `set(**attrs)` adds attributes
+    known only at the end; it is a no-op on the disabled path."""
     if not _STATE.enabled:
         return _NULL_SPAN
     return _Span(name, attrs, trace=trace, parent=parent, links=links)
@@ -459,10 +486,15 @@ def ingest_spans(events: list[dict], offset_s: float = 0.0,
 
 def export_chrome_trace(path: str | None = None, trace_id=None) -> dict:
     """Render the flight recorder as `chrome://tracing` / Perfetto JSON
-    (complete 'X' events; microsecond timestamps). Returns the document;
-    writes it to `path` when given — load alongside a
-    `profiler.profile()` capture to line host spans up with XLA device
-    slices. `trace_id` filters to one request's spans. Events ingested
+    (complete 'X' events; microsecond timestamps on the host's monotonic
+    clock). Returns the document; writes it to `path` when given. This is
+    the export for what only the ring holds: retrospective spans
+    (`serving.queue_wait`, `serving.request`) and spans ingested from
+    other processes. It does NOT line up with a `profiler.profile()`
+    capture (that file has its own time origin); to see host spans
+    against XLA device slices read the capture itself, which holds every
+    live span on the device trace's clock. `trace_id` filters to one
+    request's spans. Events ingested
     from pod workers (`ingest_spans`) keep their origin pid, so a
     cross-process request renders as one timeline with one row-group
     per process."""

@@ -123,6 +123,12 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, chip_compile, seq):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     _assert_kernel_inside(compiled, at_least=3)  # fwd, dQ, dK/dV
+    # each kernel carries its name into the compiled program, which is how
+    # a device trace shows it (`%flash_attention_fwd.N custom-call[...]`)
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert name in text, name
 
 
 def test_flash_under_four_device_mesh_compiles_for_v5e(topo, chip_compile):

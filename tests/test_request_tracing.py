@@ -259,6 +259,151 @@ class TestEngineRequestTrace:
                    for _, label, _ in exemplars.values())
 
 
+# ---------------------------------------------------------------------------
+# the engine's host pass in phase spans (ISSUE 24): structure, not durations
+# ---------------------------------------------------------------------------
+
+DECODE_STEP = ["serving.schedule", "serving.stage_inputs", "serving.decode",
+               "serving.host_read", "serving.commit", "serving.bookkeeping"]
+
+
+def _by_start(events=None):
+    events = flight_recorder() if events is None else events
+    return sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"]))
+
+
+def _inside(inner, outer):
+    return (outer["start_ns"] <= inner["start_ns"]
+            and inner["start_ns"] + inner["dur_ns"]
+            <= outer["start_ns"] + outer["dur_ns"])
+
+
+class TestEnginePhaseSpans:
+    def test_every_decode_step_is_tiled_by_its_phases_in_order(
+            self, gpt2_setup):
+        configure_tracing(enabled=True, annotate=False)
+        eng = _make_engine(gpt2_setup)
+        r = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=5)
+        list(eng.stream(r))
+        # the engine's own pass: live spans outside every request's trace,
+        # top level only (the allocator's spans nest inside a phase)
+        phases = [e for e in _by_start()
+                  if e["name"] in DECODE_STEP + ["serving.prefill"]]
+        starts = [i for i, e in enumerate(phases)
+                  if e["name"] == "serving.schedule"
+                  and e["attrs"]["action"] == "decode"]
+        assert len(starts) == 4  # the first token comes from the prefill
+        for i in starts:
+            step = phases[i:i + len(DECODE_STEP)]
+            assert [e["name"] for e in step] == DECODE_STEP
+            assert len({e["thread"] for e in step}) == 1
+            for a, b in zip(step, step[1:]):
+                assert a["start_ns"] + a["dur_ns"] <= b["start_ns"]
+        assert [e["name"] for e in phases].count("serving.decode") == 4
+        host_reads = [e for e in phases if e["name"] == "serving.host_read"]
+        assert [e["attrs"]["program"] for e in host_reads] == (
+            ["prefill"] + ["decode"] * 4)
+        commits = [e for e in phases if e["name"] == "serving.commit"]
+        assert sum(e["attrs"]["tokens"] for e in commits) == 5
+        assert sum(e["attrs"]["finished"] for e in commits) == 1
+        # a phase belongs to no trace: nothing of it in the per-trace index
+        assert all(e["trace_id"] == 0 for e in phases
+                   if e["name"] not in ("serving.decode", "serving.prefill"))
+
+    def test_admit_pending_contains_the_allocation_and_the_admit_dispatch(
+            self, gpt2_setup):
+        configure_tracing(enabled=True, annotate=False)
+        eng = _make_engine(gpt2_setup, num_slots=1)
+        first = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+        second = eng.submit(np.arange(2, 9, dtype=np.int32), max_new_tokens=2)
+        eng.run_until_idle()
+        events = _by_start()
+        pendings = [e for e in events
+                    if e["name"] == "serving.admit_pending"
+                    and e["attrs"]["admitted"] == 1]
+        assert len(pendings) == 2
+        submit_of_first = next(e for e in events
+                               if e["name"] == "serving.submit"
+                               and e["trace_id"] == first.trace_id)
+        # the first is admitted inside its own submit, the second by a step
+        assert _inside(pendings[0], submit_of_first)
+        assert submit_of_first["attrs"] == {"prompt_len": 5, "admitted": True,
+                                            "shed": 0}
+        submit_of_second = next(e for e in events
+                                if e["name"] == "serving.submit"
+                                and e["trace_id"] == second.trace_id)
+        assert submit_of_second["attrs"]["admitted"] is False
+        assert not _inside(pendings[1], submit_of_second)
+        for pending, req in zip(pendings, (first, second)):
+            inside = [e for e in events
+                      if e is not pending and _inside(e, pending)]
+            allocate = [e for e in inside
+                        if e["name"] == "serving.kv.allocate"]
+            admit = [e for e in inside if e["name"] == "serving.admit"]
+            assert len(allocate) == 1 and len(admit) == 1
+            assert admit[0]["trace_id"] == req.trace_id
+            assert allocate[0]["parent_id"] == pending["span_id"]
+            assert allocate[0]["attrs"]["pages"] > 0
+            assert allocate[0]["attrs"]["evicted"] == 0
+            assert pending["trace_id"] == 0 and allocate[0]["trace_id"] == 0
+        release = [e for e in events if e["name"] == "serving.kv.release"]
+        commits = [e for e in events if e["name"] == "serving.commit"]
+        assert len(release) == 2
+        assert all(any(_inside(r, c) for c in commits) for r in release)
+
+    def test_submit_joins_the_requests_trace_only_when_sampled(
+            self, gpt2_setup):
+        configure_tracing(enabled=True, annotate=False,
+                          sample_rates={"quiet": 0.0})
+        eng = _make_engine(gpt2_setup)
+        loud = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+        quiet = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2,
+                           tenant="quiet", trace_id="ab" * 16)
+        eng.run_until_idle()
+        mine = [e for e in trace_events(loud.trace_id)
+                if e["name"] == "serving.submit"]
+        assert len(mine) == 1 and mine[0]["parent_id"] == loud.span_id
+        assert trace_events("ab" * 16) == []
+        submits = [e for e in flight_recorder()
+                   if e["name"] == "serving.submit"]
+        assert len(submits) == 2
+        other = next(e for e in submits if e is not mine[0])
+        assert other["trace_id"] not in ("ab" * 16, loud.trace_id)
+        assert not quiet.trace_sampled and other["parent_id"] == 0
+
+    def test_speculative_reads_lie_under_host_read(self, gpt2_setup):
+        configure_tracing(enabled=True, annotate=False)
+        family, cfg, params = gpt2_setup
+        eng = _make_engine(gpt2_setup, speculative=(family, cfg, params),
+                           draft_k=3)
+        r = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=6)
+        list(eng.stream(r))
+        events = _by_start()
+        names = [e["name"] for e in events]
+        verifies = [e for e in events if e["name"] == "serving.verify"]
+        assert verifies and names.count("serving.draft") == len(verifies)
+        reads = [e for e in events if e["name"] == "serving.host_read"
+                 and e["attrs"]["program"] == "verify"]
+        assert len(reads) == len(verifies)
+        for verify, read in zip(verifies, reads):
+            assert verify["start_ns"] + verify["dur_ns"] <= read["start_ns"]
+        commits = [e for e in events if e["name"] == "serving.commit"]
+        assert sum(e["attrs"]["tokens"] for e in commits) == len(r.tokens)
+
+    def test_an_idle_engine_records_nothing(self, gpt2_setup):
+        """The benchmark (and any server loop) polls `step()` every half
+        millisecond: an idle engine must not flood the recorder."""
+        configure_tracing(enabled=True, annotate=False)
+        eng = _make_engine(gpt2_setup)
+        r = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+        list(eng.stream(r))
+        before = len(flight_recorder())
+        assert before > 0
+        for _ in range(1000):
+            assert eng.step() is False
+        assert len(flight_recorder()) == before
+
+
 class TestEngineIntrospection:
     def test_debug_views_reflect_live_state(self, gpt2_setup):
         eng = _make_engine(gpt2_setup, num_slots=1)

@@ -38,6 +38,7 @@ from accelerate_tpu.telemetry import (
     tracing_enabled,
 )
 from accelerate_tpu.telemetry.aggregate import merged_registry
+from accelerate_tpu.telemetry.trace import DEFAULT_RING_SIZE
 from accelerate_tpu.telemetry.watchdog import StallError
 
 
@@ -244,7 +245,7 @@ class TestTracing:
         events = flight_recorder()
         assert len(events) == 8
         assert events[-1]["name"] == "s49"
-        configure_tracing(enabled=False, ring_size=4096)
+        configure_tracing(enabled=False, ring_size=DEFAULT_RING_SIZE)
 
     def test_span_records_on_exception(self):
         configure_tracing(enabled=True, annotate=False)
@@ -734,6 +735,27 @@ class TestTraceContext:
         doc = export_chrome_trace(trace_id=tid)
         assert len(doc["traceEvents"]) == 3
 
+    def test_record_span_and_span_share_a_timebase(self):
+        """The ring is one timeline: a retrospective span given in
+        `time.monotonic` (or `perf_counter`) seconds and a live span, which
+        reads `perf_counter_ns`, nest as the calls did. On this platform
+        the two clocks are one (CLOCK_MONOTONIC); were they not,
+        `record_span` would have to convert."""
+        configure_tracing(enabled=True, annotate=False)
+        assert abs(time.monotonic_ns() - time.perf_counter_ns()) < 1_000_000
+        for clock in (time.monotonic, time.perf_counter):
+            clear_flight_recorder()
+            t0 = clock()
+            with span("live"):
+                time.sleep(0.002)
+            t1 = clock()
+            record_span("retro", t0, t1)
+            live, retro = flight_recorder()
+            assert retro["start_ns"] <= live["start_ns"]
+            assert (live["start_ns"] + live["dur_ns"]
+                    <= retro["start_ns"] + retro["dur_ns"] + 1000)
+            assert retro["dur_ns"] < live["dur_ns"] + 50_000_000
+
     def test_span_links(self):
         """A span serving many requests at once (one batched decode step)
         links their traces without belonging to any one of them."""
@@ -756,7 +778,7 @@ class TestTraceContext:
             assert trace_events("t0") == []          # evicted AND pruned
             assert len(trace_events("t9")) == 1
         finally:
-            configure_tracing(enabled=False, ring_size=4096)
+            configure_tracing(enabled=False, ring_size=DEFAULT_RING_SIZE)
 
     def test_record_span_disabled_is_free(self):
         from accelerate_tpu.telemetry import record_span, trace_events
@@ -1052,11 +1074,14 @@ class TestOverheadGuards:
         fn()
         return time.perf_counter() - t0
 
-    def test_disabled_span_cost_bounded(self):
+    @pytest.mark.parametrize("site", ["bare", "attrs_and_set"])
+    def test_disabled_span_cost_bounded(self, site):
         """Disabled spans sit on dispatch-path code permanently; their cost
         must stay within a generous multiple of a plain function call (and
         an absolute per-iteration ceiling, so tier-1 stays deterministic
-        on slow shared runners)."""
+        on slow shared runners). `attrs_and_set` is the shape of the
+        serving engine's phase sites: scalars at hand going in, counts
+        attached at the end."""
         assert not tracing_enabled()
 
         def noop():
@@ -1066,10 +1091,17 @@ class TestOverheadGuards:
             for _ in range(self.N):
                 noop()
 
-        def with_span():
+        def bare():
             for _ in range(self.N):
                 with span("x"):
                     pass
+
+        def attrs_and_set():
+            for i in range(self.N):
+                with span("x", trace=0, queue_depth=i) as sp:
+                    sp.set(admitted=i, shed=0)
+
+        with_span = {"bare": bare, "attrs_and_set": attrs_and_set}[site]
 
         baseline()  # warm both paths
         with_span()
@@ -1078,6 +1110,56 @@ class TestOverheadGuards:
         per_iter_us = spanned / self.N * 1e6
         assert per_iter_us < 50.0, f"disabled span {per_iter_us:.2f}us/iter"
         assert spanned < max(base, 1e-9) * 100, (spanned, base)
+
+    @pytest.mark.parametrize("what", ["no_span_object", "no_links_list"])
+    def test_disabled_engine_step_builds_nothing_for_tracing(
+            self, what, monkeypatch):
+        """With tracing off a serving step constructs no `_Span` (every
+        phase site gets the shared null span) and builds no `links` list
+        for its decode dispatch."""
+        import jax
+        import jax.numpy as jnp
+
+        from accelerate_tpu.models import gpt2
+        from accelerate_tpu.serving import Engine, EngineConfig
+        from accelerate_tpu.telemetry import trace as trace_mod
+
+        assert not tracing_enabled()
+        cfg = gpt2.GPT2Config.tiny()
+        eng = Engine(gpt2, cfg, gpt2.init_params(cfg, jax.random.key(0)),
+                     EngineConfig(num_slots=2, max_len=64, prefill_chunk=8,
+                                  cache_dtype=jnp.float32))
+        built = []
+        if what == "no_span_object":
+            init = trace_mod._Span.__init__
+
+            def counting_init(self, *args, **kwargs):
+                built.append(args[0])
+                init(self, *args, **kwargs)
+
+            monkeypatch.setattr(trace_mod._Span, "__init__", counting_init)
+        else:
+            links = Engine._step_links
+
+            def recording_links(slots):
+                built.append(links(slots))
+                return built[-1]
+
+            monkeypatch.setattr(Engine, "_step_links",
+                                staticmethod(recording_links))
+        r = eng.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=4)
+        assert len(list(eng.stream(r))) == 4
+        if what == "no_span_object":
+            assert built == []
+        else:
+            assert built == [None] * eng.metrics.decode_steps and built
+        assert flight_recorder() == []
+        # the same step with tracing on does build them: the probes work
+        configure_tracing(enabled=True, annotate=False)
+        built.clear()
+        r = eng.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=2)
+        list(eng.stream(r))
+        assert built and built != [None] * len(built)
 
     def test_registry_increment_cost_bounded(self):
         r = MetricsRegistry()
