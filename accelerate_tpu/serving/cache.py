@@ -37,10 +37,11 @@ program shape, so the engine's compile count stays flat.
 Write-safety under sharing, the invariant the allocator maintains: only
 FULL prompt pages ever enter the tree, and reuse is capped at
 `(prompt_len - 1) // page_size` pages (the last prompt token always
-prefills, producing the first output logits). Writes land at a slot's
-current `length`, which always lies in a private page; the scatter of a
-slot's whole view re-writes shared pages with their unchanged values,
-which is a byte-identical no-op however many sharers race.
+prefills, producing the first output logits). Writes land at or past a
+slot's current `length`, which always lies in a private page, so a
+shared page is never written. The compiled write is page-granular
+(`_scatter_rows`): it re-writes the OTHER rows of that private page with
+their unchanged bytes, a no-op on a page that has this one writer.
 
 Correctness invariant (why retired slots never need zeroing): a write
 always lands at the slot's current `length`, and the position mask
@@ -341,56 +342,89 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
 def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
                      slot: jax.Array, new_k: jax.Array, new_v: jax.Array,
                      advance: jax.Array, chunk: int) -> PagedKVCache:
-    """Scatter the rows a prefill chunk wrote back to the pool and
+    """Write the rows a prefill chunk produced back to the pool and
     advance the slot's length by `advance` REAL tokens. The chunk only
     changes view rows [length, length + chunk), so exactly those `chunk`
-    rows scatter (row -> its page via `table_row`) — per-chunk write
-    traffic is O(chunk), not O(max_len), and a full-view scatter with
-    traced page indices would also defeat XLA's donation aliasing (a
-    pool copy per chunk). `chunk` must be a static python int. Row
-    granularity (rather than the former whole-page window) is what makes
-    the int8 mode safe: every written row is at or past `length`, hence
-    in a PRIVATE page by the allocator's invariant — shared
-    copy-on-write pages are never re-encoded, so their codes/scales stay
-    bit-identical however many sharers race (an int8 round-trip is NOT
-    idempotent, so rewriting a shared page with "the same values" would
-    actually drift them)."""
+    rows (padding included) are handed to `_scatter_rows`, which rewrites
+    the `chunk // page_size + 1` pages they straddle — per-chunk write
+    traffic is O(chunk), not O(max_len). `chunk` must be a static python
+    int. Every written row is at or past `length`, hence in a PRIVATE
+    page by the allocator's invariant — shared copy-on-write pages are
+    never touched, and the rows of a private page below `length` are
+    put back as the bytes they were (selected, not re-encoded: an int8
+    round-trip is NOT idempotent, so re-quantizing "the same values"
+    would drift them)."""
     L, _, H, ps, D = cache.k.shape
     R = cache.rows
     length = cache.lengths[slot]
     # rows never spill past the view: length <= max_len and pad_slack
     # covers the chunk padding (module docstring)
     rows = length + jnp.arange(chunk, dtype=jnp.int32)
-    pages = jnp.take(table_row, rows // ps)
-    offs = rows % ps
-    win_k = jnp.take(new_k.reshape(L, R, H, D), rows, axis=1)
-    win_v = jnp.take(new_v.reshape(L, R, H, D), rows, axis=1)
-    return _scatter_rows(cache, pages, offs, win_k, win_v,
+    win_k = jnp.take(new_k.reshape(L, R, H, D), rows, axis=1)[:, None]
+    win_v = jnp.take(new_v.reshape(L, R, H, D), rows, axis=1)[:, None]
+    return _scatter_rows(cache, table_row[None], length[None],
+                         jnp.full((1,), chunk, jnp.int32), win_k, win_v,
                          cache.lengths.at[slot].set(length + advance))
 
 
-def _scatter_rows(cache: PagedKVCache, pages: jax.Array, offs: jax.Array,
-                  rows_k: jax.Array, rows_v: jax.Array,
+def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
+                  count: jax.Array, win_k: jax.Array, win_v: jax.Array,
                   new_lengths: jax.Array) -> PagedKVCache:
-    """Scatter row payloads [L, *idx, H, D] at (page, offset) pairs
-    (`pages`/`offs` of index shape `idx`), quantizing codes + per-row
-    scales on an int8 pool. The shared tail of every pool write path
-    (prefill chunks, decode appends, both engine attention modes)."""
+    """Write, for every lane n, the first `count[n]` rows of its window
+    (`win_k`/`win_v` [L, N, W, H, D]) at view rows [start[n], start[n] +
+    count[n]) of the lane's page-table row (`table` [N, pages_per_slot]),
+    quantizing codes + per-row scales on an int8 pool. The shared tail of
+    every pool write path (prefill chunks, decode appends in both engine
+    attention modes, the speculative commit).
 
-    def put(pool, rows):
-        # the page and row indices straddle the pool's head axis, so
-        # the indexed result leads with the index dims: [*idx, L, H(, D)]
-        return pool.at[:, pages, :, offs].set(
-            jnp.moveaxis(rows, 0, pages.ndim).astype(pool.dtype))
+    The write is a read-modify-write of WHOLE pages: gather the pages the
+    W rows can straddle, put the new rows over them with a select, and
+    write the pages back with an update that indexes the page axis only.
+    That axis lies outside the chip's (8, 128) tile of a page, so the
+    pool keeps its layout and the donated update happens in place. (A
+    scatter of single ROWS indexes `page_size`, the tile's sublane axis:
+    the TPU compiler then re-lays the whole pool half out, scatters, and
+    copies it back — two whole-pool copies for K and two for V in every
+    decode and prefill call; PERF.md, PR 25.) Rows
+    of a page that are not written keep their bytes bit for bit: they are
+    selected, never re-encoded. A written row is at or past the lane's
+    length, hence in a PRIVATE page with this one writer; page-table
+    entries past the view and the padding of a table are the trash page,
+    which takes every duplicate write."""
+    ps = cache.page_size
+    N, W = win_k.shape[1], win_k.shape[2]
+    n_pages = (W + ps - 2) // ps + 1    # most that W consecutive rows touch
+    lane_page = (start // ps)[:, None] + jnp.arange(n_pages, dtype=jnp.int32)
+    # a last page past the table's end holds no written row: the trash page
+    pages = jnp.take_along_axis(
+        table, lane_page, axis=1, mode="fill",
+        fill_value=cache.trash_page).reshape(N * n_pages)
+    # the window row that belongs at every row of those pages
+    src = (lane_page[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
+           - start[:, None, None])                         # [N, n_pages, ps]
+    write = ((src >= 0) & (src < count[:, None, None])).reshape(
+        N * n_pages, 1, ps)
+    src = jnp.clip(src, 0, W - 1).reshape(N, n_pages * ps)
+
+    def put(pool, win):
+        # win [L, N, W, H, *tail] -> page-major [L, N * n_pages, H, ps, *tail]
+        tail = win.ndim - 4
+        new = jnp.take_along_axis(
+            win, src.reshape((1, N, n_pages * ps, 1) + (1,) * tail), axis=2)
+        new = jnp.swapaxes(
+            new.reshape((win.shape[0], N * n_pages, ps) + win.shape[3:]),
+            2, 3).astype(pool.dtype)
+        mask = write.reshape(write.shape + (1,) * tail)
+        return pool.at[:, pages].set(jnp.where(mask, new, pool[:, pages]))
 
     if not cache.quantized:
         return dataclasses.replace(
-            cache, k=put(cache.k, rows_k), v=put(cache.v, rows_v),
+            cache, k=put(cache.k, win_k), v=put(cache.v, win_v),
             lengths=new_lengths)
     from ..ops.quant import kv_quantize_rows
 
-    ck, sk = kv_quantize_rows(rows_k)
-    cv, sv = kv_quantize_rows(rows_v)
+    ck, sk = kv_quantize_rows(win_k)
+    cv, sv = kv_quantize_rows(win_v)
     return dataclasses.replace(
         cache,
         k=put(cache.k, ck), v=put(cache.v, cv),
@@ -419,23 +453,19 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
                       live: jax.Array) -> PagedKVCache:
     """Write each slot's SINGLE new row ([L, S, H, D] — the K/V of the
     token decode just produced, at view row `length`) to its page and
-    advance live lanes' lengths by one. Scattering one row per slot
-    keeps per-token write traffic O(slots), not O(pool) (a full-view
-    scatter with dynamic page indices also defeats XLA's donation
-    aliasing, so it would copy the pool every step). A live slot's
-    current-length row always lies in a PRIVATE page (allocator
-    invariant), so no two live lanes collide; retired lanes' tables are
-    all-trash (the engine resets them at release), so their dead writes
-    land in the trash page — never in a page that may have been
-    reallocated. This is the write half of BOTH decode attention modes:
-    the dense gather path extracts the row from the returned views
-    (`paged_append_batch`), the Pallas kernel path hands the rows over
-    directly."""
-    ps = cache.page_size
-    row = cache.lengths                                  # [S] view row
-    page = jnp.take_along_axis(table, (row // ps)[:, None], axis=1)[:, 0]
-    off = row % ps
-    return _scatter_rows(cache, page, off, row_k, row_v,
+    advance live lanes' lengths by one. One page per slot is rewritten
+    (`_scatter_rows`), so per-token write traffic is O(slots), not
+    O(pool). A live slot's current-length row always lies in a PRIVATE
+    page (allocator invariant), so no two live lanes collide; retired
+    lanes' tables are all-trash (the engine resets them at release), so
+    their dead writes land in the trash page — never in a page that may
+    have been reallocated. This is the write half of BOTH decode
+    attention modes: the dense gather path extracts the row from the
+    returned views (`paged_append_batch`), the Pallas kernel path hands
+    the rows over directly."""
+    return _scatter_rows(cache, table, cache.lengths,
+                         jnp.ones_like(cache.lengths), row_k[:, :, None],
+                         row_v[:, :, None],
                          cache.lengths + live.astype(jnp.int32))
 
 
@@ -460,21 +490,15 @@ def paged_append_window(cache: PagedKVCache, table: jax.Array,
     lanes' lengths by their count. The speculative-decoding commit: the
     verify program produces W candidate rows per slot but only the
     accepted prefix is real, so rows at or past a slot's count (and every
-    row of a dead lane) are routed to the trash page — the scatter stays
-    fixed-shape whatever the per-slot accept counts. Every written row is
-    at or past `length`, hence in a PRIVATE page (allocator invariant),
-    so shared copy-on-write pages are untouched — the same write-safety
-    argument as `paged_append_rows`, W rows at a time."""
-    ps = cache.page_size
-    W = win_k.shape[2]
-    rows = cache.lengths[:, None] + jnp.arange(W, dtype=jnp.int32)  # [S, W]
-    valid = (jnp.arange(W, dtype=jnp.int32)[None, :] < counts[:, None]) \
-        & live[:, None]
-    pages = jnp.take_along_axis(table, rows // ps, axis=1)
-    pages = jnp.where(valid, pages, cache.trash_page)
-    offs = rows % ps
-    new_lengths = cache.lengths + jnp.where(live, counts, 0)
-    return _scatter_rows(cache, pages, offs, win_k, win_v, new_lengths)
+    row of a dead lane) are not written: the pages the window straddles
+    keep their own bytes there — the update stays fixed-shape
+    whatever the per-slot accept counts. Every written row is at or past
+    `length`, hence in a PRIVATE page (allocator invariant), so shared
+    copy-on-write pages are untouched — the same write-safety argument
+    as `paged_append_rows`, W rows at a time."""
+    counts = jnp.where(live, counts, 0)
+    return _scatter_rows(cache, table, cache.lengths, counts, win_k, win_v,
+                         cache.lengths + counts)
 
 
 def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,

@@ -608,8 +608,10 @@ class Engine:
     def _build_programs(self) -> None:
         forward, config = self._forward, self.config
         chunk = self.engine_config.prefill_chunk
-        # donation keeps the (large) cache update in place instead of
-        # copying it every step; (1, 2) = cache, tokens in both programs
+        # donation lets the (large) cache be updated in place; that it IS,
+        # on the chip, takes the page-granular write of
+        # cache._scatter_rows besides (a row scatter into the donated pool
+        # was copied around); (1, 2) = cache, tokens in both programs
         don = (1, 2) if self.engine_config.donate else ()
         don_admit = (0, 1, 2) if self.engine_config.donate else ()
         # meshed engines pin output shardings to the input layout so the
@@ -756,9 +758,9 @@ class Engine:
           out for the accept rule;
         - `verify`: ONE batched K-token target forward over every slot's
           paged view (exactly PR 10's short-sequence paged forward), the
-          accept rule, and the fixed-shape commit (accepted rows scatter
-          to their pages, rejected rows to trash — per-slot counts are
-          traced data, so accept patterns never change a shape).
+          accept rule, and the fixed-shape commit (accepted rows go to
+          their pages, rejected rows are not written — per-slot counts
+          are traced data, so accept patterns never change a shape).
 
         Sampling keys: token at absolute position p in the NON-speculative
         engine uses fold_in(request_key, p); the speculative step needs
@@ -890,8 +892,8 @@ class Engine:
             new_tok = committed[jnp.arange(S), jnp.maximum(counts, 1) - 1]
             tokens = jnp.where(live, new_tok, tokens)
             # keep exactly the accepted inputs' K/V rows (t0..d_{c-1});
-            # rejected candidates' rows route to trash inside the
-            # fixed-shape window scatter
+            # rejected candidates' rows are left unwritten by the
+            # fixed-shape window write
             rows = cache.lengths[:, None] + jnp.arange(K, dtype=jnp.int32)
             idx = rows[None, :, :, None, None]
             win_k = jnp.take_along_axis(nk, idx, axis=2)

@@ -9,7 +9,9 @@ guard every later PR at no chip time:
 - paged decode attention, bf16 and int8 pools, at real widths;
 - flash attention forward + backward at the bench shape (8 x 2048 x 12 x
   128, causal) and at the loss-sliced length 2047;
-- flash attention under a 4-device mesh (`flash_attention_on_mesh`).
+- flash attention under a 4-device mesh (`flash_attention_on_mesh`);
+- the serving engine's own `decode` and `prefill` programs at the chat
+  cell's shape, bf16 and int8 pools: the KV pool is updated in place.
 
 The topology is described ONLY inside this file's module-scoped fixture:
 one process at a time may load the TPU's library, the xdist workers all
@@ -20,6 +22,7 @@ process; and all of them live in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +110,111 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
             paged_decode_attention(q, kn, vn, pk, pv, meta, window=window)[0]
         ).lower(q, kn, kn, pk, pk, meta).compile()
         _assert_kernel_inside(compiled)
+
+
+def _ops_of_shape(text, dtype, shape):
+    """Count, by kind, the operations of a compiled module whose result is
+    a `dtype[shape]` array (parameters and the free re-namings of one
+    buffer left out)."""
+    pattern = re.compile(r"= " + dtype + re.escape(
+        "[" + ",".join(map(str, shape)) + "]") + r"\S* ([\w-]+)\(")
+    kinds = {}
+    for kind in pattern.findall(text):
+        if kind not in ("parameter", "get-tuple-element", "bitcast"):
+            kinds[kind] = kinds.get(kind, 0) + 1
+    return kinds
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
+        one_chip, chip_compile, kv_dtype):
+    """The engine's own `decode` and `prefill`, Qwen2-1.5B widths and the
+    chat cell's shape (32 slots x 2048, page 16, chunk 256, 4,096 pages),
+    compiled for the chip: besides the aliased update itself no operation
+    produces an array of a pool half's shape, and no temporary grows with
+    the pool.
+
+    This test fails on the row scatter `_scatter_rows` had up to PR 24.
+    A row index falls on the second-minor axis of the chip's (8, 128)
+    tile, so the compiler re-laid a whole pool half out around each
+    scatter: read at the parent, `decode` and `prefill` each held 4
+    `copy bf16[28,4097,2,16,128]` and 942.0 / 948.7 MB of temporaries
+    (941.6 MB in the reduced program of ISSUE 25; int8: 4 copies of the
+    codes, 539.5 / 543.3 MB), 11 ms of EVERY call on the chip. Page
+    indices lie outside the tile: the scatter of whole pages is the
+    in-place update, and the temporaries are what the programs hold
+    beside the pool (1.0 / 157.0 MB; int8 69.0 / 173.3 MB).
+
+    The engine is built with the smallest pool it accepts and abstract
+    weights (nothing of 3 GB is made here); the programs take the pool
+    as an argument, so they are lowered with the chat cell's 4,096
+    pages."""
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.serving import Engine, EngineConfig, PagedKVCache
+
+    slots, max_len, page, chunk, num_pages = 32, 2048, 16, 256, 4096
+    cfg = llama.LlamaConfig(
+        vocab_size=151936, hidden_size=1536, intermediate_size=8960,
+        num_hidden_layers=28, num_attention_heads=12, num_key_value_heads=2,
+        max_position_embeddings=32768, rope_theta=1e6, rms_norm_eps=1e-6,
+        tie_word_embeddings=True, attention_bias=True)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    engine = Engine(llama, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=(max_len + chunk) // page,
+        paged_attention=True, kv_dtype=kv_dtype))
+    small = engine.cache
+    cache = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        cfg.num_hidden_layers, slots, max_len, cfg.num_key_value_heads,
+        cfg.head_dim, page_size=page, pad_slack=small.pad_slack,
+        num_pages=num_pages, kv_dtype=kv_dtype)))
+    assert cache.pages_per_slot == small.pages_per_slot
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_),
+            arg((slots, cache.pages_per_slot), jnp.int32))),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32), arg((cache.pages_per_slot,), jnp.int32),
+            arg((chunk,), jnp.int32), arg((), jnp.int32))),
+    }
+    half = cache.k.shape                       # (28, 4097, 2, 16, 128)
+    half_bytes = cache.k.size * cache.k.dtype.itemsize
+    # what a program may hold beside the pool, from its shapes: a chunk's
+    # float32 logits in prefill (155.6 MB, under the copies at the parent
+    # too) and, on an int8 pool, the two SCALE arrays: [L, pages, H, 16]
+    # lies on the chip with the pages on the lanes, so their update still
+    # re-lays them out with L there (28 of 128 lanes used), 33.6 MB each
+    beside = {"decode": 0, "prefill": chunk * cfg.vocab_size * 4}
+    scales = 2 * (half[1] * half[2] * half[3] * 128 * 2) if kv_dtype else 0
+    for name, (program, args) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        # (a) of a pool half's shape: K's and V's page scatter, each in its
+        # fusion, and nothing else; both halves alias their arguments
+        assert _ops_of_shape(text, "s8" if kv_dtype else "bf16", half) == {
+            "scatter": 2, "fusion": 2}, name
+        assert memory.alias_size_in_bytes >= 2 * half_bytes, name
+        # (b) no temporary of the pool's order
+        assert (memory.temp_size_in_bytes - beside[name] - scales
+                < half_bytes // 10), (name, memory.temp_size_in_bytes)
+        # (c) decode still walks the page table in the kernel (a chunk
+        # attends its slot's gathered view: no kernel in prefill)
+        if name == "decode":
+            _assert_kernel_inside(compiled)
 
 
 @pytest.mark.parametrize("seq", [2048, 2047], ids=["bench-2048", "loss-2047"])

@@ -9,6 +9,8 @@ each other's cancellation/retirement, the compile count stays flat
 across hit/miss/eviction mixes, and strict-mode audits pass on the
 gather/scatter programs."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -571,3 +573,194 @@ def test_int8_prefix_reuse_vs_no_reuse_same_trace(gpt2_setup):
                           eng.metrics.prefill_chunks)
     assert results[True][0] == results[False][0]
     assert results[True][1] < results[False][1]
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: every pool write is a whole-page read-modify-write
+# ---------------------------------------------------------------------------
+
+
+class _RowPool:
+    """The pool as plain NumPy arrays, written ONE ROW AT A TIME at
+    (page, offset): the semantics every write path had before its tail
+    became page-granular, and the model the new tail must match bit for
+    bit. Rows are encoded by the program's own `kv_quantize_rows` (a
+    whole window at once, as the program does), then placed here."""
+
+    def __init__(self, cache):
+        self.quantized = cache.quantized
+        self.dtype = cache.k.dtype
+        self.ps, self.trash = cache.page_size, cache.trash_page
+        self.pools = [np.array(a) for a in self._arrays(cache)]
+        self.lengths = np.array(cache.lengths)
+
+    @staticmethod
+    def _arrays(cache):
+        return ([cache.k, cache.v, cache.k_scale, cache.v_scale]
+                if cache.quantized else [cache.k, cache.v])
+
+    def encode(self, win_k, win_v):
+        """[L, ..., H, D] payloads -> what lands in each pool array."""
+        if not self.quantized:
+            return [np.asarray(jnp.asarray(w).astype(self.dtype))
+                    for w in (win_k, win_v)]
+        from accelerate_tpu.ops.quant import kv_quantize_rows
+
+        (ck, sk), (cv, sv) = (kv_quantize_rows(jnp.asarray(w))
+                              for w in (win_k, win_v))
+        return [np.asarray(a) for a in (ck, cv, sk, sv)]
+
+    def put_row(self, page, row, encoded, at):
+        """`encoded[i][(slice(None),) + at]` -> view row `row` of `page`."""
+        for pool, enc in zip(self.pools, encoded):
+            pool[:, page, :, row % self.ps] = enc[(slice(None),) + at]
+
+    def assert_matches(self, cache, frozen=()):
+        """Every page but the trash page is bit-identical (codes AND
+        scales), and the `frozen` pages still hold their first bytes."""
+        assert np.array_equal(np.asarray(cache.lengths), self.lengths)
+        for pool, got in zip(self.pools, self._arrays(cache)):
+            got = np.asarray(got)
+            bits = np.uint16 if got.dtype.itemsize == 2 else np.int8
+            np.testing.assert_array_equal(
+                got.view(bits)[:, :self.trash], pool.view(bits)[:, :self.trash])
+        for first, page in frozen:
+            for was, got in zip(first, self._arrays(cache)):
+                assert np.array_equal(
+                    np.asarray(got[:, page]).view(np.uint8),
+                    was[:, page].view(np.uint8))
+
+
+def _random_pool(kv_dtype, num_slots, max_len, pad_slack, lengths, seed):
+    """A pool whose every byte is random, so that a row the write should
+    have left alone shows if it did not."""
+    cache = PagedKVCache.create(num_layers=2, num_slots=num_slots,
+                                max_len=max_len, num_kv_heads=2, head_dim=8,
+                                page_size=4, pad_slack=pad_slack,
+                                kv_dtype=kv_dtype, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+
+    def like(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.normal(size=a.shape), a.dtype)
+
+    fields = {"k": like(cache.k), "v": like(cache.v),
+              "lengths": jnp.asarray(lengths, jnp.int32)}
+    if cache.quantized:
+        fields.update(k_scale=like(cache.k_scale), v_scale=like(cache.v_scale))
+    return dataclasses.replace(cache, **fields), rng
+
+
+def _replay_append_rows(kv_dtype):
+    """Decode appends: offsets 0 and page_size - 1, lanes that cross a
+    page boundary, a dead lane (all-trash table), two lanes that share a
+    full first page, a lane mid-prefill (not live, real table)."""
+    from accelerate_tpu.serving.cache import paged_append_rows
+
+    cache, rng = _random_pool(kv_dtype, 5, 12, 0, [4, 7, 0, 9, 6], seed=25)
+    T = cache.trash_page
+    table = np.asarray([[0, 1, 2], [0, 3, 4],     # page 0 shared, full
+                        [5, 6, T], [T, T, T],     # slot 3 retired
+                        [7, 8, 9]], np.int32)
+    live = np.asarray([True, True, True, False, False])
+    model = _RowPool(cache)
+    first = [a.copy() for a in model.pools]
+    step = jax.jit(paged_append_rows)
+    for _ in range(5):                  # slot 1: offsets 3, 0, 1, ...
+        row_k, row_v = (rng.normal(size=(2, 5, 2, 8)).astype(np.float32)
+                        for _ in range(2))
+        enc = model.encode(row_k, row_v)
+        for s in range(5):
+            row = int(model.lengths[s])
+            model.put_row(table[s, row // 4], row, enc, (s,))
+        model.lengths += live
+        cache = step(cache, jnp.asarray(table), jnp.asarray(row_k),
+                     jnp.asarray(row_v), jnp.asarray(live))
+        model.assert_matches(cache, frozen=[(first, 0)])
+
+
+def _replay_write_slot(kv_dtype):
+    """Prefill chunks of 6 rows on 4-row pages: a start inside a page
+    after a shared full page, chunks that straddle three pages, one that
+    runs into the table's padding, and one whose last page index lies
+    past the table (view rows [12, 18) of a 5-page view)."""
+    from accelerate_tpu.serving.cache import paged_write_slot
+
+    chunk = 6
+    cache, rng = _random_pool(kv_dtype, 3, 14, chunk, [0, 4, 0], seed=26)
+    T = cache.trash_page
+    assert cache.pages_per_slot == 5
+    tables = {1: np.asarray([0, 1, 2, 3, 4], np.int32),   # page 0 shared
+              2: np.asarray([0, 5, 6, T, T], np.int32),
+              0: np.asarray([7, 8, 9, 10, 11], np.int32)}
+    model = _RowPool(cache)
+    first = [a.copy() for a in model.pools]
+    write = jax.jit(paged_write_slot, static_argnames="chunk")
+    # (slot, length before, real tokens): slot 2's second chunk ends in
+    # table padding; slot 0's third starts at row 12 of 20
+    plan = [(1, 4, 5), (1, 9, 3), (2, 4, 6), (2, 10, 1), (0, 0, 6),
+            (0, 6, 6), (0, 12, 2)]
+    for slot, length, advance in plan:
+        cache = dataclasses.replace(
+            cache, lengths=cache.lengths.at[slot].set(length))
+        model.lengths[slot] = length
+        new_k, new_v = (rng.normal(size=(2, 1, cache.rows, 2, 8))
+                        .astype(np.float32) for _ in range(2))
+        rows = np.arange(length, length + chunk)
+        enc = model.encode(new_k[:, 0, rows], new_v[:, 0, rows])
+        for i, row in enumerate(rows):
+            model.put_row(tables[slot][row // 4], row, enc, (i,))
+        model.lengths[slot] += advance
+        cache = write(cache, jnp.asarray(tables[slot]), jnp.int32(slot),
+                      jnp.asarray(new_k), jnp.asarray(new_v),
+                      jnp.int32(advance), chunk=chunk)
+        model.assert_matches(cache, frozen=[(first, 0)])
+
+
+def _replay_append_window(kv_dtype):
+    """Speculative commits of 3-row windows: windows inside one page and
+    over two, accepted counts 0 to 3 (rows past the count keep the
+    page's bytes), a dead lane with a real table and one with none."""
+    from accelerate_tpu.serving.cache import paged_append_window
+
+    cache, rng = _random_pool(kv_dtype, 5, 16, 3, [4, 7, 0, 9, 6], seed=27)
+    T = cache.trash_page
+    table = np.asarray([[0, 1, 2, 3, 4], [0, 5, 6, 7, T],   # page 0 shared
+                        [8, 9, T, T, T], [T, T, T, T, T],
+                        [10, 11, 12, T, T]], np.int32)
+    live = np.asarray([True, True, True, False, False])
+    model = _RowPool(cache)
+    first = [a.copy() for a in model.pools]
+    commit = jax.jit(paged_append_window)
+    for counts in ([3, 1, 0, 2, 3], [1, 3, 3, 3, 0], [2, 2, 1, 0, 1],
+                   [3, 3, 3, 3, 3]):
+        counts = np.asarray(counts, np.int32)
+        win_k, win_v = (rng.normal(size=(2, 5, 3, 2, 8)).astype(np.float32)
+                        for _ in range(2))
+        enc = model.encode(win_k, win_v)
+        for s in range(5):
+            for w in range(3):
+                row = int(model.lengths[s]) + w
+                valid = live[s] and w < counts[s]
+                model.put_row(table[s, row // 4] if valid else T, row, enc,
+                              (s, w))
+        model.lengths += np.where(live, counts, 0)
+        cache = commit(cache, jnp.asarray(table), jnp.asarray(win_k),
+                       jnp.asarray(win_v), jnp.asarray(counts),
+                       jnp.asarray(live))
+        model.assert_matches(cache, frozen=[(first, 0)])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("replay", [_replay_append_rows, _replay_write_slot,
+                                    _replay_append_window],
+                         ids=["append_rows", "write_slot", "append_window"])
+def test_page_granular_writes_match_the_row_by_row_pool(replay, kv_dtype):
+    """`_scatter_rows` rewrites whole pages (gather, select, write back
+    by page index) where it used to scatter single rows. The result has
+    to be the same pool, bit for bit, in codes and in scales: rows of a
+    touched page that are not written are put back as they were, never
+    re-encoded; shared full pages are never touched; dead lanes change
+    the trash page only (the one page the comparison leaves out)."""
+    replay(kv_dtype)
