@@ -178,6 +178,25 @@ jax.tree_util.register_pytree_node(SlotKVCache, _flatten, _unflatten)
 
 
 @dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a family caches for one token in one layer, as the family
+    declares it (`family.cache_spec(config)`; families that declare
+    nothing are GQA/MHA stacks and get `kind="kv"` from their config).
+
+    `kind="kv"`: a K row and a V row of `heads` x `width`, two pools.
+    `kind="latent"`: ONE row of `heads` x `width` (heads = 1 for MLA)
+    that is key and value at once; the pool is one array and
+    `PagedKVCache.v` is None. A page is `page_size` token positions of
+    either kind, so the allocator, the prefix index, admission and the
+    scheduler do not know the kind."""
+
+    num_layers: int
+    heads: int
+    width: int
+    kind: str = "kv"
+
+
+@dataclasses.dataclass(frozen=True)
 class PagedKVCache:
     """Paged KV pool with fixed-shape per-slot page tables.
 
@@ -201,10 +220,20 @@ class PagedKVCache:
     materializing a dense copy. Per-ROW scales keep appends independent
     (a new row never re-scales a page's existing rows), which is what
     keeps shared copy-on-write pages bit-stable.
+
+    LATENT mode (`create(latent=True)`; `CacheSpec.kind == "latent"`):
+    `k` holds the one row a token has and `v` is None. Every view then
+    carries None in V's place and every write takes None for V's rows;
+    int8 codes are not implemented for it.
+
+    `stats`: a family's own device counters (`family.init_serving_stats`),
+    or None. They ride here because the cache is what both engine
+    programs donate and return: they are accumulated on the device and
+    never read on a step's path (`Engine.device_counters`).
     """
 
     k: jax.Array
-    v: jax.Array
+    v: jax.Array | None
     lengths: jax.Array
     page_size: int
     pages_per_slot: int
@@ -213,6 +242,7 @@ class PagedKVCache:
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
     compute_dtype: Any = jnp.bfloat16
+    stats: Any = None
 
     @classmethod
     def create(
@@ -227,6 +257,8 @@ class PagedKVCache:
         pad_slack: int = 0,
         num_pages: int | None = None,
         kv_dtype: Any = None,
+        latent: bool = False,
+        stats: Any = None,
     ) -> "PagedKVCache":
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -235,6 +267,11 @@ class PagedKVCache:
                 f"kv_dtype must be None (store in `dtype`) or 'int8', "
                 f"got {kv_dtype!r}")
         quantized = kv_dtype is not None
+        if latent and quantized:
+            raise ValueError(
+                "an int8 latent pool is not implemented: kv_dtype='int8' "
+                "quantizes K and V rows per head, and a latent row's key "
+                "and value parts would need scales of their own")
         # a slot's view must cover max_len rows plus the chunk-padding
         # spill (see SlotKVCache docstring) — round up to whole pages
         pages_per_slot = -(-(max_len + pad_slack) // page_size)
@@ -248,7 +285,8 @@ class PagedKVCache:
         scale_shape = shape[:-1]
         return cls(
             k=jnp.zeros(shape, jnp.int8 if quantized else dtype),
-            v=jnp.zeros(shape, jnp.int8 if quantized else dtype),
+            v=None if latent
+            else jnp.zeros(shape, jnp.int8 if quantized else dtype),
             lengths=jnp.zeros((num_slots,), jnp.int32),
             page_size=page_size,
             pages_per_slot=pages_per_slot,
@@ -259,11 +297,16 @@ class PagedKVCache:
             v_scale=jnp.zeros(scale_shape, jnp.bfloat16) if quantized
             else None,
             compute_dtype=dtype,
+            stats=stats,
         )
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
 
     @property
     def num_layers(self) -> int:
@@ -291,20 +334,26 @@ class PagedKVCache:
     @property
     def page_nbytes(self) -> int:
         """HBM bytes one page costs across K and V (codes + scales in
-        quantized mode) and all layers — the unit behind the
-        `serving_kv_bytes_in_use` gauge and the HBM math in
+        quantized mode; the one pool in latent mode) and all layers —
+        the unit behind the `serving_kv_bytes_in_use` gauge and the HBM math in
         docs/serving.md: pages a budget holds = budget / page_nbytes."""
         L, _, H, ps, D = self.k.shape
         per = L * ps * H * D * self.k.dtype.itemsize
         if self.quantized:
             per += L * ps * H * self.k_scale.dtype.itemsize
-        return 2 * per
+        return per if self.latent else 2 * per
 
     def nbytes(self) -> int:
-        total = self.k.nbytes + self.v.nbytes
+        total = self.k.nbytes + (0 if self.latent else self.v.nbytes)
         if self.quantized:
             total += self.k_scale.nbytes + self.v_scale.nbytes
         return total
+
+
+def _both(f, k, v):
+    """`f` over K's array and over V's; a latent pool has no V, and None
+    stays None."""
+    return f(k), (None if v is None else f(v))
 
 
 def _dense_pages(codes: jax.Array, scales: jax.Array | None,
@@ -332,10 +381,11 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
     mapping."""
     L, _, H, ps, D = cache.k.shape
     P = cache.pages_per_slot
-    ks = _dense_pages(cache.k, cache.k_scale, table_row,
-                      cache.compute_dtype).reshape(L, 1, P * ps, H, D)
-    vs = _dense_pages(cache.v, cache.v_scale, table_row,
-                      cache.compute_dtype).reshape(L, 1, P * ps, H, D)
+    ks, vs = _both(
+        lambda kv: _dense_pages(*kv, table_row, cache.compute_dtype).reshape(
+            L, 1, P * ps, H, D),
+        (cache.k, cache.k_scale), None if cache.latent
+        else (cache.v, cache.v_scale))
     return ks, vs, cache.lengths[slot]
 
 
@@ -360,8 +410,9 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
     # rows never spill past the view: length <= max_len and pad_slack
     # covers the chunk padding (module docstring)
     rows = length + jnp.arange(chunk, dtype=jnp.int32)
-    win_k = jnp.take(new_k.reshape(L, R, H, D), rows, axis=1)[:, None]
-    win_v = jnp.take(new_v.reshape(L, R, H, D), rows, axis=1)[:, None]
+    win_k, win_v = _both(
+        lambda new: jnp.take(new.reshape(L, R, H, D), rows, axis=1)[:, None],
+        new_k, new_v)
     return _scatter_rows(cache, table_row[None], length[None],
                          jnp.full((1,), chunk, jnp.int32), win_k, win_v,
                          cache.lengths.at[slot].set(length + advance))
@@ -418,9 +469,9 @@ def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
         return pool.at[:, pages].set(jnp.where(mask, new, pool[:, pages]))
 
     if not cache.quantized:
-        return dataclasses.replace(
-            cache, k=put(cache.k, win_k), v=put(cache.v, win_v),
-            lengths=new_lengths)
+        k, v = _both(lambda pw: put(*pw), (cache.k, win_k),
+                     None if cache.latent else (cache.v, win_v))
+        return dataclasses.replace(cache, k=k, v=v, lengths=new_lengths)
     from ..ops.quant import kv_quantize_rows
 
     ck, sk = kv_quantize_rows(win_k)
@@ -441,11 +492,11 @@ def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     L, _, H, ps, D = cache.k.shape
     S = cache.num_slots
     P = cache.pages_per_slot
-    ks = _dense_pages(cache.k, cache.k_scale, table,
-                      cache.compute_dtype).reshape(L, S, P * ps, H, D)
-    vs = _dense_pages(cache.v, cache.v_scale, table,
-                      cache.compute_dtype).reshape(L, S, P * ps, H, D)
-    return ks, vs
+    return _both(
+        lambda kv: _dense_pages(*kv, table, cache.compute_dtype).reshape(
+            L, S, P * ps, H, D),
+        (cache.k, cache.k_scale), None if cache.latent
+        else (cache.v, cache.v_scale))
 
 
 def paged_append_rows(cache: PagedKVCache, table: jax.Array,
@@ -464,8 +515,8 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
     returned views (`paged_append_batch`), the Pallas kernel path hands
     the rows over directly."""
     return _scatter_rows(cache, table, cache.lengths,
-                         jnp.ones_like(cache.lengths), row_k[:, :, None],
-                         row_v[:, :, None],
+                         jnp.ones_like(cache.lengths),
+                         *_both(lambda row: row[:, :, None], row_k, row_v),
                          cache.lengths + live.astype(jnp.int32))
 
 
@@ -477,8 +528,9 @@ def paged_append_batch(cache: PagedKVCache, table: jax.Array,
     the one changed row per slot (view row `length`), then scatter."""
     row = cache.lengths
     idx = row[None, :, None, None, None]
-    row_k = jnp.take_along_axis(new_k, idx, axis=2)[:, :, 0]   # [L, S, H, D]
-    row_v = jnp.take_along_axis(new_v, idx, axis=2)[:, :, 0]
+    row_k, row_v = _both(                                      # [L, S, H, D]
+        lambda new: jnp.take_along_axis(new, idx, axis=2)[:, :, 0],
+        new_k, new_v)
     return paged_append_rows(cache, table, row_k, row_v, live)
 
 
@@ -511,18 +563,20 @@ def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,
 
 
 def _flatten_paged(cache: PagedKVCache):
-    return (cache.k, cache.v, cache.lengths, cache.k_scale, cache.v_scale), (
+    return (cache.k, cache.v, cache.lengths, cache.k_scale, cache.v_scale,
+            cache.stats), (
         cache.page_size, cache.pages_per_slot, cache.max_len,
         cache.pad_slack, cache.compute_dtype)
 
 
 def _unflatten_paged(aux, children):
-    k, v, lengths, k_scale, v_scale = children
+    k, v, lengths, k_scale, v_scale, stats = children
     page_size, pages_per_slot, max_len, pad_slack, compute_dtype = aux
     return PagedKVCache(k=k, v=v, lengths=lengths, page_size=page_size,
                         pages_per_slot=pages_per_slot, max_len=max_len,
                         pad_slack=pad_slack, k_scale=k_scale,
-                        v_scale=v_scale, compute_dtype=compute_dtype)
+                        v_scale=v_scale, compute_dtype=compute_dtype,
+                        stats=stats)
 
 
 jax.tree_util.register_pytree_node(PagedKVCache, _flatten_paged,

@@ -72,6 +72,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import inspect
 import time
 from functools import partial
 from typing import Any, AsyncIterator, Iterator
@@ -95,6 +96,7 @@ from ..telemetry.trace import (
 )
 from ..telemetry.watchdog import StallWatchdog, resolve_stall_timeout
 from .cache import (
+    CacheSpec,
     PagedAllocator,
     PagedKVCache,
     SlotKVCache,
@@ -339,14 +341,29 @@ class EngineConfig:
     mesh: Any = None
 
 
-def _cache_spec(config) -> tuple[int, int, int]:
-    """(num_layers, num_kv_heads, head_dim) from any family config: GQA
-    families carry num_key_value_heads, MHA families fall back to
-    num_attention_heads."""
+def _cache_spec(config, family=None) -> CacheSpec:
+    """What the pool holds for a token in a layer. A family that declares
+    `cache_spec(config)` says so itself (a latent pool). Every other
+    family is a K/V stack read off its config: GQA families carry
+    num_key_value_heads, MHA families fall back to num_attention_heads."""
+    declared = getattr(family, "cache_spec", None)
+    if declared is not None:
+        return declared(config)
     kv = getattr(config, "num_key_value_heads", None)
     if kv is None:
         kv = config.num_attention_heads
-    return config.num_hidden_layers, kv, config.head_dim
+    return CacheSpec(config.num_hidden_layers, kv, config.head_dim)
+
+
+def _unported_with_latent_cache(ec: "EngineConfig") -> list[str]:
+    """The options a latent pool does not implement yet (ROADMAP M3)."""
+    return [what for what, on in (
+        ("kv_dtype='int8' (int8 latent pages)", ec.kv_dtype is not None),
+        ("host_tier_bytes > 0 (the host tier's page shipments carry a K "
+         "and a V half)", ec.host_tier_bytes > 0),
+        ("mesh (a sharded latent pool and its kernel)", ec.mesh is not None),
+        ("speculative (the verify step's multi-token latent attention)",
+         ec.speculative is not None)) if on]
 
 
 def _resolve_paged_attention(setting, mesh, speculative=None) -> bool:
@@ -417,6 +434,11 @@ class Engine:
             # args and TP reductions that can never exist on one chip
             self.engine_config = ec = dataclasses.replace(ec, mesh=None)
         self._forward = family if callable(family) else family.forward
+        # the page shape is the family's to declare (`cache_spec`); what
+        # its forward is handed beyond the uniform decode contract follows
+        # from what it takes (`_build_programs`)
+        self._cache_spec = _cache_spec(config, family)
+        self._family = family
         self._tracker = tracker
         self._log_every = log_every
         self._last_logged = 0
@@ -427,6 +449,14 @@ class Engine:
         if ec.strict is not None and ec.strict not in ("warn", "error"):
             raise ValueError(
                 f"strict must be None, 'warn', or 'error'; got {ec.strict!r}")
+        if self._cache_spec.kind == "latent":
+            unported = _unported_with_latent_cache(ec)
+            if unported:
+                raise ValueError(
+                    "this family caches one latent row a token "
+                    "(CacheSpec.kind='latent'), which is not implemented "
+                    "together with: " + "; ".join(unported) + ". Nothing "
+                    "falls back to a K/V pool.")
         self._spec = ec.speculative is not None
         if self._spec:
             if ec.mesh is not None:
@@ -472,7 +502,8 @@ class Engine:
         self._audited: dict = {}
         self._sanitize = resolve_sanitize(ec.sanitize)
 
-        num_layers, num_kv, head_dim = _cache_spec(config)
+        spec = self._cache_spec
+        stats = getattr(family, "init_serving_stats", None)
         # pad_slack covers BOTH overshoot sources: chunk padding can spill
         # chunk-1 rows past max_len, and a speculative verify can write up
         # to draft_k candidate rows past the last budgeted token (the slot
@@ -481,13 +512,21 @@ class Engine:
         self._pad_slack = max(ec.prefill_chunk,
                               ec.draft_k if self._spec else 0)
         self.cache = PagedKVCache.create(
-            num_layers, ec.num_slots, ec.max_len, num_kv, head_dim,
-            dtype=ec.cache_dtype, page_size=ec.page_size,
+            spec.num_layers, ec.num_slots, ec.max_len, spec.heads,
+            spec.width, dtype=ec.cache_dtype, page_size=ec.page_size,
             pad_slack=self._pad_slack, num_pages=ec.num_pages,
-            kv_dtype=ec.kv_dtype,
+            kv_dtype=ec.kv_dtype, latent=spec.kind == "latent",
+            # one set of counters a program: a reader wants the decode
+            # steps' experts apart from the chunks'
+            stats=None if stats is None else {
+                "prefill": stats(config), "decode": stats(config)},
         )
         if self._spec:
-            dl, dkv, dhd = _cache_spec(self._draft_config)
+            draft = _cache_spec(self._draft_config, dfam)
+            if draft.kind != "kv":
+                raise ValueError(
+                    "a draft model with a latent cache is not implemented")
+            dl, dkv, dhd = draft.num_layers, draft.heads, draft.width
             # the draft's own state is a DENSE slot cache (it is small,
             # and its K/V is a different model's — cached target pages
             # can never seed it, which is why prefix hits run draft-only
@@ -608,6 +647,34 @@ class Engine:
     def _build_programs(self) -> None:
         forward, config = self._forward, self.config
         chunk = self.engine_config.prefill_chunk
+        # what a family is handed beyond the uniform decode contract
+        # follows from what it takes, one thing at a time: a forward with a
+        # `logit_rows` keyword computes the head for the one row that is
+        # read; a family with `accumulate_serving_stats` counts on the
+        # device (`token_mask`, `return_stats`) and sees all slots' tokens
+        # in ONE dense-decode forward, as under the kernel
+        try:
+            one_row = "logit_rows" in inspect.signature(forward).parameters
+        except (TypeError, ValueError):
+            one_row = False
+        fold_stats = getattr(self._family, "accumulate_serving_stats", None)
+
+        def serving_forward(program, params, cache, ids, positions,
+                            kv_caches, logit_rows, token_mask):
+            """-> (logits, new caches, cache): `forward`, with the head for
+            `logit_rows` only ([B, 1, V]) where it takes them, and the
+            family's counters, where it has any, folded into the cache's."""
+            extra = {"logit_rows": logit_rows} if one_row else {}
+            if cache.stats is not None:
+                extra.update(token_mask=token_mask, return_stats=True)
+            out = forward(config, params, ids, positions=positions,
+                          kv_caches=kv_caches, **extra)
+            if cache.stats is not None:
+                cache = dataclasses.replace(cache, stats=dict(
+                    cache.stats, **{program: fold_stats(
+                        cache.stats[program], out[2])}))
+            return out[0], out[1], cache
+
         # donation lets the (large) cache be updated in place; that it IS,
         # on the chip, takes the page-granular write of
         # cache._scatter_rows besides (a row scatter into the donated pool
@@ -672,15 +739,19 @@ class Engine:
                     table_row, ids, real_len):
             ks, vs, length = paged_slot_view(cache, table_row, slot)
             positions = (length + jnp.arange(chunk, dtype=jnp.int32))[None, :]
-            logits, (nk, nv, _) = forward(
-                config, params, ids[None, :], positions=positions,
-                kv_caches=(ks, vs, length),
-            )
+            logits, (nk, nv, _), cache = serving_forward(
+                "prefill", params, cache, ids[None, :], positions,
+                (ks, vs, length), (real_len - 1)[None],
+                (jnp.arange(chunk) < real_len)[None, :])
+            if one_row:  # the one row that is read, not the chunk's
+                last = logits[0, 0].astype(jnp.float32)
+            else:
+                last = jax.lax.dynamic_index_in_dim(
+                    logits[0].astype(jnp.float32), real_len - 1,
+                    keepdims=False)
             cache = paged_write_slot(cache, table_row, slot, nk, nv, real_len,
                                      chunk)
             new_len = length + real_len
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0].astype(jnp.float32), real_len - 1, keepdims=False)
             tok, lp = sample_slot(last, slot_keys[slot], new_len, temps[slot])
             tokens = tokens.at[slot].set(tok)
             return cache, tokens, lp
@@ -692,6 +763,7 @@ class Engine:
             from ..ops.paged_attention import PagedDecodeMeta, PagedKV
 
             rows = self.cache.rows
+            latent = self._cache_spec.kind == "latent"
 
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
             def decode(params, cache, tokens, slot_keys, temps, live, table):
@@ -701,18 +773,21 @@ class Engine:
                 # streams each slot's live pages through VMEM in place and
                 # hands back only the per-slot new K/V rows to scatter
                 kvc = (PagedKV(cache.k, cache.k_scale, cache.compute_dtype),
-                       PagedKV(cache.v, cache.v_scale, cache.compute_dtype),
+                       None if latent else PagedKV(
+                           cache.v, cache.v_scale, cache.compute_dtype),
                        PagedDecodeMeta(table, cache.lengths, rows=rows))
-                logits, (row_k, row_v, _) = forward(
-                    config, params, tokens[:, None],
-                    positions=cache.lengths[:, None], kv_caches=kvc,
-                )
+                lengths = cache.lengths
+                logits, (row_k, row_v, _), cache = serving_forward(
+                    "decode", params, cache, tokens[:, None],
+                    lengths[:, None], kvc, jnp.zeros_like(lengths),
+                    live[:, None])
                 last = logits[:, 0].astype(jnp.float32)
                 next_tok, lps = jax.vmap(sample_slot)(
                     last, slot_keys, cache.lengths + 1, temps)
                 tokens = jnp.where(live, next_tok, tokens)
-                cache = paged_append_rows(cache, table, row_k[:, :, 0],
-                                          row_v[:, :, 0], live)
+                cache = paged_append_rows(
+                    cache, table, row_k[:, :, 0],
+                    None if row_v is None else row_v[:, :, 0], live)
                 return cache, tokens, lps
         else:
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
@@ -732,9 +807,20 @@ class Engine:
                     return (logits[0, 0].astype(jnp.float32), nk[:, 0],
                             nv[:, 0])
 
-                last, nk, nv = jax.vmap(
-                    single, in_axes=(0, 0, 1, 1), out_axes=(0, 1, 1)
-                )(tokens, cache.lengths, k_all, v_all)
+                if cache.stats is not None:
+                    # ONE batched forward with a length a slot (a family
+                    # that counts sees every slot's token in one call, as
+                    # under the kernel)
+                    lengths = cache.lengths
+                    logits, (nk, nv, _), cache = serving_forward(
+                        "decode", params, cache, tokens[:, None],
+                        lengths[:, None], (k_all, v_all, lengths),
+                        jnp.zeros_like(lengths), live[:, None])
+                    last = logits[:, 0].astype(jnp.float32)
+                else:
+                    last, nk, nv = jax.vmap(
+                        single, in_axes=(0, 0, 1, 1), out_axes=(0, 1, 1)
+                    )(tokens, cache.lengths, k_all, v_all)
                 next_tok, lps = jax.vmap(sample_slot)(
                     last, slot_keys, cache.lengths + 1, temps)
                 tokens = jnp.where(live, next_tok, tokens)
@@ -905,6 +991,16 @@ class Engine:
         self._draft_prefill_p = draft_prefill
         self._draft_p = draft
         self._verify_p = verify
+
+    def device_counters(self) -> dict:
+        """The family's own counters (`family.init_serving_stats`), one
+        set a program ("prefill", "decode"), as NumPy arrays; {} for a
+        family that declares none. They accumulate on the device inside
+        the two programs and cross to the host HERE, on demand: nothing
+        on a step's path reads them."""
+        if self.cache is None or self.cache.stats is None:
+            return {}
+        return jax.tree_util.tree_map(np.asarray, self.cache.stats)
 
     def compile_stats(self) -> dict[str, int]:
         """Compiled-program counts per engine program — the recompile
@@ -1312,12 +1408,16 @@ class Engine:
             if self._n_params is None:
                 self._n_params = count_params(self.params)
             n = self._n_params
-        num_layers, num_kv, head_dim = _cache_spec(cfg)
+        # a draft model is always a K/V stack (checked at construction)
+        spec = _cache_spec(cfg, self._family if cfg is self.config else None)
+        num_layers = spec.num_layers
         hidden = getattr(cfg, "hidden_size", 0) or (
-            getattr(cfg, "num_attention_heads", 1) * head_dim)
+            getattr(cfg, "num_attention_heads", 1) * spec.width)
         avg_ctx = max(1, ec.max_len // 2)
         elt = 2  # bf16 weights/activations
-        kv_row = num_kv * head_dim * elt * 2  # one K row + one V row
+        # one K row + one V row, or the one latent row
+        kv_row = spec.heads * spec.width * elt * (
+            1 if spec.kind == "latent" else 2)
         if name == "decode":
             tokens = ec.num_slots
             flops = causal_lm_infer_flops(n, tokens, num_layers, hidden,

@@ -105,6 +105,10 @@ class PageTransport:
     programs (serving/pod/mesh.py)."""
 
     def __init__(self, engine):
+        if engine.cache.latent:
+            raise ValueError(
+                "page shipments of a latent pool are not implemented: a "
+                "KVPageShipment carries a K and a V half (ROADMAP M3)")
         self._engine = engine
         self._quantized = engine.cache.quantized
         install_out = None

@@ -11,7 +11,11 @@ guard every later PR at no chip time:
   128, causal) and at the loss-sliced length 2047;
 - flash attention under a 4-device mesh (`flash_attention_on_mesh`);
 - the serving engine's own `decode` and `prefill` programs at the chat
-  cell's shape, bf16 and int8 pools: the KV pool is updated in place.
+  cell's shape, bf16 and int8 pools: the KV pool is updated in place;
+- the same two programs for the latent-attention expert model at the
+  shape of `serve-joyai-flash-docqa-long`: the latent kernel and the
+  grouped expert products compile, the one-array pool is updated in
+  place, no expert matrix is copied.
 
 The topology is described ONLY inside this file's module-scoped fixture:
 one process at a time may load the TPU's library, the xdist workers all
@@ -215,6 +219,89 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
         # attends its slot's gathered view: no kernel in prefill)
         if name == "decode":
             _assert_kernel_inside(compiled)
+
+
+def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
+    """`decode` and `prefill` of `serve-joyai-flash-docqa-long` (JoyAI-
+    LLM-Flash widths, 1 dense + 4 expert layers, 16 slots x 17920, page
+    16, chunk 512, 17,920 pages of 640-lane latent rows), abstract
+    weights and pool, compiled for the chip:
+
+    (a) the latent paged-attention kernel is in `decode`, once a layer,
+        under its own name, and takes the WHOLE stacked pool (nothing of
+        a layer's slice shape is produced around it);
+    (b) the expert products are `ragged-dot` kernels (3 an expert layer)
+        in both programs, nothing of an expert matrix's shape
+        (`[256, 2048, 768]`, 805 MB) is copied or re-laid out, and no
+        layer sits under control flow (no `conditional`);
+    (c) the pool is ONE array, aliased to its argument; of its shape only
+        the page scatter is produced;
+    (d) temporaries: `decode` under 64 MB (15 MB read), `prefill` under
+        640 MB (435 MB read: the slot's gathered view three times over,
+        118 MB each, and a chunk's blocks), neither of the pool's order;
+        a chunk's logits are ONE row (517 KB), not `[512, 129280]`
+        float32 (265 MB)."""
+    from accelerate_tpu.models import deepseek
+    from accelerate_tpu.serving import Engine, EngineConfig, PagedKVCache
+
+    slots, max_len, page, chunk, num_pages = 16, 17920, 16, 512, 17920
+    cfg = deepseek.DeepseekConfig(num_hidden_layers=5,
+                                  max_position_embeddings=32768)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: deepseek.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 11.1e9 < weights < 11.13e9
+    engine = Engine(deepseek, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=(max_len + chunk) // page,
+        paged_attention=True))
+    small = engine.cache
+    spec = deepseek.cache_spec(cfg)
+    cache = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        spec.num_layers, slots, max_len, spec.heads, spec.width,
+        page_size=page, pad_slack=small.pad_slack, num_pages=num_pages,
+        latent=True, stats=small.stats)))
+    assert cache.v is None and cache.k.shape == (5, 17921, 1, 16, 640)
+    pool_bytes = cache.k.size * 2
+    assert pool_bytes == 1_835_110_400
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_),
+            arg((slots, cache.pages_per_slot), jnp.int32)), 64e6),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32), arg((cache.pages_per_slot,), jnp.int32),
+            arg((chunk,), jnp.int32), arg((), jnp.int32)), 640e6),
+    }
+    for name, (program, args, temp_limit) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        kernels = len(re.findall(
+            r"%latent_paged_decode_attention[.\d]* = ", text))
+        assert kernels == (5 if name == "decode" else 0), name
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 12, name
+        assert " conditional(" not in text, name
+        assert _ops_of_shape(text, "bf16", (256, 2048, 768)) == {}, name
+        assert _ops_of_shape(text, "bf16", (256, 768, 2048)) == {}, name
+        assert _ops_of_shape(text, "bf16", (17921, 16, 640)) == {}, name
+        assert _ops_of_shape(text, "bf16", (5, 17921, 16, 640)) == {
+            "scatter": 1, "fusion": 1}, name
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        assert memory.temp_size_in_bytes < temp_limit, (
+            name, memory.temp_size_in_bytes)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
 
 
 @pytest.mark.parametrize("seq", [2048, 2047], ids=["bench-2048", "loss-2047"])
