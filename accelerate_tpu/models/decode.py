@@ -136,6 +136,42 @@ def decode_attention(q, k, v, kv_cache, positions, mask=None,
     return out, new_cache
 
 
+def scan_decode_layers(layer_step, x, layers, kv_caches):
+    """The decode path's `lax.scan` over layers, for either cache flavor:
+    ONE compiled layer body at any depth.
+
+    `layer_step(x, layer, cache) -> (y, new_cache)` is a family's layer
+    body on the cached path; `layers` its stacked layer params;
+    `kv_caches` the stacked `(k, v, cache_len)`. Returns `(x, (nk, nv))`
+    with the layers' new caches stacked again.
+
+    What rides the scan beside the layer params depends on the flavor,
+    and is known here only: a dense cache [L, B, M, H, D] is sliced a
+    layer a step, as a scan does; the serving engine's paged pool
+    (`PagedKV`) is NOT scanned over: it is closed over whole, the scan
+    carries `arange(L)`, and each step reads the pool at its own index
+    (the paged kernel takes the stacked pool and a layer: no per-layer
+    slice of the pool is ever made)."""
+    ck, cv, cache_len = kv_caches
+    if getattr(ck, "is_paged_kv", False):
+        def layer_caches(i):
+            return ck.at_layer(i), cv.at_layer(i), cache_len
+
+        xs = jnp.arange(ck.data.shape[0], dtype=jnp.int32)
+    else:
+        def layer_caches(kv):
+            return kv[0], kv[1], cache_len
+
+        xs = (ck, cv)
+
+    def body(carry, step):
+        layer, at = step
+        y, (nk, nv, _) = layer_step(carry, layer, layer_caches(at))
+        return y, (nk, nv)
+
+    return jax.lax.scan(body, x, (layers, xs))
+
+
 def _is_batched_keys(key) -> bool:
     """A batch of PRNG keys (one per row) vs a single key: typed key arrays
     batch when they carry any leading dims; raw uint32 keys are [2] single,

@@ -32,6 +32,7 @@ from .decode import (
     build_streamed_generate,
     decode_attention,
     make_kv_caches,
+    scan_decode_layers,
 )
 
 
@@ -163,23 +164,19 @@ def forward(
     x = params["wte"]["embedding"][input_ids] + params["wpe"]["embedding"][positions]
 
     if kv_caches is not None:
-        ck, cv, cache_len = kv_caches
+        def layer_step(y, layer, cache):
+            return _layer_body(config, y, layer, attention_mask, positions,
+                               cache)[:2]
 
-        def decode_body(carry, xs):
-            layer, ck_l, cv_l = xs
-            y, cache, _ = _layer_body(config, carry, layer, attention_mask,
-                                      positions, (ck_l, cv_l, cache_len))
-            nk, nv, _ = cache
-            return y, (nk, nv)
-
-        x, (nk, nv) = jax.lax.scan(decode_body, x, (params["layers"], ck, cv))
+        x, (nk, nv) = scan_decode_layers(layer_step, x, params["layers"],
+                                         kv_caches)
         x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"],
                        config.layer_norm_epsilon)
         logits = jnp.einsum(
             "bsh,vh->bsv", x, params["wte"]["embedding"].astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
-        return logits, (nk, nv, cache_len + input_ids.shape[1])
+        return logits, (nk, nv, kv_caches[2] + input_ids.shape[1])
 
     if fp8_state is not None:
         # per-layer metas ride the scan as xs, updated metas stack as ys

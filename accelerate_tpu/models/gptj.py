@@ -32,6 +32,7 @@ from .decode import (
     decode_attention,
     make_kv_caches,
     rope_table_len,
+    scan_decode_layers,
 )
 
 
@@ -202,19 +203,14 @@ def forward(
         rope_table_len(config.max_position_embeddings, kv_caches))
 
     if kv_caches is not None:
-        ck, cv, cache_len = kv_caches
+        def layer_step(y, layer, cache):
+            return _layer_body(config, y, layer, sin, cos, positions,
+                               attention_mask, cache)[:2]
 
-        def decode_body(carry, xs):
-            layer, ck_l, cv_l = xs
-            y, cache, _ = _layer_body(config, carry, layer, sin, cos,
-                                      positions, attention_mask,
-                                      (ck_l, cv_l, cache_len))
-            nk, nv, _ = cache
-            return y, (nk, nv)
-
-        x, (nk, nv) = jax.lax.scan(decode_body, x, (params["layers"], ck, cv))
+        x, (nk, nv) = scan_decode_layers(layer_step, x, params["layers"],
+                                         kv_caches)
         return (_project_out(config, params, x),
-                (nk, nv, cache_len + input_ids.shape[1]))
+                (nk, nv, kv_caches[2] + input_ids.shape[1]))
 
     if fp8_state is not None:
         def scan_body(carry, xs):

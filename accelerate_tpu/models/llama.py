@@ -33,6 +33,7 @@ from .decode import (
     decode_attention,
     make_kv_caches,
     rope_table_len,
+    scan_decode_layers,
 )
 from .common import (
     apply_rope,
@@ -338,22 +339,15 @@ def forward(
         # decode path: caches stack on a leading layer dim and ride the same
         # lax.scan as training — ONE compiled layer body at any depth (the
         # old per-layer python loop compiled L bodies per decode program)
-        ck, cv, cache_len = kv_caches
+        def layer_step(y, layer, cache):
+            return _layer_body(config, y, layer, cos, sin, positions,
+                               attention_mask, cache)[:2]
 
-        def decode_body(carry, xs):
-            layer, ck_l, cv_l = xs
-            y, cache, _ = _layer_body(config, carry, layer, cos, sin,
-                                      positions, attention_mask,
-                                      (ck_l, cv_l, cache_len))
-            nk, nv, _ = cache
-            return y, (nk, nv)
-
-        x, (nk, nv) = jax.lax.scan(
-            decode_body, x, (params["layers"], ck, cv)
-        )
+        x, (nk, nv) = scan_decode_layers(layer_step, x, params["layers"],
+                                         kv_caches)
         x = rms_norm(x, params["norm"]["scale"], config.rms_norm_eps)
         logits = _project_out(config, params, x)
-        return logits, (nk, nv, cache_len + input_ids.shape[1])
+        return logits, (nk, nv, kv_caches[2] + input_ids.shape[1])
 
     body = partial(_layer_body, config)
     sp = sp_constrain if config.sequence_parallel else (lambda y: y)

@@ -10,12 +10,12 @@ cached token is ONE dot product with that row, and the value it sums is
 the row's first `kv_rank` lanes: keys and values are the same bytes, read
 once for all heads. The caller multiplies the result by `W_UV` afterwards.
 
-The kernel, unlike `ops/paged_attention.py`'s, takes the WHOLE stacked
-pool `[L, pages + 1, page_size, width]` where it lies in HBM
-(`memory_space=pl.ANY`) and a layer index: nothing slices a layer out of
-the pool around the call (PERF.md section 7: the K/V kernel's per-layer
-slices cost ~21 ms a decode step at 16,384 pages). One grid step is one
-slot. Inside it a loop with
+The kernel takes the WHOLE stacked pool `[L, pages + 1, page_size,
+width]` where it lies in HBM (`memory_space=pl.ANY`) and a layer index:
+nothing slices a layer out of the pool around the call (the K/V kernel of
+`ops/paged_attention.py` has the same form since PR 27; the two share no
+page walker, by decision: each cell's programs stay their own). One grid
+step is one slot. Inside it a loop with
 a DYNAMIC trip count walks the slot's live pages in groups of
 `pages_per_group`: each group's pages are copied page by page into one of
 two VMEM buffers while the other is computed on, so the work follows the

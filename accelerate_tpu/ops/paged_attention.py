@@ -5,44 +5,74 @@ dense [L, S, rows, H, D] view (`serving/cache.py paged_batch_view`)
 *before* the vmapped family forward — O(pool) HBM reads per token,
 rebuilt outside the attention op, growing with `pages_per_slot` however
 short the live sequences are. This kernel inverts that: the pool stays
-in place in HBM and the page table drives the kernel's BlockSpec index
-maps (scalar prefetch), so each grid step stages exactly ONE page of one
-slot's K/V into VMEM — pages are read once, where they live, and only a
-slot's *live* pages are visited (dead table entries re-map to an
-already-fetched block, so Mosaic's pipeline revisit elides the fetch).
-The pjit/TPUv4 rule (arxiv 2204.06514) still holds: the table and
-lengths are traced *data*, so one compiled program covers every page
-mapping, request mix, and eviction history.
+in place in HBM and the kernel's work follows the LIVE pages and nothing
+else. It takes the WHOLE stacked pool `[L, pages + 1, Hkv, page_size, D]`
+(`memory_space=pl.ANY`) and a layer index, so nothing slices a layer out
+of the pool around its call and a decode step does not know the pool's
+size. One grid step is one slot; inside it a loop with a DYNAMIC trip
+count walks the slot's live pages in groups of `PAGES_PER_GROUP`, each
+page copied (both KV heads, one copy) into one of two VMEM buffers while
+the other is folded into the online softmax. A lane whose length the
+engine masked to 0 (retired, or mid-prefill) costs one grid step and no
+copy. The pjit/TPUv4 rule (arxiv 2204.06514) still holds: the table,
+lengths and layer are traced *data*, so one compiled program covers every
+page mapping, request mix, and eviction history.
 
 Layout and semantics:
 
-- pool K/V: [num_pages + 1, Hkv, page_size, D] per layer (the serving
-  pool minus its leading layer dim — the kernel is called inside the
-  family forward's `lax.scan` over layers). Heads sit OUTSIDE the page
-  rows so one head's page is a whole [page_size, D] tile: the TPU
-  compiler only takes blocks whose trailing two dims are whole array
-  dims or (8, 128) multiples. The last page is the reserved trash page
-  backing padded table entries.
+- pool K/V: [L, num_pages + 1, Hkv, page_size, D], the serving pool as it
+  is stored; `PagedKV.layer` says which layer this call attends (the
+  family forwards scan over `arange(L)`, not over the pool:
+  `models/decode.scan_decode_layers`). Heads sit OUTSIDE the page rows so
+  one head's page is a whole [page_size, D] tile, and a group's pages of
+  one head, [G, page_size, D], read as [G * page_size, D] rows for free.
+  The last page is the reserved trash page backing padded table entries.
 - page table: [slots, pages_per_slot] int32; lengths: [slots] int32.
 - q: one token per slot, GQA grouped as [slots, Hkv, group, D] — the
-  head-group broadcast happens in-kernel (each grid step dots every kv
-  head's whole q group, padded to 8 sublanes, against that head's
-  page), so K/V are never `repeat_kv`'d.
+  head-group broadcast happens in-kernel (each group of pages dots every
+  kv head's whole q group, padded to 8 sublanes, against that head's
+  rows), so K/V are never `repeat_kv`'d.
 - the NEW token's K/V (this step's, position == length) are folded into
   the online softmax as a final single-key update instead of being
   written to the pool first: the kernel never writes, the engine
   scatters the one new row per slot afterwards (`paged_append_rows`).
-- int8 pools (`PagedKV.scales` set) dequantize per page INSIDE the
-  kernel — per-row-per-head scales applied to the scores and the
-  probabilities, which is the same product as scaling the codes — so
-  the HBM stream is the int8 bytes, not a pre-dequantized bf16 copy.
+- arithmetic: scores, softmax state and accumulators in float32; q and K
+  meet on the MXU in the pool's dtype when q has it too (bf16 x bf16 with
+  a float32 result is the exact product); the probabilities are NOT
+  rounded for the PV product.
+- masked rows cannot reach an output: a group is copied whole, with the
+  last page's slack, pages past the length and the trash page behind
+  padded table entries (where retired lanes' dead writes land), so the
+  masked rows of V are zeroed before the PV product (a masked key's
+  probability is an exact 0, and 0 x inf is NaN).
+- int8 pools (`PagedKV.scales` set) and heads whose width is no whole
+  128-lane tile keep the OLDER kernel, separately (`_page_step_kernel`;
+  the two share no logic): one grid step a page of ONE layer's pool,
+  staged through BlockSpec index maps, given `pool[layer]` by an explicit
+  index. Both are the chip compiler's refusals (compiled for a described
+  v5e, PR 27): an array whose last dimension is under 128 lies padded to
+  128 lanes in HBM, and a page cannot be cut out of it for a copy. For a
+  64-wide head's pool: "Slice shape along dimension 4 must be aligned to
+  tiling (128), but is 64"; for a page's [Hkv, page_size] scales out of
+  [L, N+1, Hkv, page_size]: "Slice shape along dimension 3 must be
+  aligned to tiling (128), but is 16". (The int8 CODES' page copy
+  compiles. With the scales gathered outside the kernel instead,
+  `scales[layer, table]`, the kernel compiles too, but the scale arrays
+  lie pages-minor on the chip and XLA re-lays both out whole for the
+  gather, 59 MB each at 4,096 pages, every decode step: not taken.
+  What lifts it is a scale layout a page can be copied from, PERF.md
+  section 7.) No benchmark cell runs either. That kernel dequantizes an
+  int8 page INSIDE the kernel — per-row-per-head scales applied to the
+  scores and the probabilities, which is the same product as scaling
+  the codes — so the HBM stream is the int8 bytes.
 
 Masking matches `models/decode.cached_attention_mask` exactly: a slot's
 query (position == length) attends pool rows < length plus its own new
 K/V; `window` applies the HF sliding-window band (key visible iff
-q - key < window). Retired slots (all-trash tables, stale lengths)
-compute garbage that the engine discards via its `live` lane mask —
-same contract as the dense gather path.
+q - key < window), and the walk starts at the first group the band
+reaches. Lanes that are not live compute garbage that the engine
+discards via its `live` lane mask — same contract as the dense gather
+path.
 
 On non-TPU backends the kernel runs in pallas interpret mode (slow, for
 tests; decided and recorded in `ops/kernel_mode.py`) — tier-1 proves
@@ -66,6 +96,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import kernel_mode
 
 NEG_INF = -1e30
+KERNEL_NAME = "paged_decode_attention"
 _LANES = 128  # TPU vector lane width; scalar-per-group state is kept 2D
 _SUBLANES = 8  # f32 sublanes per vreg; the query group pads to this
 
@@ -86,13 +117,15 @@ __all__ = [
 class PagedKV:
     """One pool buffer (K or V) as it threads through a family forward.
 
-    `data` is the [L, pages+1, Hkv, page_size, D] pool (or a per-layer
-    slice of it — `lax.scan` over the leading dim slices both children
-    together); `scales` is the int8 mode's [L, pages+1, Hkv, page_size]
-    per-row-per-head scale array, None for a bf16 pool. `compute_dtype`
-    is the dtype attention math materializes K/V rows in (and the dtype
-    of the new-token rows handed back for the engine to write); None
-    defaults to `data.dtype` (bf16 pools) or bfloat16 (int8 pools).
+    `data` is the WHOLE stacked pool [L, pages+1, Hkv, page_size, D];
+    `layer` (int32 scalar, traced inside the forwards' layer scan) says
+    which layer of it an attention call reads, None where the caller
+    keeps its own index (`models/deepseek.py`). `scales` is the int8
+    mode's [L, pages+1, Hkv, page_size] per-row-per-head scale array,
+    None for a bf16 pool. `compute_dtype` is the dtype attention math
+    materializes K/V rows in (and the dtype of the new-token rows handed
+    back for the engine to write); None defaults to `data.dtype` (bf16
+    pools) or bfloat16 (int8 pools).
 
     The `is_paged_kv` marker lets `models/decode.decode_attention`
     dispatch without importing this (pallas-importing) module on the
@@ -100,10 +133,11 @@ class PagedKV:
 
     is_paged_kv = True
 
-    def __init__(self, data, scales=None, compute_dtype=None):
+    def __init__(self, data, scales=None, compute_dtype=None, layer=None):
         self.data = data
         self.scales = scales
         self.compute_dtype = compute_dtype
+        self.layer = layer
 
     @property
     def quantized(self) -> bool:
@@ -116,13 +150,17 @@ class PagedKV:
             return self.compute_dtype
         return jnp.bfloat16 if self.quantized else self.data.dtype
 
+    def at_layer(self, layer) -> "PagedKV":
+        """The same pool, read at `layer`."""
+        return PagedKV(self.data, self.scales, self.compute_dtype, layer)
+
     def tree_flatten(self):
-        return (self.data, self.scales), (self.compute_dtype,)
+        return (self.data, self.scales, self.layer), (self.compute_dtype,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        data, scales = children
-        return cls(data, scales, compute_dtype=aux[0])
+        data, scales, layer = children
+        return cls(data, scales, compute_dtype=aux[0], layer=layer)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -158,15 +196,204 @@ class PagedDecodeMeta:
 
 
 # ---------------------------------------------------------------------------
-# the kernel
+# the bf16 / float pool's kernel: the work follows the live pages
+# ---------------------------------------------------------------------------
+
+# Pages copied and folded at a time: swept on the chip over 8 / 16 / 32 /
+# 64 in both Qwen serving cells, PERF.md section 6 (PR 27).
+PAGES_PER_GROUP = 32
+# What the four group buffers (K and V, two each) may take of the chip's
+# 16 MB of scoped VMEM. A Qwen page (2 kv heads x 16 x 128 bf16) is 8 KB
+# and its buffers 1 MB; an MHA page of 32 heads is 128 KB, and 32 of them
+# four times over do not fit.
+_GROUP_BUFFER_BYTES = 8 << 20
+
+
+def _pages_per_group(pages_per_slot: int, page_shape, dtype) -> int:
+    """`PAGES_PER_GROUP`, clamped by the table's width and by what the
+    group buffers may hold of pages shaped `page_shape` [Hkv, ps, D]; a
+    clamped group stays a whole number of 128-lane score tiles where it
+    can."""
+    heads, ps, width = page_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    # as a page lies in VMEM: its rows padded to the dtype's sublane tile
+    tile_rows = 32 // itemsize
+    page_bytes = heads * -(-ps // tile_rows) * tile_rows * width * itemsize
+    fit = max(1, _GROUP_BUFFER_BYTES // (4 * page_bytes))
+    if fit >= 8:
+        fit -= fit % 8
+    return min(PAGES_PER_GROUP, pages_per_slot, fit)
+
+
+def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
+                       vn_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
+                       sm_scale: float, page_size: int, pages_per_slot: int,
+                       pages_per_group: int, num_kv_heads: int,
+                       window: int | None):
+    """Grid [slots]: one step is one slot. A loop with a DYNAMIC trip
+    count walks the slot's live pages in groups of `pages_per_group`:
+    each page of a group is one copy (every kv head of it) out of the
+    whole stacked pool `k_hbm`/`v_hbm` [L, N+1, Hkv, ps, D], where it
+    lies in HBM, into one of two VMEM buffers, while the other buffer is
+    folded into the online softmax. A slot of length 0 (a lane the
+    engine masked out) starts no copy at all."""
+    s = pl.program_id(0)
+    length = lengths_ref[s]
+    layer = layer_ref[0]
+    G, ps, P = pages_per_group, page_size, pages_per_slot
+    rows = G * ps
+    n_groups = (length + rows - 1) // rows
+    # under a sliding window the walk starts at the first group that
+    # holds a visible key (position > length - window)
+    first = 0 if window is None else jnp.maximum(
+        length - window + 1, 0) // rows
+
+    def start(g, slot):
+        """Start the copies of every page of group `g` into buffer `slot`."""
+        for j in range(G):
+            # entries past the table's end re-read its last page: masked
+            page = table_ref[s * P + jnp.minimum(g * G + j, P - 1)]
+            pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, j],
+                                  sem.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, j],
+                                  sem.at[1, slot]).start()
+
+    @pl.when(first < n_groups)
+    def _first():
+        start(first, first % 2)
+
+    # K and q go to the MXU as they are stored when their dtypes agree
+    # (bf16 x bf16 with a float32 result is the exact product); the
+    # probabilities stay float32 through the PV product
+    dot_dtype = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    qs = [q_ref[0, h].astype(dot_dtype) for h in range(num_kv_heads)]
+
+    def fold(state, s_blk, pv):
+        """One online-softmax update with scaled, masked scores [Gp, n];
+        `pv` maps the probabilities to their value sum [Gp, D]."""
+        m, l, acc = state
+        m_new = jnp.maximum(m, jnp.max(s_blk, axis=-1, keepdims=True))
+        # a fully masked block keeps m_new at NEG_INF, where exp(s - m)
+        # would be exp(0) = 1 a masked key: zero those explicitly
+        p = jnp.where(s_blk <= NEG_INF / 2, 0.0, jnp.exp(s_blk - m_new))
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + pv(p))
+
+    def body(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            start(g + 1, 1 - slot)
+
+        # ONE wait a buffer: a DMA semaphore counts bytes, and a group's
+        # copies fill exactly the buffer this descriptor names (a wait
+        # a page read 11% slower in docqa's shape, PERF.md section 6)
+        for buf, which in ((kbuf, 0), (vbuf, 1)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[which, slot]).wait()
+        def visible(shape, axis):
+            """Which of the group's rows the query may attend, laid along
+            `axis` of `shape`."""
+            pos = g * rows + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            if window is None:
+                return pos < length
+            # HF sliding-window convention: key visible iff q - key <
+            # window; the query sits at position == length
+            return (pos < length) & (pos > length - window)
+
+        keep = visible((1, rows), 1)
+        # the same mask down the rows of V: a group is copied whole, so
+        # its buffer also holds rows nobody wrote for this request (the
+        # slack of the last page, pages past the length, the trash page
+        # behind padded table entries). A masked key's probability is an
+        # exact 0, but 0 x inf is NaN: those rows are zeroed, so whatever
+        # lies there cannot reach a live slot's output
+        keep_row = visible((rows, 1), 0)
+        out = []
+        for h in range(num_kv_heads):
+            # a head's pages of the group, [G, ps, D], as rows [G*ps, D]
+            k = kbuf[slot, :, h].reshape(rows, -1).astype(dot_dtype)
+            v = jnp.where(keep_row, vbuf[slot, :, h].reshape(
+                rows, -1).astype(jnp.float32), 0.0)
+            # q @ k^T as an NT contraction (no in-kernel transpose)
+            s_blk = jax.lax.dot_general(
+                qs[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s_blk = jnp.where(keep, s_blk * sm_scale, NEG_INF)
+            out.append(fold(carry[h], s_blk, lambda p, v=v: jnp.dot(
+                p, v, preferred_element_type=jnp.float32)))
+        return tuple(out)
+
+    Gp, D = q_ref.shape[2], q_ref.shape[3]
+    carry = tuple((jnp.full((Gp, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((Gp, 1), jnp.float32),
+                   jnp.zeros((Gp, D), jnp.float32))
+                  for _ in range(num_kv_heads))
+    carry = jax.lax.fori_loop(first, n_groups, body, carry)
+    # the new token's K/V (position == length, always visible: its window
+    # distance is 0) folds as one more single-key update, on the VPU (a
+    # one-column matmul has no legal MXU shape); then finalize. l > 0
+    # always: this key contributes exp(0) when it is the running max.
+    for h in range(num_kv_heads):
+        q = qs[h].astype(jnp.float32)
+        kn = kn_ref[0, h].astype(jnp.float32)              # [1, D]
+        vn = vn_ref[0, h].astype(jnp.float32)
+        s_new = jnp.sum(q * kn, axis=-1, keepdims=True) * sm_scale
+        _, l, acc = fold(carry[h], s_new, lambda p, vn=vn: p * vn)
+        o_ref[0, h] = (acc / l).astype(o_ref.dtype)
+
+
+def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths,
+                     window: int | None, interpret: bool):
+    """q4 [S, Hkv, Gp, D], kn/vn [S, Hkv, 1, D], pools [L, N+1, Hkv, ps,
+    D], layer int32 scalar -> out [S, Hkv, Gp, D]."""
+    S, Hkv, Gp, D = q4.shape
+    P = table.shape[1]
+    ps = pool_k.shape[3]
+    G = _pages_per_group(P, pool_k.shape[2:], pool_k.dtype)
+    kernel = functools.partial(
+        _live_pages_kernel, sm_scale=1.0 / math.sqrt(D), page_size=ps,
+        pages_per_slot=P, pages_per_group=G, num_kv_heads=Hkv, window=window)
+    per_slot = lambda s, *_: (s, 0, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, Hkv, Gp, D), per_slot),
+                  pl.BlockSpec((1, Hkv, 1, D), per_slot),
+                  pl.BlockSpec((1, Hkv, 1, D), per_slot),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, Hkv, Gp, D), per_slot),
+        scratch_shapes=[pltpu.VMEM((2, G, Hkv, ps, D), pool_k.dtype),
+                        pltpu.VMEM((2, G, Hkv, ps, D), pool_v.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, Gp, D), q4.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=KERNEL_NAME,
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q4, kn, vn, pool_k, pool_v)
+
+
+# ---------------------------------------------------------------------------
+# the older kernel: one grid step a page of ONE layer's pool. Kept, as it
+# was, for what the kernel above cannot take: int8 pools, and heads whose
+# width is no whole 128-lane tile
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
-                         pk_ref, pv_ref, *rest, sm_scale: float,
-                         page_size: int, pages_per_slot: int,
-                         num_kv_heads: int, window: int | None,
-                         quantized: bool):
+def _page_step_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
+                      pk_ref, pv_ref, *rest, sm_scale: float,
+                      page_size: int, pages_per_slot: int,
+                      num_kv_heads: int, window: int | None,
+                      quantized: bool):
     """Grid [slots, pages_per_slot] (pages innermost/arbitrary): each
     step folds one page of one slot — every kv head of it, in a static
     loop — into the online softmax; the last step also folds the new
@@ -269,25 +496,16 @@ def _paged_decode_kernel(table_ref, lengths_ref, q_ref, kn_ref, vn_ref,
                 o_ref.dtype)
 
 
-def _paged_attention_call(q4, kn, vn, pool_k, pool_v, k_scales, v_scales,
-                          table, lengths, window: int | None,
-                          interpret: bool):
-    """q4 [S, Hkv, G, D], kn/vn [S, Hkv, D], pool [N+1, Hkv, ps, D]
-    (+ scales [N+1, Hkv, ps] when quantized) -> out [S, Hkv, G, D]."""
-    S, Hkv, G, D = q4.shape
+def _page_step_call(q4, kn, vn, pool_k, pool_v, k_scales, v_scales,
+                    table, lengths, window: int | None, interpret: bool):
+    """q4 [S, Hkv, Gp, D], kn/vn [S, Hkv, 1, D], ONE layer's pool [N+1,
+    Hkv, ps, D] (+ scales [N+1, Hkv, ps] when quantized) -> out [S, Hkv,
+    Gp, D]."""
+    S, Hkv, Gp, D = q4.shape
     P = table.shape[1]
     ps = pool_k.shape[2]
     quantized = k_scales is not None
     sm_scale = 1.0 / math.sqrt(D)
-    # the query group is the sublane dim of every score block: pad it to
-    # a whole f32 sublane tile (zero rows attend uniformly and are
-    # sliced off) so G in {1, 4, 6} lowers like G = 8
-    Gp = -(-G // _SUBLANES) * _SUBLANES
-    if Gp != G:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    # [S, Hkv, 1, D]: a unit sublane dim makes the per-head new row a
-    # whole-trailing-dims block
-    kn, vn = kn[:, :, None, :], vn[:, :, None, :]
 
     def page_map(s, j, table_ref, lengths_ref):
         # dead steps (page start >= length) re-target page 0 of the
@@ -325,20 +543,19 @@ def _paged_attention_call(q4, kn, vn, pool_k, pool_v, k_scales, v_scales,
         ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=sm_scale, page_size=ps,
+        _page_step_kernel, sm_scale=sm_scale, page_size=ps,
         pages_per_slot=P, num_kv_heads=Hkv, window=window,
         quantized=quantized)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((S, Hkv, Gp, D), q4.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        name="paged_decode_attention",
+        name=KERNEL_NAME,
         interpret=interpret,
     )(table.reshape(-1), lengths, *operands)
-    return out[:, :, :G]
 
 
 # ---------------------------------------------------------------------------
@@ -356,7 +573,8 @@ def paged_decode_attention(
     window: int | None = None,
     interpret: bool | None = None,
 ):
-    """One decode step of paged attention for every slot at once.
+    """One decode step of paged attention for every slot at once, in the
+    layer `pk.layer` of the stacked pool.
 
     q: [S, 1, H, D] (S slots, one token each, H = Hkv * group);
     k_new/v_new: [S, 1, Hkv, D] — this step's K/V, folded in-kernel and
@@ -373,9 +591,14 @@ def paged_decode_attention(
     if meta.table.shape[0] != S:
         raise ValueError(
             f"page table covers {meta.table.shape[0]} slots, q has {S}")
+    if pk.data.ndim != 5 or pk.layer is None:
+        raise ValueError(
+            "paged decode attention takes the whole stacked pool [L, "
+            "pages + 1, Hkv, page_size, D] and a layer index; got a pool of "
+            f"shape {pk.data.shape} and layer {pk.layer!r}")
     if window is not None and (window <= 0 or window >= meta.rows):
         window = None  # band wider than the cache reach: plain causal
-    interpret = kernel_mode.resolve_interpret("paged_decode_attention", interpret)
+    interpret = kernel_mode.resolve_interpret(KERNEL_NAME, interpret)
     G = H // Hkv
     row_dtype = pk.row_dtype
     # the fold must see exactly the bytes the engine will write, so a
@@ -383,10 +606,30 @@ def paged_decode_attention(
     k_row = k_new.astype(row_dtype)
     v_row = v_new.astype(row_dtype)
     q4 = q[:, 0].reshape(S, Hkv, G, D)
-    out = _paged_attention_call(
-        q4, k_row[:, 0], v_row[:, 0], pk.data, pv.data, pk.scales,
-        pv.scales, meta.table, meta.lengths, window, interpret)
-    return out.reshape(S, 1, H, D), (k_row, v_row)
+    # the query group is the sublane dim of every score block: pad it to
+    # a whole f32 sublane tile (zero rows attend uniformly and are
+    # sliced off) so G in {1, 4, 6} lowers like G = 8
+    Gp = -(-G // _SUBLANES) * _SUBLANES
+    if Gp != G:
+        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    # [S, Hkv, 1, D]: a unit sublane dim makes the per-head new row a
+    # whole-trailing-dims block
+    kn, vn = k_row[:, 0, :, None, :], v_row[:, 0, :, None, :]
+    if pk.quantized or D % _LANES:
+        # the page-a-grid-step kernel, given its layer's slice of the
+        # pool (module docstring: what the live-pages kernel cannot take)
+        def layer_of(stacked):
+            return None if stacked is None else jax.lax.dynamic_index_in_dim(
+                stacked, pk.layer, keepdims=False)
+
+        out = _page_step_call(
+            q4, kn, vn, layer_of(pk.data), layer_of(pv.data),
+            layer_of(pk.scales), layer_of(pv.scales), meta.table,
+            meta.lengths, window, interpret)
+    else:
+        out = _live_pages_call(q4, kn, vn, pk.data, pv.data, pk.layer,
+                               meta.table, meta.lengths, window, interpret)
+    return out[:, :, :G].reshape(S, 1, H, D), (k_row, v_row)
 
 
 def paged_decode_reference(
@@ -399,7 +642,8 @@ def paged_decode_reference(
     window: int | None = None,
 ):
     """Dense-gather reference with identical semantics (and the
-    executable spec of them): gather every table page, dequantize,
+    executable spec of them): gather every table page of the pool's layer
+    `pk.layer`, dequantize,
     overlay the new token's row at position == length, mask rows the
     query may not see, plain f32 softmax. The exactness tests pin the
     kernel to this; the serving engine's dense path is the same math
@@ -407,15 +651,16 @@ def paged_decode_reference(
     S, _, H, D = q.shape
     Hkv = k_new.shape[2]
     G = H // Hkv
-    ps = pk.data.shape[2]
+    ps = pk.data.shape[3]
     R = meta.table.shape[1] * ps
     row_dtype = pk.row_dtype
 
     def dense(p: PagedKV):
-        pages = p.data[meta.table]                      # [S, P, Hkv, ps, D]
+        pages = p.data[p.layer][meta.table]             # [S, P, Hkv, ps, D]
         full = pages.astype(jnp.float32)
         if p.quantized:
-            full = full * p.scales[meta.table].astype(jnp.float32)[..., None]
+            full = full * p.scales[p.layer][meta.table].astype(
+                jnp.float32)[..., None]
         return jnp.swapaxes(full, 2, 3).reshape(S, R, Hkv, D)
 
     k_all, v_all = dense(pk), dense(pv)

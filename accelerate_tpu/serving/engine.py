@@ -772,11 +772,16 @@ class Engine:
                 # cache-attend step (models/decode.decode_attention)
                 # streams each slot's live pages through VMEM in place and
                 # hands back only the per-slot new K/V rows to scatter
+                lengths = cache.lengths
+                # the K/V kernel's work follows the lengths it is given:
+                # a retired or mid-prefill lane (a stale length; its
+                # result is discarded below) walks no page at length 0.
+                # The latent kernel's program stays as it was (PR 26)
+                walked = lengths if latent else jnp.where(live, lengths, 0)
                 kvc = (PagedKV(cache.k, cache.k_scale, cache.compute_dtype),
                        None if latent else PagedKV(
                            cache.v, cache.v_scale, cache.compute_dtype),
-                       PagedDecodeMeta(table, cache.lengths, rows=rows))
-                lengths = cache.lengths
+                       PagedDecodeMeta(table, walked, rows=rows))
                 logits, (row_k, row_v, _), cache = serving_forward(
                     "decode", params, cache, tokens[:, None],
                     lengths[:, None], kvc, jnp.zeros_like(lengths),

@@ -315,7 +315,8 @@ def train_phase(s: Sizes, work_dir: str, expect_chip: bool, seed: int = 0):
 
 def kernel_op_check(s: Sizes, seed: int = 0) -> None:
     """`paged_decode_attention` against `paged_decode_reference` on seeded
-    pools and tables at the serving widths, bf16 and int8 pools."""
+    pools and tables at the serving widths, bf16 and int8 pools: a
+    stacked pool of two layers, read at the second."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -332,7 +333,8 @@ def kernel_op_check(s: Sizes, seed: int = 0) -> None:
     S, P, N, ps = s.op_slots, s.op_pages_per_slot, s.op_num_pages, 16
     Hkv, H, D = s.num_key_value_heads, s.num_attention_heads, cfg.head_dim
     rng = np.random.default_rng(seed)
-    shape = (N + 1, Hkv, ps, D)
+    shape = (2, N + 1, Hkv, ps, D)
+    layer = jnp.int32(1)
     pool_k = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
     pool_v = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
     # each slot owns a random set of pages; lengths cover empty, mid-page,
@@ -354,9 +356,10 @@ def kernel_op_check(s: Sizes, seed: int = 0) -> None:
     ck, sk = kv_quantize_rows(pool_k)
     cv, sv = kv_quantize_rows(pool_v)
     for name, pk, pv in (
-            ("bf16", PagedKV(pool_k), PagedKV(pool_v)),
-            ("int8", PagedKV(ck, sk, jnp.bfloat16),
-             PagedKV(cv, sv, jnp.bfloat16))):
+            ("bf16", PagedKV(pool_k, layer=layer),
+             PagedKV(pool_v, layer=layer)),
+            ("int8", PagedKV(ck, sk, jnp.bfloat16, layer),
+             PagedKV(cv, sv, jnp.bfloat16, layer))):
         out, _ = jax.jit(paged_decode_attention)(q, kn, vn, pk, pv, meta)
         with jax.default_matmul_precision("highest"):
             ref, _ = jax.jit(paged_decode_reference)(q, kn, vn, pk, pv, meta)

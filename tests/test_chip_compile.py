@@ -6,12 +6,15 @@ rehearsal 3). Interpret mode cannot see what it refuses: block shapes off
 the (8, 128) tiling, kernels GSPMD cannot partition. These few compiles
 guard every later PR at no chip time:
 
-- paged decode attention, bf16 and int8 pools, at real widths;
+- paged decode attention, bf16 and int8 pools, at real widths, on the
+  whole stacked pool and a layer index;
 - flash attention forward + backward at the bench shape (8 x 2048 x 12 x
   128, causal) and at the loss-sliced length 2047;
 - flash attention under a 4-device mesh (`flash_attention_on_mesh`);
 - the serving engine's own `decode` and `prefill` programs at the chat
-  cell's shape, bf16 and int8 pools: the KV pool is updated in place;
+  cell's shape, bf16 and int8 pools: the KV pool is updated in place,
+  and `decode` on a bf16 pool slices no layer out of it and holds the
+  same temporaries at 4,096 and 16,384 pages;
 - the same two programs for the latent-attention expert model at the
   shape of `serve-joyai-flash-docqa-long`: the latent kernel and the
   grouped expert products compile, the one-array pool is updated in
@@ -84,7 +87,11 @@ def _assert_kernel_inside(compiled, at_least=1):
     (2, 6, 128, True),
     (8, 4, 128, True),    # Llama-3-8B: 32 heads over 8 KV heads
     (12, 1, 64, False),   # GPT-2: MHA, 64-wide heads
-], ids=["qwen2-bf16", "qwen2-int8", "llama3-int8", "gpt2-bf16"])
+    (8, 4, 128, False),   # Llama-3-8B on a bf16 pool
+    (32, 1, 128, False),  # Llama-2-7B / OPT-6.7B: MHA, a page is 128 KB
+    (16, 1, 256, False),  # GPT-J-6B: MHA, 256-wide heads
+], ids=["qwen2-bf16", "qwen2-int8", "llama3-int8", "gpt2-bf16",
+        "llama3-bf16", "llama2-mha-bf16", "gptj-bf16"])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
                                               group, d, quantized):
     from accelerate_tpu.ops.paged_attention import (
@@ -93,16 +100,16 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
         paged_decode_attention,
     )
 
-    slots, pages_per_slot, num_pages, page = 16, 64, 1024, 16
+    layers, slots, pages_per_slot, num_pages, page = 4, 16, 64, 1024, 16
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((num_pages + 1, hkv, page, d),
+    pool = sds((layers, num_pages + 1, hkv, page, d),
                jnp.int8 if quantized else jnp.bfloat16)
-    scales = sds((num_pages + 1, hkv, page), jnp.bfloat16) if quantized \
-        else None
-    pk = PagedKV(pool, scales, jnp.bfloat16)
+    scales = sds((layers, num_pages + 1, hkv, page), jnp.bfloat16) \
+        if quantized else None
+    pk = PagedKV(pool, scales, jnp.bfloat16, sds((), jnp.int32))
     meta = PagedDecodeMeta(sds((slots, pages_per_slot), jnp.int32),
                            sds((slots,), jnp.int32),
                            rows=pages_per_slot * page)
@@ -114,6 +121,11 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
             paged_decode_attention(q, kn, vn, pk, pv, meta, window=window)[0]
         ).lower(q, kn, kn, pk, pk, meta).compile()
         _assert_kernel_inside(compiled)
+        # the live-pages kernel takes the pool where it lies (the older
+        # one, for int8 pools and 64-wide heads, is given a layer's slice)
+        if not quantized and d % 128 == 0:
+            assert not _ops_of_shape(compiled.as_text(), "bf16",
+                                     pool.shape[1:])
 
 
 def _ops_of_shape(text, dtype, shape):
@@ -129,34 +141,18 @@ def _ops_of_shape(text, dtype, shape):
     return kinds
 
 
-@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
-def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
-        one_chip, chip_compile, kv_dtype):
-    """The engine's own `decode` and `prefill`, Qwen2-1.5B widths and the
-    chat cell's shape (32 slots x 2048, page 16, chunk 256, 4,096 pages),
-    compiled for the chip: besides the aliased update itself no operation
-    produces an array of a pool half's shape, and no temporary grows with
-    the pool.
-
-    This test fails on the row scatter `_scatter_rows` had up to PR 24.
-    A row index falls on the second-minor axis of the chip's (8, 128)
-    tile, so the compiler re-laid a whole pool half out around each
-    scatter: read at the parent, `decode` and `prefill` each held 4
-    `copy bf16[28,4097,2,16,128]` and 942.0 / 948.7 MB of temporaries
-    (941.6 MB in the reduced program of ISSUE 25; int8: 4 copies of the
-    codes, 539.5 / 543.3 MB), 11 ms of EVERY call on the chip. Page
-    indices lie outside the tile: the scatter of whole pages is the
-    in-place update, and the temporaries are what the programs hold
-    beside the pool (1.0 / 157.0 MB; int8 69.0 / 173.3 MB).
+def _chat_cell_programs(one_chip, kv_dtype, num_pages=4096):
+    """The engine's own `decode` and `prefill` at Qwen2-1.5B widths and the
+    chat cell's shape (32 slots x 2048, page 16, chunk 256), each with
+    the abstract arguments to lower it with, and the abstract cache.
 
     The engine is built with the smallest pool it accepts and abstract
     weights (nothing of 3 GB is made here); the programs take the pool
-    as an argument, so they are lowered with the chat cell's 4,096
-    pages."""
+    as an argument, so they are lowered with `num_pages` pages."""
     from accelerate_tpu.models import llama
     from accelerate_tpu.serving import Engine, EngineConfig, PagedKVCache
 
-    slots, max_len, page, chunk, num_pages = 32, 2048, 16, 256, 4096
+    slots, max_len, page, chunk = 32, 2048, 16, 256
     cfg = llama.LlamaConfig(
         vocab_size=151936, hidden_size=1536, intermediate_size=8960,
         num_hidden_layers=28, num_attention_heads=12, num_key_value_heads=2,
@@ -194,6 +190,36 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
             arg((), jnp.int32), arg((cache.pages_per_slot,), jnp.int32),
             arg((chunk,), jnp.int32), arg((), jnp.int32))),
     }
+    return programs, cache, chunk * cfg.vocab_size * 4
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
+        one_chip, chip_compile, kv_dtype):
+    """The engine's own `decode` and `prefill`, Qwen2-1.5B widths and the
+    chat cell's shape (32 slots x 2048, page 16, chunk 256, 4,096 pages),
+    compiled for the chip: besides the aliased update itself no operation
+    produces an array of a pool half's shape, and no temporary grows with
+    the pool.
+
+    This test fails on the row scatter `_scatter_rows` had up to PR 24.
+    A row index falls on the second-minor axis of the chip's (8, 128)
+    tile, so the compiler re-laid a whole pool half out around each
+    scatter: read at the parent, `decode` and `prefill` each held 4
+    `copy bf16[28,4097,2,16,128]` and 942.0 / 948.7 MB of temporaries
+    (941.6 MB in the reduced program of ISSUE 25; int8: 4 copies of the
+    codes, 539.5 / 543.3 MB), 11 ms of EVERY call on the chip. Page
+    indices lie outside the tile: the scatter of whole pages is the
+    in-place update, and the temporaries are what the programs hold
+    beside the pool (1.0 / 157.0 MB; int8 69.0 / 173.3 MB).
+
+    Since PR 27 `decode` on a bf16 pool also produces nothing of ONE
+    LAYER's shape: the kernel takes the whole stacked pool and a layer
+    index. At the parent the layer scan sliced `bf16[4097,2,16,128]` out
+    of each half for the kernel's call, twice a layer (2.55 ms a decode
+    step on the chip at 4,096 pages, ~21 ms at 16,384). An int8 pool
+    keeps the older kernel and its four slices (codes and scales)."""
+    programs, cache, logits_bytes = _chat_cell_programs(one_chip, kv_dtype)
     half = cache.k.shape                       # (28, 4097, 2, 16, 128)
     half_bytes = cache.k.size * cache.k.dtype.itemsize
     # what a program may hold beside the pool, from its shapes: a chunk's
@@ -201,7 +227,7 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
     # too) and, on an int8 pool, the two SCALE arrays: [L, pages, H, 16]
     # lies on the chip with the pages on the lanes, so their update still
     # re-lays them out with L there (28 of 128 lanes used), 33.6 MB each
-    beside = {"decode": 0, "prefill": chunk * cfg.vocab_size * 4}
+    beside = {"decode": 0, "prefill": logits_bytes}
     scales = 2 * (half[1] * half[2] * half[3] * 128 * 2) if kv_dtype else 0
     for name, (program, args) in programs.items():
         compiled = program.lower(*args).compile()
@@ -216,9 +242,34 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
         assert (memory.temp_size_in_bytes - beside[name] - scales
                 < half_bytes // 10), (name, memory.temp_size_in_bytes)
         # (c) decode still walks the page table in the kernel (a chunk
-        # attends its slot's gathered view: no kernel in prefill)
+        # attends its slot's gathered view: no kernel in prefill), and on
+        # a bf16 pool nothing slices a layer out of the pool for it
         if name == "decode":
             _assert_kernel_inside(compiled)
+            layer_slices = _ops_of_shape(
+                text, "s8" if kv_dtype else "bf16", half[1:])
+            assert bool(layer_slices) == bool(kv_dtype), layer_slices
+
+
+def test_decode_does_not_know_the_pool_size_on_v5e(one_chip, chip_compile):
+    """`decode` of the chat cell's shape lowered with a pool of 4,096
+    pages and of 16,384: the same temporaries to the byte, and the same
+    operations but for the pool's shape in their types. At the parent the
+    per-layer slices around the kernel's call grew with the pool (two
+    `bf16[pages + 1, 2, 16, 128]` a layer: 16.8 MB at 4,096 pages, 67.1 MB
+    at 16,384)."""
+    seen = {}
+    for num_pages in (4096, 16384):
+        programs, cache, _ = _chat_cell_programs(one_chip, None, num_pages)
+        program, args = programs["decode"]
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        assert not _ops_of_shape(text, "bf16", cache.k.shape[1:])
+        seen[num_pages] = (
+            compiled.memory_analysis().temp_size_in_bytes,
+            len(re.findall(r"^\s+(?:ROOT )?%[\w.-]+ = ", text, re.M)))
+    assert seen[4096] == seen[16384], seen
+    assert seen[4096][0] < 8 << 20, seen
 
 
 def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):

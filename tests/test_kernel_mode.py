@@ -54,7 +54,8 @@ def test_kernel_wrappers_go_through_the_one_place(kernel):
             paged_decode_attention,
         )
 
-        pool = PagedKV(jnp.zeros((3, 2, 8, 16), jnp.float32))
+        pool = PagedKV(jnp.zeros((1, 3, 2, 8, 16), jnp.float32),
+                       layer=jnp.int32(0))
         meta = PagedDecodeMeta(jnp.zeros((1, 2), jnp.int32),
                                jnp.zeros((1,), jnp.int32), rows=16)
         q = jnp.ones((1, 1, 4, 16), jnp.float32)

@@ -1,17 +1,23 @@
-"""Pallas paged-attention decode kernel (ops/paged_attention.py).
+"""Pallas paged-attention decode kernels (ops/paged_attention.py).
 
 Interpret-mode exactness vs the dense-gather reference across the page
 geometry the serving engine actually produces — page-boundary lengths,
 mid-page lengths, GQA head groups, trash-padded table rows, sliding
-windows, reused (stale-content) pages — plus the int8-pool in-kernel
+windows, reused (stale-content) pages — on a STACKED pool whose layers
+all differ, read at a layer other than 0, plus the int8-pool in-kernel
 dequantization and the `PagedKV`/`PagedDecodeMeta` plumbing types the
-family forwards thread."""
+family forwards thread.
+
+`D = 128` is the live-pages kernel (one grid step a slot, grouped page
+copies out of the whole pool); `D = 16` and int8 pools are the older
+page-a-grid-step kernel, given its layer's slice."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from accelerate_tpu.ops import paged_attention as pa
 from accelerate_tpu.ops.paged_attention import (
     PagedDecodeMeta,
     PagedKV,
@@ -21,13 +27,18 @@ from accelerate_tpu.ops.paged_attention import (
 from accelerate_tpu.ops.quant import kv_dequantize_rows, kv_quantize_rows
 
 
-def _setup(seed=0, S=3, P=4, ps=8, Hkv=2, G=3, D=16, num_pages=12,
-           quantized=False, dtype=jnp.float32):
+LIVE, OLDER = 128, 16   # head widths: which kernel a pool is given to
+
+
+def _setup(seed=0, S=3, P=4, ps=8, Hkv=2, G=3, D=LIVE, num_pages=12,
+           quantized=False, dtype=jnp.float32, layers=3, layer=2):
     """A pool + table geometry exercising the engine's corner cases:
     slot 0 mid-page length, slot 1 exactly at a page boundary, slot 2
-    nearly empty with a trash-padded table row."""
+    nearly empty with a trash-padded table row. The pool is stacked,
+    every layer of it different, and read at `layer`."""
     rng = np.random.default_rng(seed)
-    shape = (num_pages + 1, Hkv, ps, D)
+    shape = (layers, num_pages + 1, Hkv, ps, D)
+    layer = jnp.int32(layer)
     pool_k = jnp.asarray(rng.normal(size=shape), dtype)
     pool_v = jnp.asarray(rng.normal(size=shape), dtype)
     table = np.full((S, P), num_pages, np.int32)  # trash-padded
@@ -43,10 +54,10 @@ def _setup(seed=0, S=3, P=4, ps=8, Hkv=2, G=3, D=16, num_pages=12,
     if quantized:
         ck, sk = kv_quantize_rows(pool_k)
         cv, sv = kv_quantize_rows(pool_v)
-        pk = PagedKV(ck, sk, compute_dtype=dtype)
-        pv = PagedKV(cv, sv, compute_dtype=dtype)
+        pk = PagedKV(ck, sk, compute_dtype=dtype, layer=layer)
+        pv = PagedKV(cv, sv, compute_dtype=dtype, layer=layer)
     else:
-        pk, pv = PagedKV(pool_k), PagedKV(pool_v)
+        pk, pv = PagedKV(pool_k, layer=layer), PagedKV(pool_v, layer=layer)
     meta = PagedDecodeMeta(jnp.asarray(table), lengths, rows=P * ps)
     return q, kn, vn, pk, pv, meta
 
@@ -57,12 +68,19 @@ def _assert_close(out, ref, tol=2e-5):
     assert err < tol, f"max err {err}"
 
 
+@pytest.fixture
+def pages_per_group(monkeypatch):
+    """Set the live-pages kernel's group (the module's one constant)."""
+    return lambda n: monkeypatch.setattr(pa, "PAGES_PER_GROUP", n)
+
+
+@pytest.mark.parametrize("D", [LIVE, OLDER], ids=["live", "older"])
 @pytest.mark.parametrize("window", [None, 5, 1000])
-def test_kernel_matches_reference_geometry_matrix(window):
+def test_kernel_matches_reference_geometry_matrix(window, D):
     """Mid-page / page-boundary / trash-padded slots, GQA groups, and
     sliding windows (incl. one wider than the cache = plain causal) all
-    match the dense reference."""
-    q, kn, vn, pk, pv, meta = _setup()
+    match the dense reference, in the pool's layer 2 of 3."""
+    q, kn, vn, pk, pv, meta = _setup(D=D)
     out, (k_row, v_row) = paged_decode_attention(q, kn, vn, pk, pv, meta,
                                                  window=window)
     ref, (rk, rv) = paged_decode_reference(q, kn, vn, pk, pv, meta,
@@ -73,9 +91,78 @@ def test_kernel_matches_reference_geometry_matrix(window):
     assert jnp.array_equal(k_row, rk) and jnp.array_equal(v_row, rv)
 
 
-def test_kernel_matches_reference_single_page_and_single_head():
+@pytest.mark.parametrize("D", [LIVE, OLDER], ids=["live", "older"])
+def test_kernel_reads_the_layer_it_is_given(D):
+    """Every layer of the stacked pool differs: the same call at another
+    layer index gives another answer, each its own reference's. A kernel
+    that ignored `layer` would fail at all but one."""
+    q, kn, vn, pk, pv, meta = _setup(D=D)
+    outs = []
+    for layer in range(pk.data.shape[0]):
+        at = jnp.int32(layer)
+        out, _ = paged_decode_attention(q, kn, vn, pk.at_layer(at),
+                                        pv.at_layer(at), meta)
+        ref, _ = paged_decode_reference(q, kn, vn, pk.at_layer(at),
+                                        pv.at_layer(at), meta)
+        _assert_close(out, ref)
+        outs.append(out)
+    assert float(jnp.max(jnp.abs(outs[0] - outs[1]))) > 1e-2
+    assert float(jnp.max(jnp.abs(outs[1] - outs[2]))) > 1e-2
+    with pytest.raises(ValueError, match="layer index"):
+        paged_decode_attention(q, kn, vn, PagedKV(pk.data), PagedKV(pv.data),
+                               meta)
+    with pytest.raises(ValueError, match="layer index"):  # a layer's slice
+        paged_decode_attention(q, kn, vn, PagedKV(pk.data[0], layer=at),
+                               PagedKV(pv.data[0], layer=at), meta)
+
+
+@pytest.mark.parametrize("group,lengths,window", [
+    (2, (13, 16, 31), None),     # lengths that are no multiple of a group
+    (2, (17, 24, 1), None),      # one row into a group; a group's end
+    (3, (25, 31, 8), None),      # the last group runs past the table's end
+    (64, (13, 16, 31), None),    # a group larger than pages_per_slot
+    (2, (0, 0, 0), None),        # length 0: no group at all
+    (2, (29, 18, 31), 6),        # the window's band crosses a group boundary
+    (1, (29, 18, 31), 11),       # ... and starts some groups into the table
+], ids=["ragged", "edges", "past-table", "group-gt-table", "empty",
+        "window-crossing", "window-late-start"])
+def test_live_pages_kernel_group_geometry(pages_per_group, group, lengths,
+                                          window):
+    """The live-pages kernel's own geometry: groups of `group` pages of 8
+    rows over a table of 4 pages a slot."""
+    pages_per_group(group)
+    q, kn, vn, pk, pv, meta = _setup()
+    meta = PagedDecodeMeta(
+        jnp.asarray([[0, 1, 2, 6], [3, 4, 7, 8], [5, 9, 10, 11]], jnp.int32),
+        jnp.asarray(lengths, jnp.int32), rows=meta.rows)
+    out, _ = paged_decode_attention(q, kn, vn, pk, pv, meta, window=window)
+    ref, _ = paged_decode_reference(q, kn, vn, pk, pv, meta, window=window)
+    _assert_close(out, ref)
+
+
+def test_live_pages_kernel_masked_lanes_with_stale_tables(pages_per_group):
+    """What the engine hands the kernel: lengths masked to 0 on lanes that
+    are not live, whose table rows still point at another tenant's pages.
+    Such a lane attends its own new token only (whatever the pages hold),
+    and the live lanes are untouched by it."""
+    pages_per_group(2)
+    q, kn, vn, pk, pv, meta = _setup()
+    live = jnp.asarray([True, False, True])
+    masked = PagedDecodeMeta(meta.table, jnp.where(live, meta.lengths, 0),
+                             rows=meta.rows)
+    out, _ = paged_decode_attention(q, kn, vn, pk, pv, masked)
+    full, _ = paged_decode_attention(q, kn, vn, pk, pv, meta)
+    _assert_close(out[0], full[0], tol=1e-6)
+    _assert_close(out[2], full[2], tol=1e-6)
+    S, _, H, D = q.shape
+    own = jnp.repeat(vn[:, 0], H // vn.shape[2], axis=1).reshape(S, 1, H, D)
+    _assert_close(out[1], own[1])
+
+
+@pytest.mark.parametrize("D", [LIVE, 8], ids=["live", "older"])
+def test_kernel_matches_reference_single_page_and_single_head(D):
     """Degenerate geometry: one page per slot, MHA (G=1)."""
-    q, kn, vn, pk, pv, meta = _setup(S=2, P=1, ps=4, Hkv=3, G=1, D=8,
+    q, kn, vn, pk, pv, meta = _setup(S=2, P=1, ps=4, Hkv=3, G=1, D=D,
                                      num_pages=4)
     meta = PagedDecodeMeta(meta.table[:2, :1],
                            jnp.asarray([3, 0], jnp.int32), rows=4)
@@ -97,32 +184,52 @@ def test_kernel_length_zero_slot_attends_only_new_token():
     _assert_close(out, expect)
 
 
-def test_kernel_ignores_stale_rows_in_reused_pages():
+@pytest.mark.parametrize("D,slack", [
+    (LIVE, np.inf), (LIVE, np.nan), (OLDER, 900.0)],
+    ids=["live-inf", "live-nan", "older"])
+def test_kernel_ignores_stale_rows_in_reused_pages(D, slack):
     """Rows at or past `length` — stale K/V from a previous tenant of
     the page (slot reuse), or allocation slack — never leak into the
-    output: poisoning them with huge values changes nothing."""
-    q, kn, vn, pk, pv, meta = _setup()
+    output, and neither do the pages behind them: every page a table
+    names past its slot's live pages, and the trash page that backs the
+    padded entries (where retired lanes' dead writes land, never
+    cleaned), hold inf / NaN here and change nothing. The live-pages
+    kernel copies such pages with their group, so it must zero what it
+    masks (0 x inf is NaN); the older kernel never fetches a dead page,
+    and is held to huge finite values in a live page's slack as it
+    always was."""
+    q, kn, vn, pk, pv, meta = _setup(D=D)
     out0, _ = paged_decode_attention(q, kn, vn, pk, pv, meta)
-    ps = pk.data.shape[2]
+    ps = pk.data.shape[3]
     poisoned_k, poisoned_v = np.asarray(pk.data).copy(), np.asarray(
         pv.data).copy()
     table, lengths = np.asarray(meta.table), np.asarray(meta.lengths)
+    live_pages = set()
     for s in range(table.shape[0]):
         for j, page in enumerate(table[s]):
+            if j * ps < lengths[s]:
+                live_pages.add(int(page))
             for r in range(ps):
-                if j * ps + r >= lengths[s]:
-                    poisoned_k[page, :, r] = 900.0
-                    poisoned_v[page, :, r] = -900.0
+                if j * ps <= lengths[s] - 1 < (j + 1) * ps \
+                        and j * ps + r >= lengths[s]:
+                    poisoned_k[:, page, :, r] = slack
+                    poisoned_v[:, page, :, r] = -slack
+    for page in range(pk.data.shape[1]):      # the trash page included
+        if page not in live_pages:
+            poisoned_k[:, page] = np.nan
+            poisoned_v[:, page] = -np.inf
+    assert table.max() == pk.data.shape[1] - 1 not in live_pages
     out1, _ = paged_decode_attention(
-        q, kn, vn, PagedKV(jnp.asarray(poisoned_k)),
-        PagedKV(jnp.asarray(poisoned_v)), meta)
+        q, kn, vn, PagedKV(jnp.asarray(poisoned_k), layer=pk.layer),
+        PagedKV(jnp.asarray(poisoned_v), layer=pk.layer), meta)
     _assert_close(out1, out0, tol=1e-6)
 
 
-def test_kernel_int8_pool_dequantizes_in_kernel():
+@pytest.mark.parametrize("D", [LIVE, OLDER])
+def test_kernel_int8_pool_dequantizes_in_kernel(D):
     """int8 pool: the kernel's in-VMEM dequantization matches the dense
     reference's gather-then-dequantize bit for bit (same math)."""
-    q, kn, vn, pk, pv, meta = _setup(quantized=True)
+    q, kn, vn, pk, pv, meta = _setup(quantized=True, D=D)
     assert pk.data.dtype == jnp.int8
     out, (k_row, v_row) = paged_decode_attention(q, kn, vn, pk, pv, meta)
     ref, _ = paged_decode_reference(q, kn, vn, pk, pv, meta)
@@ -170,6 +277,10 @@ def test_paged_types_are_pytrees_and_meta_add_is_noop():
     bf = PagedKV(pk.data.astype(jnp.bfloat16))
     leaves, treedef = jax.tree_util.tree_flatten(bf)
     assert not jax.tree_util.tree_unflatten(treedef, leaves).quantized
+    # the layer index is a child (it is traced inside the layer scan)
+    at = jax.tree_util.tree_unflatten(*reversed(
+        jax.tree_util.tree_flatten(bf.at_layer(jnp.int32(1)))))
+    assert int(at.layer) == 1 and at.data is bf.data and bf.layer is None
 
 
 def test_kv_quantize_roundtrip_error_bound():

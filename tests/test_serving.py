@@ -699,12 +699,35 @@ def _run_trace(eng, prompts, temps, budget=6):
     return [r.tokens for r in reqs]
 
 
-def test_paged_kernel_decode_token_exact_vs_dense(gpt2_setup):
+def _kernel_setup(kernel, monkeypatch):
+    """A tiny model for each of the two paged decode kernels, chosen by
+    what the op sees: `older` is 16-wide heads (the page-a-grid-step
+    kernel), `live` is 128-wide heads over 2 KV heads of 2 query heads
+    each (the live-pages kernel the Qwen cells serve with), its groups
+    cut to 2 pages so that a slot of these lengths walks several."""
+    if kernel == "older":
+        return llama.LlamaConfig.tiny()
+    from accelerate_tpu.ops import paged_attention
+
+    monkeypatch.setattr(paged_attention, "PAGES_PER_GROUP", 2)
+    cfg = llama.LlamaConfig.tiny(hidden_size=512, num_attention_heads=4,
+                                 num_key_value_heads=2)
+    assert cfg.head_dim == 128
+    return cfg
+
+
+@pytest.mark.parametrize("kernel", ["older-gpt2", "older", "live"])
+def test_paged_kernel_decode_token_exact_vs_dense(gpt2_setup, kernel,
+                                                  monkeypatch):
     """The acceptance bar: decode with paged_attention=True (the Pallas
     kernel, interpret mode on CPU) is token-exact vs the dense-gather
     reference path on the same seeded trace — greedy AND sampled lanes —
     with compile counts still admit/prefill/decode = 1/1/1."""
-    cfg, params = gpt2_setup
+    if kernel == "older-gpt2":
+        family, (cfg, params) = gpt2, gpt2_setup
+    else:
+        family, cfg = llama, _kernel_setup(kernel, monkeypatch)
+        params = llama.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(7)
     prompts = [_prompt(rng, n, cfg.vocab_size) for n in (5, 17, 3)]
     # two shared-prefix prompts ride along so the kernel path is also
@@ -721,8 +744,10 @@ def test_paged_kernel_decode_token_exact_vs_dense(gpt2_setup):
         out = _run_trace(eng, prompts[:4], temps[:4])
         return out + _run_trace(eng, prompts[4:], temps[4:])
 
-    dense = run(_engine(cfg, params, page_size=8, paged_attention=False))
-    eng = _engine(cfg, params, page_size=8, paged_attention=True)
+    dense = run(_engine(cfg, params, family=family, page_size=8,
+                        paged_attention=False))
+    eng = _engine(cfg, params, family=family, page_size=8,
+                  paged_attention=True)
     kernel = run(eng)
     assert kernel == dense
     assert eng.metrics.prefix_hits >= 1
@@ -732,12 +757,17 @@ def test_paged_kernel_decode_token_exact_vs_dense(gpt2_setup):
     assert ctr.value > 0
 
 
-def test_paged_kernel_gqa_and_slot_reuse_token_exact():
+@pytest.mark.parametrize("kernel", ["older", "live"])
+def test_paged_kernel_gqa_and_slot_reuse_token_exact(kernel, monkeypatch):
     """llama's GQA head groups broadcast in-kernel, and reused slots
     (more requests than slots — stale pool rows under fresh tables)
-    stay exact, under strict=error so the kernel-backed decode program
-    passes the full analysis audit with no findings."""
-    cfg = llama.LlamaConfig.tiny()
+    stay exact; the older kernel under strict=error, so the
+    kernel-backed decode program passes the full analysis audit with no
+    findings (the live-pages kernel's interpreter, which has to act out
+    copies and semaphores, calls back into Python from the program: on
+    the CPU the audit would find those calls, and no kernel)."""
+    cfg = _kernel_setup(kernel, monkeypatch)
+    audit = {"strict": "error"} if kernel == "older" else {}
     params = llama.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(8)
     prompts = [_prompt(rng, n, cfg.vocab_size) for n in (6, 13, 9, 4, 11)]
@@ -747,8 +777,62 @@ def test_paged_kernel_gqa_and_slot_reuse_token_exact():
                        prompts, temps)
     kernel = _run_trace(_engine(cfg, params, family=llama, num_slots=2,
                                 page_size=8, paged_attention=True,
-                                strict="error"), prompts, temps)
+                                **audit), prompts, temps)
     assert kernel == dense
+
+
+@pytest.mark.parametrize("window", [None, 12], ids=["causal", "window"])
+def test_live_pages_kernel_token_exact_with_dead_lanes(window):
+    """128-wide heads take the live-pages kernel (one grid step a slot,
+    grouped page copies out of the whole stacked pool, a layer index from
+    the families' shared scan). The engine hands it lengths masked by the
+    decode call's `live` lanes: this trace holds decode calls with a
+    RETIRED lane (a short answer done early, its length stale) and with a
+    MID-PREFILL lane (a long prompt, chunks alternating with decode
+    steps, its length the rows prefilled so far), and stays token-exact
+    against the dense-gather path, greedy and sampled lanes alike."""
+    from accelerate_tpu.serving.scheduler import SlotState
+
+    cfg = llama.LlamaConfig.tiny(hidden_size=256, num_attention_heads=2,
+                                 num_key_value_heads=1,
+                                 sliding_window=window)
+    assert cfg.head_dim == 128
+    params = llama.init_params(cfg, jax.random.key(1))
+    rng = np.random.default_rng(12)
+    first = [(_prompt(rng, 6, cfg.vocab_size), 2, 0.0),     # retires early
+             (_prompt(rng, 11, cfg.vocab_size), 12, 0.7)]
+    late = (_prompt(rng, 29, cfg.vocab_size), 4, 0.0)       # 4 chunks of 8
+
+    def run(eng):
+        dead = set()
+        run_decode = eng._run_decode
+
+        def watched(slots):
+            lengths = np.asarray(eng.cache.lengths)
+            for s in eng.scheduler.slots:
+                if s not in slots and lengths[s.index] > 0:
+                    dead.add(s.state)
+            run_decode(slots)
+
+        eng._run_decode = watched
+        reqs = [eng.submit(p, max_new_tokens=n, temperature=t)
+                for p, n, t in first]
+        for _ in range(5):
+            eng.step()
+        reqs.append(eng.submit(late[0], max_new_tokens=late[1],
+                               temperature=late[2]))
+        eng.run_until_idle()
+        assert all(r.status is RequestStatus.FINISHED for r in reqs)
+        return [r.tokens for r in reqs], dead
+
+    dense, _ = run(_engine(cfg, params, family=llama, page_size=8,
+                           paged_attention=False))
+    eng = _engine(cfg, params, family=llama, page_size=8,
+                  paged_attention=True)
+    kernel, dead = run(eng)
+    assert kernel == dense
+    assert dead == {SlotState.IDLE, SlotState.PREFILL}
+    assert eng.compile_stats() == {"admit": 1, "prefill": 1, "decode": 1}
 
 
 def test_compile_flat_across_kernel_and_int8_mixes(gpt2_setup):
