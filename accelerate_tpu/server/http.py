@@ -14,7 +14,7 @@ the server too, and an inference front door needs exactly these routes:
                                  read-only live introspection, gated by
                                  ServerConfig(debug_endpoints=True)
     GET  /debug/pod              role/router state when the engine is a
-                                 serving.pod.PodEngine (404 on a single
+                                 pod router (serving.pod; 404 on a single
                                  engine, and — like every /debug route —
                                  for every method when the gate is off)
     GET  /debug/profile?duration_s=N[&logdir=D]

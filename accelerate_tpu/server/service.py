@@ -152,7 +152,7 @@ class InferenceService:
         if section == "scheduler":
             return self.engine.debug_scheduler()
         if section == "pod":
-            # only a pod-backed engine (serving.pod.PodEngine) has role/
+            # only a pod router (serving.pod.PodRouter) has role/
             # router state; on a single engine the route 404s like any
             # unknown section
             build = getattr(self.engine, "debug_pod", None)

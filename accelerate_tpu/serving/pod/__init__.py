@@ -8,21 +8,21 @@ Two composable layers over the continuous-batching engine:
   compile count stays flat. `sharded_engine(...)` is the factory;
   `EngineConfig(mesh=...)` is the knob it turns.
 
-- **Layer 2 (MPMD, `pod.router` / `pod.transfer`)** — prefill and
-  decode split into dedicated worker groups shipping KV pages:
-  `PodRouter` (alias `PodEngine`) exposes the ordinary `ServingEngine`
-  API over the fleet, with role assignment, page-transfer bookkeeping,
-  and decode-side backpressure handled host-side.
+- **Layer 2 (MPMD, `pod.distributed` / `pod.transfer`)** — prefill and
+  decode split into dedicated worker groups shipping KV pages behind the
+  ordinary `ServingEngine` API: ONE router, `DistributedPodRouter`
+  (alias `PodRouter`), over two transports. `local`: in-process workers
+  the router pumps itself — `PodEngine(family, config, params,
+  engine_config, PodConfig(...))` builds one (it is
+  `build_local_distributed_pod`), deterministic and optionally
+  mesh-sharded (layer 1 under layer 2). `socket`: `pod-worker` OS
+  processes dialing the router over TCP. Both run the same wire format,
+  heartbeats, re-prefill-from-prompt recovery and role bookkeeping;
+  `PodConfig` is `DistributedPodConfig`.
 
-- **Layer 3 (multi-host, `pod.distributed`)** — the same dataflow over
-  OS processes: a socket wire format for shipments, worker heartbeats,
-  re-prefill-from-prompt failure recovery, and elastic role
-  rebalancing. `DistributedPodRouter` is the front; `PodRouter` stays
-  the in-process `local` transport.
-
-All layers are proven token-exact against the single-device engine on
+Both layers are proven token-exact against the single-device engine on
 seeded traces (tier-1, forced-host-device CPU meshes). See
-docs/serving.md "Pod-scale serving" and "True multi-host pod".
+docs/serving.md "Pod-scale serving".
 """
 
 from .mesh import (
@@ -31,7 +31,6 @@ from .mesh import (
     sharded_engine,
     tensor_mesh,
 )
-from .router import PodConfig, PodEngine, PodRouter
 from .transfer import KVPageShipment, PageTransport, place_shipment
 
 __all__ = [
@@ -48,12 +47,22 @@ __all__ = [
 ]
 
 
+# the pod's short names are the one implementation's: no second class
+# behind them
+_ALIASES = {
+    "PodConfig": "DistributedPodConfig",
+    "PodRouter": "DistributedPodRouter",
+    "PodEngine": "build_local_distributed_pod",
+}
+
+
 def __getattr__(name):
-    # layer 3 is import-heavy (sockets/threads) and optional for layer
-    # 1/2 users — load it lazily on first touch
-    if name in ("DistributedPodConfig", "DistributedPodRouter",
-                "WorkerHandle", "build_local_distributed_pod"):
+    # the router package is import-heavy (sockets/threads) and optional
+    # for layer-1 users — load it lazily on first touch
+    target = _ALIASES.get(name, name)
+    if target in ("DistributedPodConfig", "DistributedPodRouter",
+                  "WorkerHandle", "build_local_distributed_pod"):
         from . import distributed
 
-        return getattr(distributed, name)
+        return getattr(distributed, target)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

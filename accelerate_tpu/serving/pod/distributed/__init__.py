@@ -1,8 +1,6 @@
-"""Layer 3: the pod across OS processes — wire transport, worker
-heartbeats + failure recovery, elastic prefill/decode rebalancing.
-
-PR 9's in-process `PodRouter` stays the `local` transport; this package
-is the same dataflow over real process boundaries:
+"""The pod's router, workers and transports: one dataflow, in one
+process or across OS processes — wire format, worker heartbeats +
+failure recovery, elastic prefill/decode rebalancing.
 
 - `wire` — length-prefixed frames (JSON header + raw numpy buffers, no
   pickle) carrying the existing fixed-shape `KVPageShipment`
@@ -16,9 +14,10 @@ is the same dataflow over real process boundaries:
 - `droute` — `DistributedPodRouter`: the `ServingEngine`-API front that
   holds no device state, recovers every failure by
   re-prefill-from-prompt (byte-exact via position-folded sampling
-  keys), and converts idle workers between roles from live load.
+  keys), and converts idle workers between roles from live load;
+  `build_local_distributed_pod` is its in-process (`local`) form.
 
-See docs/serving.md "True multi-host pod".
+See docs/serving.md "Pod-scale serving".
 """
 
 from .droute import (

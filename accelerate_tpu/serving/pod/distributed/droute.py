@@ -1,19 +1,23 @@
-"""DistributedPodRouter: the multi-host pod front, behind the same API.
+"""DistributedPodRouter: THE pod router, one class over two transports.
 
-PR 9's `PodRouter` proved the disaggregated dataflow (prefill workers
-produce KV page shipments, decode workers own slots) inside one process.
-This router runs the SAME dataflow over channels: workers are separate
-OS processes reached through `SocketChannel`s (or in-process
-`WorkerServer`s over `LocalChannel`s — the deterministic test form), and
-the router holds no model, no params, no device state — it is pure
-bookkeeping plus the user-facing scheduler, which is exactly what lets
-it survive any worker dying.
+Prefill workers turn prompts into KV page shipments and a first token,
+decode workers own slots and stream tokens (the MPMD split of arxiv
+2412.14374; the hand-off is serving/pod/transfer.py). This router runs
+that dataflow over channels: `socket` workers are separate OS processes
+reached through `SocketChannel`s, `local` workers are in-process
+`WorkerServer`s over `LocalChannel`s that the router pumps itself
+(`build_local_distributed_pod`, exported as `PodEngine` — deterministic
+under a fake clock, and the form tier-1 pins byte-exact against a single
+engine). Either way the router holds no model, no params, no device
+state — it is pure bookkeeping plus the user-facing scheduler behind the
+ordinary `ServingEngine` API, which is exactly what lets it survive any
+worker dying.
 
 Exactness is inherited, not engineered: sampling keys fold the request
 key with the ABSOLUTE position (`engine.sample_slot`), so token `i` of a
 request is a pure function of (params, prompt, key, position) — the same
-schedule-independence that made the in-process pod byte-identical to the
-single engine makes the process boundary invisible, and makes recovery a
+schedule-independence that makes the pod byte-identical to the single
+engine makes the process boundary invisible, and makes recovery a
 replay: re-prefilling `prompt + delivered_tokens` with the original key
 samples its "first token" at position `prompt_len + d`, which IS token
 `d` of the original stream. Delivered tokens stand; the continuation is
@@ -30,7 +34,7 @@ Failure model (every path funnels into `_replay_flight`):
 - worker refuses an install -> `install_refused`; worker kills an
   internal -> `worker_drop`; each replay bumps `attempt`, so stale
   messages from superseded attempts are recognized and dropped
-- a flight that exhausts `max_attempts` is shed with the PR 9 shed
+- a flight that exhausts `max_attempts` is shed with the engine's shed
   vocabulary (`SHED_WORKER_DROP` + retry_after) instead of looping
 
 Every recovery appends a `recovery_log` entry with its shed-code-style
@@ -47,10 +51,15 @@ dead zone, so it cannot flap) and bounded to one conversion per
 a role has NO alive workers, any alive worker takes its work — a pod
 reduced to one surviving worker keeps serving.
 
-Backpressure is unchanged from PR 9: the router's pending-shipment
-buffer is bounded (`_assign_prefill` stops feeding when full) and
-`SocketChannel.send` blocks on a full send queue — the decode side
-stalls the ROUTER, never a prefill worker.
+Backpressure: the router's pending-shipment buffer is bounded
+(`_assign_prefill` stops feeding when full) and `SocketChannel.send`
+blocks on a full send queue — the decode side stalls the ROUTER, never a
+prefill worker.
+
+Placement is by load, except that a shipment prefers a decode worker
+whose radix tree already holds its prompt's prefix. The router can only
+ask a tree it can see (`handle.local`), so in-process pods place by
+affinity and socket pods by load alone (ROADMAP D13).
 """
 
 from __future__ import annotations
@@ -80,8 +89,12 @@ from ...engine import (
 )
 from ...metrics import ServingMetrics
 from ...sanitizer import check_distributed_router, resolve_sanitize
-from ...scheduler import Request, RequestStatus, SHED_WORKER_DROP
-from ..router import _FrontScheduler
+from ...scheduler import (
+    Request,
+    RequestStatus,
+    Scheduler,
+    SHED_WORKER_DROP,
+)
 from ..transfer import KVPageShipment
 from .transport import Channel, ChannelListener
 from .wire import (
@@ -107,12 +120,25 @@ RECOVER_GAVE_UP = "gave_up"
 
 @dataclasses.dataclass(frozen=True)
 class DistributedPodConfig:
-    """Knobs for the multi-host pod front (`PodConfig`'s distributed
-    sibling). Timeouts are generous by default — CPU-test prefills are
-    slow; production tightens them."""
+    """Role split, transfer and recovery knobs of a pod (exported as
+    `PodConfig` too). Timeouts are generous by default — CPU-test
+    prefills are slow; production tightens them.
+
+    `prefill_slots` and `tensor_parallel` shape the workers that
+    `build_local_distributed_pod` builds in this process: prefill slot
+    tables of their own size (None = the engine config's num_slots,
+    which decode workers always use), and every worker mesh-sharded over
+    that many devices. A socket `pod-worker` sizes and shards its own
+    engine from its spec and reads neither.
+    `max_pending_shipments` bounds the prefill->decode buffer: when full
+    the router stops assigning new prompts to prefill workers — the
+    backpressure valve (None = one full decode worker's worth of slots,
+    floor 2)."""
 
     prefill_workers: int = 1
     decode_workers: int = 1
+    prefill_slots: int | None = None
+    tensor_parallel: int = 1
     max_pending_shipments: int | None = None
     heartbeat_interval_s: float = 0.25
     heartbeat_timeout_s: float = 5.0
@@ -120,7 +146,9 @@ class DistributedPodConfig:
     # heartbeats -> the message (not the worker) was lost: replay
     flight_timeout_s: float = 60.0
     max_attempts: int = 5
-    rebalance: bool = True
+    # None = on for a socket pod, off for an in-process one (its workers
+    # share one host's devices, and its tests read workers by role)
+    rebalance: bool | None = None
     rebalance_window_s: float = 10.0
     occupancy_high: float = 0.85
     occupancy_low: float = 0.25
@@ -140,7 +168,13 @@ class DistributedPodConfig:
 
     def __post_init__(self):
         if self.prefill_workers < 1 or self.decode_workers < 1:
-            raise ValueError("a pod needs at least one worker per role")
+            raise ValueError(
+                "a pod needs at least one worker per role (got "
+                f"prefill={self.prefill_workers}, "
+                f"decode={self.decode_workers})")
+        if self.tensor_parallel < 1:
+            raise ValueError(
+                f"tensor_parallel must be >= 1, got {self.tensor_parallel}")
         if not (0.0 <= self.occupancy_low < self.occupancy_high <= 1.0):
             raise ValueError(
                 "rebalance bands must satisfy 0 <= low < high <= 1 (got "
@@ -196,8 +230,28 @@ class _DFlight:
     #                                   (a replay span links its original)
 
 
+class _FrontScheduler(Scheduler):
+    """The pod's user-facing admission queue: the whole tenant/tier/DRR/
+    SLO policy of the base scheduler with ZERO slots of its own — the
+    router pops requests in policy order and places them on workers, so
+    `live_slots`/`running` report the router's in-flight set (the server
+    drive loop and drain path read these)."""
+
+    def __init__(self, router: "DistributedPodRouter", **kwargs):
+        super().__init__(num_slots=0, **kwargs)
+        self._router = router
+
+    @property
+    def live_slots(self) -> int:  # type: ignore[override]
+        return len(self._router._flights)
+
+    def running(self):
+        return [f.user for f in self._router._flights.values()]
+
+
 class DistributedPodRouter:
-    """Multi-host pod front behind the `ServingEngine` API."""
+    """The pod front behind the `ServingEngine` API (exported as
+    `PodRouter` too): see the module docstring."""
 
     def __init__(
         self,
@@ -207,7 +261,10 @@ class DistributedPodRouter:
         listener: ChannelListener | None = None,
     ):
         self.engine_config = ec = engine_config or EngineConfig()
-        self.pod_config = pc = pod_config or DistributedPodConfig()
+        pc = pod_config or DistributedPodConfig()
+        if pc.rebalance is None:
+            pc = dataclasses.replace(pc, rebalance=True)
+        self.pod_config = pc
         self._clock = clock
         self.listener = listener
         self._unclaimed: list[Channel] = []
@@ -226,6 +283,7 @@ class DistributedPodRouter:
         # (queue pressure exists before decode occupancy can) would
         # reshape the pod before it ever ran its configured shape
         self._last_rebalance = self._clock()
+        self._stepped_at: float | None = None
         self.last_step_worked = False
         self.recovery_log: deque[dict] = deque(maxlen=256)
 
@@ -238,6 +296,7 @@ class DistributedPodRouter:
         self._c_shipments = reg.counter("serving_pod_shipments_total")
         self._c_pages_shipped = reg.counter("serving_pod_pages_shipped_total")
         self._c_stalls = reg.counter("serving_pod_backpressure_stalls_total")
+        self._c_affinity = reg.counter("serving_pod_affinity_hits_total")
         self._c_lost = reg.counter("serving_pod_worker_lost_total")
         self._c_recovered = reg.counter("serving_pod_worker_recovered_total")
         self._c_replayed = reg.counter("serving_pod_requests_replayed_total")
@@ -282,10 +341,13 @@ class DistributedPodRouter:
                         local: "WorkerServer | None" = None) -> WorkerHandle:
         """Attach a worker the router already knows the identity of
         (in-process factories, pre-spawned CLI workers). Socket workers
-        that dial the listener instead self-identify via `hello`."""
+        that dial the listener instead self-identify via `hello`. An
+        in-process worker is alive by construction, so the first submit
+        is assigned eagerly, like a single engine's."""
         handle = WorkerHandle(
             worker_id=int(worker_id), channel=channel, role=role,
             slots=slots if slots is not None else self.engine_config.num_slots,
+            alive=local is not None,
             last_heartbeat=self._clock(), local=local)
         self.workers[handle.worker_id] = handle
         return handle
@@ -425,6 +487,14 @@ class DistributedPodRouter:
         if self.watchdog is not None:
             self.watchdog.tick()
         t0 = self._clock()
+        if self._stepped_at is not None:
+            # an in-process worker speaks only when this loop pumps it:
+            # the time the caller spent between steps is not its silence
+            # (an idle server must not lose its whole pod to the first
+            # step after the lull)
+            for handle in self.workers.values():
+                if handle.local is not None:
+                    handle.last_heartbeat += t0 - self._stepped_at
         self.scheduler.shed_expired(t0)
         for victim in self.scheduler.drain_shed():
             self._finalize(victim)
@@ -439,7 +509,7 @@ class DistributedPodRouter:
             if handle.local is not None and not handle.lost:
                 worked = handle.local.run_once() or worked
         self._update_gauges()
-        self.metrics.stopped_at = self._clock()
+        self.metrics.stopped_at = self._stepped_at = self._clock()
         if worked:
             self.scheduler.note_step_time(self.metrics.stopped_at - t0)
             live = len([f for f in self._flights.values()
@@ -897,19 +967,48 @@ class DistributedPodRouter:
     def _worker_load(self, wid: int) -> int:
         return sum(1 for f in self._flights.values() if f.worker == wid)
 
-    def _pick_worker(self, role: str) -> WorkerHandle | None:
-        best, best_cap = None, 0
+    def _pick_worker(self, role: str,
+                     prompt: np.ndarray | None = None) -> WorkerHandle | None:
+        """The worker of the role's pool with the most free slots; for a
+        shipment (`prompt` given) a worker's `_placement_hint` outranks
+        emptiness. None when every candidate is full."""
+        best, best_key = None, None
         for h in self._role_pool(role):
             cap = h.slots - self._worker_load(h.worker_id)
-            if cap > best_cap:
-                best, best_cap = h, cap
+            if cap <= 0:
+                continue
+            key = self._placement_hint(h, prompt) + (cap,)
+            if best_key is None or key > best_key:
+                best, best_key = h, key
         return best
+
+    @staticmethod
+    def _placement_hint(handle: WorkerHandle,
+                        prompt: np.ndarray | None) -> tuple[int, int]:
+        """(prefix residency, pages free) of a worker whose engine the
+        router can see. Prefix affinity: a worker whose radix tree
+        already holds this prompt's prefix turns the shipment's leading
+        pages into a local hit (HBM: free; host tier: one swap-in's
+        worth of reserve, and the shipment bytes overwrite the reserved
+        pages value-exactly, so the mirror is just dropped). HBM
+        residency outranks host, residency outranks emptiness, ties fall
+        to the emptiest pool. `residency_probe` never touches LRU order
+        — probing every worker must not manufacture recency for the
+        losers. A socket worker scores (0, 0) and places by load alone."""
+        if handle.local is None or prompt is None:
+            return 0, 0
+        allocator = handle.local.engine.allocator
+        hbm = host = 0
+        if allocator.index is not None:
+            hbm, host = allocator.index.residency_probe(prompt)
+        return 2 * hbm + host, allocator.pages_free
 
     def _assign_prefill(self) -> bool:
         """Replay queue first (recovery outranks fresh admissions — the
         user already has a live stream), then the front queue in policy
-        order. Stops at the pending-shipment bound: same backpressure
-        valve as PR 9."""
+        order. Stops at the pending-shipment bound: a full buffer means
+        the decode side owes us capacity, and prefilling further prompts
+        would only pile pages up."""
         worked = False
         now = self._clock()
         while True:
@@ -965,8 +1064,9 @@ class DistributedPodRouter:
                 prompt = user.prompt
             # budget 2 keeps the worker's internal RUNNING past its first
             # token so pages are still mapped at extract — unless the
-            # prompt is one short of max_len (PR 9's rule, re-applied to
-            # the REPLAY length)
+            # prompt (the REPLAY length) is one short of max_len, where
+            # budget 1 is forced and the harvest relies on
+            # extract-before-next-step
             budget = 2 if len(prompt) + 2 <= self.engine_config.max_len \
                 else 1
             try:
@@ -1002,20 +1102,22 @@ class DistributedPodRouter:
 
     def _forward_pending(self) -> bool:
         """Land pending shipments on decode workers, strictly FIFO (no
-        skip-ahead, PR 9's rule). The bounded channel send queue is the
-        transport half of backpressure; this loop's stall counter is the
-        router half — at most one increment per step."""
+        skip-ahead: a big request must not starve behind luckier small
+        ones). The bounded channel send queue is the transport half of
+        backpressure; this loop's stall counter is the router half — at
+        most one increment per step, so it counts stalled steps, not
+        client submit attempts."""
         worked = False
         while self._pending:
             flight = self._flights.get(self._pending[0])
             if flight is None or flight.user.done:
                 self._pending.popleft()
                 continue
-            handle = self._pick_worker("decode")
+            shipment = flight.shipment
+            handle = self._pick_worker("decode", shipment.prompt)
             if handle is None:
                 self._c_stalls.inc()
                 break
-            shipment = flight.shipment
             try:
                 handle.channel.send(shipment_to_message(
                     shipment, flight_id=flight.flight_id,
@@ -1035,6 +1137,8 @@ class DistributedPodRouter:
             #                            bounded; a lost shipment replays
             self._c_shipments.inc()
             self._c_pages_shipped.inc(shipment.n_prompt_pages)
+            if self._placement_hint(handle, shipment.prompt)[0] > 0:
+                self._c_affinity.inc()
             if flight.user.trace_sampled:
                 # extracted_at was stamped on the PREFILL worker's clock:
                 # rebase it into router time so the transfer span doesn't
@@ -1125,13 +1229,17 @@ class DistributedPodRouter:
             self._g_occupancy[role].set(live / max(1, cap))
 
     def compile_stats(self) -> dict[str, int]:
-        """Per-program compile counts as reported by worker heartbeats,
-        aggregated as the MAX per program across workers — flat per
-        program is still the pod's recompile guard."""
+        """Per-program compile counts, aggregated as the MAX per program
+        across workers — flat per program is the pod's recompile guard
+        (a single worker creeping means its sharding layout lost its
+        fixed point). An in-process worker is read directly; a socket
+        worker's counts are its last heartbeat's."""
         out = {"admit": 0, "prefill": 0, "decode": 0, "extract": 0,
                "install": 0}
         for h in self.workers.values():
-            for k, v in (h.compiles or {}).items():
+            compiles = (h.local.compile_stats() if h.local is not None
+                        else h.compiles or {})
+            for k, v in compiles.items():
                 out[k] = max(out.get(k, 0), int(v))
         return out
 
@@ -1142,6 +1250,7 @@ class DistributedPodRouter:
         out["pod_shipments"] = float(self._c_shipments.value)
         out["pod_pages_shipped"] = float(self._c_pages_shipped.value)
         out["pod_backpressure_stalls"] = float(self._c_stalls.value)
+        out["pod_affinity_hits"] = float(self._c_affinity.value)
         out["pod_workers_lost"] = float(self._c_lost.value)
         out["pod_workers_recovered"] = float(self._c_recovered.value)
         out["pod_requests_replayed"] = float(self._c_replayed.value)
@@ -1242,13 +1351,17 @@ class DistributedPodRouter:
         }
 
     def debug_pod(self) -> dict:
+        """Role/router state for the `/debug/pod` route: who holds what,
+        how full the shipment buffer is, whether backpressure or
+        recovery has been biting. Read-only, JSON-safe."""
         phases: dict[str, int] = {}
         for f in self._flights.values():
             phases[f.phase] = phases.get(f.phase, 0) + 1
         now = self._clock()
-        return {
-            "workers": [{
-                "worker_id": h.worker_id, "role": h.role,
+        roles: dict[str, list] = {"prefill": [], "decode": []}
+        for h in self.workers.values():
+            roles.setdefault(h.role, []).append({
+                "worker": h.worker_id,
                 "alive": h.alive, "lost": h.lost, "draining": h.draining,
                 "busy": h.busy, "pid": h.pid,
                 "slots": h.slots,
@@ -1263,12 +1376,18 @@ class DistributedPodRouter:
                                       if h.last_span_at is not None
                                       else None),
                 "stats": h.stats, "compiles": h.compiles,
-            } for h in self.workers.values()],
+            })
+        return {
+            "roles": roles,
+            "tensor_parallel": self.pod_config.tensor_parallel,
             "in_flight": phases,
             "queued": self.scheduler.queue_depth,
             "pending_shipments": len(self._pending),
             "replay_queue": len(self._replay),
             "max_pending_shipments": self._max_pending,
+            "shipments_total": int(self._c_shipments.value),
+            "pages_shipped_total": int(self._c_pages_shipped.value),
+            "backpressure_stalls_total": int(self._c_stalls.value),
             "workers_lost_total": int(self._c_lost.value),
             "workers_recovered_total": int(self._c_recovered.value),
             "requests_replayed_total": int(self._c_replayed.value),
@@ -1286,11 +1405,15 @@ class DistributedPodRouter:
         } for h in self.workers.values()]
 
     def debug_pages(self) -> dict:
-        return {str(h.worker_id): {
-            "role": h.role, "alive": h.alive,
-            "pages_free": (h.stats or {}).get("pages_free"),
-            "pages_in_use": (h.stats or {}).get("pages_in_use"),
-        } for h in self.workers.values()}
+        return {
+            "workers": [{
+                "worker": h.worker_id, "role": h.role, "alive": h.alive,
+                "pages_free": (h.stats or {}).get("pages_free"),
+                "pages_in_use": (h.stats or {}).get("pages_in_use"),
+            } for h in self.workers.values()],
+            "pages_shipped": int(self._c_pages_shipped.value),
+            "pending_shipments": len(self._pending),
+        }
 
     def debug_scheduler(self) -> dict:
         out = self.scheduler.debug_state()
@@ -1446,7 +1569,7 @@ class DistributedPodRouter:
 
 
 # ---------------------------------------------------------------------------
-# in-process factory (the deterministic `local` distributed form)
+# in-process factory (the deterministic `local` transport)
 # ---------------------------------------------------------------------------
 
 
@@ -1456,44 +1579,63 @@ def build_local_distributed_pod(
     pod_config: DistributedPodConfig | None = None,
     clock=time.monotonic,
     channel_wrap=None,
-):
-    """Router + in-process `WorkerServer`s over `LocalChannel` pairs —
-    every message still crosses the wire codec, the clock can be fake,
-    and the router pumps the workers itself, so the whole distributed
+) -> DistributedPodRouter:
+    """The in-process pod (exported as `PodEngine`): a router plus
+    `WorkerServer`s over `LocalChannel` pairs, constructed like an
+    `Engine` plus a pod config. Every message still crosses the wire
+    codec, the clock can be fake, and the router pumps the workers
+    itself in `step()` (`router.workers[wid].local`), so the whole
     protocol (heartbeats, recovery, rebalancing) runs deterministically
     in one interpreter. `channel_wrap(worker_id, role, channel)` may
     wrap the ROUTER-side endpoint (e.g. with `FlakyTransport`).
 
-    Returns (router, workers)."""
+    What differs from a socket pod lives here: roles stay fixed unless
+    the config asks for rebalancing, prefill workers may have a slot
+    table of their own size, and `tensor_parallel` > 1 (or an
+    `EngineConfig.mesh`) shards EVERY worker over one shared mesh with
+    ONE placed copy of the params — a real pod gives each worker its own
+    slice and its own copy."""
     from ...engine import Engine
+    from ..mesh import shard_params, tensor_mesh
     from .transport import LocalChannel
 
     ec = engine_config or EngineConfig()
     pc = pod_config or DistributedPodConfig()
+    if pc.rebalance is None:
+        pc = dataclasses.replace(pc, rebalance=False)
+    mesh = ec.mesh
+    if mesh is None and pc.tensor_parallel > 1:
+        mesh = tensor_mesh(pc.tensor_parallel)
+    if mesh is not None:
+        params = shard_params(params, mesh)
+    # workers own no observability side-cars (the router is the one
+    # exporter/watchdog surface) and no tenants (admission policy is the
+    # front queue's). speculative is stripped: a spec worker's
+    # five-program surface doesn't match the extract/install protocol
+    # (the install path drives the classic admit program directly)
     worker_ec = dataclasses.replace(
-        ec, tenants=None, metrics_port=None, watchdog_timeout_s=None,
-        incident_dir=None, speculative=None)
+        ec, mesh=mesh, tenants=None, metrics_port=None,
+        watchdog_timeout_s=None, incident_dir=None, speculative=None)
+    prefill_ec = dataclasses.replace(
+        worker_ec, num_slots=pc.prefill_slots or ec.num_slots)
     router = DistributedPodRouter(
         engine_config=ec, pod_config=pc, clock=clock)
-    workers = []
-    wid = 0
-    for role, count in (("prefill", pc.prefill_workers),
-                        ("decode", pc.decode_workers)):
-        for _ in range(count):
-            router_side, worker_side = LocalChannel.pair()
-            if channel_wrap is not None:
-                router_side = channel_wrap(wid, role, router_side)
-            engine = Engine(family, config, params, worker_ec, clock=clock)
-            engine.close()   # heartbeats are the worker's only exporter
-            server = WorkerServer(
-                engine, worker_side, worker_id=wid, role=role,
-                heartbeat_interval_s=pc.heartbeat_interval_s, clock=clock,
-                # in-process workers share the router's span ring —
-                # exporting over the wire would double every span
-                export_spans=False)
-            router.register_worker(router_side, wid, role,
-                                   slots=len(engine.scheduler.slots),
-                                   local=server)
-            workers.append(server)
-            wid += 1
-    return router, workers
+    roles = ["prefill"] * pc.prefill_workers + ["decode"] * pc.decode_workers
+    for wid, role in enumerate(roles):
+        router_side, worker_side = LocalChannel.pair()
+        if channel_wrap is not None:
+            router_side = channel_wrap(wid, role, router_side)
+        engine = Engine(family, config, params,
+                        prefill_ec if role == "prefill" else worker_ec,
+                        clock=clock)
+        engine.close()   # stop any env-armed exporter/watchdog side-cars
+        server = WorkerServer(
+            engine, worker_side, worker_id=wid, role=role,
+            heartbeat_interval_s=pc.heartbeat_interval_s, clock=clock,
+            # in-process workers share the router's span ring —
+            # exporting over the wire would double every span
+            export_spans=False)
+        router.register_worker(router_side, wid, role,
+                               slots=len(engine.scheduler.slots),
+                               local=server)
+    return router

@@ -65,6 +65,7 @@ from ....telemetry.trace import (
     record_span,
     tracing_enabled,
 )
+from ...sanitizer import check_pod_worker, resolve_sanitize
 from ...scheduler import RequestStatus
 from ..transfer import PageTransport, place_shipment
 from .transport import Channel
@@ -176,9 +177,12 @@ class WorkerServer:
         # two middle timestamps of the NTP exchange the router completes
         self._last_ack: dict | None = None
         self._last_step_s = 0.0
-        # the admit hook mirrors PodRouter._record_admit: a short prompt
-        # can admit, prefill and retire inside ONE engine.step(), and the
-        # alloc dies with the slot — snapshot pages the moment they exist
+        self._sanitize = resolve_sanitize(engine.engine_config.sanitize)
+        # hook the engine's admission (Engine.on_admit): the page
+        # allocation must be snapshotted the instant it exists — a short
+        # prompt can admit, prefill and retire inside ONE engine.step(),
+        # and the alloc dies with the slot (the page *content* survives
+        # until the next admission, which is the window extract uses)
         engine.on_admit = self._record_admit
         self._send(Message("hello", {
             "worker_id": self.worker_id, "role": self.role,
@@ -189,7 +193,14 @@ class WorkerServer:
     # -- plumbing ------------------------------------------------------------
 
     def _record_admit(self, slot, req) -> None:
+        # this engine serves only the router's internals, so recording
+        # every admission is recording ours — also one that happens
+        # inside `engine.submit`, before the job exists
         self._admit_pages[id(req)] = list(slot.alloc.pages)
+
+    def compile_stats(self) -> dict[str, int]:
+        return {**self.engine.compile_stats(),
+                **self.transport.compile_stats()}
 
     def _send(self, msg: Message) -> None:
         try:
@@ -331,11 +342,12 @@ class WorkerServer:
     # -- outbound ------------------------------------------------------------
 
     def _harvest_prefill(self) -> None:
-        """Ship every prefill job whose first token exists (mirror of
-        PodRouter._harvest, result crossing the channel instead of a
-        deque). Extraction happens HERE, before the engine steps again —
-        a retired slot's pages are only reallocatable at the next
-        admission, which cannot happen before the next step."""
+        """Ship every prefill job whose first token exists: the pages
+        and the first token cross the channel, and the router delivers
+        that token (TTFT lands there). Extraction happens HERE, before
+        the engine steps again — a retired slot's pages are only
+        reallocatable at the next admission, which cannot happen before
+        the next step."""
         now = self._clock()
         for job in list(self._jobs.values()):
             if job.mode != "prefill":
@@ -466,8 +478,7 @@ class WorkerServer:
                 "pages_free": eng.allocator.pages_free,
                 "pages_in_use": eng.allocator.pages_in_use,
             },
-            "compiles": {**eng.compile_stats(),
-                         **self.transport.compile_stats()},
+            "compiles": self.compile_stats(),
             # the registry snapshot IS the telemetry merge payload:
             # counters/gauges/sketches aggregate router-side without a
             # jax process group (telemetry/aggregate.py)
@@ -517,6 +528,8 @@ class WorkerServer:
             worked = True
         self._harvest_prefill()
         self._sync_decode()
+        if self._sanitize:
+            check_pod_worker(self)
         self._maybe_heartbeat()
         if self.draining and not self._jobs \
                 and not self.engine.scheduler.has_work():

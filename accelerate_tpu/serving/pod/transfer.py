@@ -34,8 +34,8 @@ before the router reclaimed the slot) are masked by the position
 invariant and overwritten by the decode worker's own appends.
 
 `KVPageShipment` is deliberately plain host data (numpy + ints): it IS
-the wire format. In-process pods hand the arrays over directly; a
-multi-host pod serializes exactly these fields.
+the wire format — `distributed/wire.py` serializes exactly these fields,
+for in-process workers too.
 """
 
 from __future__ import annotations
@@ -255,9 +255,8 @@ def place_shipment(engine, transport: PageTransport, shipment: KVPageShipment,
     ``(internal, slot, alloc)`` or ``None`` when the engine has no free
     slot or pages right now (nothing mutated on None).
 
-    This is the single placement path shared by the in-process
-    `PodRouter._try_install` and the multi-host worker's `install`
-    handler — the process boundary must not fork the landing semantics.
+    The pod worker's `shipment` handler is its caller, whichever
+    transport the shipment arrived over.
     """
     from ..scheduler import Request
 

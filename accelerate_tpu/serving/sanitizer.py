@@ -50,7 +50,7 @@ import numpy as np
 from .scheduler import RequestStatus, SlotState
 
 __all__ = ["SanitizerViolation", "resolve_sanitize", "check_engine",
-           "check_router", "check_distributed_router"]
+           "check_pod_worker", "check_distributed_router"]
 
 SANITIZE_ENV = "ACCELERATE_TPU_SANITIZE"
 
@@ -58,7 +58,7 @@ SANITIZE_ENV = "ACCELERATE_TPU_SANITIZE"
 class SanitizerViolation(RuntimeError):
     """One broken cross-structure invariant. `check` is the stable
     invariant name (page-conservation, refcount, table, lengths,
-    scheduler-books, router-books); `details` is a JSON-safe dict that
+    scheduler-books, worker-books, droute-books); `details` is a JSON-safe dict that
     lands in the incident bundle."""
 
     def __init__(self, check: str, message: str,
@@ -356,51 +356,19 @@ def check_engine(engine) -> None:
               rings=ring_members, tenants=sorted(keys))
 
 
-def check_router(router) -> None:
-    """PodRouter-level joins: flight phases vs the pending deque vs the
-    admit-hook page snapshots vs the front queue. (Worker engines check
-    themselves inside their own step().)"""
-    flights = router._flights
-    phases = {"prefill", "pending", "decode"}
-    pending_ids = {id(f) for f in router._pending}
-    for f in flights.values():
-        if f.phase not in phases:
-            _fail("router-books", "unknown flight phase",
-                  phase=f.phase, request_id=f.user.request_id)
-        if f.user.done:
-            _fail("router-books",
-                  "a terminal request still has a live flight",
-                  request_id=f.user.request_id,
-                  status=f.user.status.value)
-        if (f.phase == "pending") != (id(f) in pending_ids):
-            _fail("router-books",
-                  "flight phase and pending-buffer membership disagree",
-                  request_id=f.user.request_id, phase=f.phase)
-    # the backpressure bound stops NEW assignments, it is not a hard cap:
-    # every already-assigned in-flight prefill may still finish and park
-    # its shipment, so the true invariant adds the prefill capacity
-    prefill_capacity = sum(len(w.scheduler.slots)
-                           for w in router.prefill_workers)
-    if len(router._pending) > router._max_pending + prefill_capacity:
-        _fail("router-books",
-              "pending shipments exceed the backpressure bound plus the "
-              "in-flight prefill capacity", pending=len(router._pending),
-              bound=router._max_pending, prefill_capacity=prefill_capacity)
-    live_internals = {id(f.internal) for f in flights.values()
-                      if f.phase == "prefill" and f.internal is not None}
-    stale = [k for k in router._admit_pages if k not in live_internals]
+def check_pod_worker(worker) -> None:
+    """A pod `WorkerServer`'s own join: its admit-hook page snapshots vs
+    its prefill jobs. Run from the worker's step (its engine checks
+    itself inside `Engine.step()`; the router checks what only it can
+    see in `check_distributed_router`)."""
+    live = {id(j.internal) for j in worker._jobs.values()
+            if j.mode == "prefill"}
+    stale = [k for k in worker._admit_pages if k not in live]
     if stale:
-        _fail("router-books",
-              "admit-hook page snapshots outlive their prefill flights "
+        _fail("worker-books",
+              "admit-hook page snapshots outlive their prefill jobs "
               "(the snapshot map would grow forever)",
-              stale_entries=len(stale))
-    from .scheduler import RequestStatus
-
-    for r in router.scheduler.queue:
-        if r.status is not RequestStatus.QUEUED:
-            _fail("router-books",
-                  "a front-queued request is not QUEUED",
-                  request_id=r.request_id, status=r.status.value)
+              worker=worker.worker_id, stale_entries=len(stale))
 
 
 def check_distributed_router(router) -> None:
@@ -469,9 +437,10 @@ def check_distributed_router(router) -> None:
             _fail("droute-books",
                   "a worker is both alive and lost (zombie bookkeeping)",
                   worker=handle.worker_id)
-    # the pending bound mirrors check_router's: assignment stops at
-    # _max_pending but already-assigned prefills may still land, so the
-    # hard cap adds the alive prefill-capable capacity
+    # the backpressure bound stops NEW assignments, it is not a hard cap:
+    # every already-assigned in-flight prefill may still finish and park
+    # its shipment, so the invariant adds the alive prefill-capable
+    # capacity (soft roles: any alive worker may be prefilling)
     prefill_capacity = sum(
         h.slots for h in router.workers.values() if h.alive)
     if len(router._pending) > router._max_pending + prefill_capacity:

@@ -29,7 +29,7 @@ each phase run as children, one after the other, each owning the chip for
 its lifetime (`_spawn_child`). The `pod_dist` phase starts worker
 processes of its own that each need a device while the router process
 holds one too, so it cannot run on one chip: it is part of `--rehearse`
-only, and fails loudly on a TPU (`serve_bench.build_tiny_distributed_pod`).
+only, and fails loudly on a TPU (`serve_bench.build_tiny_pod`).
 
 The reference publishes no training-throughput numbers (BASELINE.md); the
 target from BASELINE.json is >=40% MFU on the causal-LM training loop, so
@@ -521,7 +521,7 @@ def _pod_row(num_requests: int = 10) -> dict:
     regression (shipments -> 0, compiles creeping) is visible in the
     same one-line JSON as the training row."""
     sb = _load_serve_bench()
-    engine, cfg = sb.build_tiny_pod_engine(
+    engine, cfg, _ = sb.build_tiny_pod(
         "llama", pod_roles=(1, 1), num_slots=4, max_len=128,
         prefill_chunk=16)
     s = sb.run_offered_load(engine, cfg.vocab_size,
@@ -546,11 +546,11 @@ def _pod_dist_row(num_requests: int = 8) -> dict:
     NOT part of the one-chip path: the router process builds an engine on
     the default device and so does every worker process, and a chip
     belongs to one process at a time. Only `--rehearse` (CPU) runs it;
-    on a TPU `build_tiny_distributed_pod` raises instead of hanging."""
+    on a TPU `build_tiny_pod` raises instead of hanging."""
     sb = _load_serve_bench()
-    engine, cfg, procs = sb.build_tiny_distributed_pod(
-        "llama", pod_roles=(1, 1), num_slots=4, max_len=128,
-        prefill_chunk=16)
+    engine, cfg, procs = sb.build_tiny_pod(
+        "llama", pod_roles=(1, 1), transport="socket", num_slots=4,
+        max_len=128, prefill_chunk=16)
     try:
         s = sb.run_offered_load(engine, cfg.vocab_size,
                                 num_requests=num_requests, rate_hz=200.0,

@@ -39,12 +39,13 @@ KV pages that ship to M decode workers owning the slots; `--pod-tp K`
 additionally mesh-shards every worker over K devices. The summary then
 carries the pod counters (`pod_shipments`, `pod_pages_shipped`,
 `pod_backpressure_stalls`) next to the usual latency percentiles.
-`--pod-transport socket` is the A/B arm for the TRUE multi-host pod
-(serving.pod.distributed): the same roles run as real `pod-worker` OS
-processes dialing the router over TCP, so the delta against the default
-`local` transport is the wire + process-boundary cost; the summary adds
-the recovery counters (`pod_requests_replayed`, `pod_workers_lost`,
-`pod_recovery_latency_*`).
+Both transports run the one router (`DistributedPodRouter`), so the
+summary also carries the recovery counters (`pod_requests_replayed`,
+`pod_workers_lost`, `pod_recovery_latency_*`). `--pod-transport socket`
+is the A/B arm for the TRUE multi-host pod: the same roles run as real
+`pod-worker` OS processes dialing the router over TCP, so the delta
+against the default `local` transport (workers in this process) is the
+wire + process-boundary cost.
 
 `--tenants` switches to the MULTI-TENANT HTTP harness (`run_http_load`):
 the real `accelerate_tpu.server` front door is stood up in-process on an
@@ -70,6 +71,19 @@ from __future__ import annotations
 import argparse
 import json
 import time
+
+
+def _tiny_family(family_name: str):
+    """(family module, tiny config) of the named family."""
+    if family_name == "llama":
+        from accelerate_tpu.models import llama as family
+
+        return family, family.LlamaConfig.tiny()
+    if family_name == "gpt2":
+        from accelerate_tpu.models import gpt2 as family
+
+        return family, family.GPT2Config.tiny()
+    raise ValueError(f"unknown family {family_name!r}")
 
 
 def build_tiny_engine(family_name: str = "llama", num_slots: int = 4,
@@ -102,16 +116,7 @@ def build_tiny_engine(family_name: str = "llama", num_slots: int = 4,
 
     from accelerate_tpu.serving import Engine, EngineConfig
 
-    if family_name == "llama":
-        from accelerate_tpu.models import llama as family
-
-        cfg = family.LlamaConfig.tiny()
-    elif family_name == "gpt2":
-        from accelerate_tpu.models import gpt2 as family
-
-        cfg = family.GPT2Config.tiny()
-    else:
-        raise ValueError(f"unknown family {family_name!r}")
+    family, cfg = _tiny_family(family_name)
     params = family.init_params(cfg, jax.random.key(seed))
     ec = EngineConfig(num_slots=num_slots, max_len=max_len,
                       prefill_chunk=prefill_chunk, max_queue=max_queue,
@@ -145,65 +150,32 @@ def parse_pod_roles(arg: str) -> tuple[int, int]:
     return roles["prefill"], roles["decode"]
 
 
-def build_tiny_pod_engine(family_name: str = "llama", pod_roles=(1, 1),
-                          tensor_parallel: int = 1, num_slots: int = 4,
-                          max_len: int = 128, prefill_chunk: int = 16,
-                          max_queue: int = 64, seed: int = 0,
-                          page_size: int = 16, prefix_cache: bool = True,
-                          metrics_port: int | None = None, tenants=None,
-                          kv_dtype=None, paged_attention="auto",
-                          num_pages: int | None = None,
-                          host_tier_bytes: int = 0):
-    """A disaggregated pod (serving.pod.PodEngine) on the named family:
-    `pod_roles=(N, M)` prefill/decode workers, optionally `tensor_parallel`
-    chips per worker. Same submit/step surface as the single engine, so
-    `run_offered_load` drives it unchanged. `kv_dtype="int8"` quantizes
-    every worker's pool AND the page shipments between them (half the
-    wire bytes)."""
-    import jax
-    import jax.numpy as jnp
-
-    from accelerate_tpu.serving import EngineConfig
-    from accelerate_tpu.serving.pod import PodConfig, PodEngine
-
-    if family_name == "llama":
-        from accelerate_tpu.models import llama as family
-
-        cfg = family.LlamaConfig.tiny()
-    elif family_name == "gpt2":
-        from accelerate_tpu.models import gpt2 as family
-
-        cfg = family.GPT2Config.tiny()
-    else:
-        raise ValueError(f"unknown family {family_name!r}")
-    params = family.init_params(cfg, jax.random.key(seed))
-    ec = EngineConfig(num_slots=num_slots, max_len=max_len,
-                      prefill_chunk=prefill_chunk, max_queue=max_queue,
-                      cache_dtype=jnp.bfloat16, seed=seed,
-                      page_size=page_size, prefix_cache=prefix_cache,
-                      metrics_port=metrics_port, tenants=tenants,
-                      kv_dtype=kv_dtype, paged_attention=paged_attention,
-                      num_pages=num_pages,
-                      host_tier_bytes=host_tier_bytes)
-    pc = PodConfig(prefill_workers=pod_roles[0], decode_workers=pod_roles[1],
-                   tensor_parallel=tensor_parallel)
-    return PodEngine(family, cfg, params, ec, pc), cfg
-
-
-def build_tiny_distributed_pod(family_name: str = "llama", pod_roles=(1, 1),
-                               num_slots: int = 4, max_len: int = 128,
-                               prefill_chunk: int = 16, max_queue: int = 64,
-                               seed: int = 0, page_size: int = 16,
-                               prefix_cache: bool = True, kv_dtype=None,
-                               metrics_port: int | None = None,
-                               worker_wait_s: float = 180.0,
-                               trace: bool = False):
-    """The TRUE multi-host pod: `DistributedPodRouter` in this process,
-    N+M real `pod-worker` OS processes dialing its listener over TCP.
+def build_tiny_pod(family_name: str = "llama", pod_roles=(1, 1),
+                   transport: str = "local", tensor_parallel: int = 1,
+                   num_slots: int = 4, max_len: int = 128,
+                   prefill_chunk: int = 16, max_queue: int = 64,
+                   seed: int = 0, page_size: int = 16,
+                   prefix_cache: bool = True, kv_dtype=None,
+                   metrics_port: int | None = None,
+                   worker_wait_s: float = 180.0, trace: bool = False,
+                   **local_engine):
+    """A disaggregated pod on the named family, behind the one router
+    (`DistributedPodRouter`): `pod_roles=(N, M)` prefill/decode workers.
     Same submit/step surface as the single engine, so `run_offered_load`
-    drives it unchanged — the A/B against `build_tiny_pod_engine` prices
-    the wire + process boundary. Returns (router, cfg, procs); the
-    caller owns `router.close()` and reaping the procs."""
+    drives it unchanged. `kv_dtype="int8"` quantizes every worker's pool
+    AND the page shipments between them (half the wire bytes).
+
+    `transport="local"` keeps the workers in this process
+    (`serving.pod.PodEngine`), optionally `tensor_parallel` chips per
+    worker; `local_engine` are `EngineConfig` fields only such a pod can
+    take (tenants, paged_attention, num_pages, host_tier_bytes).
+    `transport="socket"` is the TRUE multi-host pod: N+M real
+    `pod-worker` OS processes dialing this process's listener over TCP,
+    each building its engine from the JSON spec — the A/B against
+    `local` prices the wire + process boundary.
+
+    Returns (router, cfg, procs): `procs` are the socket workers (empty
+    for `local`); the caller owns `router.close()` and reaping them."""
     import os
     import sys as _sys
     import time as _time
@@ -212,9 +184,34 @@ def build_tiny_distributed_pod(family_name: str = "llama", pod_roles=(1, 1),
 
     import accelerate_tpu
     from accelerate_tpu.commands.pod import spawn_socket_workers
-    from accelerate_tpu.serving.pod.distributed import (
-        ChannelListener, DistributedPodConfig, DistributedPodRouter)
+    from accelerate_tpu.serving.pod import PodConfig, PodEngine, PodRouter
+    from accelerate_tpu.serving.pod.distributed import ChannelListener
+    from accelerate_tpu.serving.pod.distributed.worker import (
+        engine_config_from_spec)
 
+    family, cfg = _tiny_family(family_name)
+    spec = {"family": family_name, "seed": seed, "num_slots": num_slots,
+            "max_len": max_len, "prefill_chunk": prefill_chunk,
+            "page_size": page_size, "max_queue": max_queue,
+            "cache_dtype": "bfloat16", "kv_dtype": kv_dtype,
+            "prefix_cache": prefix_cache}
+    ec = engine_config_from_spec(spec, metrics_port=metrics_port,
+                                 **local_engine)
+    pc = PodConfig(
+        prefill_workers=pod_roles[0], decode_workers=pod_roles[1],
+        tensor_parallel=tensor_parallel,
+        # first-request compiles stall worker heartbeats; generous
+        # timeouts keep a loaded box from counting phantom losses
+        heartbeat_timeout_s=120.0, flight_timeout_s=300.0)
+    if transport == "local":
+        params = family.init_params(cfg, jax.random.key(seed))
+        return PodEngine(family, cfg, params, ec, pc), cfg, []
+    if local_engine or tensor_parallel != 1:
+        raise ValueError(
+            "a socket pod-worker builds its engine from the JSON spec: "
+            f"{sorted(local_engine) + ['tensor_parallel']} reach only "
+            "in-process workers")
+    roles = ["prefill"] * pod_roles[0] + ["decode"] * pod_roles[1]
     if jax.devices()[0].platform == "tpu":
         # a chip belongs to one process at a time: this process holds the
         # TPU already (it drives the load and builds engines), and every
@@ -223,31 +220,13 @@ def build_tiny_distributed_pod(family_name: str = "llama", pod_roles=(1, 1),
         # worker to a chip of its own, so however many chips the host
         # has, N+M+1 processes cannot share them.
         raise RuntimeError(
-            f"the socket pod starts {sum(pod_roles)} worker processes that "
+            f"the socket pod starts {len(roles)} worker processes that "
             "each need the accelerator while this process holds it "
             f"({len(jax.devices())} TPU chip(s), one process per chip): not "
             "runnable on one TPU host from one launcher. Run it on the CPU "
             "(JAX_PLATFORMS=cpu), or start router and workers on hosts of "
             "their own with `accelerate-tpu pod-router --no-spawn` / "
             "`pod-worker`.")
-    from accelerate_tpu.serving.pod.distributed.worker import (
-        engine_config_from_spec)
-
-    spec = {"family": family_name, "seed": seed, "num_slots": num_slots,
-            "max_len": max_len, "prefill_chunk": prefill_chunk,
-            "page_size": page_size, "max_queue": max_queue,
-            "cache_dtype": "bfloat16", "kv_dtype": kv_dtype,
-            "prefix_cache": prefix_cache}
-    if family_name == "llama":
-        from accelerate_tpu.models import llama as family
-
-        cfg = family.LlamaConfig.tiny()
-    elif family_name == "gpt2":
-        from accelerate_tpu.models import gpt2 as family
-
-        cfg = family.GPT2Config.tiny()
-    else:
-        raise ValueError(f"unknown family {family_name!r}")
     listener = ChannelListener("127.0.0.1", 0)
     # workers must import accelerate_tpu from this checkout even when it
     # is not pip-installed
@@ -264,18 +243,9 @@ def build_tiny_distributed_pod(family_name: str = "llama", pod_roles=(1, 1),
 
         env["ACCELERATE_TPU_TRACE"] = "1"
         configure_tracing(enabled=True, default_sample_rate=1.0)
-    roles = (["prefill"] * pod_roles[0] + ["decode"] * pod_roles[1])
     procs = spawn_socket_workers(listener.port, spec, roles, env=env,
                                  stderr=_sys.stderr)
-    router = DistributedPodRouter(
-        engine_config=engine_config_from_spec(spec,
-                                              metrics_port=metrics_port),
-        pod_config=DistributedPodConfig(
-            prefill_workers=pod_roles[0], decode_workers=pod_roles[1],
-            # first-request compiles stall worker heartbeats; generous
-            # timeouts keep a loaded box from counting phantom losses
-            heartbeat_timeout_s=120.0, flight_timeout_s=300.0),
-        listener=listener)
+    router = PodRouter(engine_config=ec, pod_config=pc, listener=listener)
     deadline = _time.monotonic() + worker_wait_s
     while sum(1 for w in router.workers.values() if w.alive) < len(roles):
         router.step()
@@ -765,10 +735,10 @@ def main() -> None:
                         "worker (mesh-sharded layer 1 under the pod)")
     p.add_argument("--pod-transport", default="local",
                    choices=("local", "socket"),
-                   help="with --pod-roles: 'local' = in-process PodEngine "
-                        "(default), 'socket' = real pod-worker OS "
-                        "processes over TCP (serving.pod.distributed) — "
-                        "the A/B prices the wire + process boundary")
+                   help="with --pod-roles: 'local' = workers in this "
+                        "process (default), 'socket' = real pod-worker OS "
+                        "processes over TCP — same router, the A/B "
+                        "prices the wire + process boundary")
     p.add_argument("--tenants", default=None,
                    help="multi-tenant HTTP harness: semicolon-separated "
                         "specs, e.g. 'gold:priority=0,weight=4,slo=0.3,"
@@ -836,28 +806,23 @@ def main() -> None:
     if args.prefix_pool and args.prefix_len:
         max_len = max(max_len, args.prefix_len + args.prompt_len[1]
                       + args.max_new_tokens[1])
-    pod_procs = None
-    if args.pod_roles and args.pod_transport == "socket":
-        engine, cfg, pod_procs = build_tiny_distributed_pod(
+    pod_procs = []
+    if args.pod_roles:
+        local = args.pod_transport == "local"
+        engine, cfg, pod_procs = build_tiny_pod(
             args.family, pod_roles=parse_pod_roles(args.pod_roles),
+            transport=args.pod_transport, tensor_parallel=args.pod_tp,
             num_slots=args.slots, max_len=max_len,
             prefill_chunk=args.prefill_chunk, seed=args.seed,
             page_size=args.page_size,
             prefix_cache=not args.no_prefix_cache,
             metrics_port=args.metrics_port,
-            kv_dtype=None if args.kv_dtype == "bf16" else args.kv_dtype)
-    elif args.pod_roles:
-        engine, cfg = build_tiny_pod_engine(
-            args.family, pod_roles=parse_pod_roles(args.pod_roles),
-            tensor_parallel=args.pod_tp, num_slots=args.slots,
-            max_len=max_len, prefill_chunk=args.prefill_chunk,
-            seed=args.seed, page_size=args.page_size,
-            prefix_cache=not args.no_prefix_cache,
-            metrics_port=args.metrics_port,
             kv_dtype=None if args.kv_dtype == "bf16" else args.kv_dtype,
-            paged_attention=False if args.no_paged_attention else "auto",
-            num_pages=args.num_pages,
-            host_tier_bytes=args.host_tier_bytes)
+            **(dict(paged_attention=(False if args.no_paged_attention
+                                     else "auto"),
+                    num_pages=args.num_pages,
+                    host_tier_bytes=args.host_tier_bytes)
+               if local else {}))
     else:
         engine, cfg = build_tiny_engine(
             args.family, num_slots=args.slots, max_len=max_len,
@@ -883,7 +848,7 @@ def main() -> None:
             seed=args.seed, prefix_pool=args.prefix_pool,
             prefix_len=args.prefix_len)
     finally:
-        if pod_procs is not None:
+        if pod_procs:
             engine.close()   # drains the workers, closes every channel
             for proc in pod_procs:
                 if proc.poll() is None:
@@ -895,12 +860,12 @@ def main() -> None:
                     proc.kill()
     if args.pod_roles:
         summary["pod_transport"] = args.pod_transport
-    if args.pod_trace and pod_procs is not None:
+    if args.pod_trace and pod_procs:
         # second arm: identical load, tracing ON. The baseline pod is
         # already closed, so the two arms never share a port or a worker
-        engine2, _, procs2 = build_tiny_distributed_pod(
+        engine2, _, procs2 = build_tiny_pod(
             args.family, pod_roles=parse_pod_roles(args.pod_roles),
-            num_slots=args.slots, max_len=max_len,
+            transport="socket", num_slots=args.slots, max_len=max_len,
             prefill_chunk=args.prefill_chunk, seed=args.seed,
             page_size=args.page_size,
             prefix_cache=not args.no_prefix_cache,

@@ -84,6 +84,25 @@ def traffic(rng_seed=7):
     return prompts, budgets, temps
 
 
+class _EarlyTokensOnly:
+    """The victim's channel as the router reads it in phase 2: `tokens`
+    frames past the third token never arrive, as if they were still in
+    flight when the connection died. The router's picture of every
+    stream on the victim then stops mid-stream however fast the worker
+    really decodes, so the SIGKILL below cannot come too late: the kill
+    window does not depend on who gets the CPU."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def poll(self):
+        return [m for m in self._inner.poll()
+                if m.kind != "tokens" or len(m.meta["tokens"]) <= 3]
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
 def drive(router, reqs, deadline_s=120.0):
     deadline = time.monotonic() + deadline_s
     while not all(r.done for r in reqs):
@@ -105,10 +124,8 @@ def main() -> None:
     # the single-process reference: same spec -> same params bytes
     _family, _cfg, _params, ref_engine = build_worker_engine(SPEC)
     prompts, budgets, temps = traffic()
-    # phase 2 streams LONGER: the SIGKILL window needs a flight that is
-    # still mid-decode after both workers' spans have ridden a heartbeat
-    # into the router — with a warm compile cache an 8-token stream can
-    # finish before the first span-bearing heartbeat is even processed
+    # phase 2 streams longer than the three tokens `_EarlyTokensOnly`
+    # lets the router see, so every stream is cut mid-decode
     budgets2 = [24, 24, 16, 16]
     # the trace runs TWICE (phase 1 exactness, phase 2 recovery) and
     # sampling keys fold in the request id, so the reference must burn
@@ -169,30 +186,28 @@ def main() -> None:
         print("PHASE1_EXACT_OK", flush=True)
 
         # phase 2: SIGKILL the decode worker process mid-stream
-        reqs = [router.submit(p, max_new_tokens=b, temperature=t)
-                for p, b, t in zip(prompts, budgets2, temps)]
         victim = next(w for w in router.workers.values()
                       if w.role == "decode")
-        # wait for a decode flight AND for both workers' spans of its
-        # trace (prefill from worker A, install from worker B) to ride a
-        # heartbeat into the router's recorder — the fleet bundle below
-        # must contain the whole cross-process timeline
+        victim.channel = _EarlyTokensOnly(victim.channel)
+        reqs = [router.submit(p, max_new_tokens=b, temperature=t)
+                for p, b, t in zip(prompts, budgets2, temps)]
+        # wait for a decode flight with decoded tokens delivered AND for
+        # both workers' spans of its trace (prefill from worker A,
+        # install from worker B) to ride a heartbeat into the router's
+        # recorder — the fleet bundle below must contain the whole
+        # cross-process timeline. The flight cannot finish meanwhile:
+        # the router never sees its fourth token.
         deadline = time.monotonic() + 120.0
         candidates = {}
         while not candidates:
             router.step()
-            # a candidate must still owe >= 2 tokens: a flight whose
-            # remaining tokens already sit in the router's socket buffer
-            # finishes instead of replaying
             candidates = {
                 f.user.request_id: f.user.trace_id
                 for f in router._flights.values()
                 if f.phase == "decode" and f.worker == victim.worker_id
-                and len(f.user.tokens) <= f.user.max_new_tokens - 2
+                and len(f.user.tokens) >= 2
                 and {"serving.pod.prefill", "serving.pod.install"}
                 <= {e["name"] for e in trace_events(f.user.trace_id)}}
-            assert not all(r.done for r in reqs), \
-                "phase-2 batch drained before a traced kill window opened"
             assert time.monotonic() < deadline, \
                 "no traced decode flight landed"
             time.sleep(0.002)

@@ -333,7 +333,7 @@ class TestSelfLint:
         files = iter_python_files(os.path.join(REPO, "accelerate_tpu"))
         pod_files = [f for f in files
                      if (os.sep + "serving" + os.sep + "pod" + os.sep) in f]
-        for name in ("router.py", "transfer.py", "mesh.py"):
+        for name in ("droute.py", "transfer.py", "mesh.py"):
             assert any(f.endswith(name) for f in pod_files), \
                 f"serving/pod/{name} must be inside the self-lint tree"
 

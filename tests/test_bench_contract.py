@@ -555,7 +555,7 @@ def test_serve_bench_pod_mode_smoke():
     same submit/step surface: miniature in-process load, shipment
     counters populated, per-role compile counts flat."""
     sb = _load_serve_bench()
-    engine, cfg = sb.build_tiny_pod_engine(
+    engine, cfg, _ = sb.build_tiny_pod(
         "gpt2", pod_roles=(1, 1), num_slots=2, max_len=32, prefill_chunk=8)
     summary = sb.run_offered_load(
         engine, cfg.vocab_size, num_requests=4, rate_hz=500.0,

@@ -307,23 +307,29 @@ def test_pod_worker_drop_carries_shed_code(gpt2_setup):
     from accelerate_tpu.serving.scheduler import SHED_WORKER_DROP
 
     cfg, params = gpt2_setup
-    pod = PodEngine(gpt2, cfg, params, _ec(prefill_chunk=4))
+    # max_attempts=1: a drop the router may not replay is a shed (with
+    # attempts left it re-prefills instead — test_pod_distributed.py)
+    pod = PodEngine(gpt2, cfg, params, _ec(prefill_chunk=4),
+                    PodConfig(max_attempts=1))
     rng = np.random.default_rng(11)
     p = rng.integers(0, cfg.vocab_size, (17,)).astype(np.int32)
     user = pod.submit(p, max_new_tokens=6)
-    flight = pod._flights[id(user)]
+    flight = pod._by_user[id(user)]
     assert flight.phase == "prefill"
+    pod.step()   # the worker takes the submit and prefills one chunk
     # simulate a worker-side wedge: the internal dies mid-prefill (the
-    # router's harvest must also clean up the admit-hook page snapshot
-    # — the step-end sanitizer validates that)
-    assert pod.prefill_workers[flight.worker].cancel(flight.internal)
-    pod.step()
+    # worker's harvest must also clean up the admit-hook page snapshot
+    # — its step-end sanitizer validates that)
+    worker = pod.workers[flight.worker].local
+    assert worker.engine.cancel(worker._jobs[flight.flight_id].internal)
+    pod.step()   # the worker reports the drop ...
+    pod.step()   # ... and the router, out of attempts, sheds
     assert user.status is RequestStatus.EXPIRED
     assert user.shed_code == SHED_WORKER_DROP
     assert user.retry_after_s is not None
     assert pod.metrics_summary()["requests_expired"] == 1.0
     # the flight is gone and the pod keeps serving
-    assert id(user) not in pod._flights
+    assert id(user) not in pod._by_user
     r2 = pod.submit(p, max_new_tokens=3)
     pod.run_until_idle()
     assert r2.status is RequestStatus.FINISHED
@@ -383,8 +389,8 @@ def test_pod_cancel_everywhere(gpt2_setup):
     assert c.status is RequestStatus.CANCELLED
     assert b.status is RequestStatus.FINISHED and len(b.tokens) == 16
     # every worker drained: all pages back except prefix-tree cached ones
-    for w in pod.decode_workers + pod.prefill_workers:
-        assert w.scheduler.live_slots == 0
+    for handle in pod.workers.values():
+        assert handle.local.engine.scheduler.live_slots == 0
     s = pod.metrics_summary()
     assert s["requests_cancelled"] == 2.0
     assert s["requests_finished"] == 1.0
@@ -460,7 +466,8 @@ def test_pod_debug_views(gpt2_setup):
                    max_new_tokens=4)
     pod.run_until_idle()
     dp = pod.debug_pod()
-    assert [w["worker"] for w in dp["roles"]["decode"]] == [0, 1]
+    # worker ids are pod-wide: the prefill worker is 0
+    assert [w["worker"] for w in dp["roles"]["decode"]] == [1, 2]
     assert dp["shipments_total"] == 2
     assert dp["pages_shipped_total"] >= 2
     assert dp["in_flight"] == {}

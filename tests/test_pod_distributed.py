@@ -25,7 +25,12 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.models import gpt2
-from accelerate_tpu.serving import Engine, EngineConfig
+from accelerate_tpu.serving import (
+    Engine,
+    EngineConfig,
+    Request,
+    RequestStatus,
+)
 from accelerate_tpu.serving.pod import KVPageShipment
 from accelerate_tpu.serving.pod.distributed import (
     DistributedPodConfig,
@@ -408,7 +413,7 @@ def test_step_never_sleeps_on_the_callers_thread(gpt2_setup, monkeypatch):
     import accelerate_tpu.serving.pod.distributed.droute as droute_mod
 
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params)
+    router = _build_pod(cfg, params)
     main = threading.current_thread()
     slept = []
     real_sleep = time_mod.sleep
@@ -487,7 +492,7 @@ def test_distributed_pod_byte_identical_to_single_engine(
     worker -> token sync reproduce the single engine's tokens and
     logprobs byte for byte, with every worker's compile count flat."""
     cfg, params = gpt2_setup
-    router, _workers = _build_pod(cfg, params, pf=1, dec=2)
+    router = _build_pod(cfg, params, pf=1, dec=2)
     reqs = _submit_traffic(router, cfg)
     _drive(router, reqs)
     ref_tokens, ref_logprobs = ref_outputs
@@ -505,7 +510,7 @@ def test_distributed_pod_byte_identical_to_single_engine(
 
 def test_distributed_pod_stream_iterates_tokens(gpt2_setup, ref_outputs):
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params, pf=1, dec=1)
+    router = _build_pod(cfg, params, pf=1, dec=1)
     prompts, budgets, temps = _traffic(cfg)
     req = router.submit(prompts[0], max_new_tokens=budgets[0],
                         temperature=temps[0])
@@ -544,7 +549,7 @@ def test_dropped_shipment_recovers_via_stalled_replay(
     def wrap(wid, role, ch):
         return FlakyTransport(ch, rules=rules) if role == "prefill" else ch
 
-    router, _ = _build_pod(cfg, params, pf=1, dec=1, wrap=wrap,
+    router = _build_pod(cfg, params, pf=1, dec=1, wrap=wrap,
                            flight_timeout_s=1.0)
     reqs = _submit_traffic(router, cfg)
     _drive(router, reqs)
@@ -570,7 +575,7 @@ def test_duplicated_shipment_is_dropped_as_stale(gpt2_setup, ref_outputs):
     def wrap(wid, role, ch):
         return FlakyTransport(ch, rules=rules) if role == "prefill" else ch
 
-    router, _ = _build_pod(cfg, params, pf=1, dec=1, wrap=wrap)
+    router = _build_pod(cfg, params, pf=1, dec=1, wrap=wrap)
     reqs = _submit_traffic(router, cfg)
     _drive(router, reqs)
     assert [list(r.tokens) for r in reqs] == ref_outputs[0]
@@ -588,7 +593,7 @@ def test_killed_decode_worker_recovers_all_flights_exactly(
     no lost tokens, no duplicated tokens."""
     cfg, params = gpt2_setup
     flaky = {}
-    router, _ = _build_pod(cfg, params, pf=1, dec=2,
+    router = _build_pod(cfg, params, pf=1, dec=2,
                            wrap=_wrap_capture(flaky))
     reqs = _submit_traffic(router, cfg)
     for _ in range(6):
@@ -624,7 +629,7 @@ def test_killed_prefill_worker_requeues_flights(gpt2_setup, ref_outputs):
     decode worker serves prefill too) — tokens exact."""
     cfg, params = gpt2_setup
     flaky = {}
-    router, _ = _build_pod(cfg, params, pf=1, dec=1,
+    router = _build_pod(cfg, params, pf=1, dec=1,
                            wrap=_wrap_capture(flaky))
     reqs = _submit_traffic(router, cfg)
     router.step()
@@ -648,7 +653,7 @@ def test_hung_worker_detected_by_heartbeat_timeout(gpt2_setup, ref_outputs):
     # busy_heartbeat_timeout_s: the victim's last delivered heartbeat may
     # announce busy=True (pre-compile), which legitimately defers the
     # heartbeat verdict — bound that deferral so the fake clock reaches it
-    router, _ = _build_pod(cfg, params, pf=1, dec=2,
+    router = _build_pod(cfg, params, pf=1, dec=2,
                            wrap=_wrap_capture(flaky),
                            heartbeat_timeout_s=1.0, flight_timeout_s=30.0,
                            busy_heartbeat_timeout_s=1.0)
@@ -675,7 +680,7 @@ def test_no_lost_requests_under_flake_storm(gpt2_setup, ref_outputs):
     exact single-engine tokens — nothing lost, nothing doubled."""
     cfg, params = gpt2_setup
     flaky = {}
-    router, _ = _build_pod(
+    router = _build_pod(
         cfg, params, pf=1, dec=2,
         wrap=_wrap_capture(flaky, flake_rate=0.05, seed=11, delay_ticks=2),
         flight_timeout_s=1.0, max_attempts=10)
@@ -701,7 +706,7 @@ def test_rebalance_converts_idle_prefill_to_decode_once_per_window(
     per window — the second spare stays put), and the converted pod
     still finishes everything."""
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params, pf=2, dec=1, rebalance=True,
+    router = _build_pod(cfg, params, pf=2, dec=1, rebalance=True,
                            rebalance_window_s=0.2,
                            occupancy_high=0.5, occupancy_low=0.1)
     prompts, _, _ = _traffic(cfg)
@@ -722,7 +727,7 @@ def test_rebalance_window_blocks_flapping(gpt2_setup):
     """No conversion fires before the warm-up window elapses, no matter
     the queue pressure at startup (the first-step-flip regression)."""
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params, pf=1, dec=2, rebalance=True,
+    router = _build_pod(cfg, params, pf=1, dec=2, rebalance=True,
                            rebalance_window_s=1e9)
     prompts, _, _ = _traffic(cfg)
     reqs = [router.submit(p, max_new_tokens=6) for p in prompts]
@@ -744,7 +749,7 @@ def test_worker_snapshots_merge_into_router_exposition(gpt2_setup):
     registry holds the router's own series PLUS the transport-backed
     cross-worker merge (no jax process group) under origin=workers."""
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params, pf=1, dec=1)
+    router = _build_pod(cfg, params, pf=1, dec=1)
     reqs = _submit_traffic(router, cfg)
     _drive(router, reqs)
     assert all(w.snapshot for w in router.workers.values()), \
@@ -769,10 +774,12 @@ def test_worker_snapshots_merge_into_router_exposition(gpt2_setup):
 
 
 def test_sanitizer_catches_corrupted_router_books(gpt2_setup):
-    """check_distributed_router: the cross-process joins only the router
-    can see — corrupt each one and watch it fail loudly."""
+    """check_distributed_router: the joins only the router can see —
+    corrupt each one and watch it fail loudly. (The one join that lives
+    on a worker, its admit-hook snapshots, is
+    test_sanitizer.py::test_router_fires_on_stale_admit_snapshot.)"""
     cfg, params = gpt2_setup
-    router, _ = _build_pod(cfg, params, pf=1, dec=1)
+    router = _build_pod(cfg, params, pf=1, dec=1)
     reqs = _submit_traffic(router, cfg)
     for _ in range(4):
         router.step()
@@ -806,6 +813,31 @@ def test_sanitizer_catches_corrupted_router_books(gpt2_setup):
     with pytest.raises(SanitizerViolation):
         check_distributed_router(router)
     router._by_user[key] = val
+
+    # a terminal request that still has a live flight
+    orig_status = flight.user.status
+    flight.user.status = RequestStatus.CANCELLED
+    with pytest.raises(SanitizerViolation, match="terminal request"):
+        check_distributed_router(router)
+    flight.user.status = orig_status
+
+    # more parked shipments than the backpressure bound plus every
+    # in-flight prefill could have produced
+    orig_bound = router._max_pending
+    router._max_pending = -1 - sum(h.slots for h in router.workers.values())
+    with pytest.raises(SanitizerViolation, match="backpressure bound"):
+        check_distributed_router(router)
+    router._max_pending = orig_bound
+
+    # a front-queued request that is not QUEUED
+    queued = Request(prompt=np.arange(1, 5, dtype=np.int32),
+                     max_new_tokens=2)
+    router.scheduler.submit(queued)
+    queued.status = RequestStatus.RUNNING
+    with pytest.raises(SanitizerViolation, match="front-queued"):
+        check_distributed_router(router)
+    queued.status = RequestStatus.QUEUED
+    assert router.scheduler.cancel(queued)
 
     check_distributed_router(router)   # restored state passes again
     _drive(router, reqs)
@@ -858,7 +890,7 @@ def test_tracing_staleness_and_fleet_bundle_acceptance(
 
     cfg, params = gpt2_setup
     flaky = {}
-    router, _ = build_local_distributed_pod(
+    router = build_local_distributed_pod(
         gpt2, cfg, params,
         engine_config=_ec(incident_dir=str(tmp_path)),
         pod_config=DistributedPodConfig(
@@ -1001,7 +1033,7 @@ def test_clock_sync_span_ingest_and_busy_deferral(gpt2_setup, _traced):
 
     cfg, params = gpt2_setup
     now = [0.0]
-    router, _ = build_local_distributed_pod(
+    router = build_local_distributed_pod(
         gpt2, cfg, params, engine_config=_ec(),
         pod_config=DistributedPodConfig(
             prefill_workers=1, decode_workers=1, rebalance=False,

@@ -305,7 +305,7 @@ def test_fork_and_speculative_run_sanitized(gpt2_setup):
 
 
 def test_router_fires_on_stale_admit_snapshot(gpt2_setup):
-    from accelerate_tpu.serving.pod import PodConfig, PodEngine
+    from accelerate_tpu.serving.pod import PodEngine
 
     cfg, params = gpt2_setup
     pod = PodEngine(gpt2, cfg, params,
@@ -316,11 +316,12 @@ def test_router_fires_on_stale_admit_snapshot(gpt2_setup):
     pod.run_until_idle()
     assert r.status is RequestStatus.FINISHED
     # a snapshot entry whose internal is long gone: the leak class the
-    # router-books join exists for
-    pod._admit_pages[123456] = [0, 1]
+    # worker-books join exists for (the snapshots live on the worker
+    # that took them; the router pumps it in its own step)
+    pod.workers[0].local._admit_pages[123456] = [0, 1]
     with pytest.raises(SanitizerViolation) as ei:
         pod.step()
-    assert ei.value.check == "router-books"
+    assert ei.value.check == "worker-books"
     assert "snapshot" in str(ei.value)
 
 

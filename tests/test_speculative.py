@@ -320,8 +320,8 @@ def test_pod_router_strips_speculation(gpt2_setup):
                       speculative=(gpt2, cfg, params), draft_k=4)
     pod = PodEngine(gpt2, cfg, params, ec,
                     PodConfig(prefill_workers=1, decode_workers=1))
-    for w in pod.prefill_workers + pod.decode_workers:
-        assert w.engine_config.speculative is None
+    for handle in pod.workers.values():
+        assert handle.local.engine.engine_config.speculative is None
     rng = np.random.default_rng(5)
     p = _prompt(rng, 9, cfg.vocab_size)
     ref_eng = _engine(cfg, params)
