@@ -39,11 +39,15 @@ correlate across slots and a request's sample sequence is independent of
 how prefills/decodes interleave. Temperature is a traced per-slot scalar
 (greedy and sampled requests share the same program).
 
-Token delivery reuses the streamed-generate host plumbing: every decode
-step ends in one small device->host read of the [S] token vector (the same
-role the per-layer device->host probe plays in
-`big_modeling.stream_layers`), which is what `stream()`/`astream()` yield
-from.
+Token delivery is one small device->host read a program: `prefill` and
+`decode` hand the host its own output (the sampled tokens and their
+logprobs, apart from the donated [S] token register), and the engine reads
+it ONE STEP LATE: `step()` dispatches the next program first and only then
+fetches and commits the last one's results, so the chip works through the
+read and the host pass instead of waiting for them (`_Unread`, `_settle`).
+A token reaches `request.tokens` in the `step()` after the one that
+computed it; `stream()`/`astream()`/`run_until_idle()` drive until every
+token is committed.
 
 Speculative decoding (`EngineConfig(speculative=(family, config, params),
 draft_k=K)`, off by default — the three-program contract above is
@@ -75,7 +79,7 @@ import dataclasses
 import inspect
 import time
 from functools import partial
-from typing import Any, AsyncIterator, Iterator
+from typing import Any, AsyncIterator, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -391,6 +395,17 @@ def _resolve_paged_attention(setting, mesh, speculative=None) -> bool:
     return use
 
 
+class _Unread(NamedTuple):
+    """A dispatched program whose results the host has not read: `out` is
+    the program's (tokens, logprobs) output on the device, its copy to the
+    host already started; `lanes` names who is owed what, as (slot, the
+    request the slot held at dispatch, index into `out`)."""
+
+    program: str
+    out: tuple
+    lanes: list
+
+
 def _as_raw_key(key) -> jax.Array:
     """uint32[2] key data from a typed key, raw key, or None."""
     if key is None:
@@ -632,6 +647,8 @@ class Engine:
             self._slot_keys = jax.device_put(self._slot_keys, rep)
             self._temps = jax.device_put(self._temps, rep)
         self._base_key = jax.random.key(ec.seed)
+        # the one dispatched program whose results are not committed yet
+        self._unread: _Unread | None = None
         # admission hook: called as on_admit(slot, request) at the END of
         # every admission, after the slot's page table and device state
         # are installed. First-class (like PagedAllocator's on_evict/
@@ -678,7 +695,9 @@ class Engine:
         # donation lets the (large) cache be updated in place; that it IS,
         # on the chip, takes the page-granular write of
         # cache._scatter_rows besides (a row scatter into the donated pool
-        # was copied around); (1, 2) = cache, tokens in both programs
+        # was copied around); (1, 2) = cache, tokens in both programs.
+        # What the host reads of a step is a third, never-donated output
+        # (live lanes of `next_tok` are the register's new entries)
         don = (1, 2) if self.engine_config.donate else ()
         don_admit = (0, 1, 2) if self.engine_config.donate else ()
         # meshed engines pin output shardings to the input layout so the
@@ -688,7 +707,8 @@ class Engine:
         if self._mesh_shardings is not None:
             cache_sh, rep = self._mesh_shardings
             admit_out = (cache_sh, rep, rep)
-            step_out = (cache_sh, rep, rep)  # cache, tokens, logprobs
+            # cache, the token register, the host's (tokens, logprobs)
+            step_out = (cache_sh, rep, (rep, rep))
 
         def sample_slot(logits, key_raw, position, temp):
             """One slot's next token from [V] logits: traced temperature
@@ -754,7 +774,9 @@ class Engine:
             new_len = length + real_len
             tok, lp = sample_slot(last, slot_keys[slot], new_len, temps[slot])
             tokens = tokens.at[slot].set(tok)
-            return cache, tokens, lp
+            # (tok, lp) is the host's: `tokens` is donated to the next
+            # program, which may be dispatched before the host reads
+            return cache, tokens, (tok, lp)
 
         decode = None
         if self._spec:
@@ -793,7 +815,7 @@ class Engine:
                 cache = paged_append_rows(
                     cache, table, row_k[:, :, 0],
                     None if row_v is None else row_v[:, :, 0], live)
-                return cache, tokens, lps
+                return cache, tokens, (next_tok, lps)
         else:
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
             def decode(params, cache, tokens, slot_keys, temps, live, table):
@@ -830,7 +852,7 @@ class Engine:
                     last, slot_keys, cache.lengths + 1, temps)
                 tokens = jnp.where(live, next_tok, tokens)
                 cache = paged_append_batch(cache, table, nk, nv, live)
-                return cache, tokens, lps
+                return cache, tokens, (next_tok, lps)
 
         self._admit_p, self._prefill_p, self._decode_p = admit, prefill, decode
         if self._spec:
@@ -1005,6 +1027,7 @@ class Engine:
         on a step's path reads them."""
         if self.cache is None or self.cache.stats is None:
             return {}
+        self._settle()  # counts and committed tokens of the same programs
         return jax.tree_util.tree_map(np.asarray, self.cache.stats)
 
     def compile_stats(self) -> dict[str, int]:
@@ -1158,6 +1181,7 @@ class Engine:
         )
 
     def cancel(self, request: Request) -> bool:
+        self._settle()  # a token on its way may finish the request first
         if self.scheduler.cancel(request):
             self._finalize_request(request)
             return True
@@ -1167,6 +1191,7 @@ class Engine:
         """Retire a running request as FINISHED before its budget (e.g.
         a server-side stop sequence matched): counts in the finished/
         latency metrics, prompt pages cached for reuse."""
+        self._settle()
         if self.scheduler.finish_early(request):
             self._finalize_request(request)
             return True
@@ -1202,7 +1227,11 @@ class Engine:
 
     def step(self) -> bool:
         """Run one scheduler action (admissions + one prefill chunk OR one
-        batched decode step). Returns False when the engine is idle."""
+        batched decode step), then read and commit the results of the
+        program the PREVIOUS step dispatched: the chip runs this step's
+        program while the host reads, commits and comes back to dispatch
+        the next. A step with nothing to dispatch commits what is unread.
+        Returns False when the engine is idle with every token committed."""
         if self.metrics.started_at is None:
             self.metrics.started_at = self._clock()
         if self.watchdog is not None:
@@ -1210,21 +1239,33 @@ class Engine:
         self._admit_pending()
         # an idle engine records nothing: callers poll step() in a tight
         # loop, and with no live slot there is nothing to schedule
-        action = None
+        kind = None
         if self.scheduler.live_slots:
             with _phase("serving.schedule") as sp:
                 action = self.scheduler.next_action()
-                sp.set(action=action[0])
-        if action is None:
+                kind = action[0] if action else None
+                sp.set(action=kind or "none")
+        unread = self._unread
+        if kind is None and unread is None:
             self.metrics.stopped_at = self._clock()
             if self._sanitize:
                 self._sanity_check()
             return False
         t0 = self._clock()
-        if action[0] == "prefill":
+        # dispatch first (a program that owes the host tokens takes
+        # `_unread`'s place; if the dispatch raises, `unread` stays there)
+        if kind == "prefill":
             self._run_prefill_chunk(action[1])
-        else:
+        elif kind == "decode":
             self._run_decode(action[1])
+        if unread is not None:
+            if self._unread is unread:
+                self._unread = None
+            self._read_and_commit(unread, behind=kind)
+        if self._spec:
+            # the speculative step's own books (draft progress, accepted
+            # counts) decide its next inputs: it keeps the synchronous order
+            self._settle()
         with _phase("serving.bookkeeping"):
             self.metrics.stopped_at = self._clock()
             # the EMA behind the scheduler's SLO / Retry-After estimates —
@@ -1276,6 +1317,49 @@ class Engine:
     def run_until_idle(self) -> None:
         while self.step():
             pass
+
+    def _leave_unread(self, program: str, out, lanes: list) -> None:
+        """The program just dispatched owes `lanes` a token each: start
+        its results' copy to the host and leave the read to the next
+        `step()` (or to `_settle`)."""
+        for leaf in out:
+            leaf.copy_to_host_async()
+        for slot, _, _ in lanes:
+            slot.unread += 1
+        self._unread = _Unread(program, out, lanes)
+
+    def _read_and_commit(self, unread: _Unread, behind=None) -> None:
+        """Fetch a dispatched program's tokens and logprobs (the one wait
+        for the chip in the host pass) and commit them. `behind` names the
+        program dispatched since, which the chip runs meanwhile."""
+        with _phase("serving.host_read", program=unread.program,
+                    behind=behind or "none"):
+            toks, lps = jax.device_get(unread.out)
+        self.metrics.note_result_read(overlapped=behind is not None)
+        with _phase("serving.commit", tokens=len(unread.lanes)) as sp:
+            if unread.program == "decode":
+                self.timer.tick(block_on=None)
+            finished = 0
+            for slot, req, at in unread.lanes:
+                if slot.request is not req:
+                    # the request finished on an EOS the host could not
+                    # count ahead, and its lane rode this step dead
+                    continue
+                slot.unread -= 1
+                if self.scheduler.note_token(slot, int(toks[at]),
+                                             logprob=float(lps[at])):
+                    self._finalize_request(req)
+                    finished += 1
+            sp.set(finished=finished)
+
+    def _settle(self) -> None:
+        """Commit the unread program's results now. Whatever acts on a
+        request's books from outside `step()` (cancel, finish, a counter
+        read, a metrics reset) calls this first, so that it never sees a
+        request with a token still on its way."""
+        unread, self._unread = self._unread, None
+        if unread is not None:
+            self._read_and_commit(unread)
 
     def _admit_pending(self) -> None:
         """Shed expired/doomed queued requests, then admit from the
@@ -1680,8 +1764,10 @@ class Engine:
             real = min(chunk, req.prompt_len - start)
             ids = np.zeros((chunk,), np.int32)
             ids[:real] = req.prompt[start:start + real]
+            # `row` is the host's own and changes in place at the next
+            # admission or release: a program in flight keeps its copy
             args = (self.params, self.cache, self._tokens, self._slot_keys,
-                    self._temps, jnp.int32(slot.index), row, ids,
+                    self._temps, jnp.int32(slot.index), row.copy(), ids,
                     jnp.int32(real))
             self._strict_audit("prefill", self._prefill_p, args)
             self._ensure_cost("prefill", self._prefill_p, args)
@@ -1690,7 +1776,7 @@ class Engine:
             with self._request_span("serving.prefill", req, slot=slot.index,
                                     chunk_start=start, chunk_tokens=real), \
                     self.timer.dispatch():
-                self.cache, self._tokens, lp = self._prefill_p(*args)
+                self.cache, self._tokens, out = self._prefill_p(*args)
             sample(self.cache)
         if self._spec:
             # joint chunk: the draft processes the same window, so both
@@ -1704,19 +1790,11 @@ class Engine:
                 # becomes shareable NOW — forks queued behind us map it at
                 # admission instead of re-prefilling
                 self.allocator.publish_prompt(slot)
-        if done:
-            # the chunk that completed the prompt also produced the
-            # request's first token — fetch it (TTFT is measured here).
-            # Index on device first: only ONE element crosses to the host,
-            # not the whole [S] token vector (self-lint ATP003 class).
-            with _phase("serving.host_read", program="prefill"):
-                tok = int(self._tokens[slot.index])
-                lp = float(lp)
-            with _phase("serving.commit", tokens=1) as sp:
-                finished = self.scheduler.note_token(slot, tok, logprob=lp)
-                if finished:
-                    self._finalize_request(req)
-                sp.set(finished=int(finished))
+            if done:
+                # the chunk that completed the prompt also produced the
+                # request's first token (TTFT is measured where it is
+                # committed); the slot decodes on from the register
+                self._leave_unread("prefill", out, [(slot, req, ())])
 
     def _run_decode(self, slots: list[Slot]) -> None:
         if self._spec:
@@ -1728,32 +1806,23 @@ class Engine:
             live = np.zeros((num_slots,), bool)
             for s in slots:
                 live[s.index] = True
+            # the table changes in place at the next admission or
+            # release: a program in flight keeps its copy
             args = (self.params, self.cache, self._tokens, self._slot_keys,
-                    self._temps, live, self._table)
+                    self._temps, live, self._table.copy())
             self._strict_audit("decode", self._decode_p, args)
             links = self._step_links(slots)
             self._ensure_cost("decode", self._decode_p, args)
         with self.cost.maybe_sample(
                 "decode", fence_in=(self.cache, self._tokens)) as sample:
-            with span("serving.decode", links=links), \
-                    self.timer.dispatch():
-                self.cache, self._tokens, lps = self._decode_p(*args)
+            with span("serving.decode", links=links):
+                with self.timer.dispatch():
+                    self.cache, self._tokens, out = self._decode_p(*args)
+                self.metrics.note_decode_step(
+                    "kernel" if self._use_paged_kernel else "dense")
+                self._leave_unread("decode", out,
+                                   [(s, s.request, s.index) for s in slots])
             sample(self.cache)
-        with _phase("serving.host_read", program="decode"):
-            toks = np.asarray(self._tokens)  # the per-step host read
-            lps = np.asarray(lps)
-        with _phase("serving.commit", tokens=len(slots)) as sp:
-            self.timer.tick(block_on=None)
-            self.metrics.note_decode_step(
-                "kernel" if self._use_paged_kernel else "dense")
-            finished = 0
-            for s in slots:
-                req = s.request
-                if self.scheduler.note_token(s, int(toks[s.index]),
-                                             logprob=float(lps[s.index])):
-                    self._finalize_request(req)
-                    finished += 1
-            sp.set(finished=finished)
 
     def _run_spec_decode(self, slots: list[Slot]) -> None:
         """One speculative step for every decoding slot: draft K
@@ -2035,6 +2104,7 @@ class Engine:
         programs, slot state, and in-flight requests are untouched. The
         registry's series objects survive (zeroed in place), so the
         Prometheus endpoint and any cached metric handles stay live."""
+        self._settle()  # a read belongs to the window of its dispatch
         self.registry.reset()
         self.metrics = ServingMetrics(registry=self.registry)
         # static program costs survive a metrics reset (the compiled

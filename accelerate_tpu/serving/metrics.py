@@ -96,6 +96,11 @@ class ServingMetrics:
         # (Engine._goodput) and keeps this gauge live per step
         self._g_goodput = r.gauge("serving_goodput")
         self._c_decode_path: dict = {}
+        # the engine reads a program's results one step late: a read that
+        # found another program already dispatched (the chip worked while
+        # the host waited) against one that found none (the chip waited)
+        self._c_reads_overlapped = r.counter("serving_reads_overlapped_total")
+        self._c_reads_settled = r.counter("serving_reads_settled_total")
         self.started_at: float | None = None
         self.stopped_at: float | None = None
 
@@ -212,6 +217,18 @@ class ServingMetrics:
 
     def note_prefill_chunk(self) -> None:
         self._c_prefill.inc()
+
+    @property
+    def reads_overlapped(self) -> int:
+        return int(self._c_reads_overlapped.value)
+
+    @property
+    def reads_settled(self) -> int:
+        return int(self._c_reads_settled.value)
+
+    def note_result_read(self, overlapped: bool) -> None:
+        (self._c_reads_overlapped if overlapped
+         else self._c_reads_settled).inc()
 
     def note_admission(self, prompt_len: int, reused_len: int,
                        host_pages: int = 0) -> None:
@@ -330,6 +347,8 @@ class ServingMetrics:
             "tokens_out": float(self.tokens_out),
             "decode_steps": float(self.decode_steps),
             "prefill_chunks": float(self.prefill_chunks),
+            "reads_overlapped": float(self.reads_overlapped),
+            "reads_settled": float(self.reads_settled),
             "prefix_hits": float(self.prefix_hits),
             "prefix_tokens_reused": float(self.prefix_tokens_reused),
             "page_evictions": float(self.page_evictions),

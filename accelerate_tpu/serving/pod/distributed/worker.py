@@ -524,6 +524,11 @@ class WorkerServer:
                 self._maybe_heartbeat(force=True, busy=True, lean=True)
             t0 = self._clock()
             self.engine.step()
+            if any(j.mode == "prefill" for j in self._jobs.values()):
+                # the engine reads a step's results one step late; a
+                # prefill job's first token ships the moment the chip has
+                # it, and its slot retires before it rides a decode step
+                self.engine._settle()
             self._last_step_s = self._clock() - t0
             worked = True
         self._harvest_prefill()

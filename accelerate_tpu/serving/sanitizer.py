@@ -29,7 +29,13 @@ validates exactly those joins after every engine step:
   match the host-tracked draft progress for prefilling lanes);
 - **scheduler books**: queued requests are QUEUED, running slots hold
   RUNNING requests, per-tenant queues/deficits/tier rings stay aligned
-  with the tenant table.
+  with the tenant table, and a slot's count of uncommitted tokens is what
+  the engine's unread program owes its request.
+
+The engine reads a step's results one step late, so these joins run with a
+program in flight. They hold there: every book above is the host's and is
+whole between steps (a commit is one host pass), and the one device read,
+the lengths, only waits for the program.
 
 All host-side: no program changes, no extra compiles (the acceptance
 guard pins compile counts flat with the sanitizer on). Enabled via
@@ -343,6 +349,18 @@ def check_engine(engine) -> None:
         elif slot.state is not SlotState.IDLE:
             _fail("scheduler-books", "an empty slot is not IDLE",
                   slot=slot.index, state=slot.state.value)
+    # tokens on their way: a slot counts exactly the lanes the engine's
+    # unread program owes its CURRENT request (a lane whose request is
+    # gone rode the step dead and is owed nothing)
+    unread = getattr(engine, "_unread", None)
+    for slot in sched.slots:
+        owed = sum(1 for s, req, _ in (unread.lanes if unread else ())
+                   if s is slot and slot.request is req)
+        if slot.unread != owed:
+            _fail("scheduler-books",
+                  "a slot's count of uncommitted tokens disagrees with "
+                  "the program the engine has not read yet",
+                  slot=slot.index, counted=slot.unread, owed=owed)
     keys = set(sched.tenants)
     if set(sched._queues) != keys or set(sched._deficit) != keys:
         _fail("scheduler-books",

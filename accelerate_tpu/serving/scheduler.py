@@ -193,6 +193,10 @@ class Slot:
     # so on a prefix hit it starts at 0 while prompt_done starts at the
     # reused length — the engine runs draft-only catch-up chunks first.
     draft_done: int = 0
+    # tokens of this slot's request that a dispatched program has computed
+    # and the engine has not committed yet (it reads a step's results one
+    # step late): with them the host counts a request's tokens ahead
+    unread: int = 0
 
     def free(self) -> None:
         self.state = SlotState.IDLE
@@ -200,6 +204,14 @@ class Slot:
         self.prompt_done = 0
         self.alloc = None
         self.draft_done = 0
+        self.unread = 0
+
+    @property
+    def budget_dispatched(self) -> bool:
+        """The request's last budgeted token is committed or on its way:
+        no further decode step may carry this lane."""
+        req = self.request
+        return len(req.tokens) + self.unread >= req.max_new_tokens
 
 
 class Scheduler:
@@ -387,7 +399,8 @@ class Scheduler:
             return 0.0
         left_prompt = max(0, req.prompt_len - slot.prompt_done)
         chunks = math.ceil(left_prompt / self.prefill_chunk)
-        return float(chunks + max(0, req.max_new_tokens - len(req.tokens)))
+        return float(chunks + max(0, req.max_new_tokens - len(req.tokens)
+                                  - slot.unread))
 
     def predicted_ttft(self, req: Request, now: float | None = None) -> float:
         """Estimated TTFT if the request stays queued: elapsed wait + the
@@ -682,9 +695,12 @@ class Scheduler:
         Strict alternation when both kinds of work exist: a decode step
         always runs between two prefill chunks, so running streams see at
         most one chunk of extra latency however long the arriving prompt.
+        None also while every live lane only waits for its last token to
+        be committed (`Slot.budget_dispatched`).
         """
         prefilling = [s for s in self.slots if s.state is SlotState.PREFILL]
-        decoding = [s for s in self.slots if s.state is SlotState.DECODE]
+        decoding = [s for s in self.slots if s.state is SlotState.DECODE
+                    and not s.budget_dispatched]
         if prefilling:
             # FIFO by admission, NOT by slot index: under sustained load a
             # freed low-index slot re-fills every step, and picking by

@@ -141,6 +141,20 @@ def _ops_of_shape(text, dtype, shape):
     return kinds
 
 
+def _assert_host_output_is_its_own(program, args, text, shape):
+    """What the host reads of a step is the program's LAST output, the
+    sampled tokens and their logprobs, of `shape`. The engine dispatches the
+    next program, which donates the pool and the token register, before it
+    reads them: every aliased output has to lie before the two."""
+    out = jax.eval_shape(program, *args)
+    assert [(x.shape, x.dtype) for x in out[2]] == [
+        (shape, jnp.int32), (shape, jnp.float32)]
+    aliased = {int(i) for i in re.findall(
+        r"\{(\d+)\}: \(\d+, \{\}", re.search(
+            r"input_output_alias=\{[^\n]*?\}, entry", text).group(0))}
+    assert aliased and max(aliased) < len(jax.tree.leaves(out)) - 2, aliased
+
+
 def _chat_cell_programs(one_chip, kv_dtype, num_pages=4096):
     """The engine's own `decode` and `prefill` at Qwen2-1.5B widths and the
     chat cell's shape (32 slots x 2048, page 16, chunk 256), each with
@@ -211,7 +225,9 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
     codes, 539.5 / 543.3 MB), 11 ms of EVERY call on the chip. Page
     indices lie outside the tile: the scatter of whole pages is the
     in-place update, and the temporaries are what the programs hold
-    beside the pool (1.0 / 157.0 MB; int8 69.0 / 173.3 MB).
+    beside the pool (1.0 / 157.0 MB; int8 69.0 / 173.3 MB; with the
+    host's own output of PR 30, 935,424 / 157,001,728 bytes against
+    903,168 / 157,033,984 without it).
 
     Since PR 27 `decode` on a bf16 pool also produces nothing of ONE
     LAYER's shape: the kernel takes the whole stacked pool and a layer
@@ -249,6 +265,9 @@ def test_engine_programs_update_the_kv_pool_in_place_on_v5e(
             layer_slices = _ops_of_shape(
                 text, "s8" if kv_dtype else "bf16", half[1:])
             assert bool(layer_slices) == bool(kv_dtype), layer_slices
+        # (d) with the host's own output beside them (read one step late)
+        _assert_host_output_is_its_own(
+            program, args, text, (32,) if name == "decode" else ())
 
 
 def test_decode_does_not_know_the_pool_size_on_v5e(one_chip, chip_compile):
@@ -353,6 +372,8 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
         assert memory.temp_size_in_bytes < temp_limit, (
             name, memory.temp_size_in_bytes)
         assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
 
 
 @pytest.mark.parametrize("seq", [2048, 2047], ids=["bench-2048", "loss-2047"])

@@ -268,7 +268,9 @@ def test_submit_drains_freed_slot_before_queue_full_check(gpt2_setup):
     rng = np.random.default_rng(16)
     a = eng.submit(_prompt(rng, 4, cfg.vocab_size), max_new_tokens=1)
     b = eng.submit(_prompt(rng, 4, cfg.vocab_size), max_new_tokens=1)
-    eng.step()  # a's prefill chunk yields its only token -> slot freed
+    eng.step()  # a's prefill chunk computes its only token...
+    assert a.status is RequestStatus.RUNNING and a.tokens == []
+    eng.step()  # ...which the next step commits -> slot freed
     assert a.status is RequestStatus.FINISHED
     assert eng.scheduler.queue_depth == 1  # b still holds the queue position
     c = eng.submit(_prompt(rng, 4, cfg.vocab_size), max_new_tokens=1)
@@ -811,6 +813,10 @@ def test_live_pages_kernel_token_exact_with_dead_lanes(window):
             lengths = np.asarray(eng.cache.lengths)
             for s in eng.scheduler.slots:
                 if s not in slots and lengths[s.index] > 0:
+                    # a DECODE lane is dead only while its last token
+                    # waits to be committed (read one step late)
+                    assert (s.state is not SlotState.DECODE
+                            or s.budget_dispatched)
                     dead.add(s.state)
             run_decode(slots)
 
@@ -831,7 +837,7 @@ def test_live_pages_kernel_token_exact_with_dead_lanes(window):
                   paged_attention=True)
     kernel, dead = run(eng)
     assert kernel == dense
-    assert dead == {SlotState.IDLE, SlotState.PREFILL}
+    assert dead - {SlotState.DECODE} == {SlotState.IDLE, SlotState.PREFILL}
     assert eng.compile_stats() == {"admit": 1, "prefill": 1, "decode": 1}
 
 
