@@ -115,9 +115,18 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
     - "linear": positions stretched by `factor` (position interpolation);
     - "llama3": Llama-3.1 wavelength-banded scaling — wavelengths beyond
       `original_max_position_embeddings/low_freq_factor` divide by `factor`,
-      short wavelengths stay, the band between interpolates smoothly.
+      short wavelengths stay, the band between interpolates smoothly;
+    - "yarn": with `d = head_dim` and `f_i = theta^(-2i/d)`, the pair `i`
+      keeps `f_i` below `low` and takes `f_i / factor` above `high`, a
+      linear ramp between, where `low` / `high` are the pairs that turn
+      `beta_fast` / `beta_slow` times over `original_max_position_embeddings`
+      (`c(r) = d ln(original / (2 pi r)) / (2 ln theta)`, floored / ceiled);
+      cos and sin are both multiplied by `attention_factor` (`0.1
+      ln(factor) + 1` where the dict gives none), which scales the scores
+      by its square.
     """
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    amplitude = 1.0
     if scaling:
         rope_type = scaling.get("rope_type", scaling.get("type", "default"))
         if rope_type == "llama3":
@@ -133,11 +142,29 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
             inv_freq = np.where(medium, smoothed, scaled)
         elif rope_type == "linear":
             inv_freq = inv_freq / scaling["factor"]
+        elif rope_type == "yarn":
+            factor = scaling["factor"]
+            old_len = scaling["original_max_position_embeddings"]
+
+            def pair_turning(rotations):
+                return (head_dim * math.log(old_len / (2 * math.pi * rotations))
+                        / (2 * math.log(theta)))
+
+            low = max(math.floor(pair_turning(scaling.get("beta_fast", 32))), 0)
+            high = min(math.ceil(pair_turning(scaling.get("beta_slow", 1))),
+                       head_dim - 1)
+            ramp = np.clip((np.arange(head_dim // 2) - low)
+                           / max(high - low, 1e-3), 0.0, 1.0)
+            inv_freq = inv_freq / factor * ramp + inv_freq * (1 - ramp)
+            amplitude = scaling.get("attention_factor")
+            if amplitude is None:
+                amplitude = 0.1 * math.log(factor) + 1.0
         elif rope_type not in ("default", None):
             raise ValueError(f"unsupported rope_scaling type {rope_type!r}")
     t = np.arange(max_len)
     freqs = np.outer(t, inv_freq)
-    return jnp.asarray(np.cos(freqs), jnp.float32), jnp.asarray(np.sin(freqs), jnp.float32)
+    return (jnp.asarray(amplitude * np.cos(freqs), jnp.float32),
+            jnp.asarray(amplitude * np.sin(freqs), jnp.float32))
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array) -> jax.Array:

@@ -20,7 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["sigmoid_topk_route", "expert_counts", "grouped_swiglu_experts"]
+__all__ = ["sigmoid_topk_route", "softmax_topk_route", "expert_counts",
+           "grouped_swiglu_experts"]
 
 
 def sigmoid_topk_route(x, router_kernel, correction_bias, top_k: int,
@@ -44,6 +45,22 @@ def sigmoid_topk_route(x, router_kernel, correction_bias, top_k: int,
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                                  + 1e-20)
         return experts.astype(jnp.int32), weights * scaling_factor
+
+
+def softmax_topk_route(x, router_kernel, top_k: int, norm_topk: bool = True):
+    """Softmax routing: `p = softmax(x W_r)` over ALL experts in float32;
+    the experts are the `top_k` of `p` and their weights `p` at those
+    experts, divided by their sum where `norm_topk`. No bias in the
+    choice and no scaling factor. x [T, h], router_kernel [h, E] ->
+    (experts [T, k] int32, weights [T, k] float32)."""
+    with jax.named_scope("moe.route"):
+        probs = jax.nn.softmax(jnp.dot(
+            x, router_kernel.astype(x.dtype),
+            preferred_element_type=jnp.float32), axis=-1)
+        weights, experts = jax.lax.top_k(probs, top_k)
+        if norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return experts.astype(jnp.int32), weights
 
 
 def expert_counts(experts, num_experts: int, token_mask=None):

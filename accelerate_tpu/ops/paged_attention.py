@@ -97,6 +97,10 @@ from . import kernel_mode
 
 NEG_INF = -1e30
 KERNEL_NAME = "paged_decode_attention"
+# the same kernel body over a RING of pages (a layer kind that keeps the
+# last `window` positions only): a name of its own in the compiled program,
+# because a device trace names an operation by nothing else
+WINDOW_KERNEL_NAME = "paged_decode_attention_window"
 _LANES = 128  # TPU vector lane width; scalar-per-group state is kept 2D
 _SUBLANES = 8  # f32 sublanes per vreg; the query group pads to this
 
@@ -229,14 +233,18 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
                        vn_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
                        sm_scale: float, page_size: int, pages_per_slot: int,
                        pages_per_group: int, num_kv_heads: int,
-                       window: int | None):
+                       window: int | None, ring: bool = False):
     """Grid [slots]: one step is one slot. A loop with a DYNAMIC trip
     count walks the slot's live pages in groups of `pages_per_group`:
     each page of a group is one copy (every kv head of it) out of the
     whole stacked pool `k_hbm`/`v_hbm` [L, N+1, Hkv, ps, D], where it
     lies in HBM, into one of two VMEM buffers, while the other buffer is
     folded into the online softmax. A slot of length 0 (a lane the
-    engine masked out) starts no copy at all."""
+    engine masked out) starts no copy at all. With `ring`, the table row
+    is a ring: the page of positions [p * ps, (p + 1) * ps) is entry `p %
+    pages_per_slot`, and what an entry held `pages_per_slot` pages ago
+    lies behind the window, where the mask (which goes by position) does
+    not look."""
     s = pl.program_id(0)
     length = lengths_ref[s]
     layer = layer_ref[0]
@@ -252,7 +260,8 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
         """Start the copies of every page of group `g` into buffer `slot`."""
         for j in range(G):
             # entries past the table's end re-read its last page: masked
-            page = table_ref[s * P + jnp.minimum(g * G + j, P - 1)]
+            page = table_ref[s * P + ((g * G + j) % P if ring
+                                      else jnp.minimum(g * G + j, P - 1))]
             pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, j],
                                   sem.at[0, slot]).start()
             pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, j],
@@ -346,7 +355,7 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
 
 
 def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths,
-                     window: int | None, interpret: bool):
+                     window: int | None, interpret: bool, ring: bool = False):
     """q4 [S, Hkv, Gp, D], kn/vn [S, Hkv, 1, D], pools [L, N+1, Hkv, ps,
     D], layer int32 scalar -> out [S, Hkv, Gp, D]."""
     S, Hkv, Gp, D = q4.shape
@@ -355,7 +364,8 @@ def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths,
     G = _pages_per_group(P, pool_k.shape[2:], pool_k.dtype)
     kernel = functools.partial(
         _live_pages_kernel, sm_scale=1.0 / math.sqrt(D), page_size=ps,
-        pages_per_slot=P, pages_per_group=G, num_kv_heads=Hkv, window=window)
+        pages_per_slot=P, pages_per_group=G, num_kv_heads=Hkv, window=window,
+        ring=ring)
     per_slot = lambda s, *_: (s, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -376,7 +386,7 @@ def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths,
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name=KERNEL_NAME,
+        name=WINDOW_KERNEL_NAME if ring else KERNEL_NAME,
         interpret=pltpu.InterpretParams() if interpret else False,
     )(table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q4, kn, vn, pool_k, pool_v)
@@ -572,9 +582,13 @@ def paged_decode_attention(
     meta: PagedDecodeMeta,
     window: int | None = None,
     interpret: bool | None = None,
+    ring: bool = False,
 ):
     """One decode step of paged attention for every slot at once, in the
-    layer `pk.layer` of the stacked pool.
+    layer `pk.layer` of the stacked pool. `ring`: the pool is a group's
+    that keeps the last `window` positions, `meta.table` its rings
+    (`serving/cache.py`), and the call is the kernel named
+    `WINDOW_KERNEL_NAME`.
 
     q: [S, 1, H, D] (S slots, one token each, H = Hkv * group);
     k_new/v_new: [S, 1, Hkv, D] — this step's K/V, folded in-kernel and
@@ -596,9 +610,17 @@ def paged_decode_attention(
             "paged decode attention takes the whole stacked pool [L, "
             "pages + 1, Hkv, page_size, D] and a layer index; got a pool of "
             f"shape {pk.data.shape} and layer {pk.layer!r}")
-    if window is not None and (window <= 0 or window >= meta.rows):
+    if ring:
+        if window is None or pk.quantized or D % _LANES:
+            raise ValueError(
+                "a ring of pages is read by the live-pages kernel under a "
+                "window: it takes a bf16 or float pool of 128-lane heads; "
+                f"got window {window!r}, head width {D}, int8 "
+                f"{pk.quantized}")
+    elif window is not None and (window <= 0 or window >= meta.rows):
         window = None  # band wider than the cache reach: plain causal
-    interpret = kernel_mode.resolve_interpret(KERNEL_NAME, interpret)
+    interpret = kernel_mode.resolve_interpret(
+        WINDOW_KERNEL_NAME if ring else KERNEL_NAME, interpret)
     G = H // Hkv
     row_dtype = pk.row_dtype
     # the fold must see exactly the bytes the engine will write, so a
@@ -628,7 +650,8 @@ def paged_decode_attention(
             meta.lengths, window, interpret)
     else:
         out = _live_pages_call(q4, kn, vn, pk.data, pv.data, pk.layer,
-                               meta.table, meta.lengths, window, interpret)
+                               meta.table, meta.lengths, window, interpret,
+                               ring)
     return out[:, :, :G].reshape(S, 1, H, D), (k_row, v_row)
 
 
@@ -640,6 +663,7 @@ def paged_decode_reference(
     pv: PagedKV,
     meta: PagedDecodeMeta,
     window: int | None = None,
+    ring: bool = False,
 ):
     """Dense-gather reference with identical semantics (and the
     executable spec of them): gather every table page of the pool's layer
@@ -667,12 +691,22 @@ def paged_decode_reference(
     k_row = k_new.astype(row_dtype)
     v_row = v_new.astype(row_dtype)
     rows = jnp.arange(R, dtype=jnp.int32)
-    sel = (rows[None, :] == meta.lengths[:, None])[:, :, None, None]
+    if ring:
+        # row r of a ring holds the newest written position p < length
+        # with p % R == r (negative: nothing of this request yet); the new
+        # token's row goes where position `length` belongs
+        last = meta.lengths[:, None] - 1
+        pos = last - (last - rows[None, :]) % R
+        pos = jnp.where(rows[None, :] == meta.lengths[:, None] % R,
+                        meta.lengths[:, None], pos)
+    else:
+        pos = jnp.broadcast_to(rows[None, :], (S, R))
+    sel = (pos == meta.lengths[:, None])[:, :, None, None]
     k_all = jnp.where(sel, k_row.astype(jnp.float32), k_all)
     v_all = jnp.where(sel, v_row.astype(jnp.float32), v_all)
-    keep = rows[None, :] <= meta.lengths[:, None]
-    if window is not None and window < R:
-        keep = keep & (rows[None, :] > meta.lengths[:, None] - window)
+    keep = (pos >= 0) & (pos <= meta.lengths[:, None])
+    if window is not None and (ring or window < R):
+        keep = keep & (pos > meta.lengths[:, None] - window)
     q4 = q[:, 0].reshape(S, Hkv, G, D).astype(jnp.float32)
     s = jnp.einsum("shgd,srhd->shgr", q4, k_all) / math.sqrt(D)
     s = jnp.where(keep[:, None, None, :], s, NEG_INF)

@@ -188,12 +188,28 @@ class CacheSpec:
     that is key and value at once; the pool is one array and
     `PagedKVCache.v` is None. A page is `page_size` token positions of
     either kind, so the allocator, the prefix index, admission and the
-    scheduler do not know the kind."""
+    scheduler do not know the kind.
+
+    A family whose layers differ in KIND returns a tuple of these, one
+    GROUP a layer kind (`GroupedPagedCache`): `layers` are the model's
+    layers the group holds, in order, and `window` is the group's
+    retention rule: None keeps every position of a request, W keeps the
+    last W (a ring of pages a slot, whatever the request's length). The
+    first group keeps every position: it is the one whose pages grow with
+    the context, and the one the allocator's books, the prefix index and
+    the engine's page gauges mean."""
 
     num_layers: int
     heads: int
     width: int
     kind: str = "kv"
+    window: int | None = None
+    layers: tuple | None = None
+
+    @property
+    def label(self) -> str:
+        """The group's name in gauges and debug output."""
+        return "full" if self.window is None else f"window{self.window}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -226,6 +242,18 @@ class PagedKVCache:
     carries None in V's place and every write takes None for V's rows;
     int8 codes are not implemented for it.
 
+    RING mode (`create(window=W)`; a group of `GroupedPagedCache` whose
+    layers see the last W positions only): a slot's table row is a ring
+    of `pages_per_slot` = ceil((W + pad_slack) / page_size) + 1 pages,
+    the page of positions [p * page_size, (p + 1) * page_size) is entry
+    `p % pages_per_slot`, and view row r holds the newest written
+    position that is r modulo `rows`. A chunk's rows (padding included)
+    overwrite positions at least W + 1 behind the chunk's first query,
+    so what a query may see is never overwritten before it is read, and
+    a slot holds the same pages at any length. Who reads a ring view
+    masks by POSITION (`ring_positions`), so stale rows of the slot's
+    last tenant, whose positions come out negative, are never seen.
+
     `stats`: a family's own device counters (`family.init_serving_stats`),
     or None. They ride here because the cache is what both engine
     programs donate and return: they are accumulated on the device and
@@ -243,6 +271,7 @@ class PagedKVCache:
     v_scale: jax.Array | None = None
     compute_dtype: Any = jnp.bfloat16
     stats: Any = None
+    window: int | None = None
 
     @classmethod
     def create(
@@ -259,6 +288,7 @@ class PagedKVCache:
         kv_dtype: Any = None,
         latent: bool = False,
         stats: Any = None,
+        window: int | None = None,
     ) -> "PagedKVCache":
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -272,9 +302,18 @@ class PagedKVCache:
                 "an int8 latent pool is not implemented: kv_dtype='int8' "
                 "quantizes K and V rows per head, and a latent row's key "
                 "and value parts would need scales of their own")
+        if window is not None and (latent or quantized or window < 1):
+            raise ValueError(
+                "a ring of pages (window=) holds K/V rows in `dtype`: a "
+                "latent or int8 ring is not implemented, and a window is "
+                f"at least 1; got window={window}")
         # a slot's view must cover max_len rows plus the chunk-padding
         # spill (see SlotKVCache docstring) — round up to whole pages
         pages_per_slot = -(-(max_len + pad_slack) // page_size)
+        if window is not None:
+            # the ring: the window, one chunk, and a page of rounding
+            pages_per_slot = min(pages_per_slot,
+                                 -(-(window + pad_slack) // page_size) + 1)
         if num_pages is None:
             num_pages = num_slots * pages_per_slot
         if num_pages < pages_per_slot:
@@ -298,7 +337,16 @@ class PagedKVCache:
             else None,
             compute_dtype=dtype,
             stats=stats,
+            window=window,
         )
+
+    @property
+    def ring(self) -> bool:
+        """A slot's table row is a ring (class docstring, RING mode)."""
+        return self.window is not None
+
+    def with_stats(self, stats) -> "PagedKVCache":
+        return dataclasses.replace(self, stats=stats)
 
     @property
     def quantized(self) -> bool:
@@ -378,7 +426,13 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
     pages_per_slot * page_size, dequantized to `compute_dtype` on an
     int8 pool. `table_row` ([pages_per_slot] int32) and `slot` are
     traced — one compiled program covers every slot and every page
-    mapping."""
+    mapping. A grouped cache takes one table row a group and gives one
+    view a group (a ring group's view is its ring)."""
+    if isinstance(cache, GroupedPagedCache):
+        views = [paged_slot_view(g, row, slot)
+                 for g, row in zip(cache.groups, table_row)]
+        return (tuple(v[0] for v in views), tuple(v[1] for v in views),
+                cache.lengths[slot])
     L, _, H, ps, D = cache.k.shape
     P = cache.pages_per_slot
     ks, vs = _both(
@@ -404,12 +458,19 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
     put back as the bytes they were (selected, not re-encoded: an int8
     round-trip is NOT idempotent, so re-quantizing "the same values"
     would drift them)."""
+    if isinstance(cache, GroupedPagedCache):
+        return cache.map_groups(
+            lambda g, row, nk, nv: paged_write_slot(g, row, slot, nk, nv,
+                                                    advance, chunk),
+            table_row, new_k, new_v)
     L, _, H, ps, D = cache.k.shape
     R = cache.rows
     length = cache.lengths[slot]
     # rows never spill past the view: length <= max_len and pad_slack
     # covers the chunk padding (module docstring)
     rows = length + jnp.arange(chunk, dtype=jnp.int32)
+    if cache.ring:
+        rows = rows % R
     win_k, win_v = _both(
         lambda new: jnp.take(new.reshape(L, R, H, D), rows, axis=1)[:, None],
         new_k, new_v)
@@ -446,10 +507,17 @@ def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
     N, W = win_k.shape[1], win_k.shape[2]
     n_pages = (W + ps - 2) // ps + 1    # most that W consecutive rows touch
     lane_page = (start // ps)[:, None] + jnp.arange(n_pages, dtype=jnp.int32)
-    # a last page past the table's end holds no written row: the trash page
-    pages = jnp.take_along_axis(
-        table, lane_page, axis=1, mode="fill",
-        fill_value=cache.trash_page).reshape(N * n_pages)
+    if cache.ring:
+        # n_pages consecutive entries of a ring are distinct pages: a
+        # ring has a page more than a window's and a chunk's rows take
+        pages = jnp.take_along_axis(
+            table, lane_page % table.shape[1], axis=1).reshape(N * n_pages)
+    else:
+        # a last page past the table's end holds no written row: the
+        # trash page
+        pages = jnp.take_along_axis(
+            table, lane_page, axis=1, mode="fill",
+            fill_value=cache.trash_page).reshape(N * n_pages)
     # the window row that belongs at every row of those pages
     src = (lane_page[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
            - start[:, None, None])                         # [N, n_pages, ps]
@@ -488,7 +556,11 @@ def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     """All slots' pages gathered into the dense decode layout:
     (k [L, S, R, H, D], v [L, S, R, H, D]), dequantized to
     `compute_dtype` on an int8 pool. `table` is the full
-    [S, pages_per_slot] int32 page table (traced)."""
+    [S, pages_per_slot] int32 page table (traced); one a group, and one
+    view a group, for a grouped cache."""
+    if isinstance(cache, GroupedPagedCache):
+        views = [paged_batch_view(g, t) for g, t in zip(cache.groups, table)]
+        return tuple(v[0] for v in views), tuple(v[1] for v in views)
     L, _, H, ps, D = cache.k.shape
     S = cache.num_slots
     P = cache.pages_per_slot
@@ -514,6 +586,10 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
     attention modes: the dense gather path extracts the row from the
     returned views (`paged_append_batch`), the Pallas kernel path hands
     the rows over directly."""
+    if isinstance(cache, GroupedPagedCache):
+        return cache.map_groups(
+            lambda g, t, rk, rv: paged_append_rows(g, t, rk, rv, live),
+            table, row_k, row_v)
     return _scatter_rows(cache, table, cache.lengths,
                          jnp.ones_like(cache.lengths),
                          *_both(lambda row: row[:, :, None], row_k, row_v),
@@ -526,7 +602,11 @@ def paged_append_batch(cache: PagedKVCache, table: jax.Array,
     """`paged_append_rows` for the dense-gather decode path, where the
     family forward returns whole updated [L, S, R, H, D] views: extract
     the one changed row per slot (view row `length`), then scatter."""
-    row = cache.lengths
+    if isinstance(cache, GroupedPagedCache):
+        return cache.map_groups(
+            lambda g, t, nk, nv: paged_append_batch(g, t, nk, nv, live),
+            table, new_k, new_v)
+    row = cache.lengths % cache.rows if cache.ring else cache.lengths
     idx = row[None, :, None, None, None]
     row_k, row_v = _both(                                      # [L, S, H, D]
         lambda new: jnp.take_along_axis(new, idx, axis=2)[:, :, 0],
@@ -558,6 +638,9 @@ def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,
     """Admit a request into `slot`: length starts at the reused prefix
     length (0 on a cold miss). Nothing is wiped — reused pages carry the
     prefix K/V, rows past `length` are masked until overwritten."""
+    if isinstance(cache, GroupedPagedCache):
+        return dataclasses.replace(cache, groups=tuple(
+            paged_admit_slot(g, slot, reused_len) for g in cache.groups))
     return dataclasses.replace(
         cache, lengths=cache.lengths.at[slot].set(reused_len))
 
@@ -566,21 +649,105 @@ def _flatten_paged(cache: PagedKVCache):
     return (cache.k, cache.v, cache.lengths, cache.k_scale, cache.v_scale,
             cache.stats), (
         cache.page_size, cache.pages_per_slot, cache.max_len,
-        cache.pad_slack, cache.compute_dtype)
+        cache.pad_slack, cache.compute_dtype, cache.window)
 
 
 def _unflatten_paged(aux, children):
     k, v, lengths, k_scale, v_scale, stats = children
-    page_size, pages_per_slot, max_len, pad_slack, compute_dtype = aux
+    (page_size, pages_per_slot, max_len, pad_slack, compute_dtype,
+     window) = aux
     return PagedKVCache(k=k, v=v, lengths=lengths, page_size=page_size,
                         pages_per_slot=pages_per_slot, max_len=max_len,
                         pad_slack=pad_slack, k_scale=k_scale,
                         v_scale=v_scale, compute_dtype=compute_dtype,
-                        stats=stats)
+                        stats=stats, window=window)
 
 
 jax.tree_util.register_pytree_node(PagedKVCache, _flatten_paged,
                                    _unflatten_paged)
+
+
+def ring_positions(rows: int, last):
+    """The position each of a ring view's `rows` rows holds once
+    positions 0..`last` are written (`last` [...] int32 -> [..., rows]):
+    row r holds the newest position that is r modulo `rows`; a negative
+    position means that nothing of this request is there. A view that
+    never wraps (`last < rows`) is the same rule."""
+    last = jnp.asarray(last, jnp.int32)[..., None]
+    return last - (last - jnp.arange(rows, dtype=jnp.int32)) % rows
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedPagedCache:
+    """The cache of a family whose layers differ in kind: one
+    `PagedKVCache` a GROUP (`CacheSpec`), each with its own stacked pool
+    pair, its own page shape and its retention rule. `groups[0]` keeps
+    every position (`window` None); a further group keeps a window, as a
+    ring of pages a slot. `layers[g]` are the model's layers group g
+    holds, in order. Every group carries the slots' lengths (they advance
+    together through the one `_scatter_rows`); the family's counters ride
+    the first. What reads ONE pool's books (`num_pages`, `page_nbytes`,
+    `pages_per_slot`, ...) reads the first group's: the one that grows
+    with context."""
+
+    groups: tuple
+    layers: tuple
+
+    @classmethod
+    def create(cls, specs, num_slots: int, max_len: int, dtype=jnp.bfloat16,
+               page_size: int = 16, pad_slack: int = 0,
+               num_pages: int | None = None,
+               stats: Any = None) -> "GroupedPagedCache":
+        """`num_pages` sizes the first group's pool; a ring group's pool
+        follows from the slots (`num_slots` rings)."""
+        if specs[0].window is not None or any(
+                s.window is None for s in specs[1:]):
+            raise ValueError(
+                "the first group of a grouped cache keeps every position "
+                "and every further one a window; got windows "
+                f"{[s.window for s in specs]}")
+        if any(s.kind != "kv" or s.layers is None for s in specs):
+            raise ValueError(
+                "every group of a grouped cache holds K/V rows "
+                "(kind='kv') and names its layers")
+        return cls(
+            groups=tuple(PagedKVCache.create(
+                s.num_layers, num_slots, max_len, s.heads, s.width,
+                dtype=dtype, page_size=page_size, pad_slack=pad_slack,
+                num_pages=num_pages if g == 0 else None,
+                stats=stats if g == 0 else None, window=s.window)
+                for g, s in enumerate(specs)),
+            layers=tuple(tuple(s.layers) for s in specs))
+
+    def map_groups(self, f, *per_group) -> "GroupedPagedCache":
+        """`f(group, *the g-th of every argument)` in every group's place."""
+        return dataclasses.replace(self, groups=tuple(
+            f(g, *args) for g, *args in zip(self.groups, *per_group)))
+
+    def with_stats(self, stats) -> "GroupedPagedCache":
+        first, *rest = self.groups
+        return dataclasses.replace(
+            self, groups=(first.with_stats(stats), *rest))
+
+    def nbytes(self) -> int:
+        return sum(g.nbytes() for g in self.groups)
+
+    # one pool's books, the lengths and the counters: the first group's
+    _OF_THE_FIRST_GROUP = (
+        "lengths", "stats", "num_pages", "trash_page", "num_slots",
+        "page_size", "pages_per_slot", "max_len", "pad_slack", "rows",
+        "page_nbytes", "compute_dtype", "quantized", "latent")
+
+    def __getattr__(self, name):
+        if name in self._OF_THE_FIRST_GROUP and "groups" in self.__dict__:
+            return getattr(self.groups[0], name)
+        raise AttributeError(name)
+
+
+jax.tree_util.register_pytree_node(
+    GroupedPagedCache,
+    lambda c: (c.groups, c.layers),
+    lambda layers, groups: GroupedPagedCache(tuple(groups), layers))
 
 
 # ---------------------------------------------------------------------------
@@ -885,12 +1052,16 @@ class PageAllocation:
     host-resident: the allocator already reserved `page` and re-homed
     the node, but the BYTES are still in the host tier — the engine must
     install them (jitted PageTransport install) before the slot's admit
-    program runs, or the reused prefix serves garbage."""
+    program runs, or the reused prefix serves garbage.
+
+    `rings`: under a grouped cache, the pages of the slot's ring in each
+    window group (one list a group), fixed from admission to release."""
 
     reused_len: int
     nodes: list
     pages: list[int]
     swap_ins: list | None = None
+    rings: tuple = ()
 
 
 class PagedAllocator:
@@ -911,11 +1082,18 @@ class PagedAllocator:
         prefix_cache: bool = True,
         on_evict: Callable[[int], None] | None = None,
         on_unmap: Callable[[int], None] | None = None,
+        rings: tuple = (),
     ):
         self.page_size = page_size
         self.pad_slack = pad_slack
         self.prefix_cache = prefix_cache
         self.pool = PagePool(num_pages)
+        # the window groups of a grouped cache, as (pages a slot's ring
+        # has at most, pages in the group's pool) each: a free list a
+        # group. A request takes its ring at admission and keeps exactly
+        # those pages until release: no page moves while it decodes
+        self.ring_pages = tuple(per_slot for per_slot, _ in rings)
+        self.ring_pools = tuple(PagePool(n) for _, n in rings)
         self.index = PrefixIndex(page_size)
         self.on_evict = on_evict
         self.on_unmap = on_unmap
@@ -959,11 +1137,20 @@ class PagedAllocator:
         """Allocated to live slots OR cached in the prefix tree."""
         return self.pool.used_count
 
-    def pages_needed(self, prompt_len: int, max_new_tokens: int) -> int:
-        """Worst-case pages for one request: every prompt+generated row
-        plus the chunk-padding spill, in whole pages."""
+    def pages_needed(self, prompt_len: int, max_new_tokens: int,
+                     group: int = 0) -> int:
+        """Worst-case pages for one request in cache group `group`: every
+        prompt+generated row plus the chunk-padding spill, in whole
+        pages; in a window group (`group` >= 1) at most the ring."""
         rows = prompt_len + max_new_tokens + self.pad_slack
-        return -(-rows // self.page_size)
+        pages = -(-rows // self.page_size)
+        return pages if group == 0 else min(pages,
+                                            self.ring_pages[group - 1])
+
+    @property
+    def ring_pages_in_use(self) -> tuple:
+        """Pages held by live slots' rings, one count a window group."""
+        return tuple(pool.used_count for pool in self.ring_pools)
 
     def allocate(self, request) -> PageAllocation | None:
         """Match the longest cached prefix and reserve the remaining
@@ -977,11 +1164,21 @@ class PagedAllocator:
             sp.set(pages=len(alloc.pages) if alloc else 0,
                    reused_len=alloc.reused_len if alloc else 0,
                    evicted=self.evictions - evictions)
+            if self.ring_pools:
+                sp.set(full_pages=len(alloc.pages) if alloc else 0,
+                       window_pages=sum(map(len, alloc.rings)) if alloc
+                       else 0)
         return alloc
 
     def _allocate(self, request) -> PageAllocation | None:
         if self.hold_admission is not None and self.hold_admission(request):
             return None
+        ring_need = [self.pages_needed(request.prompt_len,
+                                       request.max_new_tokens, g + 1)
+                     for g in range(len(self.ring_pools))]
+        if any(pool.free_count < n
+               for pool, n in zip(self.ring_pools, ring_need)):
+            return None     # before anything is matched, acquired or evicted
         path = (self.index.match(request.prompt)
                 if self.prefix_cache else [])
         # residency along a matched path is an HBM prefix then a host
@@ -1050,6 +1247,8 @@ class PagedAllocator:
             nodes=hbm_nodes + host_nodes,
             pages=[n.page for n in hbm_nodes + host_nodes] + private,
             swap_ins=swap_ins or None,
+            rings=tuple(pool.alloc(n)
+                        for pool, n in zip(self.ring_pools, ring_need)),
         )
 
     def rollback(self, alloc: PageAllocation) -> None:
@@ -1063,6 +1262,8 @@ class PagedAllocator:
         host tier keeps the mirror."""
         self.index.release(alloc.nodes)
         self.pool.release(alloc.pages[len(alloc.nodes):])
+        for pool, ring in zip(self.ring_pools, alloc.rings):
+            pool.release(ring)
         for node, page in (alloc.swap_ins or ()):
             node.page = -1
             node.residency = "host"
@@ -1133,6 +1334,8 @@ class PagedAllocator:
                      if full > n_cached else [])
             freed = spare + alloc.pages[full:]
             self.pool.release(freed)
+            for pool, ring in zip(self.ring_pools, alloc.rings):
+                pool.release(ring)
             if self.on_unmap is not None:
                 self.on_unmap(slot.index)
             sp.set(pages=len(freed),

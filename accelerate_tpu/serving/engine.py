@@ -101,6 +101,7 @@ from ..telemetry.trace import (
 from ..telemetry.watchdog import StallWatchdog, resolve_stall_timeout
 from .cache import (
     CacheSpec,
+    GroupedPagedCache,
     PagedAllocator,
     PagedKVCache,
     SlotKVCache,
@@ -345,14 +346,16 @@ class EngineConfig:
     mesh: Any = None
 
 
-def _cache_spec(config, family=None) -> CacheSpec:
+def _cache_spec(config, family=None):
     """What the pool holds for a token in a layer. A family that declares
-    `cache_spec(config)` says so itself (a latent pool). Every other
-    family is a K/V stack read off its config: GQA families carry
+    `cache_spec(config)` says so itself: a latent pool, or a TUPLE of
+    specs, one group a layer kind, for layers that differ in kind. Every
+    other family is a K/V stack read off its config: GQA families carry
     num_key_value_heads, MHA families fall back to num_attention_heads."""
     declared = getattr(family, "cache_spec", None)
     if declared is not None:
-        return declared(config)
+        spec = declared(config)
+        return tuple(spec) if isinstance(spec, (tuple, list)) else spec
     kv = getattr(config, "num_key_value_heads", None)
     if kv is None:
         kv = config.num_attention_heads
@@ -367,6 +370,22 @@ def _unported_with_latent_cache(ec: "EngineConfig") -> list[str]:
          "and a V half)", ec.host_tier_bytes > 0),
         ("mesh (a sharded latent pool and its kernel)", ec.mesh is not None),
         ("speculative (the verify step's multi-token latent attention)",
+         ec.speculative is not None)) if on]
+
+
+def _unported_with_grouped_cache(ec: "EngineConfig") -> list[str]:
+    """The options a cache with one group a layer kind does not implement
+    yet (ROADMAP M2), each by what it would take."""
+    return [what for what, on in (
+        ("prefix_cache=True (a hit at position p needs a window layer's "
+         "last rows before p, which a ring has overwritten: published pages "
+         "need a retention rule of their own)", ec.prefix_cache),
+        ("kv_dtype='int8' (an int8 ring and its kernel)",
+         ec.kv_dtype is not None),
+        ("host_tier_bytes > 0 (a page shipment is one pool's pages)",
+         ec.host_tier_bytes > 0),
+        ("mesh (sharded groups and their kernels)", ec.mesh is not None),
+        ("speculative (the verify step's multi-token window attention)",
          ec.speculative is not None)) if on]
 
 
@@ -453,6 +472,12 @@ class Engine:
         # its forward is handed beyond the uniform decode contract follows
         # from what it takes (`_build_programs`)
         self._cache_spec = _cache_spec(config, family)
+        # one group a layer kind (serving/cache.py GroupedPagedCache), or
+        # None: the one pool every layer shares
+        self._cache_groups = None
+        if isinstance(self._cache_spec, tuple):
+            self._cache_groups = self._cache_spec
+            self._cache_spec = self._cache_groups[0]
         self._family = family
         self._tracker = tracker
         self._log_every = log_every
@@ -472,6 +497,15 @@ class Engine:
                     "(CacheSpec.kind='latent'), which is not implemented "
                     "together with: " + "; ".join(unported) + ". Nothing "
                     "falls back to a K/V pool.")
+        if self._cache_groups is not None:
+            unported = _unported_with_grouped_cache(ec)
+            if unported:
+                raise ValueError(
+                    "this family's layers differ in kind (cache_spec gives "
+                    f"{len(self._cache_groups)} groups: "
+                    f"{[g.label for g in self._cache_groups]}), which is "
+                    "not implemented together with: " + "; ".join(unported)
+                    + ". Nothing falls back to a one-kind pool.")
         self._spec = ec.speculative is not None
         if self._spec:
             if ec.mesh is not None:
@@ -526,16 +560,23 @@ class Engine:
         # pages and are never attended)
         self._pad_slack = max(ec.prefill_chunk,
                               ec.draft_k if self._spec else 0)
-        self.cache = PagedKVCache.create(
-            spec.num_layers, ec.num_slots, ec.max_len, spec.heads,
-            spec.width, dtype=ec.cache_dtype, page_size=ec.page_size,
-            pad_slack=self._pad_slack, num_pages=ec.num_pages,
-            kv_dtype=ec.kv_dtype, latent=spec.kind == "latent",
-            # one set of counters a program: a reader wants the decode
-            # steps' experts apart from the chunks'
-            stats=None if stats is None else {
-                "prefill": stats(config), "decode": stats(config)},
-        )
+        # one set of counters a program: a reader wants the decode steps'
+        # experts apart from the chunks'
+        stats = None if stats is None else {
+            "prefill": stats(config), "decode": stats(config)}
+        if self._cache_groups is not None:
+            self.cache = GroupedPagedCache.create(
+                self._cache_groups, ec.num_slots, ec.max_len,
+                dtype=ec.cache_dtype, page_size=ec.page_size,
+                pad_slack=self._pad_slack, num_pages=ec.num_pages,
+                stats=stats)
+        else:
+            self.cache = PagedKVCache.create(
+                spec.num_layers, ec.num_slots, ec.max_len, spec.heads,
+                spec.width, dtype=ec.cache_dtype, page_size=ec.page_size,
+                pad_slack=self._pad_slack, num_pages=ec.num_pages,
+                kv_dtype=ec.kv_dtype, latent=spec.kind == "latent",
+                stats=stats)
         if self._spec:
             draft = _cache_spec(self._draft_config, dfam)
             if draft.kind != "kv":
@@ -590,6 +631,8 @@ class Engine:
             prefix_cache=ec.prefix_cache,
             on_evict=lambda n: self.metrics.note_page_evictions(n),
             on_unmap=self._unmap_slot,
+            rings=tuple((g.pages_per_slot, g.num_pages)
+                        for g in self._ring_groups()),
         )
         # COW forking: parent_id -> parent handle, consulted by the
         # admission hold below (entries drop as parents reach a terminal
@@ -626,6 +669,10 @@ class Engine:
         self._table = np.full(
             (ec.num_slots, self.cache.pages_per_slot),
             self.cache.trash_page, np.int32)
+        # and one table a window group: a slot's row is its ring
+        self._ring_tables = [
+            np.full((ec.num_slots, g.pages_per_slot), g.trash_page, np.int32)
+            for g in self._ring_groups()]
         # opt-in observability: Prometheus endpoint + stall watchdog
         self.metrics_server = start_metrics_server(
             ec.metrics_port, registry=self.registry)
@@ -659,6 +706,21 @@ class Engine:
         self.on_admit: Any = None
         self._build_programs()
 
+    def _ring_groups(self) -> tuple:
+        """The cache's window groups (none without a grouped cache)."""
+        return self.cache.groups[1:] if self._cache_groups else ()
+
+    def _tables(self, slot: int | None = None):
+        """The host's page tables as a program takes them, copied (they
+        change in place at the next admission or release, and a program
+        in flight keeps its copy): the table, or the row of `slot`; one a
+        group under a grouped cache."""
+        at = slice(None) if slot is None else slot
+        if not self._cache_groups:
+            return self._table[at].copy()
+        return (self._table[at].copy(),
+                *(t[at].copy() for t in self._ring_tables))
+
     # -- compiled programs ---------------------------------------------------
 
     def _build_programs(self) -> None:
@@ -675,6 +737,7 @@ class Engine:
         except (TypeError, ValueError):
             one_row = False
         fold_stats = getattr(self._family, "accumulate_serving_stats", None)
+        grouped = self._cache_groups is not None
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
@@ -687,7 +750,7 @@ class Engine:
             out = forward(config, params, ids, positions=positions,
                           kv_caches=kv_caches, **extra)
             if cache.stats is not None:
-                cache = dataclasses.replace(cache, stats=dict(
+                cache = cache.with_stats(dict(
                     cache.stats, **{program: fold_stats(
                         cache.stats[program], out[2])}))
             return out[0], out[1], cache
@@ -787,6 +850,15 @@ class Engine:
             rows = self.cache.rows
             latent = self._cache_spec.kind == "latent"
 
+            def pools(cache, which):
+                """The K (or V) pool as a family forward takes it: one a
+                group under a grouped cache."""
+                if isinstance(cache, GroupedPagedCache):
+                    return tuple(pools(g, which) for g in cache.groups)
+                data, scales = ((cache.k, cache.k_scale) if which == "k"
+                                else (cache.v, cache.v_scale))
+                return PagedKV(data, scales, cache.compute_dtype)
+
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
             def decode(params, cache, tokens, slot_keys, temps, live, table):
                 # the Pallas kernel walks the page table INSIDE attention:
@@ -800,9 +872,8 @@ class Engine:
                 # result is discarded below) walks no page at length 0.
                 # The latent kernel's program stays as it was (PR 26)
                 walked = lengths if latent else jnp.where(live, lengths, 0)
-                kvc = (PagedKV(cache.k, cache.k_scale, cache.compute_dtype),
-                       None if latent else PagedKV(
-                           cache.v, cache.v_scale, cache.compute_dtype),
+                kvc = (pools(cache, "k"),
+                       None if latent else pools(cache, "v"),
                        PagedDecodeMeta(table, walked, rows=rows))
                 logits, (row_k, row_v, _), cache = serving_forward(
                     "decode", params, cache, tokens[:, None],
@@ -812,9 +883,11 @@ class Engine:
                 next_tok, lps = jax.vmap(sample_slot)(
                     last, slot_keys, cache.lengths + 1, temps)
                 tokens = jnp.where(live, next_tok, tokens)
-                cache = paged_append_rows(
-                    cache, table, row_k[:, :, 0],
-                    None if row_v is None else row_v[:, :, 0], live)
+                # (one row array a group under a grouped cache; no V rows
+                # from a latent pool)
+                row_k, row_v = jax.tree.map(lambda r: r[:, :, 0],
+                                            (row_k, row_v))
+                cache = paged_append_rows(cache, table, row_k, row_v, live)
                 return cache, tokens, (next_tok, lps)
         else:
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
@@ -834,10 +907,11 @@ class Engine:
                     return (logits[0, 0].astype(jnp.float32), nk[:, 0],
                             nv[:, 0])
 
-                if cache.stats is not None:
+                if cache.stats is not None or grouped:
                     # ONE batched forward with a length a slot (a family
                     # that counts sees every slot's token in one call, as
-                    # under the kernel)
+                    # under the kernel; a family with groups is handed
+                    # one view a group)
                     lengths = cache.lengths
                     logits, (nk, nv, _), cache = serving_forward(
                         "decode", params, cache, tokens[:, None],
@@ -1499,6 +1573,11 @@ class Engine:
             n = self._n_params
         # a draft model is always a K/V stack (checked at construction)
         spec = _cache_spec(cfg, self._family if cfg is self.config else None)
+        if isinstance(spec, tuple):
+            # groups: every layer counted as keeping its rows whole (an
+            # upper bound for the window groups)
+            spec = dataclasses.replace(
+                spec[0], num_layers=sum(g.num_layers for g in spec))
         num_layers = spec.num_layers
         hidden = getattr(cfg, "hidden_size", 0) or (
             getattr(cfg, "num_attention_heads", 1) * spec.width)
@@ -1620,9 +1699,19 @@ class Engine:
         lane's masked ride-along writes in later decode steps can never
         land in a page now owned by someone else."""
         self._table[index, :] = self.cache.trash_page
+        for table, group in zip(self._ring_tables, self._ring_groups()):
+            table[index, :] = group.trash_page
+        self._set_page_gauges()
+
+    def _set_page_gauges(self) -> None:
+        alloc = self.allocator
         self.metrics.set_page_gauges(
-            self.allocator.pages_in_use, self.allocator.pages_free,
-            self.allocator.pages_in_use * self.cache.page_nbytes)
+            alloc.pages_in_use, alloc.pages_free,
+            alloc.pages_in_use * self.cache.page_nbytes)
+        if self._cache_groups:
+            self.metrics.set_group_page_gauges(dict(zip(
+                (g.label for g in self._cache_groups),
+                (alloc.pages_in_use, *alloc.ring_pages_in_use))))
 
     def _run_swap_in(self, slot: Slot, req: Request, alloc) -> None:
         """Install a host-resident prefix's bytes into the pages the
@@ -1677,6 +1766,10 @@ class Engine:
         row = self._table[slot.index]
         row[:] = self.cache.trash_page
         row[:len(alloc.pages)] = alloc.pages
+        for table, group, ring in zip(self._ring_tables, self._ring_groups(),
+                                      alloc.rings):
+            table[slot.index, :] = group.trash_page
+            table[slot.index, :len(ring)] = ring
         if alloc.swap_ins:
             # host-resident prefix: land the swapped-out bytes in the
             # freshly reserved pages BEFORE the admit program publishes
@@ -1684,9 +1777,7 @@ class Engine:
             self._run_swap_in(slot, req, alloc)
         self.metrics.note_admission(req.prompt_len, alloc.reused_len,
                                     host_pages=len(alloc.swap_ins or ()))
-        self.metrics.set_page_gauges(
-            self.allocator.pages_in_use, self.allocator.pages_free,
-            self.allocator.pages_in_use * self.cache.page_nbytes)
+        self._set_page_gauges()
         if req.trace_sampled:
             # the queue-wait span is only known in retrospect: it closes
             # the moment admission happens
@@ -1764,11 +1855,9 @@ class Engine:
             real = min(chunk, req.prompt_len - start)
             ids = np.zeros((chunk,), np.int32)
             ids[:real] = req.prompt[start:start + real]
-            # `row` is the host's own and changes in place at the next
-            # admission or release: a program in flight keeps its copy
             args = (self.params, self.cache, self._tokens, self._slot_keys,
-                    self._temps, jnp.int32(slot.index), row.copy(), ids,
-                    jnp.int32(real))
+                    self._temps, jnp.int32(slot.index),
+                    self._tables(slot.index), ids, jnp.int32(real))
             self._strict_audit("prefill", self._prefill_p, args)
             self._ensure_cost("prefill", self._prefill_p, args)
         with self.cost.maybe_sample(
@@ -1806,10 +1895,8 @@ class Engine:
             live = np.zeros((num_slots,), bool)
             for s in slots:
                 live[s.index] = True
-            # the table changes in place at the next admission or
-            # release: a program in flight keeps its copy
             args = (self.params, self.cache, self._tokens, self._slot_keys,
-                    self._temps, live, self._table.copy())
+                    self._temps, live, self._tables())
             self._strict_audit("decode", self._decode_p, args)
             links = self._step_links(slots)
             self._ensure_cost("decode", self._decode_p, args)
@@ -2033,6 +2120,17 @@ class Engine:
             "host_pages": alloc.index.host_pages,
             **({"host_tier": self._host_tier.stats()}
                if self._host_tier is not None else {}),
+            # a cache with one group a layer kind: every group's books
+            # (the keys above are the first group's, which keeps every
+            # position)
+            **({"groups": [
+                {"group": spec.label, "layers": list(spec.layers),
+                 "window": spec.window, "pages_per_slot": g.pages_per_slot,
+                 "num_pages": g.num_pages, "pages_in_use": used}
+                for spec, g, used in zip(
+                    self._cache_groups, self.cache.groups,
+                    (alloc.pages_in_use, *alloc.ring_pages_in_use))]}
+               if self._cache_groups else {}),
         }
 
     def debug_scheduler(self) -> dict:
@@ -2115,9 +2213,7 @@ class Engine:
                                name="serving_step")
         # page-pool gauges reflect CURRENT state, not a window: re-sync
         # (the prefix tree and its cached pages survive a metrics reset)
-        self.metrics.set_page_gauges(
-            self.allocator.pages_in_use, self.allocator.pages_free,
-            self.allocator.pages_in_use * self.cache.page_nbytes)
+        self._set_page_gauges()
         if self._host_tier is not None:
             self.metrics.set_host_tier_gauges(self._host_tier.pages_in_use,
                                               self._host_tier.bytes_in_use)

@@ -96,6 +96,11 @@ class ServingMetrics:
         # (Engine._goodput) and keeps this gauge live per step
         self._g_goodput = r.gauge("serving_goodput")
         self._c_decode_path: dict = {}
+        # pages in use a cache group (a family whose layers differ in
+        # kind: serving/cache.py GroupedPagedCache), labelled by group;
+        # created at the first observation, so other engines keep the
+        # series they had
+        self._g_group_pages: dict = {}
         # the engine reads a program's results one step late: a read that
         # found another program already dispatched (the chip worked while
         # the host waited) against one that found none (the chip waited)
@@ -273,6 +278,15 @@ class ServingMetrics:
         if bytes_in_use is not None:
             self._g_kv_bytes.set(bytes_in_use)
 
+    def set_group_page_gauges(self, in_use: dict) -> None:
+        """`in_use`: pages held a cache group, by the group's label."""
+        for group, pages in in_use.items():
+            gauge = self._g_group_pages.get(group)
+            if gauge is None:
+                gauge = self._g_group_pages[group] = self.registry.gauge(
+                    "serving_group_pages_in_use", group=group)
+            gauge.set(pages)
+
     def observe_step(self, live_slots: int, num_slots: int,
                      queue_depth: int) -> None:
         occ = live_slots / max(1, num_slots)
@@ -356,6 +370,8 @@ class ServingMetrics:
             "pages_free": float(self._g_pages_free.value),
             "kv_bytes_in_use": float(self._g_kv_bytes.value),
         }
+        for group, gauge in self._g_group_pages.items():
+            out[f"pages_in_use.{group}"] = float(gauge.value)
         if self.decode_steps:
             out["tokens_per_decode_step"] = (
                 self.tokens_out / self.decode_steps)

@@ -105,6 +105,11 @@ class PageTransport:
     programs (serving/pod/mesh.py)."""
 
     def __init__(self, engine):
+        if getattr(engine.cache, "groups", None) is not None:
+            raise ValueError(
+                "page shipments of a cache with one group a layer kind are "
+                "not implemented: a KVPageShipment carries ONE pool's "
+                "pages, and a window group's ring is no prefix (ROADMAP M2)")
         if engine.cache.latent:
             raise ValueError(
                 "page shipments of a latent pool are not implemented: a "

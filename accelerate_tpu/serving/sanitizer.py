@@ -282,6 +282,52 @@ def check_engine(engine) -> None:
                   slot=slot.index,
                   row=[int(x) for x in np.asarray(row)])
 
+    # -- the window groups of a grouped cache: a ring a live slot ----------
+    groups = getattr(engine.cache, "groups", None)
+    for g, (ring_pool, per_slot, ring_table) in enumerate(zip(
+            alloc.ring_pools, alloc.ring_pages,
+            getattr(engine, "_ring_tables", ())), start=1):
+        group = groups[g]
+        ring_free = set(ring_pool._free)
+        if len(ring_free) != len(ring_pool._free) or any(
+                not (0 <= p < ring_pool.num_pages) for p in ring_free):
+            _fail("page-conservation",
+                  "a window group's free list holds duplicate or "
+                  "out-of-range pages", group=g)
+        owner: dict = {}
+        for slot in sched.slots:
+            ring = list(slot.alloc.rings[g - 1]) if slot.alloc else []
+            if len(ring) > per_slot:
+                _fail("page-conservation",
+                      "a slot's ring holds more pages than the window "
+                      "group's bound (window + chunk + page rounding)",
+                      group=g, slot=slot.index, pages=len(ring),
+                      bound=per_slot)
+            for p in ring:
+                if p in owner or p in ring_free:
+                    _fail("page-conservation",
+                          "a ring page is owned twice, or owned and free",
+                          group=g, page=p, slot=slot.index)
+                owner[p] = slot.index
+            row = ring_table[slot.index]
+            if list(row[:len(ring)]) != ring or not np.all(
+                    row[len(ring):] == group.trash_page):
+                _fail("table",
+                      "a slot's ring table row disagrees with its ring "
+                      "(its pages, then trash; an idle slot's all trash)",
+                      group=g, slot=slot.index,
+                      row=[int(x) for x in row], ring=ring)
+        if len(ring_free) + len(owner) != ring_pool.num_pages:
+            _fail("page-conservation",
+                  "a window group's pages are lost or double-counted: free "
+                  "+ rings != pool size", group=g, free=len(ring_free),
+                  rings=len(owner), pool=ring_pool.num_pages)
+        if not np.array_equal(np.asarray(group.lengths),
+                              np.asarray(engine.cache.lengths)):
+            _fail("lengths",
+                  "a window group's slot lengths diverged from the first "
+                  "group's (they advance together)", group=g)
+
     # -- length bounds -------------------------------------------------------
     lengths = np.asarray(engine.cache.lengths)
     ps = engine.cache.page_size

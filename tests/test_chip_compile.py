@@ -376,6 +376,121 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
             program, args, text, (slots,) if name == "decode" else ())
 
 
+def test_mixed_window_engine_programs_compile_for_v5e(one_chip, chip_compile):
+    """`decode` and `prefill` of `serve-mellum2-code-mixed-closed`
+    (Mellum2-12B-A2.5B widths, 8 layers s s s f s s s f, 48 slots x 32768,
+    page 16, chunk 512; 24,576 pages for the two full layers and a ring of
+    97 pages a slot for the six sliding ones), abstract weights and pools,
+    compiled for the chip:
+
+    (a) `decode` holds BOTH kernel names: `paged_decode_attention` twice
+        (the full layers) and `paged_decode_attention_window` six times
+        (the sliding layers, whose walk the window bounds); each takes its
+        group's whole stacked pool (nothing of a layer's slice shape is
+        produced around it); `prefill` holds neither (a chunk attends the
+        slot's gathered views);
+    (b) the expert products are `ragged-dot` kernels, 3 a layer, in both
+        programs, and nothing of an expert matrix's shape is copied;
+    (c) both groups' K and V pools are aliased to their arguments, and of
+        a pool half's shape only the page scatter is produced;
+    (d) a chunk's logits are one row, not `[512, 98304]` float32 (201 MB),
+        and no temporary is of the full pool's order."""
+    from accelerate_tpu.models import mellum
+    from accelerate_tpu.ops import paged_attention
+    from accelerate_tpu.serving import Engine, EngineConfig
+    from accelerate_tpu.serving.cache import GroupedPagedCache
+
+    slots, max_len, page, chunk, num_pages = 48, 32768, 16, 512, 24576
+    cfg = mellum.MellumConfig(
+        num_hidden_layers=8, max_position_embeddings=32768,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000}})
+    assert cfg.layer_types == ("sliding_attention",) * 3 + (
+        "full_attention",) + ("sliding_attention",) * 3 + ("full_attention",)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: mellum.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * 3_794_968_832
+    engine = Engine(mellum, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=(max_len + chunk) // page,
+        paged_attention=True, prefix_cache=False))
+    small = engine.cache
+    cache = on_chip(jax.eval_shape(lambda: GroupedPagedCache.create(
+        mellum.cache_spec(cfg), slots, max_len, page_size=page,
+        pad_slack=small.pad_slack, num_pages=num_pages, stats=small.stats)))
+    full, ring = cache.groups
+    assert full.k.shape == (2, 24577, 4, 16, 128)
+    assert ring.k.shape == (6, 48 * 97 + 1, 4, 16, 128)
+    assert (full.pages_per_slot, ring.pages_per_slot) == (2080, 97)
+    pool_bytes = 2 * (full.k.size + ring.k.size) * 2
+    assert 2.5e9 < pool_bytes < 2.55e9
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_),
+            (arg((slots, 2080), jnp.int32), arg((slots, 97), jnp.int32)))),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32),
+            (arg((2080,), jnp.int32), arg((97,), jnp.int32)),
+            arg((chunk,), jnp.int32), arg((), jnp.int32))),
+    }
+    for name, (program, args) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        names = (paged_attention.KERNEL_NAME,
+                 paged_attention.WINDOW_KERNEL_NAME)
+        # a kernel call is an instruction under the kernel's name, which
+        # is what a device trace shows of it
+        calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
+                 for n in names]
+        assert calls == ([2, 6] if name == "decode" else [0, 0]), (
+            name, calls)
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 24, name
+        assert " conditional(" not in text, name
+        assert _ops_of_shape(text, "bf16", (64, 2304, 896)) == {}, name
+        assert _ops_of_shape(text, "bf16", (64, 896, 2304)) == {}, name
+        for group in (full, ring):
+            assert _ops_of_shape(text, "bf16", group.k.shape[1:]) == {}, name
+            assert _ops_of_shape(text, "bf16", group.k.shape) == {
+                "scatter": 2, "fusion": 2}, (name, group.k.shape)
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        assert memory.temp_size_in_bytes < full.k.size * 2 // 2, (
+            name, memory.temp_size_in_bytes)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
+
+
+def test_qwen_decode_holds_the_one_kernel_name_on_v5e(one_chip,
+                                                      chip_compile):
+    """The Qwen cells' `decode` still calls `paged_decode_attention` alone:
+    the window kernel's name belongs to a ring of pages, which only a
+    cache with one group a layer kind has."""
+    programs, _, _ = _chat_cell_programs(one_chip, None)
+    program, args = programs["decode"]
+    text = program.lower(*args).compile().as_text()
+    assert len(re.findall(r"%paged_decode_attention(?:\.\d+)? = ", text)) == 1
+    assert "paged_decode_attention_window" not in text
+
+
 @pytest.mark.parametrize("seq", [2048, 2047], ids=["bench-2048", "loss-2047"])
 def test_flash_forward_backward_compiles_for_v5e(one_chip, chip_compile, seq):
     from accelerate_tpu.ops.flash_attention import flash_attention
