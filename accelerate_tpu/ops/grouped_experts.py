@@ -6,22 +6,44 @@ capacity are dropped) and `models/mixtral.py`'s "dense" form runs every
 expert on every token. Neither serves a model with hundreds of small
 experts: here no token is dropped at any imbalance, the products cost
 `tokens x top_k` expert applications (not `tokens x num_experts`), and an
-expert's weights are read once a call. The grouped product is
-`jax.lax.ragged_dot`, which the TPU compiler lowers to a grouped-matmul
-kernel (`ragged-dot` in a device trace) and every other backend to a
-plain masked product.
+expert's weights are read once a call.
 
 Shapes are static whatever the routing: `tokens * top_k` rows, sorted by
-expert, with the group sizes as data.
+expert, with the group sizes as data. The grouped product over those
+rows has two kernels, chosen from the static shapes by ONE rule
+(`few_rows_an_expert`):
+
+- MANY rows an expert (a prefill chunk: 4,096 rows over 64 or 256
+  experts): `jax.lax.ragged_dot`, which the TPU compiler lowers to its
+  own grouped-matmul kernel (`ragged-dot-none` in a device trace) and
+  every other backend to a plain masked product. That kernel multiplies
+  a large row tile for every group it visits, which is the right shape
+  of work when an expert has tens of rows or more.
+- FEW rows an expert (a decode step: 384 rows over 64 experts, 128 over
+  256), on matrices of whole 128-lane tiles: the rows kernel below
+  (`ragged-dot-rows`), a Pallas kernel that walks only the experts that
+  HAVE rows, streams each one's matrix through VMEM once, double-
+  buffered, as it is held in HBM (no copy, no concatenated array), and
+  multiplies a window of `row_tile` rows. XLA's kernel cannot be told
+  its tile and spends a decode step's time on masked rows (`PERF.md`
+  section 6, PR 32); the two get their own code, the sort, the route
+  and the combine around them stay shared.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import kernel_mode
 
 __all__ = ["sigmoid_topk_route", "softmax_topk_route", "expert_counts",
-           "grouped_swiglu_experts"]
+           "grouped_swiglu_experts", "grouped_rows_matmul",
+           "few_rows_an_expert", "ROWS_KERNEL_NAME"]
 
 
 def sigmoid_topk_route(x, router_kernel, correction_bias, top_k: int,
@@ -72,22 +94,156 @@ def expert_counts(experts, num_experts: int, token_mask=None):
     return jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
 
 
+# ---------------------------------------------------------------------------
+# the grouped product for FEW rows an expert
+# ---------------------------------------------------------------------------
+
+ROWS_KERNEL_NAME = "ragged-dot-rows"
+_ROW_ALIGN = 16   # a bf16 sublane tile: where a window of rows may start
+# Rows a product: 16, 32 and 64 read the same on the chip (`PERF.md`
+# section 6, PR 32); at 32 one window holds any group of up to 17 rows
+# wherever it starts.
+ROW_TILE = 32
+
+
+def _rows_kernel(ids_ref, offsets_ref, x_ref, w_ref, o_ref, *, row_tile,
+                 rows):
+    """One grid step = one expert THAT HAS ROWS: its matrix `w_ref`
+    [K, N] is fetched once, by the pipeline, while the step before
+    multiplies. All rows and the whole result stay in VMEM; the expert's
+    rows are covered by windows of `row_tile` rows that start on a
+    sublane tile, and what a window holds of other experts' rows is
+    masked out of the sums."""
+    g = pl.program_id(0)
+
+    @pl.when(g == 0)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    expert = ids_ref[g]
+    start, end = offsets_ref[expert], offsets_ref[expert + 1]
+    first = (start // _ROW_ALIGN) * _ROW_ALIGN
+    w = w_ref[...]
+
+    def window(i, carry):
+        lo = first + i * row_tile
+        # the last window is pulled back inside the rows; what it then
+        # holds of the window before is masked (`row >= lo`)
+        r0 = pl.multiple_of(jnp.minimum(lo, rows - row_tile), _ROW_ALIGN)
+        acc = jnp.dot(x_ref[pl.ds(r0, row_tile), :], w,
+                      preferred_element_type=jnp.float32)
+        row = r0 + jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1), 0)
+        keep = (row >= jnp.maximum(start, lo)) & (row < end)
+        o_ref[pl.ds(r0, row_tile), :] += jnp.where(keep, acc, 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(end - first, row_tile), window, None)
+
+
+def _group_walk(sizes):
+    """What the rows kernel prefetches to walk the groups of `sizes` [E]:
+    (ids [E] int32: the groups that HAVE rows, in order, at the front,
+    and a valid id behind them; offsets [E + 1] int32: each group's first
+    row; how many have rows). The compiler computes it once for the
+    products of one layer (they hold the same expression of `sizes`)."""
+    sizes = sizes.astype(jnp.int32)
+    E = sizes.shape[0]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                               jnp.cumsum(sizes, dtype=jnp.int32)])
+    seen = jnp.cumsum(sizes > 0, dtype=jnp.int32)   # groups with rows so far
+    # the j-th group with rows is the first whose `seen` passes j: a
+    # compare and a sum, where a sort would be a program of its own
+    ids = jnp.sum(seen[None, :] <= jnp.arange(E, dtype=jnp.int32)[:, None],
+                  axis=1, dtype=jnp.int32)
+    return jnp.minimum(ids, E - 1), offsets, seen[-1]
+
+
+def grouped_rows_matmul(x, w, sizes, *, row_tile: int = ROW_TILE,
+                        interpret: bool | None = None):
+    """`jax.lax.ragged_dot(x, w, sizes, preferred_element_type=float32)`
+    for FEW rows a group: x [M, K] sorted by group, w [E, K, N] as it is
+    held (never copied), sizes [E] int32 summing to M -> float32 [M, N].
+
+    The grid walks the groups that HAVE rows (`_group_walk(sizes)`,
+    scalar-prefetched; a group without rows costs no step and no read):
+    every weight byte is read once, a whole matrix a step, double-
+    buffered behind the products. Operands keep x's dtype, accumulation
+    is float32."""
+    M, K = x.shape
+    N = w.shape[2]
+    if row_tile % _ROW_ALIGN:
+        raise ValueError(f"a window of {row_tile} rows is not whole "
+                         f"{_ROW_ALIGN}-row sublane tiles")
+    interpret = kernel_mode.resolve_interpret(ROWS_KERNEL_NAME, interpret)
+    rows = max(-(-M // _ROW_ALIGN) * _ROW_ALIGN, row_tile)
+    x = jnp.pad(x, ((0, rows - M), (0, 0)))
+    ids, offsets, live = _group_walk(sizes)
+    item = jnp.dtype(x.dtype).itemsize
+    buffers = 2 * (rows * K * item + K * N * item + rows * N * 4)
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, row_tile=row_tile, rows=rows),
+        out_shape=jax.ShapeDtypeStruct((rows, N), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(live,),
+            in_specs=[
+                pl.BlockSpec((rows, K), lambda g, ids, offsets: (0, 0)),
+                pl.BlockSpec((None, K, N),
+                             lambda g, ids, offsets: (ids[g], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((rows, N), lambda g, ids, offsets: (0, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=buffers + (16 << 20)),
+        name=ROWS_KERNEL_NAME,
+        interpret=interpret,
+    )(ids, offsets, x, w.astype(x.dtype))
+    return out[:M]
+
+
+# Under this many rows an expert (`rows // E`, static) the products take
+# the rows kernel: XLA's `ragged-dot` costs a large row tile for every
+# group it touches whatever the group's rows, the rows kernel a window of
+# `ROW_TILE`. Both cells' decode steps lie under it (6 and 0), both
+# cells' chunks over it (64 and 16); `PERF.md` section 6, PR 32 has the
+# sweep, and why the chunks stay on `ragged_dot` for now.
+ROWS_KERNEL_BELOW = 8
+# A step holds an expert's whole matrix twice (this step's and the
+# next's): the largest one the kernel takes.
+_MATRIX_BYTES = 8 << 20
+_LANES = 128
+
+
+def few_rows_an_expert(rows: int, num_experts: int, k: int, n: int,
+                       dtype=jnp.bfloat16) -> bool:
+    """The one rule that picks the grouped product's kernel, from static
+    shapes alone: few rows an expert, over [k, n] (and [n, k]) matrices
+    of whole lane tiles that fit the rows kernel's VMEM block."""
+    return (rows // num_experts < ROWS_KERNEL_BELOW
+            and k % _LANES == 0 and n % _LANES == 0
+            and k * n * jnp.dtype(dtype).itemsize <= _MATRIX_BYTES)
+
+
 def grouped_swiglu_experts(x, experts, weights, gate, up, down):
     """`y[t] = sum_k weights[t, k] * E_{experts[t, k]}(x[t])` with every
     expert `W_down(silu(W_gate x) * W_up x)`. x [T, h]; experts, weights
     [T, k]; gate, up [E, h, f]; down [E, f, h]. Products take x's dtype
     with float32 accumulation; returns float32 [T, h]."""
     T, k = experts.shape
-    E = gate.shape[0]
+    E, h, f = gate.shape
     with jax.named_scope("moe.sort"):
         flat = experts.reshape(T * k)
         order = jnp.argsort(flat, stable=True)
         sizes = expert_counts(experts, E)
         rows = x[order // k]                                # [T * k, h]
     with jax.named_scope("moe.experts"):
-        def product(a, w):
-            return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes,
-                                      preferred_element_type=jnp.float32)
+        if few_rows_an_expert(T * k, E, h, f, x.dtype):
+            product = functools.partial(grouped_rows_matmul, sizes=sizes)
+        else:
+            def product(a, w):
+                return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes,
+                                          preferred_element_type=jnp.float32)
 
         act = (jax.nn.silu(product(rows, gate))
                * product(rows, up)).astype(x.dtype)

@@ -17,8 +17,10 @@ guard every later PR at no chip time:
   same temporaries at 4,096 and 16,384 pages;
 - the same two programs for the latent-attention expert model at the
   shape of `serve-joyai-flash-docqa-long`: the latent kernel and the
-  grouped expert products compile, the one-array pool is updated in
-  place, no expert matrix is copied.
+  grouped expert products compile (XLA's `ragged-dot` over a chunk's
+  rows, the rows kernel over a decode step's), the one-array pool is
+  updated in place, no expert matrix is copied;
+- the rows kernel alone at both expert cells' decode shapes.
 
 The topology is described ONLY inside this file's module-scoped fixture:
 one process at a time may load the TPU's library, the xdist workers all
@@ -291,6 +293,46 @@ def test_decode_does_not_know_the_pool_size_on_v5e(one_chip, chip_compile):
     assert seen[4096][0] < 8 << 20, seen
 
 
+@pytest.mark.parametrize("rows,experts,k,n", [
+    (384, 64, 2304, 896), (384, 64, 896, 2304),
+    (128, 256, 2048, 768), (128, 256, 768, 2048),
+], ids=["mellum-gate", "mellum-down", "joyai-gate", "joyai-down"])
+def test_rows_kernel_compiles_for_v5e(one_chip, chip_compile, rows, experts,
+                                      k, n):
+    """The grouped product for few rows an expert at the decode shapes of
+    both expert cells: it compiles as ONE kernel under its own name (what
+    a device trace shows of it, and what `%ragged-dot` still finds), reads
+    the stacked `[E, K, N]` matrices as they are (nothing of their shape
+    is produced) and holds no temporary beyond its scalars."""
+    from accelerate_tpu.ops.grouped_experts import (
+        ROWS_KERNEL_NAME,
+        grouped_rows_matmul,
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(grouped_rows_matmul).lower(
+        sds((rows, k), jnp.bfloat16), sds((experts, k, n), jnp.bfloat16),
+        sds((experts,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall("%" + ROWS_KERNEL_NAME + r"[.\d]* = ", text)) == 1
+    assert "%ragged-dot-none" not in text
+    assert _ops_of_shape(text, "bf16", (experts, k, n)) == {}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _grouped_products(text):
+    """(XLA's `ragged-dot` kernels, calls of the rows kernel) in a
+    compiled program's text: an expert layer's three products are all of
+    one kind, `ragged-dot-none` over a chunk's rows and the rows kernel
+    (`ops/grouped_experts.py`, few rows an expert) in a decode step."""
+    from accelerate_tpu.ops.grouped_experts import ROWS_KERNEL_NAME
+
+    return (len(re.findall(r"%ragged-dot-none[.\d]* = ", text)),
+            len(re.findall("%" + ROWS_KERNEL_NAME + r"[.\d]* = ", text)))
+
+
 def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
     """`decode` and `prefill` of `serve-joyai-flash-docqa-long` (JoyAI-
     LLM-Flash widths, 1 dense + 4 expert layers, 16 slots x 17920, page
@@ -300,8 +342,10 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
     (a) the latent paged-attention kernel is in `decode`, once a layer,
         under its own name, and takes the WHOLE stacked pool (nothing of
         a layer's slice shape is produced around it);
-    (b) the expert products are `ragged-dot` kernels (3 an expert layer)
-        in both programs, nothing of an expert matrix's shape
+    (b) the expert products, 3 an expert layer, are `ragged-dot` kernels
+        in `prefill` and the rows kernel (`ragged-dot-rows`, which reads
+        the stacked matrices as they are held) in `decode`, where no
+        `ragged-dot-none` is left; nothing of an expert matrix's shape
         (`[256, 2048, 768]`, 805 MB) is copied or re-laid out, and no
         layer sits under control flow (no `conditional`);
     (c) the pool is ONE array, aliased to its argument; of its shape only
@@ -361,7 +405,8 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
         kernels = len(re.findall(
             r"%latent_paged_decode_attention[.\d]* = ", text))
         assert kernels == (5 if name == "decode" else 0), name
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 12, name
+        assert _grouped_products(text) == (
+            (0, 12) if name == "decode" else (12, 0)), name
         assert " conditional(" not in text, name
         assert _ops_of_shape(text, "bf16", (256, 2048, 768)) == {}, name
         assert _ops_of_shape(text, "bf16", (256, 768, 2048)) == {}, name
@@ -389,8 +434,10 @@ def test_mixed_window_engine_programs_compile_for_v5e(one_chip, chip_compile):
         group's whole stacked pool (nothing of a layer's slice shape is
         produced around it); `prefill` holds neither (a chunk attends the
         slot's gathered views);
-    (b) the expert products are `ragged-dot` kernels, 3 a layer, in both
-        programs, and nothing of an expert matrix's shape is copied;
+    (b) the expert products, 3 a layer, are `ragged-dot` kernels in
+        `prefill` and the rows kernel (`ragged-dot-rows`) in `decode`,
+        where no `ragged-dot-none` is left, and nothing of an expert
+        matrix's shape is copied in either;
     (c) both groups' K and V pools are aliased to their arguments, and of
         a pool half's shape only the page scatter is produced;
     (d) a chunk's logits are one row, not `[512, 98304]` float32 (201 MB),
@@ -463,7 +510,8 @@ def test_mixed_window_engine_programs_compile_for_v5e(one_chip, chip_compile):
                  for n in names]
         assert calls == ([2, 6] if name == "decode" else [0, 0]), (
             name, calls)
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 24, name
+        assert _grouped_products(text) == (
+            (0, 24) if name == "decode" else (24, 0)), name
         assert " conditional(" not in text, name
         assert _ops_of_shape(text, "bf16", (64, 2304, 896)) == {}, name
         assert _ops_of_shape(text, "bf16", (64, 896, 2304)) == {}, name
