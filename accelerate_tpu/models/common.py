@@ -21,6 +21,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+NEG_INF = -1e30
+
+
+def hashable(value):
+    """Nested dicts and lists as sorted item tuples (a config is a jit and
+    lru_cache key)."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, hashable(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(hashable(v) for v in value)
+    return value
+
 
 def sp_constrain(x: jax.Array, axis: str | None = None) -> jax.Array:
     """Sequence-parallel activation constraint (Megatron SP, ref
@@ -177,6 +189,33 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Arra
     return out.astype(dtype)
 
 
+def apply_mrope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                positions: jax.Array, sections) -> jax.Array:
+    """Multimodal rotary embedding (Qwen2-VL's M-RoPE), half-split pairs.
+
+    x: [B, S, H, D]; positions: [3, B, S], the temporal, height and width
+    position id of every token; `sections` (`mrope_section`): how many of
+    the D/2 rotary pairs take each of the three rows, in order (their sum
+    is D/2). Pair i rotates by the angle of ITS row's position, with its
+    own frequency. Text gives the three rows one id, which is `apply_rope`
+    at that id."""
+    if sum(sections) != x.shape[-1] // 2 or positions.shape[0] != len(sections):
+        raise ValueError(
+            f"mrope sections {tuple(sections)} must sum to head_dim / 2 = "
+            f"{x.shape[-1] // 2}, one position row each; got positions "
+            f"{positions.shape}")
+    row_of_pair = np.repeat(np.arange(len(sections)), sections)    # [D/2]
+    pair = np.arange(x.shape[-1] // 2)
+    # [B, S, D/2]: pair i at the position its section's row gives
+    at = jnp.moveaxis(positions, 0, -1)[..., row_of_pair]
+    dtype = x.dtype
+    cos = cos[at, pair][:, :, None, :]
+    sin = sin[at, pair][:, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(dtype)
+
+
 # --- attention --------------------------------------------------------------
 
 
@@ -220,6 +259,111 @@ def dot_product_attention(
         scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def blocked_attention(q, q_pos, k_view, v_view, key_pos, window, block,
+                      lo=None, hi=None, select=None):
+    """Causal attention of q [B, S, H, D] at positions `q_pos` [B, S] over
+    keys `k_view` / `v_view` [B, R, Hkv, D] at positions `key_pos` [B, R]
+    (negative: nothing there), `block` rows at a time in an online
+    softmax; a `window` drops keys with `q - key >= window`, and `select`
+    [B, S, R] bool (a learned selection) every key a query did not
+    choose. Only blocks [lo, hi) are visited (all of them by default): the
+    `[H, S, R]` scores never exist whole. Returns [B, S, H, D]."""
+    B, S, H, D = q.shape
+    R, Hkv = k_view.shape[1], k_view.shape[2]
+    blk = min(block, R)
+    if R % blk:
+        pad = blk - R % blk
+        k_view, v_view = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for a in (k_view, v_view))
+        key_pos = jnp.pad(key_pos, ((0, 0), (0, pad)), constant_values=-1)
+        if select is not None:
+            select = jnp.pad(select, ((0, 0), (0, 0), (0, pad)))
+    n_blocks = k_view.shape[1] // blk
+    q5 = q.reshape(B, S, Hkv, H // Hkv, D)
+    scale = 1.0 / math.sqrt(D)
+    at = q_pos[:, None, None, :, None]
+
+    def body(i, carry):
+        m, l, acc = carry
+        kb, vb = (jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis=1)
+                  .astype(q.dtype) for a in (k_view, v_view))
+        pb = jax.lax.dynamic_slice_in_dim(
+            key_pos, i * blk, blk, axis=1)[:, None, None, None, :]
+        s = jnp.einsum("bskgd,brkd->bkgsr", q5, kb,
+                       preferred_element_type=jnp.float32) * scale
+        see = (pb >= 0) & (pb <= at)
+        if window is not None:
+            see = see & (at - pb < window)
+        if select is not None:
+            see = see & jax.lax.dynamic_slice_in_dim(
+                select, i * blk, blk, axis=2)[:, None, None]
+        s = jnp.where(see, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(see, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bkgsr,brkd->bkgsd", p.astype(q.dtype), vb,
+                        preferred_element_type=jnp.float32)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + pv)
+
+    shape = (B, Hkv, H // Hkv, S)
+    carry = (jnp.full(shape + (1,), NEG_INF, jnp.float32),
+             jnp.zeros(shape + (1,), jnp.float32),
+             jnp.zeros(shape + (D,), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0 if lo is None else lo,
+                                  n_blocks if hi is None else hi,
+                                  body, carry)
+    out = acc / jnp.maximum(l, 1e-30)                   # [B, Hkv, G, S, D]
+    return jnp.moveaxis(out, 3, 1).reshape(B, S, H, D).astype(q.dtype)
+
+
+def write_view(view, rows, start, wraps: bool):
+    """Rows [B, S, Hkv, D] written into view [B, R, Hkv, D] at positions
+    `start` [B] onward, position p at row `p % R`. A view that keeps every
+    position takes them as one slice; a ring, which may wrap, by a select
+    over its (few) rows."""
+    rows = rows.astype(view.dtype)
+    if not wraps:
+        return jax.vmap(lambda v, r, s: jax.lax.dynamic_update_slice(
+            v, r, (s, 0, 0)))(view, rows, start)
+    R, S = view.shape[1], rows.shape[1]
+    off = (jnp.arange(R, dtype=jnp.int32)[None, :] - start[:, None]) % R
+    new = jnp.take_along_axis(
+        rows, jnp.minimum(off, S - 1)[:, :, None, None], axis=1)
+    return jnp.where((off < S)[:, :, None, None], new, view)
+
+
+# --- experts ----------------------------------------------------------------
+
+
+def softmax_moe_layer(config, m: dict, x, token_mask=None):
+    """A softmax-routed expert layer (`config.num_experts` experts, the
+    `num_experts_per_tok` largest of a float32 softmax, renormalised iff
+    `norm_topk_prob`; `ops/grouped_experts.py`) over x [B, S, h] -> (y,
+    assignments per expert [E] of the tokens `token_mask` [B, S] keeps;
+    all of them without a mask).
+    Padding and dead lanes are routed and computed like any row (shapes
+    are static); the mask only says which tokens the counters count."""
+    from ..ops.grouped_experts import (
+        expert_counts,
+        grouped_swiglu_experts,
+        softmax_topk_route,
+    )
+
+    c = config
+    B, S, h = x.shape
+    flat = x.reshape(B * S, h)
+    experts, weights = softmax_topk_route(
+        flat, m["router"]["kernel"], c.num_experts_per_tok, c.norm_topk_prob)
+    e = m["experts"]
+    y = grouped_swiglu_experts(flat, experts, weights, e["gate_proj"],
+                               e["up_proj"], e["down_proj"])
+    counts = expert_counts(experts, c.num_experts,
+                           None if token_mask is None
+                           else token_mask.reshape(B * S))
+    return y.astype(x.dtype).reshape(B, S, h), counts
 
 
 # --- initializers -----------------------------------------------------------

@@ -51,33 +51,26 @@ kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_experts import (
-    expert_counts,
-    grouped_swiglu_experts,
-    softmax_topk_route,
+from .common import (
+    apply_rope,
+    blocked_attention,
+    dense,
+    hashable,
+    normal_init,
+    rms_norm,
+    rope_frequencies,
+    softmax_moe_layer,
+    write_view,
 )
-from .common import apply_rope, dense, normal_init, rms_norm, rope_frequencies
 from .decode import build_generate, rope_table_len
 from .deepseek import accumulate_serving_stats  # noqa: F401 - the contract
 
-NEG_INF = -1e30
 FULL, SLIDING = "full_attention", "sliding_attention"
-
-
-def _hashable(value):
-    """Nested dicts and lists as sorted item tuples (a config is a jit and
-    lru_cache key)."""
-    if isinstance(value, dict):
-        return tuple(sorted((k, _hashable(v)) for k, v in value.items()))
-    if isinstance(value, (list, tuple)):
-        return tuple(_hashable(v) for v in value)
-    return value
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,7 +138,7 @@ class MellumConfig:
                 raise ValueError(
                     f"rope_parameters[{kind!r}]: only rope_type 'default' "
                     f"and 'yarn' are implemented; got {p['rope_type']!r}")
-        object.__setattr__(self, "rope_parameters", _hashable(rope))
+        object.__setattr__(self, "rope_parameters", hashable(rope))
         if not self.norm_topk_prob:
             raise ValueError(
                 "norm_topk_prob=False (the chosen experts' softmax weights "
@@ -254,74 +247,6 @@ def init_params(config: MellumConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _blocked_attention(q, q_pos, k_view, v_view, key_pos, window, block,
-                       lo=None, hi=None):
-    """Causal attention of q [B, S, H, D] at positions `q_pos` [B, S] over
-    keys `k_view` / `v_view` [B, R, Hkv, D] at positions `key_pos` [B, R]
-    (negative: nothing there), `block` rows at a time in an online
-    softmax; a `window` drops keys with `q - key >= window`. Only blocks
-    [lo, hi) are visited (all of them by default): the `[H, S, R]` scores
-    never exist whole. Returns [B, S, H, D]."""
-    B, S, H, D = q.shape
-    R, Hkv = k_view.shape[1], k_view.shape[2]
-    blk = min(block, R)
-    if R % blk:
-        pad = blk - R % blk
-        k_view, v_view = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                          for a in (k_view, v_view))
-        key_pos = jnp.pad(key_pos, ((0, 0), (0, pad)), constant_values=-1)
-    n_blocks = k_view.shape[1] // blk
-    q5 = q.reshape(B, S, Hkv, H // Hkv, D)
-    scale = 1.0 / math.sqrt(D)
-    at = q_pos[:, None, None, :, None]
-
-    def body(i, carry):
-        m, l, acc = carry
-        kb, vb = (jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis=1)
-                  .astype(q.dtype) for a in (k_view, v_view))
-        pb = jax.lax.dynamic_slice_in_dim(
-            key_pos, i * blk, blk, axis=1)[:, None, None, None, :]
-        s = jnp.einsum("bskgd,brkd->bkgsr", q5, kb,
-                       preferred_element_type=jnp.float32) * scale
-        see = (pb >= 0) & (pb <= at)
-        if window is not None:
-            see = see & (at - pb < window)
-        s = jnp.where(see, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(see, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        pv = jnp.einsum("bkgsr,brkd->bkgsd", p.astype(q.dtype), vb,
-                        preferred_element_type=jnp.float32)
-        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
-                acc * alpha + pv)
-
-    shape = (B, Hkv, H // Hkv, S)
-    carry = (jnp.full(shape + (1,), NEG_INF, jnp.float32),
-             jnp.zeros(shape + (1,), jnp.float32),
-             jnp.zeros(shape + (D,), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0 if lo is None else lo,
-                                  n_blocks if hi is None else hi,
-                                  body, carry)
-    out = acc / jnp.maximum(l, 1e-30)                   # [B, Hkv, G, S, D]
-    return jnp.moveaxis(out, 3, 1).reshape(B, S, H, D).astype(q.dtype)
-
-
-def _write_view(view, rows, start, wraps: bool):
-    """Rows [B, S, Hkv, D] written into view [B, R, Hkv, D] at positions
-    `start` [B] onward, position p at row `p % R`. A view that keeps every
-    position takes them as one slice; a ring, which may wrap, by a select
-    over its (few) rows."""
-    rows = rows.astype(view.dtype)
-    if not wraps:
-        return jax.vmap(lambda v, r, s: jax.lax.dynamic_update_slice(
-            v, r, (s, 0, 0)))(view, rows, start)
-    R, S = view.shape[1], rows.shape[1]
-    off = (jnp.arange(R, dtype=jnp.int32)[None, :] - start[:, None]) % R
-    new = jnp.take_along_axis(
-        rows, jnp.minimum(off, S - 1)[:, :, None, None], axis=1)
-    return jnp.where((off < S)[:, :, None, None], new, view)
-
-
 def _attend_view(config, q, k, v, positions, view_k, view_v, start, window):
     """This call's K/V rows written into a group's view [B, R, Hkv, D] and
     the queries attended over it -> (out, new view k, new view v)."""
@@ -329,8 +254,8 @@ def _attend_view(config, q, k, v, positions, view_k, view_v, start, window):
 
     S, R = q.shape[1], view_k.shape[1]
     wraps = window is not None
-    view_k = _write_view(view_k, k, start, wraps)
-    view_v = _write_view(view_v, v, start, wraps)
+    view_k = write_view(view_k, k, start, wraps)
+    view_v = write_view(view_v, v, start, wraps)
     last = start + S - 1
     blk = min(config.kv_block, R)
     n_blocks = -(-R // blk)
@@ -343,9 +268,9 @@ def _attend_view(config, q, k, v, positions, view_k, view_v, start, window):
         lo = jnp.where(wrapped, 0, jnp.maximum(
             jnp.min(positions) - window + 1, 0) // blk)
         hi = jnp.where(wrapped, n_blocks, hi)
-    out = _blocked_attention(q, positions, view_k, view_v,
-                             ring_positions(R, last), window,
-                             config.kv_block, lo, jnp.minimum(hi, n_blocks))
+    out = blocked_attention(q, positions, view_k, view_v,
+                            ring_positions(R, last), window,
+                            config.kv_block, lo, jnp.minimum(hi, n_blocks))
     return out, view_k, view_v
 
 
@@ -368,8 +293,8 @@ def _attention(config, a, x, rope, positions, window, cache):
     new = None
     with jax.named_scope("attn.attend"):
         if cache is None:
-            out = _blocked_attention(q, positions, k, v, positions, window,
-                                     c.kv_block)
+            out = blocked_attention(q, positions, k, v, positions, window,
+                                    c.kv_block)
         elif cache[0] == "paged":
             from ..ops.paged_attention import paged_decode_attention
 
@@ -384,30 +309,6 @@ def _attention(config, a, x, rope, positions, window, cache):
     with jax.named_scope("attn.output"):
         out = dense(out.reshape(B, S, H * D), a["o_proj"]["kernel"])
     return out, new
-
-
-# ---------------------------------------------------------------------------
-# the expert layer
-# ---------------------------------------------------------------------------
-
-
-def moe_layer(config: MellumConfig, m: dict, x, token_mask=None):
-    """The expert layer over x [B, S, h] -> (y, assignments per expert [E]
-    of the tokens `token_mask` [B, S] keeps; all of them without a mask).
-    Padding and dead lanes are routed and computed like any row (shapes
-    are static); the mask only says which tokens the counters count."""
-    c = config
-    B, S, h = x.shape
-    flat = x.reshape(B * S, h)
-    experts, weights = softmax_topk_route(
-        flat, m["router"]["kernel"], c.num_experts_per_tok, c.norm_topk_prob)
-    e = m["experts"]
-    y = grouped_swiglu_experts(flat, experts, weights, e["gate_proj"],
-                               e["up_proj"], e["down_proj"])
-    counts = expert_counts(experts, c.num_experts,
-                           None if token_mask is None
-                           else token_mask.reshape(B * S))
-    return y.astype(x.dtype).reshape(B, S, h), counts
 
 
 # ---------------------------------------------------------------------------
@@ -491,7 +392,7 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
         y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
                      c.rms_norm_eps)
         with jax.named_scope("moe"):
-            out, n = moe_layer(c, layer["moe"], y, token_mask)
+            out, n = softmax_moe_layer(c, layer["moe"], y, token_mask)
         counts.append(n)
         x = x + out
     x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)

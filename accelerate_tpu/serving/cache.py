@@ -64,7 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -197,7 +197,13 @@ class CacheSpec:
     last W (a ring of pages a slot, whatever the request's length). The
     first group keeps every position: it is the one whose pages grow with
     the context, and the one the allocator's books, the prefix index and
-    the engine's page gauges mean."""
+    the engine's page gauges mean.
+
+    `side_width` > 0: a third per-token row of that many lanes, in the
+    pool's dtype, that lives in the SAME pages as K and V (`PagedKVCache`,
+    SIDE ROW): what a family keeps for a second scorer of its keys (a
+    learned indexer's key). Like K and V it depends only on the tokens
+    before it, so it is cached, shared and released with its page."""
 
     num_layers: int
     heads: int
@@ -205,11 +211,26 @@ class CacheSpec:
     kind: str = "kv"
     window: int | None = None
     layers: tuple | None = None
+    side_width: int = 0
 
     @property
     def label(self) -> str:
         """The group's name in gauges and debug output."""
         return "full" if self.window is None else f"window{self.window}"
+
+
+class WithSide(NamedTuple):
+    """What stands in K's place wherever a cache with a side row hands K
+    to a family or takes it back: K's rows, views or pool, and the side
+    row's beside them (the same leading axes, one head)."""
+
+    rows: Any
+    side: Any
+
+
+def _split_side(cache, k):
+    """(K's part, the side row's part or None) of what stands in K's place."""
+    return (k.rows, k.side) if cache.side is not None else (k, None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,6 +275,20 @@ class PagedKVCache:
     masks by POSITION (`ring_positions`), so stale rows of the slot's
     last tenant, whose positions come out negative, are never seen.
 
+    SIDE ROW (`create(side_width=w)`; `CacheSpec.side_width`): `side`
+    holds a third row of w lanes a token and layer under the SAME page
+    ids, so the page table, the allocator, the prefix index, a fork and a
+    release carry it without knowing it. A page's `page_size` x w lanes
+    are stored as whole 128-lane rows, [L, pages + 1, page_size * w / 128,
+    128] (token t of a page at row `t * w // 128`, lanes from `t * w %
+    128`): a [page_size, 64] page would lie padded to 128 lanes on the
+    chip, twice its bytes, while this one is a whole (8, 128)(2, 1) tile
+    of bf16 and a page is still indexed outside the tile. So w divides 128
+    and page_size x w is a whole number of such rows, or `create` raises.
+    Views carry the side rows as one head, [L, B, R, 1, w], beside K's in a
+    `WithSide`; int8 codes, a latent pool and a ring are not implemented
+    with it.
+
     `stats`: a family's own device counters (`family.init_serving_stats`),
     or None. They ride here because the cache is what both engine
     programs donate and return: they are accumulated on the device and
@@ -272,6 +307,7 @@ class PagedKVCache:
     compute_dtype: Any = jnp.bfloat16
     stats: Any = None
     window: int | None = None
+    side: jax.Array | None = None
 
     @classmethod
     def create(
@@ -289,6 +325,7 @@ class PagedKVCache:
         latent: bool = False,
         stats: Any = None,
         window: int | None = None,
+        side_width: int = 0,
     ) -> "PagedKVCache":
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -307,6 +344,16 @@ class PagedKVCache:
                 "a ring of pages (window=) holds K/V rows in `dtype`: a "
                 "latent or int8 ring is not implemented, and a window is "
                 f"at least 1; got window={window}")
+        if side_width and (latent or quantized or window is not None):
+            raise ValueError(
+                "a side row (side_width=) lives beside K/V rows in `dtype` "
+                "under every position: with a latent pool, int8 codes or a "
+                "ring it is not implemented")
+        if side_width and (128 % side_width or page_size * side_width % 128):
+            raise ValueError(
+                "a page's side rows are stored as whole 128-lane rows: "
+                f"side_width={side_width} must divide 128 and page_size="
+                f"{page_size} x side_width be a multiple of 128")
         # a slot's view must cover max_len rows plus the chunk-padding
         # spill (see SlotKVCache docstring) — round up to whole pages
         pages_per_slot = -(-(max_len + pad_slack) // page_size)
@@ -338,7 +385,16 @@ class PagedKVCache:
             compute_dtype=dtype,
             stats=stats,
             window=window,
+            side=jnp.zeros(shape[:2] + (page_size * side_width // 128, 128),
+                           dtype) if side_width else None,
         )
+
+    @property
+    def side_width(self) -> int:
+        """Lanes of the side row a token and layer (0 without one)."""
+        if self.side is None:
+            return 0
+        return self.side.shape[2] * self.side.shape[3] // self.page_size
 
     @property
     def ring(self) -> bool:
@@ -389,12 +445,22 @@ class PagedKVCache:
         per = L * ps * H * D * self.k.dtype.itemsize
         if self.quantized:
             per += L * ps * H * self.k_scale.dtype.itemsize
-        return per if self.latent else 2 * per
+        return (per if self.latent else 2 * per) + self.side_page_nbytes
+
+    @property
+    def side_page_nbytes(self) -> int:
+        """Of `page_nbytes`, what the side row takes (0 without one)."""
+        if self.side is None:
+            return 0
+        return (self.num_layers * self.page_size * self.side_width
+                * self.side.dtype.itemsize)
 
     def nbytes(self) -> int:
         total = self.k.nbytes + (0 if self.latent else self.v.nbytes)
         if self.quantized:
             total += self.k_scale.nbytes + self.v_scale.nbytes
+        if self.side is not None:
+            total += self.side.nbytes
         return total
 
 
@@ -419,6 +485,13 @@ def _dense_pages(codes: jax.Array, scales: jax.Array | None,
     return jnp.swapaxes(pages, -3, -2)
 
 
+def _side_view(cache: PagedKVCache, idx: jax.Array, batch: int) -> jax.Array:
+    """The side rows of the pages at `idx` ([batch, P] or [P] with batch
+    1) as a view of one head, [L, batch, P * page_size, 1, w]."""
+    return cache.side[:, idx].reshape(
+        cache.num_layers, batch, -1, 1, cache.side_width)
+
+
 def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
                     slot: jax.Array):
     """One slot's pages gathered into `models/decode.py` layout:
@@ -440,6 +513,8 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
             L, 1, P * ps, H, D),
         (cache.k, cache.k_scale), None if cache.latent
         else (cache.v, cache.v_scale))
+    if cache.side is not None:
+        ks = WithSide(ks, _side_view(cache, table_row, 1))
     return ks, vs, cache.lengths[slot]
 
 
@@ -471,9 +546,13 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
     rows = length + jnp.arange(chunk, dtype=jnp.int32)
     if cache.ring:
         rows = rows % R
+    new_k, new_side = _split_side(cache, new_k)
     win_k, win_v = _both(
         lambda new: jnp.take(new.reshape(L, R, H, D), rows, axis=1)[:, None],
         new_k, new_v)
+    if new_side is not None:
+        win_k = WithSide(win_k, jnp.take(new_side.reshape(
+            L, R, 1, cache.side_width), rows, axis=1)[:, None])
     return _scatter_rows(cache, table_row[None], length[None],
                          jnp.full((1,), chunk, jnp.int32), win_k, win_v,
                          cache.lengths.at[slot].set(length + advance))
@@ -504,6 +583,7 @@ def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
     entries past the view and the padding of a table are the trash page,
     which takes every duplicate write."""
     ps = cache.page_size
+    win_k, win_side = _split_side(cache, win_k)
     N, W = win_k.shape[1], win_k.shape[2]
     n_pages = (W + ps - 2) // ps + 1    # most that W consecutive rows touch
     lane_page = (start // ps)[:, None] + jnp.arange(n_pages, dtype=jnp.int32)
@@ -534,12 +614,18 @@ def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
             new.reshape((win.shape[0], N * n_pages, ps) + win.shape[3:]),
             2, 3).astype(pool.dtype)
         mask = write.reshape(write.shape + (1,) * tail)
-        return pool.at[:, pages].set(jnp.where(mask, new, pool[:, pages]))
+        # a side pool's page is stored as whole 128-lane rows: the select
+        # runs on the page as [1, ps, w], what goes back is as stored
+        old = pool[:, pages]
+        return pool.at[:, pages].set(jnp.where(
+            mask, new, old.reshape(new.shape)).reshape(old.shape))
 
     if not cache.quantized:
         k, v = _both(lambda pw: put(*pw), (cache.k, win_k),
                      None if cache.latent else (cache.v, win_v))
-        return dataclasses.replace(cache, k=k, v=v, lengths=new_lengths)
+        side = None if win_side is None else put(cache.side, win_side)
+        return dataclasses.replace(cache, k=k, v=v, side=side,
+                                   lengths=new_lengths)
     from ..ops.quant import kv_quantize_rows
 
     ck, sk = kv_quantize_rows(win_k)
@@ -564,11 +650,14 @@ def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     L, _, H, ps, D = cache.k.shape
     S = cache.num_slots
     P = cache.pages_per_slot
-    return _both(
+    ks, vs = _both(
         lambda kv: _dense_pages(*kv, table, cache.compute_dtype).reshape(
             L, S, P * ps, H, D),
         (cache.k, cache.k_scale), None if cache.latent
         else (cache.v, cache.v_scale))
+    if cache.side is not None:
+        ks = WithSide(ks, _side_view(cache, table, S))
+    return ks, vs
 
 
 def paged_append_rows(cache: PagedKVCache, table: jax.Array,
@@ -590,9 +679,11 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
         return cache.map_groups(
             lambda g, t, rk, rv: paged_append_rows(g, t, rk, rv, live),
             table, row_k, row_v)
+    # (a `WithSide` in K's place is mapped leaf by leaf)
     return _scatter_rows(cache, table, cache.lengths,
                          jnp.ones_like(cache.lengths),
-                         *_both(lambda row: row[:, :, None], row_k, row_v),
+                         *jax.tree.map(lambda row: row[:, :, None],
+                                       (row_k, row_v)),
                          cache.lengths + live.astype(jnp.int32))
 
 
@@ -608,9 +699,9 @@ def paged_append_batch(cache: PagedKVCache, table: jax.Array,
             table, new_k, new_v)
     row = cache.lengths % cache.rows if cache.ring else cache.lengths
     idx = row[None, :, None, None, None]
-    row_k, row_v = _both(                                      # [L, S, H, D]
+    row_k, row_v = jax.tree.map(                               # [L, S, H, D]
         lambda new: jnp.take_along_axis(new, idx, axis=2)[:, :, 0],
-        new_k, new_v)
+        (new_k, new_v))
     return paged_append_rows(cache, table, row_k, row_v, live)
 
 
@@ -647,20 +738,20 @@ def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,
 
 def _flatten_paged(cache: PagedKVCache):
     return (cache.k, cache.v, cache.lengths, cache.k_scale, cache.v_scale,
-            cache.stats), (
+            cache.stats, cache.side), (
         cache.page_size, cache.pages_per_slot, cache.max_len,
         cache.pad_slack, cache.compute_dtype, cache.window)
 
 
 def _unflatten_paged(aux, children):
-    k, v, lengths, k_scale, v_scale, stats = children
+    k, v, lengths, k_scale, v_scale, stats, side = children
     (page_size, pages_per_slot, max_len, pad_slack, compute_dtype,
      window) = aux
     return PagedKVCache(k=k, v=v, lengths=lengths, page_size=page_size,
                         pages_per_slot=pages_per_slot, max_len=max_len,
                         pad_slack=pad_slack, k_scale=k_scale,
                         v_scale=v_scale, compute_dtype=compute_dtype,
-                        stats=stats, window=window)
+                        stats=stats, window=window, side=side)
 
 
 jax.tree_util.register_pytree_node(PagedKVCache, _flatten_paged,
@@ -736,7 +827,8 @@ class GroupedPagedCache:
     _OF_THE_FIRST_GROUP = (
         "lengths", "stats", "num_pages", "trash_page", "num_slots",
         "page_size", "pages_per_slot", "max_len", "pad_slack", "rows",
-        "page_nbytes", "compute_dtype", "quantized", "latent")
+        "page_nbytes", "compute_dtype", "quantized", "latent", "side",
+        "side_width", "side_page_nbytes")
 
     def __getattr__(self, name):
         if name in self._OF_THE_FIRST_GROUP and "groups" in self.__dict__:

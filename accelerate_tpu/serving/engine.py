@@ -105,6 +105,7 @@ from .cache import (
     PagedAllocator,
     PagedKVCache,
     SlotKVCache,
+    WithSide,
     paged_admit_slot,
     paged_append_batch,
     paged_append_rows,
@@ -362,31 +363,73 @@ def _cache_spec(config, family=None):
     return CacheSpec(config.num_hidden_layers, kv, config.head_dim)
 
 
-def _unported_with_latent_cache(ec: "EngineConfig") -> list[str]:
-    """The options a latent pool does not implement yet (ROADMAP M3)."""
-    return [what for what, on in (
-        ("kv_dtype='int8' (int8 latent pages)", ec.kv_dtype is not None),
-        ("host_tier_bytes > 0 (the host tier's page shipments carry a K "
-         "and a V half)", ec.host_tier_bytes > 0),
-        ("mesh (a sharded latent pool and its kernel)", ec.mesh is not None),
-        ("speculative (the verify step's multi-token latent attention)",
-         ec.speculative is not None)) if on]
+# The engine options a pool OTHER than one K/V stack does not implement
+# yet, by the trait of the cache a family declares (`cache_spec`): what the
+# error calls the trait's fallback, then for each option what porting it
+# would take. ROADMAP M3 (latent), M2 (grouped), M8 (side).
+_OPTIONS = {
+    "prefix_cache=True": lambda ec: ec.prefix_cache,
+    "kv_dtype='int8'": lambda ec: ec.kv_dtype is not None,
+    "host_tier_bytes > 0": lambda ec: ec.host_tier_bytes > 0,
+    "mesh": lambda ec: ec.mesh is not None,
+    "speculative": lambda ec: ec.speculative is not None,
+}
+_UNPORTED = {
+    # one latent row a token (CacheSpec.kind='latent')
+    "latent": ("a K/V pool", {
+        "kv_dtype='int8'": "int8 latent pages",
+        "host_tier_bytes > 0": "the host tier's page shipments carry a K "
+                               "and a V half",
+        "mesh": "a sharded latent pool and its kernel",
+        "speculative": "the verify step's multi-token latent attention"}),
+    # one group a layer kind (cache_spec gives a tuple)
+    "grouped": ("a one-kind pool", {
+        "prefix_cache=True": "a hit at position p needs a window layer's "
+                             "last rows before p, which a ring has "
+                             "overwritten: published pages need a retention "
+                             "rule of their own",
+        "kv_dtype='int8'": "an int8 ring and its kernel",
+        "host_tier_bytes > 0": "a page shipment is one pool's pages",
+        "mesh": "sharded groups and their kernels",
+        "speculative": "the verify step's multi-token window attention"}),
+    # a side row a token beside K and V (CacheSpec.side_width)
+    "side": ("attention over every key", {
+        "kv_dtype='int8'": "the side row's codes and scales, and a sparse "
+                           "kernel that reads int8 pages",
+        "host_tier_bytes > 0": "a page shipment carries a K and a V half, "
+                               "no side row",
+        "mesh": "a sharded index pool, and the selection across shards",
+        "speculative": "the verify step's multi-token selection"}),
+}
 
 
-def _unported_with_grouped_cache(ec: "EngineConfig") -> list[str]:
-    """The options a cache with one group a layer kind does not implement
-    yet (ROADMAP M2), each by what it would take."""
-    return [what for what, on in (
-        ("prefix_cache=True (a hit at position p needs a window layer's "
-         "last rows before p, which a ring has overwritten: published pages "
-         "need a retention rule of their own)", ec.prefix_cache),
-        ("kv_dtype='int8' (an int8 ring and its kernel)",
-         ec.kv_dtype is not None),
-        ("host_tier_bytes > 0 (a page shipment is one pool's pages)",
-         ec.host_tier_bytes > 0),
-        ("mesh (sharded groups and their kernels)", ec.mesh is not None),
-        ("speculative (the verify step's multi-token window attention)",
-         ec.speculative is not None)) if on]
+def _refuse_unported(ec: "EngineConfig", spec: CacheSpec, groups) -> None:
+    """Raise for every option that is set and that a trait of this
+    family's cache does not implement: nothing falls back silently."""
+    traits = []
+    if spec.kind == "latent":
+        traits.append(("latent", "this family caches one latent row a token "
+                       "(CacheSpec.kind='latent')"))
+    if groups is not None:
+        traits.append(("grouped", "this family's layers differ in kind "
+                       f"(cache_spec gives {len(groups)} groups: "
+                       f"{[g.label for g in groups]})"))
+    if spec.side_width:
+        traits.append(("side", "this family caches a side row a token "
+                       f"beside K and V (CacheSpec.side_width="
+                       f"{spec.side_width}: an indexer's key, which chooses "
+                       "the keys attention reads)"))
+    for trait, said in traits:
+        instead, options = _UNPORTED[trait]
+        unported = [f"{option} ({takes})" for option, takes in options.items()
+                    if _OPTIONS[option](ec)]
+        if trait == "side" and groups is not None:
+            unported.append("layers that differ in kind (a side row in a "
+                            "ring of pages)")
+        if unported:
+            raise ValueError(
+                f"{said}, which is not implemented together with: "
+                + "; ".join(unported) + f". Nothing falls back to {instead}.")
 
 
 def _resolve_paged_attention(setting, mesh, speculative=None) -> bool:
@@ -489,23 +532,7 @@ class Engine:
         if ec.strict is not None and ec.strict not in ("warn", "error"):
             raise ValueError(
                 f"strict must be None, 'warn', or 'error'; got {ec.strict!r}")
-        if self._cache_spec.kind == "latent":
-            unported = _unported_with_latent_cache(ec)
-            if unported:
-                raise ValueError(
-                    "this family caches one latent row a token "
-                    "(CacheSpec.kind='latent'), which is not implemented "
-                    "together with: " + "; ".join(unported) + ". Nothing "
-                    "falls back to a K/V pool.")
-        if self._cache_groups is not None:
-            unported = _unported_with_grouped_cache(ec)
-            if unported:
-                raise ValueError(
-                    "this family's layers differ in kind (cache_spec gives "
-                    f"{len(self._cache_groups)} groups: "
-                    f"{[g.label for g in self._cache_groups]}), which is "
-                    "not implemented together with: " + "; ".join(unported)
-                    + ". Nothing falls back to a one-kind pool.")
+        _refuse_unported(ec, self._cache_spec, self._cache_groups)
         self._spec = ec.speculative is not None
         if self._spec:
             if ec.mesh is not None:
@@ -576,7 +603,7 @@ class Engine:
                 spec.width, dtype=ec.cache_dtype, page_size=ec.page_size,
                 pad_slack=self._pad_slack, num_pages=ec.num_pages,
                 kv_dtype=ec.kv_dtype, latent=spec.kind == "latent",
-                stats=stats)
+                stats=stats, side_width=spec.side_width)
         if self._spec:
             draft = _cache_spec(self._draft_config, dfam)
             if draft.kind != "kv":
@@ -857,7 +884,11 @@ class Engine:
                     return tuple(pools(g, which) for g in cache.groups)
                 data, scales = ((cache.k, cache.k_scale) if which == "k"
                                 else (cache.v, cache.v_scale))
-                return PagedKV(data, scales, cache.compute_dtype)
+                pool = PagedKV(data, scales, cache.compute_dtype)
+                if which == "k" and cache.side is not None:
+                    return WithSide(pool, PagedKV(cache.side, None,
+                                                  cache.compute_dtype))
+                return pool
 
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
             def decode(params, cache, tokens, slot_keys, temps, live, table):
@@ -1708,6 +1739,9 @@ class Engine:
         self.metrics.set_page_gauges(
             alloc.pages_in_use, alloc.pages_free,
             alloc.pages_in_use * self.cache.page_nbytes)
+        if self.cache.side is not None:
+            self.metrics.set_side_bytes_gauge(
+                alloc.pages_in_use * self.cache.side_page_nbytes)
         if self._cache_groups:
             self.metrics.set_group_page_gauges(dict(zip(
                 (g.label for g in self._cache_groups),

@@ -101,6 +101,10 @@ class ServingMetrics:
         # created at the first observation, so other engines keep the
         # series they had
         self._g_group_pages: dict = {}
+        # of `serving_kv_bytes_in_use`, the bytes of the side rows a pool
+        # keeps beside K and V (an indexer's keys: CacheSpec.side_width);
+        # created at the first observation, as above
+        self._g_side_bytes = None
         # the engine reads a program's results one step late: a read that
         # found another program already dispatched (the chip worked while
         # the host waited) against one that found none (the chip waited)
@@ -278,6 +282,12 @@ class ServingMetrics:
         if bytes_in_use is not None:
             self._g_kv_bytes.set(bytes_in_use)
 
+    def set_side_bytes_gauge(self, bytes_in_use: int) -> None:
+        if self._g_side_bytes is None:
+            self._g_side_bytes = self.registry.gauge(
+                "serving_kv_side_bytes_in_use")
+        self._g_side_bytes.set(bytes_in_use)
+
     def set_group_page_gauges(self, in_use: dict) -> None:
         """`in_use`: pages held a cache group, by the group's label."""
         for group, pages in in_use.items():
@@ -372,6 +382,8 @@ class ServingMetrics:
         }
         for group, gauge in self._g_group_pages.items():
             out[f"pages_in_use.{group}"] = float(gauge.value)
+        if self._g_side_bytes is not None:
+            out["kv_side_bytes_in_use"] = float(self._g_side_bytes.value)
         if self.decode_steps:
             out["tokens_per_decode_step"] = (
                 self.tokens_out / self.decode_steps)

@@ -114,6 +114,11 @@ class PageTransport:
             raise ValueError(
                 "page shipments of a latent pool are not implemented: a "
                 "KVPageShipment carries a K and a V half (ROADMAP M3)")
+        if engine.cache.side is not None:
+            raise ValueError(
+                "page shipments of a pool with a side row a token are not "
+                "implemented: a KVPageShipment carries a K and a V half, "
+                "and a page here holds an indexer's keys too (ROADMAP M8)")
         self._engine = engine
         self._quantized = engine.cache.quantized
         install_out = None

@@ -527,6 +527,111 @@ def test_mixed_window_engine_programs_compile_for_v5e(one_chip, chip_compile):
             program, args, text, (slots,) if name == "decode" else ())
 
 
+def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
+    """`decode` and `prefill` of `serve-keye-vl2-docqa-32k-closed`
+    (Keye-VL-2.0-30B-A3B widths, 6 layers, 16 slots x 43008, page 16, chunk
+    512, 21,504 pages of K, V and a 64-lane index key a token), abstract
+    weights and pool, compiled for the chip:
+
+    (a) `decode` holds the indexer-score kernel and the sparse attention
+        kernel once a layer, each under its own name, and the selection's
+        loops (`while`: the value's bits, and the ties' cut under a
+        `conditional`); each kernel takes its whole stacked pool (nothing
+        of a layer's slice shape is produced around it); `prefill` holds
+        neither kernel (a chunk scores and attends the slot's gathered
+        views) and the same selection;
+    (b) the expert products, 3 a layer, are `ragged-dot` kernels in
+        `prefill` and the rows kernel in `decode`; nothing of an expert
+        matrix's shape is copied in either;
+    (c) K, V and the index pool are aliased to their arguments; the index
+        pool lies as [6, 21505, 8, 128] bf16, whole (8, 128)(2, 1) tiles:
+        its argument takes its logical bytes, not twice them;
+    (d) a chunk's logits are one row; temporaries: `decode` under 300 MB
+        (90 MB read), `prefill` under 1.7 GB (1.57 GB read: the slot's
+        gathered K and V views, 267 MB each, their updated stacks, and a
+        layer's [512, 43520] scores, their ordered image and the mask),
+        beside 8.75 GB of weights and 4.49 GB of pool."""
+    from accelerate_tpu.models import keye
+    from accelerate_tpu.ops import sparse_paged_attention as sparse
+    from accelerate_tpu.serving import Engine, EngineConfig, PagedKVCache
+
+    slots, max_len, page, chunk, num_pages = 16, 43008, 16, 512, 21504
+    cfg = keye.KeyeConfig(num_hidden_layers=6, max_position_embeddings=49152)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: keye.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * 4_374_622_464
+    engine = Engine(keye, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=(max_len + chunk) // page,
+        paged_attention=True))
+    small = engine.cache
+    spec = keye.cache_spec(cfg)
+    cache = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        spec.num_layers, slots, max_len, spec.heads, spec.width,
+        page_size=page, pad_slack=small.pad_slack, num_pages=num_pages,
+        stats=small.stats, side_width=spec.side_width)))
+    assert cache.k.shape == (6, 21505, 4, 16, 128)
+    assert cache.side.shape == (6, 21505, 8, 128)
+    assert cache.pages_per_slot == 2720
+    assert cache.page_nbytes == 16 * 13_056
+    pool_bytes = 2 * cache.k.size * 2 + cache.side.size * 2
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_), arg((slots, 2720), jnp.int32)), 300e6),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32), arg((2720,), jnp.int32),
+            arg((chunk,), jnp.int32), arg((), jnp.int32)), 1.7e9),
+    }
+    for name, (program, args, temp_limit) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
+                 for n in (sparse.SCORES_KERNEL_NAME,
+                           sparse.ATTENTION_KERNEL_NAME)]
+        assert calls == ([6, 6] if name == "decode" else [0, 0]), (
+            name, calls)
+        # the selection: a loop over the value's bits a layer, and the
+        # ties' cut (a second loop) under a conditional; in `decode` no
+        # other loop (a trace names the selection `%while` there), in
+        # `prefill` also the blocks of the scores and of the attention
+        assert len(re.findall(r" while\(", text)) == (
+            12 if name == "decode" else 24), name
+        assert len(re.findall(r" conditional\(", text)) == 6, name
+        assert _grouped_products(text) == (
+            (0, 18) if name == "decode" else (18, 0)), name
+        assert _ops_of_shape(text, "bf16", (128, 2048, 768)) == {}, name
+        assert _ops_of_shape(text, "bf16", (128, 768, 2048)) == {}, name
+        assert _ops_of_shape(text, "bf16", cache.k.shape[1:]) == {}, name
+        assert _ops_of_shape(text, "bf16", cache.side.shape[1:]) == {}, name
+        assert _ops_of_shape(text, "bf16", cache.k.shape) == {
+            "scatter": 2, "fusion": 2}, name
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        # the index pool as it lies on the chip: whole tiles, no padding
+        assert re.search(r"bf16\[6,21505,8,128\]\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+                         text), name
+        assert memory.temp_size_in_bytes < temp_limit, (
+            name, memory.temp_size_in_bytes)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
+        print(name, "temp", memory.temp_size_in_bytes, "args",
+              memory.argument_size_in_bytes)
+
+
 def test_qwen_decode_holds_the_one_kernel_name_on_v5e(one_chip,
                                                       chip_compile):
     """The Qwen cells' `decode` still calls `paged_decode_attention` alone:

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.models import mellum
-from accelerate_tpu.models.common import rope_frequencies
+from accelerate_tpu.models.common import rope_frequencies, softmax_moe_layer
 from accelerate_tpu.ops.grouped_experts import softmax_topk_route
 from accelerate_tpu.serving import Engine, EngineConfig
 from accelerate_tpu.serving.sanitizer import SanitizerViolation, check_engine
@@ -220,7 +220,7 @@ def test_router_and_expert_layer_against_the_masked_combine(params, case):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(REF.moe(REF_CFG, m, flat))
         experts, weights = REF.route(REF_CFG, m, flat)
-        got, counts = mellum.moe_layer(CFG, m, x)
+        got, counts = softmax_moe_layer(CFG, m, x)
         mine, mine_w = softmax_topk_route(flat, m["router"]["kernel"], k)
         _, raw = softmax_topk_route(flat, m["router"]["kernel"], k,
                                     norm_topk=False)
