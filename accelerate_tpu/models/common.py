@@ -14,6 +14,7 @@ uses einsum forms XLA maps onto the MXU, layers stack on a leading dim for
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -421,6 +422,96 @@ def cross_entropy_loss(
     if mask is not None:
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
     return jnp.mean(nll)
+
+
+def _head_loss_block(h, head, labels, weights, tied: bool, with_grads: bool):
+    """One block of `fused_head_loss`: the weighted NLL summed over `h`
+    [B, c, H] and, `with_grads`, the gradients of that sum by `h` and by
+    `head` (float32, this block's part). The block's logits and their
+    gradient live here and nowhere else."""
+    logits = jnp.einsum("bsh,vh->bsv" if tied else "bsh,hv->bsv", h, head,
+                        preferred_element_type=jnp.float32)
+    shifted = logits - jnp.max(logits, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True))
+    hit = labels[..., None] == jax.lax.broadcasted_iota(
+        labels.dtype, logits.shape, logits.ndim - 1)
+    nll = lse[..., 0] - jnp.sum(jnp.where(hit, shifted, 0.0), axis=-1)
+    loss_sum = jnp.sum(nll * weights)
+    if not with_grads:
+        return loss_sum, None, None
+    # d(loss_sum)/d(logits), float32, rounded ONCE where it enters the two
+    # products (their other operand is in h's dtype already)
+    d = ((jnp.exp(shifted - lse) - hit) * weights[..., None]).astype(h.dtype)
+    dh = jnp.einsum("bsv,vh->bsh" if tied else "bsv,hv->bsh", d, head,
+                    preferred_element_type=jnp.float32).astype(h.dtype)
+    dhead = jnp.einsum("bsv,bsh->vh" if tied else "bsv,bsh->hv", d, h,
+                       preferred_element_type=jnp.float32)
+    return loss_sum, dh, dhead
+
+
+def _head_loss_blocks(hidden, head, labels, weights, tied, chunk, with_grads):
+    """`_head_loss_block` over S // chunk blocks of `chunk` positions (all B
+    rows of each: a batch sharded over a mesh keeps every device busy in
+    every block). The head's gradient adds up in float32 in the carry."""
+    B, S, H = hidden.shape
+    n = S // chunk
+
+    def blocks(x):
+        return jnp.moveaxis(x.reshape(B, n, chunk, *x.shape[2:]), 1, 0)
+
+    def body(carry, xs):
+        loss_sum, dhead = carry
+        h, l, m = xs
+        part, dh, dhead_part = _head_loss_block(h, head, l, m, tied,
+                                                with_grads)
+        if with_grads:
+            dhead = dhead + dhead_part
+        return (loss_sum + part, dhead), dh
+
+    dhead0 = jnp.zeros(head.shape, jnp.float32) if with_grads else None
+    (loss_sum, dhead), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), dhead0),
+        (blocks(hidden), blocks(labels), blocks(weights)))
+    if with_grads:
+        dh = jnp.moveaxis(dh, 0, 1).reshape(B, S, H)
+    return loss_sum, dh, dhead
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def fused_head_loss(hidden, head, labels, weights, tied: bool, chunk: int):
+    """The LM head's projection and its cross-entropy as ONE op:
+    `sum(token_nll(project(hidden), labels) * weights)` over blocks of
+    `chunk` positions (S % chunk == 0), never holding more of the logits
+    than one block `[B, chunk, V]` in float32.
+
+    `hidden` [B, S, H] (after the final norm), `head` the tied embedding
+    [V, H] (`tied`) or `lm_head.kernel` [H, V], already in `hidden`'s
+    dtype; `labels` int [B, S], `weights` float32 [B, S].
+
+    Differentiated (`jax.grad` / `value_and_grad`), the FORWARD makes the
+    gradients too: each block's `d = (softmax - onehot) * weights` is used
+    for `d @ head` and `d^T @ hidden` while it exists, so the logits are
+    projected once, nothing vocabulary-wide is kept or recomputed, and the
+    backward only scales the two residuals (`dh` [B, S, H] and the head's
+    gradient, float32, head-shaped) by the incoming cotangent. Called
+    without differentiation it computes the loss alone."""
+    return _head_loss_blocks(hidden, head, labels, weights, tied, chunk,
+                             with_grads=False)[0]
+
+
+def _fused_head_loss_fwd(hidden, head, labels, weights, tied, chunk):
+    loss_sum, dh, dhead = _head_loss_blocks(
+        hidden, head, labels, weights, tied, chunk, with_grads=True)
+    return loss_sum, (dh, dhead)
+
+
+def _fused_head_loss_bwd(tied, chunk, residuals, g):
+    dh, dhead = residuals  # head came in hidden's dtype, so dh's is both's
+    return ((dh * g).astype(dh.dtype), (dhead * g).astype(dh.dtype),
+            None, None)
+
+
+fused_head_loss.defvjp(_fused_head_loss_fwd, _fused_head_loss_bwd)
 
 
 def count_params(params: Any) -> int:

@@ -39,7 +39,7 @@ from .common import (
     apply_rope,
     shifted_padding_masks,
     cross_entropy_loss,
-    token_nll,
+    fused_head_loss,
     dense,
     dot_product_attention,
     init_dense,
@@ -449,13 +449,25 @@ def causal_lm_loss(config: LlamaConfig, params: dict, batch: dict,
 
     Large vocab x long sequence makes the [B, S, V] f32 logits the single
     biggest buffer of the step (e.g. 16 x 2048 x 32000 f32 = 4.2 GB). When
-    S divides into `loss_chunk_size` chunks (auto-picked so a chunk's logits
-    stay ~256 MB), the projection + cross-entropy run under `lax.scan` per
-    chunk and the full logits never exist.
+    S divides into blocks of `loss_chunk_size` positions (`loss_plan`:
+    picked from B and V so that one block's f32 logits stay under
+    `_LOGITS_BLOCK_BYTES`; the caller's explicit word wins, e.g. to fit a
+    small chip), the head and its cross-entropy run as ONE op block by
+    block (`common.fused_head_loss`) and the full logits never exist.
+    What it holds: one block of logits and their gradient
+    (rows x V x 6 bytes), the head's gradient in float32 (head-shaped) and
+    the hidden rows' gradient [B, S, H]. Under `jax.grad` /
+    `value_and_grad` (the intended use: a train step) the gradients of the
+    hidden rows and of the head are made in the op's FORWARD, while a
+    block's logits exist, so the logits are projected once and the backward
+    has no vocabulary-wide product; not differentiated (an evaluation loop)
+    the op computes the loss alone, block by block. Where S does not divide,
+    the whole logits already fit one block, or `loss_chunk_size >= S`, the
+    whole-logits path runs (`forward` + `cross_entropy_loss`).
 
     With `fp8_state` (mixed_precision="fp8"), layer projections run fp8 and
     the return is (loss, new_fp8_state) — the fused train step threads it
-    through TrainState.fp8_state.
+    through TrainState.fp8_state. The head is never fp8.
 
     The attention_mask threads into the forward as a key-padding mask
     (flash/ring/ulysses all take it natively) so padded tokens cannot leak
@@ -468,11 +480,8 @@ def causal_lm_loss(config: LlamaConfig, params: dict, batch: dict,
     attn_mask, mask = shifted_padding_masks(batch.get("attention_mask"))
     B, S = labels.shape
 
-    if loss_chunk_size is None:
-        budget = 256 * 2**20 // 4  # f32 elements per chunk of logits
-        loss_chunk_size = max(1, budget // max(1, B * config.vocab_size))
-    chunk = _pick_chunk(S, loss_chunk_size)
-    if chunk is None or chunk >= S:
+    plan = loss_plan(B, S, config.vocab_size, loss_chunk_size)
+    if plan["path"] == "full":
         out = forward(config, params, input_ids[:, :-1],
                       attention_mask=attn_mask, fp8_state=fp8_state)
         if fp8_state is not None:
@@ -484,34 +493,45 @@ def causal_lm_loss(config: LlamaConfig, params: dict, batch: dict,
                   attention_mask=attn_mask, return_hidden=True,
                   fp8_state=fp8_state)
     hidden, new_fp8 = out if fp8_state is not None else (out, None)
-    n = S // chunk
-    h_chunks = hidden.reshape(B, n, chunk, -1).transpose(1, 0, 2, 3)
-    l_chunks = labels.reshape(B, n, chunk).transpose(1, 0, 2)
-    m_chunks = (
-        mask.reshape(B, n, chunk).transpose(1, 0, 2)
-        if mask is not None else jnp.ones((n, B, chunk), jnp.float32)
-    )
-
-    def body(carry, xs):
-        h, l, m = xs
-        nll = token_nll(_project_out(config, params, h), l)
-        loss_sum, count = carry
-        return (loss_sum + jnp.sum(nll * m), count + jnp.sum(m)), None
-
-    # checkpoint the chunk body: otherwise scan's backward saves every
-    # chunk's logits and the full [B,S,V] buffer is back
-    body = jax.checkpoint(body, prevent_cse=False)
-    (loss_sum, count), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.float32(0.0)), (h_chunks, l_chunks, m_chunks)
-    )
-    loss = loss_sum / jnp.maximum(count, 1)
+    tied = config.tie_word_embeddings
+    head = (params["embed_tokens"]["embedding"] if tied
+            else params["lm_head"]["kernel"]).astype(hidden.dtype)
+    if mask is None:
+        mask = jnp.ones((B, S), jnp.float32)
+    loss_sum = fused_head_loss(hidden, head, labels, mask, tied,
+                               plan["rows_per_block"] // B)
+    loss = loss_sum / jnp.maximum(jnp.sum(mask), 1)
     return (loss, new_fp8) if fp8_state is not None else loss
 
 
+# One block of float32 logits in `fused_head_loss`. Sized on the chip (v5e,
+# B 2, S 2048, V 151936, H 1536; PERF.md section 6, PR 37): blocks of 512 /
+# 1,024 / 2,048 rows read 25.5k / 27.1k / 28.0k tokens/s in the train cell
+# (21.3k before), so 2,048 rows of that vocabulary fit; a block's three
+# products then sit far over the chip's ridge of ~240 FLOP a weight byte and
+# the head's float32 gradient is read and written twice a step.
+_LOGITS_BLOCK_BYTES = 1280 * 2**20
+
+
+def loss_plan(B: int, S: int, V: int,
+              loss_chunk_size: int | None = None) -> dict:
+    """How `causal_lm_loss` computes the loss of a [B, S] batch over a
+    V-wide head, from shapes alone: {"path": "fused" | "full",
+    "rows_per_block", "blocks"}. `loss_chunk_size` is positions a block
+    (each block takes them from all B rows); None picks as many as keep a
+    block's float32 logits under `_LOGITS_BLOCK_BYTES`."""
+    if loss_chunk_size is None:
+        loss_chunk_size = max(1, _LOGITS_BLOCK_BYTES // 4 // max(1, B * V))
+    chunk = _pick_chunk(S, loss_chunk_size)
+    if chunk is None:
+        return {"path": "full", "rows_per_block": B * S, "blocks": 1}
+    return {"path": "fused", "rows_per_block": B * chunk, "blocks": S // chunk}
+
+
 def _pick_chunk(S: int, target: int) -> int | None:
-    """Largest divisor of S that is <= target; None when chunking is not
-    worthwhile (S already small, or — e.g. prime S — the best divisor is so
-    small the scan would degenerate into per-token matmuls)."""
+    """Largest divisor of S that is <= target; None when blocks are not
+    worthwhile (S already fits one, or — e.g. prime S — the best divisor is
+    so small the blocks would degenerate into per-token matmuls)."""
     if S <= target:
         return None
     best = None
@@ -519,9 +539,9 @@ def _pick_chunk(S: int, target: int) -> int | None:
         if S % c == 0:
             best = c
             break
-    # a divisor far below the target (prime-ish S) degenerates the scan into
-    # per-token matmuls — prefer the full path then. When the memory budget
-    # itself demands tiny chunks, honor them: slow beats OOM.
+    # a divisor far below the target (prime-ish S) degenerates the blocks
+    # into per-token matmuls — prefer the full path then. When the memory
+    # budget itself demands tiny blocks, honor them: slow beats OOM.
     if best is None or best < max(1, target // 8):
         return None
     return best

@@ -20,7 +20,10 @@ guard every later PR at no chip time:
   grouped expert products compile (XLA's `ragged-dot` over a chunk's
   rows, the rows kernel over a decode step's), the one-array pool is
   updated in place, no expert matrix is copied;
-- the rows kernel alone at both expert cells' decode shapes.
+- the rows kernel alone at both expert cells' decode shapes;
+- the train step's head and loss as one op (`fused_head_loss`) at the
+  train cell's shape: three vocabulary-wide products, the logits once,
+  one block of float32 logits, the head's gradient summed in float32.
 
 The topology is described ONLY inside this file's module-scoped fixture:
 one process at a time may load the TPU's library, the xdist workers all
@@ -691,3 +694,43 @@ def test_flash_under_four_device_mesh_compiles_for_v5e(topo, chip_compile):
         # batch and heads are split where they already live: nothing is
         # gathered around the kernel
         assert " all-gather(" not in text
+
+
+def test_fused_head_loss_compiles_for_v5e_with_one_projection(one_chip,
+                                                              chip_compile):
+    """The train cell's head and loss (2 x 2048 rows over Qwen2's 151,936 x
+    1,536 tied head, bf16) as the chip's compiler sees them: three
+    vocabulary-wide products in all (the logits ONCE, the hidden rows'
+    gradient, the head's gradient), one block of float32 logits and never
+    the whole [B, S, V], the head's gradient summed in float32."""
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.models.common import fused_head_loss
+
+    B, S, H, V = 2, 2048, 1536, 151936
+    plan = llama.loss_plan(B, S, V)
+    assert plan["path"] == "fused"
+    chunk = plan["rows_per_block"] // B
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss_and_grads(hidden, head, labels, weights):
+        return jax.value_and_grad(
+            lambda h, w: fused_head_loss(h, w, labels, weights, True, chunk)
+            / jnp.maximum(jnp.sum(weights), 1), argnums=(0, 1))(hidden, head)
+
+    compiled = jax.jit(loss_and_grads).lower(
+        sds((B, S, H), jnp.bfloat16), sds((V, H), jnp.bfloat16),
+        sds((B, S), jnp.int32), sds((B, S), jnp.float32)).compile()
+    text = compiled.as_text()
+    # every product of the program, by the einsum it came from
+    products = re.findall(
+        r' convolution\([^\n]*op_name="[^"\n]*?/([a-z,]+->[a-z]+)/dot_general',
+        text)
+    assert sorted(products) == ["bsh,vh->bsv", "bsv,bsh->vh", "bsv,vh->bsh"]
+    assert f"f32[{B},{chunk},{V}]" in text
+    assert f"[{B},{S},{V}]" not in text
+    assert f"f32[{V},{H}]" in text  # the accumulator
+    # one block of logits + the accumulator + what the compiler keeps
+    # beside them: far from the whole logits' 2.5 GB + a saved copy
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
