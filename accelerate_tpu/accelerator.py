@@ -47,6 +47,7 @@ from .sharding import (
 from .state import AcceleratorState, GradientState, PartialState
 from .telemetry.cost import CostTable, fence as _cost_fence, resolve_sample_every
 from .telemetry.export import start_metrics_server
+from .models.common import part
 from .telemetry.registry import get_registry
 from .telemetry.trace import span
 from .telemetry.watchdog import StallWatchdog, resolve_stall_timeout
@@ -1152,20 +1153,24 @@ class Accelerator:
                 # metas updated every micro-step (amax history is per-step
                 # statistics, independent of the accumulation boundary)
                 state = dataclasses.replace(state, fp8_state=new_fp8)
-            if use_scale:
-                grads = jax.tree_util.tree_map(
-                    lambda g: g / state.loss_scale.scale, grads
-                )
-            finite = jnp.isfinite(optax.global_norm(grads)) if use_scale else jnp.bool_(True)
+            # unscaling and the accumulation buffer are billed with the
+            # clip and the update (`training.py`): the step's `optimizer` part
+            with part("optimizer"):
+                if use_scale:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g / state.loss_scale.scale, grads
+                    )
+                finite = jnp.isfinite(optax.global_norm(grads)) if use_scale else jnp.bool_(True)
 
             if k > 1:
                 # overflowed micro-batches must not poison the buffer: their
                 # contribution is zeroed (GradScaler-style skip per micro-step)
-                accum = jax.tree_util.tree_map(
-                    lambda a, g: a + jnp.where(finite, g, 0.0) / k,
-                    state.grad_accum,
-                    grads,
-                )
+                with part("optimizer"):
+                    accum = jax.tree_util.tree_map(
+                        lambda a, g: a + jnp.where(finite, g, 0.0) / k,
+                        state.grad_accum,
+                        grads,
+                    )
                 micro = state.step + 1
                 do_apply = micro % k == 0
 

@@ -13,6 +13,7 @@ uses einsum forms XLA maps onto the MXU, layers stack on a leading dim for
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -23,6 +24,48 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG_INF = -1e30
+
+# The parts of a model a device operation is billed to, the same in every
+# family, the train step and the engine's own programs. Flat and few; a
+# layer is told by its order in the call, not by a name. A scope is HLO
+# metadata (an operation's `op_name`): it changes no program and costs
+# nothing on the device. docs/observability.md, "Device time by part".
+PARTS = (
+    "embed",
+    "attn.project", "attn.indexer", "attn.select", "attn.attend",
+    "attn.output",
+    "cache.view", "cache.write",
+    "mlp",
+    "moe.route", "moe.sort", "moe.experts", "moe.combine", "moe.shared",
+    "head", "sample", "loss", "optimizer",
+)
+
+
+class _Part(contextlib.ContextDecorator):
+    """`jax.named_scope(name)`, opened anew at every entry: a decorated
+    function may call itself (a grouped cache maps its groups through the
+    function that was called), which one shared scope object would not
+    survive."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self._open: list = []
+
+    def __enter__(self):
+        self._open.append(jax.named_scope(self.name))
+        return self._open[-1].__enter__()
+
+    def __exit__(self, *exc):
+        return self._open.pop().__exit__(*exc)
+
+
+def part(name: str):
+    """The scope of one of `PARTS`, as a context or as a function's
+    decorator; any other name raises."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is not a part of the model; parts: "
+                         f"{', '.join(PARTS)}")
+    return _Part(name)
 
 
 def hashable(value):
@@ -320,6 +363,7 @@ def blocked_attention(q, q_pos, k_view, v_view, key_pos, window, block,
     return jnp.moveaxis(out, 3, 1).reshape(B, S, H, D).astype(q.dtype)
 
 
+@part("cache.write")
 def write_view(view, rows, start, wraps: bool):
     """Rows [B, S, Hkv, D] written into view [B, R, Hkv, D] at positions
     `start` [B] onward, position p at row `p % R`. A view that keeps every
@@ -361,10 +405,12 @@ def softmax_moe_layer(config, m: dict, x, token_mask=None):
     e = m["experts"]
     y = grouped_swiglu_experts(flat, experts, weights, e["gate_proj"],
                                e["up_proj"], e["down_proj"])
-    counts = expert_counts(experts, c.num_experts,
-                           None if token_mask is None
-                           else token_mask.reshape(B * S))
-    return y.astype(x.dtype).reshape(B, S, h), counts
+    with part("moe.route"):
+        counts = expert_counts(experts, c.num_experts,
+                               None if token_mask is None
+                               else token_mask.reshape(B * S))
+    with part("moe.combine"):
+        return y.astype(x.dtype).reshape(B, S, h), counts
 
 
 # --- initializers -----------------------------------------------------------

@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import dot_product_attention, repeat_kv
+from .common import dot_product_attention, part, repeat_kv
 
 
 def make_kv_caches(num_layers: int, batch: int, max_len: int,
@@ -124,15 +124,18 @@ def decode_attention(q, k, v, kv_cache, positions, mask=None,
                 "key-padding masks are not supported on the paged decode "
                 "path (the engine's position masking is in-kernel)")
         pk, pv, meta = kv_cache
-        out, (k_row, v_row) = paged_decode_attention(q, k, v, pk, pv, meta,
-                                                     window=window)
+        with part("attn.attend"):
+            out, (k_row, v_row) = paged_decode_attention(
+                q, k, v, pk, pv, meta, window=window)
         return out, (k_row, v_row, meta)
-    k_full, v_full, new_cache = extend_cache(kv_cache, k, v)
-    m = windowed_cached_attention_mask(k_full.shape[1], positions, mask,
-                                       window)
-    out = dot_product_attention(q, repeat_kv(k_full, n_rep),
-                                repeat_kv(v_full, n_rep), mask=m,
-                                causal=False)
+    with part("cache.write"):
+        k_full, v_full, new_cache = extend_cache(kv_cache, k, v)
+    with part("attn.attend"):
+        m = windowed_cached_attention_mask(k_full.shape[1], positions, mask,
+                                           window)
+        out = dot_product_attention(q, repeat_kv(k_full, n_rep),
+                                    repeat_kv(v_full, n_rep), mask=m,
+                                    causal=False)
     return out, new_cache
 
 
@@ -183,6 +186,7 @@ def _is_batched_keys(key) -> bool:
     return key.ndim >= 2
 
 
+@part("sample")
 def sample_token(logits, key, temperature: float):
     """Next token from the last position's logits: argmax at temperature 0,
     else temperature-scaled categorical. The ONE sampling rule shared by the

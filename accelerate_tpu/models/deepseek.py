@@ -54,7 +54,7 @@ from ..ops.grouped_experts import (
     grouped_swiglu_experts,
     sigmoid_topk_route,
 )
-from .common import dense, normal_init, rms_norm, rope_frequencies
+from .common import dense, normal_init, part, rms_norm, rope_frequencies
 from .decode import build_generate, rope_table_len
 
 NEG_INF = -1e30
@@ -315,7 +315,7 @@ def _attention(config, a, x, cos, sin, positions, cache, layer_index):
     c = config
     B, S, _ = x.shape
     H = c.num_attention_heads
-    with jax.named_scope("mla.project"):
+    with part("attn.project"):
         c_q = rms_norm(dense(x, a["q_a_proj"]["kernel"]),
                        a["q_a_layernorm"]["scale"], c.rms_norm_eps)
         q = dense(c_q, a["q_b_proj"]["kernel"]).reshape(B, S, H,
@@ -334,7 +334,7 @@ def _attention(config, a, x, cos, sin, positions, cache, layer_index):
             axis=-1)                                        # [B, S, W]
 
     if cache is None:
-        with jax.named_scope("mla.attend"):
+        with part("attn.attend"):
             out = _decompressed_attention(c, a, q_nope, q_pe, row, positions)
         new = None
     elif getattr(cache[0], "is_paged_kv", False):
@@ -346,29 +346,32 @@ def _attention(config, a, x, cos, sin, positions, cache, layer_index):
                 "(chunked prefill attends the slot's gathered view)")
         pool, meta = cache[0].data, cache[2]
         new_row = row[:, 0].astype(cache[0].row_dtype)
-        with jax.named_scope("mla.absorb"):
+        with part("attn.project"):
             q_abs = _absorb_query(c, a, q_nope, q_pe)[:, 0]
         # the pool's unit head axis folds away: [L, pages + 1, ps, W]
-        o_lat = latent_paged_decode_attention(
-            q_abs, new_row, pool.reshape(pool.shape[:2] + pool.shape[3:]),
-            layer_index, meta.table, meta.lengths,
-            value_width=c.kv_lora_rank,
-            sm_scale=1.0 / math.sqrt(c.qk_head_dim))
-        with jax.named_scope("mla.unabsorb"):
+        with part("attn.attend"):
+            o_lat = latent_paged_decode_attention(
+                q_abs, new_row,
+                pool.reshape(pool.shape[:2] + pool.shape[3:]),
+                layer_index, meta.table, meta.lengths,
+                value_width=c.kv_lora_rank,
+                sm_scale=1.0 / math.sqrt(c.qk_head_dim))
+        with part("attn.output"):
             out = _unabsorb_output(c, a, o_lat[:, None].astype(x.dtype))
         new = new_row[:, None, None, :]                     # [B, 1, 1, W]
     else:
         view, cache_len = cache[0][:, :, 0, :], cache[2]    # [B, M, W]
-        start = jnp.broadcast_to(cache_len, (B,))
-        view = jax.vmap(lambda v, r, s: jax.lax.dynamic_update_slice(
-            v, r, (s, 0)))(view, row.astype(view.dtype), start)
-        with jax.named_scope("mla.attend"):
+        with part("cache.write"):
+            start = jnp.broadcast_to(cache_len, (B,))
+            view = jax.vmap(lambda v, r, s: jax.lax.dynamic_update_slice(
+                v, r, (s, 0)))(view, row.astype(view.dtype), start)
+        with part("attn.attend"):
             # one query token a row: absorbed; a chunk: decompressed
             attend = (_absorbed_attention if S == 1
                       else _decompressed_attention)
             out = attend(c, a, q_nope, q_pe, view, positions)
         new = view[:, :, None, :]
-    with jax.named_scope("mla.output"):
+    with part("attn.output"):
         out = dense(out.reshape(B, S, H * c.v_head_dim),
                     a["o_proj"]["kernel"])
     return out, new
@@ -399,12 +402,13 @@ def moe_layer(config: DeepseekConfig, m: dict, x, token_mask=None):
     e = m["experts"]
     y = grouped_swiglu_experts(flat, experts, weights, e["gate_proj"],
                                e["up_proj"], e["down_proj"])
-    with jax.named_scope("moe.shared"):
+    with part("moe.shared"):
         y = (y + _swiglu(m["shared"], flat).astype(jnp.float32)).astype(
             x.dtype)
-    counts = expert_counts(experts, c.n_routed_experts,
-                           None if token_mask is None
-                           else token_mask.reshape(B * S))
+    with part("moe.route"):
+        counts = expert_counts(experts, c.n_routed_experts,
+                               None if token_mask is None
+                               else token_mask.reshape(B * S))
     return y.reshape(B, S, h), counts
 
 
@@ -443,33 +447,43 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
     cos, sin = rope_frequencies(
         c.qk_rope_head_dim,
         rope_table_len(c.max_position_embeddings, kv_caches), c.rope_theta)
-    x = params["embed_tokens"]["embedding"][input_ids]
+    with part("embed"):
+        x = params["embed_tokens"]["embedding"][input_ids]
     new_rows, counts = [], []
     for i, layer in enumerate(params["layers"]):
         cache = None
         if dense_cache:
-            cache = (kv_caches[0][i], None, kv_caches[2])
+            with part("cache.view"):
+                cache = (kv_caches[0][i], None, kv_caches[2])
         elif paged:
             cache = kv_caches
-        y = rms_norm(x, layer["input_layernorm"]["scale"], c.rms_norm_eps)
+        # a norm is billed with the part it feeds, a residual add with the
+        # part it closes
+        with part("attn.project"):
+            y = rms_norm(x, layer["input_layernorm"]["scale"],
+                         c.rms_norm_eps)
         attn, new = _attention(c, layer["attn"], y, cos, sin, positions,
                                cache, i)
         new_rows.append(new)
-        x = x + attn
-        y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
-                     c.rms_norm_eps)
+        with part("attn.output"):
+            x = x + attn
         if "moe" in layer:
-            with jax.named_scope("moe"):
-                out, n = moe_layer(c, layer["moe"], y, token_mask)
+            with part("moe.route"):
+                y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
+                             c.rms_norm_eps)
+            out, n = moe_layer(c, layer["moe"], y, token_mask)
             counts.append(n)
+            with part("moe.combine"):
+                x = x + out
         else:
-            with jax.named_scope("mlp"):
-                out = _swiglu(layer["mlp"], y)
-        x = x + out
-    x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
-    if logit_rows is not None:
-        x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
-    with jax.named_scope("head"):
+            with part("mlp"):
+                y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
+                             c.rms_norm_eps)
+                x = x + _swiglu(layer["mlp"], y)
+    with part("head"):
+        x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
         logits = jnp.einsum(
             "bsh,hv->bsv", x, params["lm_head"]["kernel"].astype(x.dtype),
             preferred_element_type=jnp.float32)
@@ -477,11 +491,16 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
         out = (logits,)
     else:
         third = kv_caches[2] if paged else kv_caches[2] + S
-        out = (logits, (jnp.stack(new_rows), None, third))
+        # the rows a decode step hands the engine to append; a chunk's
+        # updated views, stacked again
+        with part("cache.write" if paged else "cache.view"):
+            new_rows = jnp.stack(new_rows)
+        out = (logits, (new_rows, None, third))
     if return_stats:
-        stats = {"expert_counts": (
-            jnp.stack(counts) if counts
-            else jnp.zeros((0, c.n_routed_experts), jnp.int32))}
+        with part("moe.route"):
+            stats = {"expert_counts": (
+                jnp.stack(counts) if counts
+                else jnp.zeros((0, c.n_routed_experts), jnp.int32))}
         out = out + (stats,)
     return out[0] if len(out) == 1 else out
 
@@ -500,11 +519,12 @@ def accumulate_serving_stats(total: dict, call: dict) -> dict:
     running counters: assignments per expert per expert layer, the sum
     over calls of the DISTINCT experts a call touched in each layer (what
     a call's expert weights cost in bytes), and the calls."""
-    n = call["expert_counts"]
-    return {"assignments": total["assignments"] + n,
-            "distinct_experts": total["distinct_experts"]
-            + jnp.sum(n > 0, axis=-1, dtype=jnp.int32),
-            "calls": total["calls"] + 1}
+    with part("moe.route"):
+        n = call["expert_counts"]
+        return {"assignments": total["assignments"] + n,
+                "distinct_experts": total["distinct_experts"]
+                + jnp.sum(n > 0, axis=-1, dtype=jnp.int32),
+                "calls": total["calls"] + 1}
 
 
 def init_kv_caches(config: DeepseekConfig, batch: int, max_len: int,

@@ -79,6 +79,7 @@ from .common import (
     hashable,
     layer_norm,
     normal_init,
+    part,
     rms_norm,
     rope_frequencies,
     softmax_moe_layer,
@@ -286,7 +287,7 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
     H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     J, w = c.indexer["indexer_num_heads"], c.indexer["indexer_head_dim"]
     at = positions[0]                          # the temporal row: causality
-    with jax.named_scope("attn.project"):
+    with part("attn.project"):
         q = dense(x, a["q_proj"]["kernel"]).reshape(B, S, H, D)
         k = dense(x, a["k_proj"]["kernel"]).reshape(B, S, Hkv, D)
         v = dense(x, a["v_proj"]["kernel"]).reshape(B, S, Hkv, D)
@@ -295,7 +296,7 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
             k = rms_norm(k, a["k_norm"]["scale"], c.rms_norm_eps)
         q = apply_mrope(q, *rope, positions, c.mrope_section)
         k = apply_mrope(k, *rope, positions, c.mrope_section)
-    with jax.named_scope("attn.indexer"):
+    with part("attn.indexer"):
         ix = a["indexer"]
         qI = apply_rope(dense(x, ix["q_proj"]["kernel"]).reshape(B, S, J, w),
                         *rope_i, at)
@@ -306,20 +307,22 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
         wts = jnp.dot(x, ix["weights_proj"]["kernel"].astype(x.dtype),
                       preferred_element_type=jnp.float32) * (J * w) ** -0.5
     new = None
-    with jax.named_scope("attn.attend"):
+    with part("attn.attend"):
         if cache is not None and cache[0] == "paged":
             _, pk, pv, pi, meta = cache
             kI = kI.astype(pi.row_dtype)
             ps = pk.data.shape[3]
             # every cached position's score, and the new token's own at
             # column `length` (its key is not in the pool yet)
-            scores = indexer_paged_scores(
-                qI[:, 0].astype(pi.data.dtype), wts[:, 0], pi, meta, ps)
-            own = indexer_scores(qI[:, 0].astype(kI.dtype), wts[:, 0],
-                                 kI[:, 0])                          # [B, 1]
-            col = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
-            scores = jnp.where(col == meta.lengths[:, None], own, scores)
-            select = exact_topk_mask(scores, c.topk)
+            with part("attn.indexer"):
+                scores = indexer_paged_scores(
+                    qI[:, 0].astype(pi.data.dtype), wts[:, 0], pi, meta, ps)
+                own = indexer_scores(qI[:, 0].astype(kI.dtype), wts[:, 0],
+                                     kI[:, 0])                      # [B, 1]
+                col = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+                scores = jnp.where(col == meta.lengths[:, None], own, scores)
+            with part("attn.select"):
+                select = exact_topk_mask(scores, c.topk)
             out, (k_row, v_row) = sparse_paged_decode_attention(
                 q, k, v, pk, pv, meta, select)
             new = (k_row, v_row, kI)
@@ -340,16 +343,20 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
                 blk = min(c.kv_block, R)
                 lo = jnp.zeros((), jnp.int32)
                 hi = jnp.minimum(jnp.max(at) // blk + 1, -(-R // blk))
-            scores = _view_scores(c, qI.astype(view_i.dtype), wts,
-                                  view_i[:, :, 0], at, key_pos)
-            select = exact_topk_mask(scores, c.topk)            # [B, S, R]
+            with part("attn.indexer"):
+                scores = _view_scores(c, qI.astype(view_i.dtype), wts,
+                                      view_i[:, :, 0], at, key_pos)
+            with part("attn.select"):
+                select = exact_topk_mask(scores, c.topk)        # [B, S, R]
             out = blocked_attention(q, at, view_k, view_v, key_pos, None,
                                     c.kv_block, lo, hi, select=select)
-        counted = (jnp.ones((B, S), bool) if token_mask is None
-                   else token_mask)
-        visible = jnp.sum(jnp.where(counted, at + 1, 0), dtype=jnp.int32)
-        chosen = jnp.sum(select & counted[:, :, None], dtype=jnp.int32)
-    with jax.named_scope("attn.output"):
+        with part("attn.select"):
+            counted = (jnp.ones((B, S), bool) if token_mask is None
+                       else token_mask)
+            visible = jnp.sum(jnp.where(counted, at + 1, 0),
+                              dtype=jnp.int32)
+            chosen = jnp.sum(select & counted[:, :, None], dtype=jnp.int32)
+    with part("attn.output"):
         out = dense(out.reshape(B, S, H * D), a["o_proj"]["kernel"])
     return out, new, (visible, chosen)
 
@@ -406,7 +413,8 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     rope_i = rope_frequencies(c.indexer["indexer_head_dim"], table_len,
                               c.rope_theta)
 
-    x = params["embed_tokens"]["embedding"][input_ids]
+    with part("embed"):
+        x = params["embed_tokens"]["embedding"][input_ids]
     new_k, new_v, new_i, counts = [], [], [], []
     visible = chosen = jnp.zeros((), jnp.int32)
     for i, layer in enumerate(params["layers"]):
@@ -416,38 +424,51 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
                      kv_caches[1].at_layer(i), kv_caches[0].side.at_layer(i),
                      kv_caches[2])
         elif views:
-            cache = ("view", kv_caches[0].rows[i], kv_caches[1][i],
-                     kv_caches[0].side[i], start)
-        y = rms_norm(x, layer["input_layernorm"]["scale"], c.rms_norm_eps)
+            with part("cache.view"):
+                cache = ("view", kv_caches[0].rows[i], kv_caches[1][i],
+                         kv_caches[0].side[i], start)
+        # a norm is billed with the part it feeds, a residual add with the
+        # part it closes
+        with part("attn.project"):
+            y = rms_norm(x, layer["input_layernorm"]["scale"],
+                         c.rms_norm_eps)
         attn, new, (n_vis, n_sel) = _attention(
             c, layer["attn"], y, rope, rope_i, positions, cache, token_mask)
         if new is not None:
             new_k.append(new[0])
             new_v.append(new[1])
             new_i.append(new[2])
-        visible, chosen = visible + n_vis, chosen + n_sel
-        x = x + attn
-        y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
-                     c.rms_norm_eps)
-        with jax.named_scope("moe"):
-            out, n = softmax_moe_layer(c, layer["moe"], y, token_mask)
+        with part("attn.select"):
+            visible, chosen = visible + n_vis, chosen + n_sel
+        with part("attn.output"):
+            x = x + attn
+        with part("moe.route"):
+            y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
+                         c.rms_norm_eps)
+        out, n = softmax_moe_layer(c, layer["moe"], y, token_mask)
         counts.append(n)
-        x = x + out
-    x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
-    if logit_rows is not None:
-        x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
-    with jax.named_scope("head"):
+        with part("moe.combine"):
+            x = x + out
+    with part("head"):
+        x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
         logits = jnp.einsum(
             "bsh,hv->bsv", x, params["lm_head"]["kernel"].astype(x.dtype),
             preferred_element_type=jnp.float32)
     if kv_caches is None:
         out = (logits,)
     else:
-        out = (logits, (WithSide(jnp.stack(new_k), jnp.stack(new_i)),
-                        jnp.stack(new_v),
-                        kv_caches[2] if paged else kv_caches[2] + S))
+        # the rows a decode step hands the engine to append; a chunk's
+        # updated views, stacked again
+        with part("cache.write" if paged else "cache.view"):
+            new = (WithSide(jnp.stack(new_k), jnp.stack(new_i)),
+                   jnp.stack(new_v))
+        out = (logits, new + (kv_caches[2] if paged else kv_caches[2] + S,))
     if return_stats:
-        out = out + ({"expert_counts": jnp.stack(counts),
+        with part("moe.route"):
+            counts = jnp.stack(counts)
+        out = out + ({"expert_counts": counts,
                       "keys_visible": visible, "keys_selected": chosen},)
     return out[0] if len(out) == 1 else out
 
@@ -487,11 +508,13 @@ def init_serving_stats(config: KeyeConfig) -> dict:
 def accumulate_serving_stats(total: dict, call: dict) -> dict:
     from .deepseek import accumulate_serving_stats as experts
 
-    return dict(
-        experts(total, call),
-        keys_visible=_add_wide(total["keys_visible"], call["keys_visible"]),
-        keys_selected=_add_wide(total["keys_selected"],
-                                call["keys_selected"]))
+    with part("attn.select"):
+        keys = dict(
+            keys_visible=_add_wide(total["keys_visible"],
+                                   call["keys_visible"]),
+            keys_selected=_add_wide(total["keys_selected"],
+                                    call["keys_selected"]))
+    return dict(experts(total, call), **keys)
 
 
 def init_kv_caches(config: KeyeConfig, batch: int, max_len: int,

@@ -44,6 +44,7 @@ from .common import (
     dot_product_attention,
     init_dense,
     normal_init,
+    part,
     repeat_kv,
     rms_norm,
     rope_frequencies,
@@ -184,20 +185,21 @@ def _attention(config: LlamaConfig, layer: dict, x, cos, sin, positions, mask,
     b, s, h = x.shape
     nh, nkv, hd = config.num_attention_heads, config.num_key_value_heads, config.head_dim
     fa = fp8["attn"] if fp8 is not None else {}
-    q, mq = _dense_maybe_fp8(x, layer["attn"]["q_proj"]["kernel"], fa.get("q_proj"))
-    k, mk = _dense_maybe_fp8(x, layer["attn"]["k_proj"]["kernel"], fa.get("k_proj"))
-    v, mv = _dense_maybe_fp8(x, layer["attn"]["v_proj"]["kernel"], fa.get("v_proj"))
-    if "bias" in layer["attn"]["q_proj"]:
-        q = q + layer["attn"]["q_proj"]["bias"].astype(q.dtype)
-    if "bias" in layer["attn"]["k_proj"]:
-        k = k + layer["attn"]["k_proj"]["bias"].astype(k.dtype)
-    if "bias" in layer["attn"]["v_proj"]:
-        v = v + layer["attn"]["v_proj"]["bias"].astype(v.dtype)
-    q = q.reshape(b, s, nh, hd)
-    k = k.reshape(b, s, nkv, hd)
-    v = v.reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    with part("attn.project"):
+        q, mq = _dense_maybe_fp8(x, layer["attn"]["q_proj"]["kernel"], fa.get("q_proj"))
+        k, mk = _dense_maybe_fp8(x, layer["attn"]["k_proj"]["kernel"], fa.get("k_proj"))
+        v, mv = _dense_maybe_fp8(x, layer["attn"]["v_proj"]["kernel"], fa.get("v_proj"))
+        if "bias" in layer["attn"]["q_proj"]:
+            q = q + layer["attn"]["q_proj"]["bias"].astype(q.dtype)
+        if "bias" in layer["attn"]["k_proj"]:
+            k = k + layer["attn"]["k_proj"]["bias"].astype(k.dtype)
+        if "bias" in layer["attn"]["v_proj"]:
+            v = v + layer["attn"]["v_proj"]["bias"].astype(v.dtype)
+        q = q.reshape(b, s, nh, hd)
+        k = k.reshape(b, s, nkv, hd)
+        v = v.reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
     new_cache = None
     if kv_cache is not None:
         # the shared cache-attend step (models/decode.py): dense stacked
@@ -208,58 +210,60 @@ def _attention(config: LlamaConfig, layer: dict, x, cos, sin, positions, mask,
             q, k, v, kv_cache, positions, mask=mask,
             window=config.sliding_window, n_rep=nh // nkv)
     else:
-        backend = select_attention_backend(
-            config.attention_backend,
-            on_tpu=jax.devices()[0].platform == "tpu",
-            decoding=False,
-            seq_len=s,
-        )
-        window = config.sliding_window
-        # flash, ring, and ulysses all take [B, S] key-padding masks
-        # natively (ring rotates mask chunks with K/V; ulysses all-gathers
-        # the mask), so padded batches keep every fast path; all three take
-        # `window` too (ring: exact global-position banding in the einsum
-        # fold; ulysses: the band rides the flash kernel after the head
-        # scatter)
-        key_mask = (mask if mask is None or getattr(mask, "ndim", 0) == 2
-                    else None)
-        if backend == "ring" and (mask is None or key_mask is not None):
-            # ring handles GQA itself: un-repeated K/V chunks ride the ring
-            # (the repeat factor never touches ICI)
-            from ..parallel.ring_attention import ring_attention
-
-            out = ring_attention(q, k, v, causal=True, mask=key_mask,
-                                 window=window)
-        elif backend == "ulysses" and (mask is None or key_mask is not None):
-            # ulysses also keeps GQA K/V un-repeated on the wire (repeat
-            # happens after its all-to-all)
-            from ..parallel.ulysses import ulysses_attention
-
-            out = ulysses_attention(q, k, v, causal=True, mask=key_mask,
-                                    window=window)
-        else:
-            k = repeat_kv(k, nh // nkv)
-            v = repeat_kv(v, nh // nkv)
-            if backend == "flash" and (
-                mask is None or getattr(mask, "ndim", 0) == 2
-            ):
-                # a Mosaic kernel cannot be partitioned by GSPMD: under
-                # a mesh it runs per shard (batch x heads) in shard_map
-                from ..ops.flash_attention import flash_attention_on_mesh
-                from ..state import PartialState
-
-                mesh = (PartialState().mesh if PartialState._shared_state
+        with part("attn.attend"):
+            backend = select_attention_backend(
+                config.attention_backend,
+                on_tpu=jax.devices()[0].platform == "tpu",
+                decoding=False,
+                seq_len=s,
+            )
+            window = config.sliding_window
+            # flash, ring, and ulysses all take [B, S] key-padding masks
+            # natively (ring rotates mask chunks with K/V; ulysses all-gathers
+            # the mask), so padded batches keep every fast path; all three take
+            # `window` too (ring: exact global-position banding in the einsum
+            # fold; ulysses: the band rides the flash kernel after the head
+            # scatter)
+            key_mask = (mask if mask is None or getattr(mask, "ndim", 0) == 2
                         else None)
-                out = flash_attention_on_mesh(q, k, v, mesh, causal=True,
-                                              mask=mask, window=window)
+            if backend == "ring" and (mask is None or key_mask is not None):
+                # ring handles GQA itself: un-repeated K/V chunks ride the ring
+                # (the repeat factor never touches ICI)
+                from ..parallel.ring_attention import ring_attention
+
+                out = ring_attention(q, k, v, causal=True, mask=key_mask,
+                                     window=window)
+            elif backend == "ulysses" and (mask is None or key_mask is not None):
+                # ulysses also keeps GQA K/V un-repeated on the wire (repeat
+                # happens after its all-to-all)
+                from ..parallel.ulysses import ulysses_attention
+
+                out = ulysses_attention(q, k, v, causal=True, mask=key_mask,
+                                        window=window)
             else:
-                out = dot_product_attention(q, k, v, mask=mask, causal=True,
-                                            window=window)
-    out = out.reshape(b, s, nh * hd)
-    o, mo = _dense_maybe_fp8(out, layer["attn"]["o_proj"]["kernel"],
-                             fa.get("o_proj"))
-    if "bias" in layer["attn"]["o_proj"]:
-        o = o + layer["attn"]["o_proj"]["bias"].astype(o.dtype)
+                k = repeat_kv(k, nh // nkv)
+                v = repeat_kv(v, nh // nkv)
+                if backend == "flash" and (
+                    mask is None or getattr(mask, "ndim", 0) == 2
+                ):
+                    # a Mosaic kernel cannot be partitioned by GSPMD: under
+                    # a mesh it runs per shard (batch x heads) in shard_map
+                    from ..ops.flash_attention import flash_attention_on_mesh
+                    from ..state import PartialState
+
+                    mesh = (PartialState().mesh if PartialState._shared_state
+                            else None)
+                    out = flash_attention_on_mesh(q, k, v, mesh, causal=True,
+                                                  mask=mask, window=window)
+                else:
+                    out = dot_product_attention(q, k, v, mask=mask, causal=True,
+                                                window=window)
+    with part("attn.output"):
+        out = out.reshape(b, s, nh * hd)
+        o, mo = _dense_maybe_fp8(out, layer["attn"]["o_proj"]["kernel"],
+                                 fa.get("o_proj"))
+        if "bias" in layer["attn"]["o_proj"]:
+            o = o + layer["attn"]["o_proj"]["bias"].astype(o.dtype)
     new_fp8 = (
         {"q_proj": mq, "k_proj": mk, "v_proj": mv, "o_proj": mo}
         if fp8 is not None else None
@@ -285,19 +289,23 @@ def _mlp(layer: dict, x, fp8=None):
 
 def _layer_body(config: LlamaConfig, x, layer, cos, sin, positions, mask,
                 kv_cache=None, fp8=None):
+    # `part` opens a `jax.named_scope` of `common.PARTS`; a norm is billed
+    # with the part it feeds, a residual add with the part it closes
+    with part("attn.project"):
+        y = rms_norm(x, layer["input_layernorm"]["scale"],
+                     config.rms_norm_eps)
     attn_out, new_cache, fp8_attn = _attention(
-        config, layer,
-        rms_norm(x, layer["input_layernorm"]["scale"], config.rms_norm_eps),
-        cos, sin, positions, mask, kv_cache, fp8,
-    )
-    x = x + attn_out
-    mlp_out, fp8_mlp = _mlp(
-        layer,
-        rms_norm(x, layer["post_attention_layernorm"]["scale"],
-                 config.rms_norm_eps),
-        fp8,
-    )
-    x = x + mlp_out
+        config, layer, y, cos, sin, positions, mask, kv_cache, fp8)
+    with part("attn.output"):
+        x = x + attn_out
+    with part("mlp"):
+        mlp_out, fp8_mlp = _mlp(
+            layer,
+            rms_norm(x, layer["post_attention_layernorm"]["scale"],
+                     config.rms_norm_eps),
+            fp8,
+        )
+        x = x + mlp_out
     new_fp8 = (
         {"attn": fp8_attn, "mlp": fp8_mlp} if fp8 is not None else None
     )
@@ -325,7 +333,8 @@ def forward(
     if fp8_state is not None and kv_caches is not None:
         raise ValueError("fp8 is a training-path feature; decode "
                          "(kv_caches) runs bf16")
-    x = params["embed_tokens"]["embedding"][input_ids]
+    with part("embed"):
+        x = params["embed_tokens"]["embedding"][input_ids]
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(input_ids.shape[1]), input_ids.shape
@@ -345,8 +354,9 @@ def forward(
 
         x, (nk, nv) = scan_decode_layers(layer_step, x, params["layers"],
                                          kv_caches)
-        x = rms_norm(x, params["norm"]["scale"], config.rms_norm_eps)
-        logits = _project_out(config, params, x)
+        with part("head"):
+            x = rms_norm(x, params["norm"]["scale"], config.rms_norm_eps)
+            logits = _project_out(config, params, x)
         return logits, (nk, nv, kv_caches[2] + input_ids.shape[1])
 
     body = partial(_layer_body, config)
@@ -385,10 +395,11 @@ def forward(
         scan_body = jax.checkpoint(scan_body, prevent_cse=False, policy=policy)
     x, scan_ys = jax.lax.scan(scan_body, x, scan_xs)
     new_fp8_state = {"layers": scan_ys} if fp8_state is not None else None
-    x = sp(rms_norm(x, params["norm"]["scale"], config.rms_norm_eps))
-    if return_hidden:
-        return (x, new_fp8_state) if fp8_state is not None else x
-    out = _project_out(config, params, x)
+    with part("head"):
+        x = sp(rms_norm(x, params["norm"]["scale"], config.rms_norm_eps))
+        if return_hidden:
+            return (x, new_fp8_state) if fp8_state is not None else x
+        out = _project_out(config, params, x)
     return (out, new_fp8_state) if fp8_state is not None else out
 
 
@@ -417,8 +428,7 @@ def forward_offloaded(
     )
 
     def final(resident, x):
-        x = rms_norm(x, resident["norm"]["scale"], config.rms_norm_eps)
-        return _project_out(config, resident, x)
+        return _project_decode(config, resident, x)
 
     return streamed_forward(
         dispatched_params,
@@ -484,23 +494,26 @@ def causal_lm_loss(config: LlamaConfig, params: dict, batch: dict,
     if plan["path"] == "full":
         out = forward(config, params, input_ids[:, :-1],
                       attention_mask=attn_mask, fp8_state=fp8_state)
-        if fp8_state is not None:
-            logits, new_fp8 = out
-            return cross_entropy_loss(logits, labels, mask), new_fp8
-        return cross_entropy_loss(out, labels, mask)
+        logits, new_fp8 = out if fp8_state is not None else (out, None)
+        with part("loss"):
+            loss = cross_entropy_loss(logits, labels, mask)
+        return (loss, new_fp8) if fp8_state is not None else loss
 
     out = forward(config, params, input_ids[:, :-1],
                   attention_mask=attn_mask, return_hidden=True,
                   fp8_state=fp8_state)
     hidden, new_fp8 = out if fp8_state is not None else (out, None)
     tied = config.tie_word_embeddings
-    head = (params["embed_tokens"]["embedding"] if tied
-            else params["lm_head"]["kernel"]).astype(hidden.dtype)
-    if mask is None:
-        mask = jnp.ones((B, S), jnp.float32)
-    loss_sum = fused_head_loss(hidden, head, labels, mask, tied,
-                               plan["rows_per_block"] // B)
-    loss = loss_sum / jnp.maximum(jnp.sum(mask), 1)
+    # the head's three products are inside the op: `head` + `loss` are one
+    # part here
+    with part("loss"):
+        head = (params["embed_tokens"]["embedding"] if tied
+                else params["lm_head"]["kernel"]).astype(hidden.dtype)
+        if mask is None:
+            mask = jnp.ones((B, S), jnp.float32)
+        loss_sum = fused_head_loss(hidden, head, labels, mask, tied,
+                                   plan["rows_per_block"] // B)
+        loss = loss_sum / jnp.maximum(jnp.sum(mask), 1)
     return (loss, new_fp8) if fp8_state is not None else loss
 
 
@@ -596,8 +609,9 @@ def make_decode_layer_step(config: LlamaConfig):
 def _project_decode(config: LlamaConfig, resident: dict, x):
     # the full forward norms before projecting (forward():377); the streamed
     # path must too or real checkpoints (norm.scale != 1) decode wrong
-    x = rms_norm(x, resident["norm"]["scale"], config.rms_norm_eps)
-    return _project_out(config, resident, x)
+    with part("head"):
+        x = rms_norm(x, resident["norm"]["scale"], config.rms_norm_eps)
+        return _project_out(config, resident, x)
 
 
 streamed_generate = build_streamed_generate(

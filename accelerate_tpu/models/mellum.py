@@ -62,6 +62,7 @@ from .common import (
     dense,
     hashable,
     normal_init,
+    part,
     rms_norm,
     rope_frequencies,
     softmax_moe_layer,
@@ -281,7 +282,7 @@ def _attention(config, a, x, rope, positions, window, cache):
     c = config
     B, S, _ = x.shape
     H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    with jax.named_scope("attn.project"):
+    with part("attn.project"):
         q = dense(x, a["q_proj"]["kernel"]).reshape(B, S, H, D)
         k = dense(x, a["k_proj"]["kernel"]).reshape(B, S, Hkv, D)
         v = dense(x, a["v_proj"]["kernel"]).reshape(B, S, Hkv, D)
@@ -291,7 +292,7 @@ def _attention(config, a, x, rope, positions, window, cache):
         q = apply_rope(q, *rope, positions)
         k = apply_rope(k, *rope, positions)
     new = None
-    with jax.named_scope("attn.attend"):
+    with part("attn.attend"):
         if cache is None:
             out = blocked_attention(q, positions, k, v, positions, window,
                                     c.kv_block)
@@ -306,7 +307,7 @@ def _attention(config, a, x, rope, positions, window, cache):
             _, view_k, view_v, start = cache
             out, *new = _attend_view(c, q, k, v, positions, view_k, view_v,
                                      start, window)
-    with jax.named_scope("attn.output"):
+    with part("attn.output"):
         out = dense(out.reshape(B, S, H * D), a["o_proj"]["kernel"])
     return out, new
 
@@ -369,7 +370,8 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
                                  rows=kv_caches[2].rows)
                  for g in range(len(groups))]
 
-    x = params["embed_tokens"]["embedding"][input_ids]
+    with part("embed"):
+        x = params["embed_tokens"]["embedding"][input_ids]
     new_k = [[] for _ in groups]
     new_v = [[] for _ in groups]
     counts = []
@@ -381,35 +383,48 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
             cache = ("paged", kv_caches[0][g].at_layer(j),
                      kv_caches[1][g].at_layer(j), metas[g])
         elif views:
-            cache = ("view", kv_caches[0][g][j], kv_caches[1][g][j], start)
-        y = rms_norm(x, layer["input_layernorm"]["scale"], c.rms_norm_eps)
+            with part("cache.view"):
+                cache = ("view", kv_caches[0][g][j], kv_caches[1][g][j],
+                         start)
+        # a norm is billed with the part it feeds, a residual add with the
+        # part it closes
+        with part("attn.project"):
+            y = rms_norm(x, layer["input_layernorm"]["scale"],
+                         c.rms_norm_eps)
         attn, new = _attention(c, layer["attn"], y, rope[kind], positions,
                                window, cache)
         if new is not None:
             new_k[g].append(new[0])
             new_v[g].append(new[1])
-        x = x + attn
-        y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
-                     c.rms_norm_eps)
-        with jax.named_scope("moe"):
-            out, n = softmax_moe_layer(c, layer["moe"], y, token_mask)
+        with part("attn.output"):
+            x = x + attn
+        with part("moe.route"):
+            y = rms_norm(x, layer["post_attention_layernorm"]["scale"],
+                         c.rms_norm_eps)
+        out, n = softmax_moe_layer(c, layer["moe"], y, token_mask)
         counts.append(n)
-        x = x + out
-    x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
-    if logit_rows is not None:
-        x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
-    with jax.named_scope("head"):
+        with part("moe.combine"):
+            x = x + out
+    with part("head"):
+        x = rms_norm(x, params["norm"]["scale"], c.rms_norm_eps)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
         logits = jnp.einsum(
             "bsh,hv->bsv", x, params["lm_head"]["kernel"].astype(x.dtype),
             preferred_element_type=jnp.float32)
     if kv_caches is None:
         out = (logits,)
     else:
-        out = (logits, (tuple(jnp.stack(rows) for rows in new_k),
-                        tuple(jnp.stack(rows) for rows in new_v),
+        # the rows a decode step hands the engine to append; a chunk's
+        # updated views, stacked again
+        with part("cache.write" if paged else "cache.view"):
+            new_k, new_v = (tuple(jnp.stack(rows) for rows in new)
+                            for new in (new_k, new_v))
+        out = (logits, (new_k, new_v,
                         kv_caches[2] if paged else kv_caches[2] + S))
     if return_stats:
-        out = out + ({"expert_counts": jnp.stack(counts)},)
+        with part("moe.route"):
+            out = out + ({"expert_counts": jnp.stack(counts)},)
     return out[0] if len(out) == 1 else out
 
 

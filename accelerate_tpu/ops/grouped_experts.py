@@ -40,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kernel_mode
+from ..models.common import part
 
 __all__ = ["sigmoid_topk_route", "softmax_topk_route", "expert_counts",
            "grouped_swiglu_experts", "grouped_rows_matmul",
@@ -56,7 +57,7 @@ def sigmoid_topk_route(x, router_kernel, correction_bias, top_k: int,
     float32). Operands that are bfloat16 values multiply exactly into the
     float32 accumulator, so this is the float32 product of the published
     router on such inputs."""
-    with jax.named_scope("moe.route"):
+    with part("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x, router_kernel.astype(x.dtype),
             preferred_element_type=jnp.float32))
@@ -75,7 +76,7 @@ def softmax_topk_route(x, router_kernel, top_k: int, norm_topk: bool = True):
     experts, divided by their sum where `norm_topk`. No bias in the
     choice and no scaling factor. x [T, h], router_kernel [h, E] ->
     (experts [T, k] int32, weights [T, k] float32)."""
-    with jax.named_scope("moe.route"):
+    with part("moe.route"):
         probs = jax.nn.softmax(jnp.dot(
             x, router_kernel.astype(x.dtype),
             preferred_element_type=jnp.float32), axis=-1)
@@ -232,12 +233,12 @@ def grouped_swiglu_experts(x, experts, weights, gate, up, down):
     with float32 accumulation; returns float32 [T, h]."""
     T, k = experts.shape
     E, h, f = gate.shape
-    with jax.named_scope("moe.sort"):
+    with part("moe.sort"):
         flat = experts.reshape(T * k)
         order = jnp.argsort(flat, stable=True)
         sizes = expert_counts(experts, E)
         rows = x[order // k]                                # [T * k, h]
-    with jax.named_scope("moe.experts"):
+    with part("moe.experts"):
         if few_rows_an_expert(T * k, E, h, f, x.dtype):
             product = functools.partial(grouped_rows_matmul, sizes=sizes)
         else:
@@ -248,7 +249,7 @@ def grouped_swiglu_experts(x, experts, weights, gate, up, down):
         act = (jax.nn.silu(product(rows, gate))
                * product(rows, up)).astype(x.dtype)
         out = product(act, down)                            # [T * k, h] f32
-    with jax.named_scope("moe.combine"):
+    with part("moe.combine"):
         back = jnp.zeros((T * k,), order.dtype).at[order].set(
             jnp.arange(T * k, dtype=order.dtype))
         return jnp.sum(out[back].reshape(T, k, -1)

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.common import part
 from ..telemetry.trace import span
 
 
@@ -127,6 +128,7 @@ class SlotKVCache:
         return self.k.nbytes + self.v.nbytes
 
 
+@part("cache.view")
 def slot_caches(cache: SlotKVCache, slot: jax.Array):
     """One slot's caches in `models/decode.py` layout: (k [L, 1, M, H, D],
     v [L, 1, M, H, D], cache_len scalar) — exactly what a family `forward`
@@ -137,6 +139,7 @@ def slot_caches(cache: SlotKVCache, slot: jax.Array):
     return ks, vs, cache.lengths[slot]
 
 
+@part("cache.write")
 def write_slot(cache: SlotKVCache, slot: jax.Array, new_k: jax.Array,
                new_v: jax.Array, advance: jax.Array) -> SlotKVCache:
     """Write one slot's updated [L, 1, M, H, D] buffers back and advance its
@@ -492,6 +495,7 @@ def _side_view(cache: PagedKVCache, idx: jax.Array, batch: int) -> jax.Array:
         cache.num_layers, batch, -1, 1, cache.side_width)
 
 
+@part("cache.view")
 def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
                     slot: jax.Array):
     """One slot's pages gathered into `models/decode.py` layout:
@@ -518,6 +522,7 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
     return ks, vs, cache.lengths[slot]
 
 
+@part("cache.write")
 def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
                      slot: jax.Array, new_k: jax.Array, new_v: jax.Array,
                      advance: jax.Array, chunk: int) -> PagedKVCache:
@@ -558,6 +563,7 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
                          cache.lengths.at[slot].set(length + advance))
 
 
+@part("cache.write")
 def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
                   count: jax.Array, win_k: jax.Array, win_v: jax.Array,
                   new_lengths: jax.Array) -> PagedKVCache:
@@ -638,6 +644,7 @@ def _scatter_rows(cache: PagedKVCache, table: jax.Array, start: jax.Array,
     )
 
 
+@part("cache.view")
 def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     """All slots' pages gathered into the dense decode layout:
     (k [L, S, R, H, D], v [L, S, R, H, D]), dequantized to
@@ -660,6 +667,7 @@ def paged_batch_view(cache: PagedKVCache, table: jax.Array):
     return ks, vs
 
 
+@part("cache.write")
 def paged_append_rows(cache: PagedKVCache, table: jax.Array,
                       row_k: jax.Array, row_v: jax.Array,
                       live: jax.Array) -> PagedKVCache:
@@ -687,6 +695,7 @@ def paged_append_rows(cache: PagedKVCache, table: jax.Array,
                          cache.lengths + live.astype(jnp.int32))
 
 
+@part("cache.write")
 def paged_append_batch(cache: PagedKVCache, table: jax.Array,
                        new_k: jax.Array, new_v: jax.Array,
                        live: jax.Array) -> PagedKVCache:
@@ -705,6 +714,7 @@ def paged_append_batch(cache: PagedKVCache, table: jax.Array,
     return paged_append_rows(cache, table, row_k, row_v, live)
 
 
+@part("cache.write")
 def paged_append_window(cache: PagedKVCache, table: jax.Array,
                         win_k: jax.Array, win_v: jax.Array,
                         counts: jax.Array, live: jax.Array) -> PagedKVCache:

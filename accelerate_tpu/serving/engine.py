@@ -85,6 +85,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.common import part
 from ..models.decode import sample_token
 from ..profiler import StepTimer, causal_lm_infer_flops
 from ..telemetry.cost import CostTable, resolve_sample_every
@@ -800,6 +801,7 @@ class Engine:
             # cache, the token register, the host's (tokens, logprobs)
             step_out = (cache_sh, rep, (rep, rep))
 
+        @part("sample")
         def sample_slot(logits, key_raw, position, temp):
             """One slot's next token from [V] logits: traced temperature
             selects greedy vs sampled, the step key derives from the
@@ -853,17 +855,20 @@ class Engine:
                 "prefill", params, cache, ids[None, :], positions,
                 (ks, vs, length), (real_len - 1)[None],
                 (jnp.arange(chunk) < real_len)[None, :])
-            if one_row:  # the one row that is read, not the chunk's
-                last = logits[0, 0].astype(jnp.float32)
-            else:
-                last = jax.lax.dynamic_index_in_dim(
-                    logits[0].astype(jnp.float32), real_len - 1,
-                    keepdims=False)
+            with part("sample"):
+                if one_row:  # the one row that is read, not the chunk's
+                    last = logits[0, 0].astype(jnp.float32)
+                else:
+                    last = jax.lax.dynamic_index_in_dim(
+                        logits[0].astype(jnp.float32), real_len - 1,
+                        keepdims=False)
             cache = paged_write_slot(cache, table_row, slot, nk, nv, real_len,
                                      chunk)
-            new_len = length + real_len
-            tok, lp = sample_slot(last, slot_keys[slot], new_len, temps[slot])
-            tokens = tokens.at[slot].set(tok)
+            with part("sample"):
+                new_len = length + real_len
+                tok, lp = sample_slot(last, slot_keys[slot], new_len,
+                                      temps[slot])
+                tokens = tokens.at[slot].set(tok)
             # (tok, lp) is the host's: `tokens` is donated to the next
             # program, which may be dispatched before the host reads
             return cache, tokens, (tok, lp)
@@ -902,7 +907,9 @@ class Engine:
                 # a retired or mid-prefill lane (a stale length; its
                 # result is discarded below) walks no page at length 0.
                 # The latent kernel's program stays as it was (PR 26)
-                walked = lengths if latent else jnp.where(live, lengths, 0)
+                with part("cache.view"):
+                    walked = (lengths if latent
+                              else jnp.where(live, lengths, 0))
                 kvc = (pools(cache, "k"),
                        None if latent else pools(cache, "v"),
                        PagedDecodeMeta(table, walked, rows=rows))
@@ -910,14 +917,16 @@ class Engine:
                     "decode", params, cache, tokens[:, None],
                     lengths[:, None], kvc, jnp.zeros_like(lengths),
                     live[:, None])
-                last = logits[:, 0].astype(jnp.float32)
-                next_tok, lps = jax.vmap(sample_slot)(
-                    last, slot_keys, cache.lengths + 1, temps)
-                tokens = jnp.where(live, next_tok, tokens)
+                with part("sample"):
+                    last = logits[:, 0].astype(jnp.float32)
+                    next_tok, lps = jax.vmap(sample_slot)(
+                        last, slot_keys, cache.lengths + 1, temps)
+                    tokens = jnp.where(live, next_tok, tokens)
                 # (one row array a group under a grouped cache; no V rows
                 # from a latent pool)
-                row_k, row_v = jax.tree.map(lambda r: r[:, :, 0],
-                                            (row_k, row_v))
+                with part("cache.write"):
+                    row_k, row_v = jax.tree.map(lambda r: r[:, :, 0],
+                                                (row_k, row_v))
                 cache = paged_append_rows(cache, table, row_k, row_v, live)
                 return cache, tokens, (next_tok, lps)
         else:
@@ -953,9 +962,10 @@ class Engine:
                     last, nk, nv = jax.vmap(
                         single, in_axes=(0, 0, 1, 1), out_axes=(0, 1, 1)
                     )(tokens, cache.lengths, k_all, v_all)
-                next_tok, lps = jax.vmap(sample_slot)(
-                    last, slot_keys, cache.lengths + 1, temps)
-                tokens = jnp.where(live, next_tok, tokens)
+                with part("sample"):
+                    next_tok, lps = jax.vmap(sample_slot)(
+                        last, slot_keys, cache.lengths + 1, temps)
+                    tokens = jnp.where(live, next_tok, tokens)
                 cache = paged_append_batch(cache, table, nk, nv, live)
                 return cache, tokens, (next_tok, lps)
 

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from .models.common import part
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -99,6 +101,7 @@ class TrainState:
             fp8_state=fp8_state,
         )
 
+    @part("optimizer")
     def apply_gradients(self, grads: Any) -> "TrainState":
         updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
         return dataclasses.replace(
@@ -345,6 +348,7 @@ def global_norm(tree: Any) -> jax.Array:
     return optax.global_norm(tree)
 
 
+@part("optimizer")
 def clip_by_global_norm(tree: Any, max_norm: float) -> tuple[Any, jax.Array]:
     """Returns (clipped, pre-clip norm) — matches torch
     clip_grad_norm_'s return (ref accelerator.py:2221)."""
