@@ -54,6 +54,18 @@ def rope_table_len(config_max: int, kv_caches) -> int:
     return max(config_max, kv_caches[0].shape[2])
 
 
+def layer_view(views, layer: int):
+    """Layer `layer`'s view [B, R, ...] of what a family that loops over
+    its layers was handed in a stacked view's place: an array [L, B, R,
+    ...], sliced; or one slot's pages read a layer at a time
+    (`serving.cache.LayerwiseSlotView`, what the serving engine's prefill
+    hands a family that declares `takes_layerwise_views`), gathered now."""
+    if getattr(views, "is_layerwise_view", False):
+        return views.at_layer(layer)
+    with part("cache.view"):
+        return views[layer]
+
+
 def extend_cache(kv_cache, k, v):
     """Write this step's K/V [B, S, H, D] at cache_len.
 

@@ -55,7 +55,7 @@ from ..ops.grouped_experts import (
     sigmoid_topk_route,
 )
 from .common import dense, normal_init, part, rms_norm, rope_frequencies
-from .decode import build_generate, rope_table_len
+from .decode import build_generate, layer_view, rope_table_len
 
 NEG_INF = -1e30
 _LANES = 128
@@ -141,6 +141,11 @@ def cache_spec(config: DeepseekConfig):
 
     return CacheSpec(num_layers=config.num_hidden_layers, heads=1,
                      width=config.latent_row_width, kind="latent")
+
+
+# prefill may hand `forward` one slot's view a layer at a time
+# (`serving.cache.LayerwiseSlotView`) and takes the chunk's rows back
+takes_layerwise_views = True
 
 
 def init_params(config: DeepseekConfig, key: jax.Array,
@@ -310,8 +315,11 @@ def _absorbed_attention(config, a, q_nope, q_pe, view, positions):
     return _unabsorb_output(c, a, o_lat)
 
 
-def _attention(config, a, x, cos, sin, positions, cache, layer_index):
-    """-> (attention output [B, S, h], this layer's new cache entry)."""
+def _attention(config, a, x, cos, sin, positions, cache, layer_index,
+               rows_back: bool = False):
+    """-> (attention output [B, S, h], this layer's new cache entry: the
+    updated dense view, or with `rows_back` this call's own rows [B, S, 1,
+    W] as the view holds them; a paged step's one row)."""
     c = config
     B, S, _ = x.shape
     H = c.num_attention_heads
@@ -370,7 +378,7 @@ def _attention(config, a, x, cos, sin, positions, cache, layer_index):
             attend = (_absorbed_attention if S == 1
                       else _decompressed_attention)
             out = attend(c, a, q_nope, q_pe, view, positions)
-        new = view[:, :, None, :]
+        new = (row.astype(view.dtype) if rows_back else view)[:, :, None, :]
     with part("attn.output"):
         out = dense(out.reshape(B, S, H * c.v_head_dim),
                     a["o_proj"]["kernel"])
@@ -425,7 +433,11 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
 
     `kv_caches` is `(latent, None, cache_len)`: a dense stacked cache
     `[L, B, M, 1, W]` (new rows written at `cache_len`, a scalar or one
-    length a row of the batch; the updated cache comes back), or the
+    length a row of the batch; the updated cache comes back), one slot's
+    view a layer at a time (`serving.cache.LayerwiseSlotView`, the serving
+    engine's prefill: each layer's view is gathered where the layer
+    attends, and the chunk's own rows `[L, 1, S, 1, W]` come back for the
+    engine to write), or the
     serving engine's paged pool (`PagedKV`, with `PagedDecodeMeta` in the
     third place; this step's rows `[L, B, 1, 1, W]` come back for the
     engine to append). `logit_rows` [B] int32: compute the head for that
@@ -439,6 +451,8 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
     if kv_caches is not None:
         paged = getattr(kv_caches[0], "is_paged_kv", False)
         dense_cache = not paged
+    layerwise = dense_cache and getattr(
+        kv_caches[0], "is_layerwise_view", False)
     if positions is None:
         start = kv_caches[2] if dense_cache else 0
         positions = (jnp.reshape(start, (-1, 1))
@@ -453,8 +467,7 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
     for i, layer in enumerate(params["layers"]):
         cache = None
         if dense_cache:
-            with part("cache.view"):
-                cache = (kv_caches[0][i], None, kv_caches[2])
+            cache = (layer_view(kv_caches[0], i), None, kv_caches[2])
         elif paged:
             cache = kv_caches
         # a norm is billed with the part it feeds, a residual add with the
@@ -463,7 +476,7 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
             y = rms_norm(x, layer["input_layernorm"]["scale"],
                          c.rms_norm_eps)
         attn, new = _attention(c, layer["attn"], y, cos, sin, positions,
-                               cache, i)
+                               cache, i, rows_back=layerwise)
         new_rows.append(new)
         with part("attn.output"):
             x = x + attn
@@ -491,9 +504,10 @@ def forward(config: DeepseekConfig, params: dict, input_ids: jax.Array,
         out = (logits,)
     else:
         third = kv_caches[2] if paged else kv_caches[2] + S
-        # the rows a decode step hands the engine to append; a chunk's
-        # updated views, stacked again
-        with part("cache.write" if paged else "cache.view"):
+        # the rows a decode step hands the engine to append and a chunk
+        # over a slot's layerwise view to write; else the updated views,
+        # stacked again
+        with part("cache.write" if paged or layerwise else "cache.view"):
             new_rows = jnp.stack(new_rows)
         out = (logits, (new_rows, None, third))
     if return_stats:

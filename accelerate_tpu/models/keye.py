@@ -85,7 +85,7 @@ from .common import (
     softmax_moe_layer,
     write_view,
 )
-from .decode import build_generate, rope_table_len
+from .decode import build_generate, layer_view, rope_table_len
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,6 +200,11 @@ def cache_spec(config: KeyeConfig):
         side_width=config.indexer["indexer_head_dim"])
 
 
+# prefill may hand `forward` one slot's views a layer at a time
+# (`serving.cache.LayerwiseSlotView`) and takes the chunk's rows back
+takes_layerwise_views = True
+
+
 def init_params(config: KeyeConfig, key: jax.Array,
                 dtype=jnp.float32) -> dict:
     c = config
@@ -276,12 +281,15 @@ def _view_scores(config, qI, wts, side_view, q_pos, key_pos):
     return jnp.moveaxis(out, 0, 2).reshape(B, -1, n * blk)[:, :, :R]
 
 
-def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
+def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
+               rows_back: bool = False):
     """-> (attention output [B, S, h], this layer's new cache entry, (keys
     visible, keys selected) of the tokens `token_mask` keeps). `cache`:
     None; ("view", k [B, R, Hkv, D], v, kI [B, R, 1, w], start [B]); or
     ("paged", PagedKV k at its layer, PagedKV v, PagedKV kI,
-    PagedDecodeMeta). `positions` [3, B, S]."""
+    PagedDecodeMeta). `positions` [3, B, S]. The new entry of a view is
+    the updated view, or with `rows_back` this call's own rows (k, v, kI
+    [B, S, *, *], as the view holds them)."""
     c = config
     B, S, _ = x.shape
     H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -338,6 +346,9 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask):
                 view_v = write_view(view_v, v, start, False)
                 view_i = write_view(view_i, kI, start, False)
                 new = (view_k, view_v, view_i)
+                if rows_back:
+                    new = tuple(r.astype(view.dtype)
+                                for r, view in zip((k, v, kI), new))
                 rows = jnp.arange(R, dtype=jnp.int32)[None, :]
                 key_pos = jnp.where(rows < (start + S)[:, None], rows, -1)
                 blk = min(c.kv_block, R)
@@ -377,9 +388,13 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     the temporal row). `kv_caches` is `(WithSide(k, kI), v, third)`
     (`serving/cache.py`). Views: k, v `[L, B, R, Hkv, D]`, kI `[L, B, R, 1,
     w]`, `third` the rows already written (a scalar, or one count a row of
-    the batch); the updated views come back. The serving engine's paged
-    pools: `PagedKV`s and a `PagedDecodeMeta`; this step's rows `[L, B, 1,
-    *, *]` come back for the engine to append. `logit_rows` [B] int32: the
+    the batch); the updated views come back. One slot's views a layer at a
+    time (`serving.cache.LayerwiseSlotView`s, the serving engine's
+    prefill): each layer's view is gathered where the layer attends, and
+    the chunk's own rows `[L, 1, S, *, *]` come back for the engine to
+    write. The serving engine's paged pools: `PagedKV`s and a
+    `PagedDecodeMeta`; this step's rows `[L, B, 1, *, *]` come back for the
+    engine to append. `logit_rows` [B] int32: the
     head for that one row of every sequence only (logits [B, 1, V]).
     `token_mask` [B, S]: which tokens are real, for the counters.
     `return_stats`: a third result `{"expert_counts": [layers, E],
@@ -392,6 +407,8 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     paged = kv_caches is not None and getattr(
         kv_caches[0].rows, "is_paged_kv", False)
     views = kv_caches is not None and not paged
+    layerwise = views and getattr(
+        kv_caches[0].rows, "is_layerwise_view", False)
     if paged and S != 1:
         raise ValueError(
             f"sparse paged decode attention is one token a slot; got {S} "
@@ -424,16 +441,16 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
                      kv_caches[1].at_layer(i), kv_caches[0].side.at_layer(i),
                      kv_caches[2])
         elif views:
-            with part("cache.view"):
-                cache = ("view", kv_caches[0].rows[i], kv_caches[1][i],
-                         kv_caches[0].side[i], start)
+            cache = ("view", *(layer_view(a, i) for a in (
+                kv_caches[0].rows, kv_caches[1], kv_caches[0].side)), start)
         # a norm is billed with the part it feeds, a residual add with the
         # part it closes
         with part("attn.project"):
             y = rms_norm(x, layer["input_layernorm"]["scale"],
                          c.rms_norm_eps)
         attn, new, (n_vis, n_sel) = _attention(
-            c, layer["attn"], y, rope, rope_i, positions, cache, token_mask)
+            c, layer["attn"], y, rope, rope_i, positions, cache, token_mask,
+            rows_back=layerwise)
         if new is not None:
             new_k.append(new[0])
             new_v.append(new[1])
@@ -459,9 +476,10 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     if kv_caches is None:
         out = (logits,)
     else:
-        # the rows a decode step hands the engine to append; a chunk's
-        # updated views, stacked again
-        with part("cache.write" if paged else "cache.view"):
+        # the rows a decode step hands the engine to append and a chunk
+        # over a slot's layerwise views to write; else the updated views,
+        # stacked again
+        with part("cache.write" if paged or layerwise else "cache.view"):
             new = (WithSide(jnp.stack(new_k), jnp.stack(new_i)),
                    jnp.stack(new_v))
         out = (logits, new + (kv_caches[2] if paged else kv_caches[2] + S,))

@@ -68,7 +68,7 @@ from .common import (
     softmax_moe_layer,
     write_view,
 )
-from .decode import build_generate, rope_table_len
+from .decode import build_generate, layer_view, rope_table_len
 from .deepseek import accumulate_serving_stats  # noqa: F401 - the contract
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -203,6 +203,12 @@ def cache_spec(config: MellumConfig):
         for _, window, layers in groups)
 
 
+# prefill may hand `forward` one slot's views a layer at a time
+# (`serving.cache.LayerwiseSlotView`, one a group) and takes the chunk's
+# rows back
+takes_layerwise_views = True
+
+
 def init_params(config: MellumConfig, key: jax.Array,
                 dtype=jnp.float32) -> dict:
     c = config
@@ -248,9 +254,12 @@ def init_params(config: MellumConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _attend_view(config, q, k, v, positions, view_k, view_v, start, window):
+def _attend_view(config, q, k, v, positions, view_k, view_v, start, window,
+                 rows_back: bool):
     """This call's K/V rows written into a group's view [B, R, Hkv, D] and
-    the queries attended over it -> (out, new view k, new view v)."""
+    the queries attended over it -> (out, new view k, new view v), or with
+    `rows_back` (out, this call's rows k, v [B, S, Hkv, D] as the views
+    hold them)."""
     from ..serving.cache import ring_positions
 
     S, R = q.shape[1], view_k.shape[1]
@@ -272,13 +281,18 @@ def _attend_view(config, q, k, v, positions, view_k, view_v, start, window):
     out = blocked_attention(q, positions, view_k, view_v,
                             ring_positions(R, last), window,
                             config.kv_block, lo, jnp.minimum(hi, n_blocks))
+    if rows_back:
+        return out, k.astype(view_k.dtype), v.astype(view_v.dtype)
     return out, view_k, view_v
 
 
-def _attention(config, a, x, rope, positions, window, cache):
+def _attention(config, a, x, rope, positions, window, cache,
+               rows_back: bool = False):
     """-> (attention output [B, S, h], this layer's new cache entry).
     `cache`: None; ("view", k [B, R, Hkv, D], v, start [B]); or ("paged",
-    PagedKV k at its layer, PagedKV v, this group's PagedDecodeMeta)."""
+    PagedKV k at its layer, PagedKV v, this group's PagedDecodeMeta). The
+    new entry of a view is the updated view, or with `rows_back` this
+    call's own rows."""
     c = config
     B, S, _ = x.shape
     H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -306,7 +320,7 @@ def _attention(config, a, x, rope, positions, window, cache):
         else:
             _, view_k, view_v, start = cache
             out, *new = _attend_view(c, q, k, v, positions, view_k, view_v,
-                                     start, window)
+                                     start, window, rows_back)
     with part("attn.output"):
         out = dense(out.reshape(B, S, H * D), a["o_proj"]["kernel"])
     return out, new
@@ -326,7 +340,11 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
     `kv_caches` is `(k, v, third)` with k and v one entry a cache GROUP
     (`cache_spec`'s order). Views: `[L_g, B, R_g, Hkv, D]` a group and
     `third` the rows already written, a scalar or one count a row of the
-    batch; the updated views come back. The serving engine's paged pools:
+    batch; the updated views come back. One slot's views a layer at a time
+    (`serving.cache.LayerwiseSlotView` a group, the serving engine's
+    prefill): each layer's view is gathered where the layer attends, and
+    the chunk's own rows `[L_g, 1, S, Hkv, D]` a group come back for the
+    engine to write. The serving engine's paged pools:
     `PagedKV` a group and a `PagedDecodeMeta` whose `table` is one table a
     group; this step's rows `[L_g, B, 1, Hkv, D]` a group come back for
     the engine to append. `logit_rows` [B] int32: the head for that one
@@ -339,6 +357,8 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
     paged = kv_caches is not None and getattr(
         kv_caches[0][0], "is_paged_kv", False)
     views = kv_caches is not None and not paged
+    layerwise = views and getattr(
+        kv_caches[0][0], "is_layerwise_view", False)
     if paged and S != 1:
         raise ValueError(
             f"paged decode attention is one token a slot; got {S} (chunked "
@@ -383,16 +403,15 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
             cache = ("paged", kv_caches[0][g].at_layer(j),
                      kv_caches[1][g].at_layer(j), metas[g])
         elif views:
-            with part("cache.view"):
-                cache = ("view", kv_caches[0][g][j], kv_caches[1][g][j],
-                         start)
+            cache = ("view", layer_view(kv_caches[0][g], j),
+                     layer_view(kv_caches[1][g], j), start)
         # a norm is billed with the part it feeds, a residual add with the
         # part it closes
         with part("attn.project"):
             y = rms_norm(x, layer["input_layernorm"]["scale"],
                          c.rms_norm_eps)
         attn, new = _attention(c, layer["attn"], y, rope[kind], positions,
-                               window, cache)
+                               window, cache, rows_back=layerwise)
         if new is not None:
             new_k[g].append(new[0])
             new_v[g].append(new[1])
@@ -415,9 +434,10 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
     if kv_caches is None:
         out = (logits,)
     else:
-        # the rows a decode step hands the engine to append; a chunk's
-        # updated views, stacked again
-        with part("cache.write" if paged else "cache.view"):
+        # the rows a decode step hands the engine to append and a chunk
+        # over a slot's layerwise views to write; else the updated views,
+        # stacked again
+        with part("cache.write" if paged or layerwise else "cache.view"):
             new_k, new_v = (tuple(jnp.stack(rows) for rows in new)
                             for new in (new_k, new_v))
         out = (logits, (new_k, new_v,

@@ -29,10 +29,12 @@ ordered page table instead of a contiguous stripe. Two things fall out:
 Every program stays jit-able because page tables are fixed-shape
 ([slots, pages_per_slot] int32, padded with a reserved trash page): the
 compiled programs gather a slot's pages into the familiar contiguous
-[L, 1, rows, H, D] view, run the unchanged family forward, and scatter
-the updated pages back. Gather/scatter indices are traced data — the
-request mix, hit/miss pattern, and eviction history never change a
-program shape, so the engine's compile count stays flat.
+[L, 1, rows, H, D] view (or, for a family that loops over its layers, one
+layer's [1, rows, H, D] at a time: `LayerwiseSlotView`), run the
+unchanged family forward, and scatter the updated pages back.
+Gather/scatter indices are traced data — the request mix, hit/miss
+pattern, and eviction history never change a program shape, so the
+engine's compile count stays flat.
 
 Write-safety under sharing, the invariant the allocator maintains: only
 FULL prompt pages ever enter the tree, and reuse is capped at
@@ -495,49 +497,117 @@ def _side_view(cache: PagedKVCache, idx: jax.Array, batch: int) -> jax.Array:
         cache.num_layers, batch, -1, 1, cache.side_width)
 
 
+class LayerwiseSlotView:
+    """One pool buffer (K's, V's or the side row's) as ONE slot sees it,
+    gathered a LAYER at a time: what stands in the place of a stacked view
+    `[L, 1, R, heads, width]` (`shape`) without that array ever existing.
+    `at_layer(i)` is layer i's `[1, R, heads, width]` from the slot's pages
+    of that layer alone, dequantised as `_dense_pages` does. In the image
+    of `ops.paged_attention.PagedKV.at_layer`: a family that loops over
+    its layers holds one layer's view at a time, writes its chunk's rows
+    into that temporary and returns THE ROWS, not the view
+    (`paged_write_chunk` takes them).
+
+    The `is_layerwise_view` marker lets a family tell it from arrays and
+    from pools by what it is, as `is_paged_kv` does."""
+
+    is_layerwise_view = True
+
+    def __init__(self, cache: PagedKVCache, pool: jax.Array,
+                 scales: jax.Array | None, table_row: jax.Array,
+                 heads: int, width: int, side: bool = False):
+        self._table_row, self._side = table_row, side
+        self._pages = pool.shape[1]
+        # the layer axis folded into the page axis (both lie outside a
+        # page's tile: no data moves), so that one layer's pages are ONE
+        # gather over one index and no slice of a layer's pool is made
+        self._pool = pool.reshape((-1,) + pool.shape[2:])
+        self._scales = None if scales is None else scales.reshape(
+            (-1,) + scales.shape[2:])
+        self.shape = (cache.num_layers, 1, cache.rows, heads, width)
+        self.dtype = cache.compute_dtype
+
+    def at_layer(self, layer: int) -> jax.Array:
+        """Layer `layer`'s view [1, R, heads, width]."""
+        with part("cache.view"):
+            at = layer * self._pages + self._table_row
+            if self._side:      # a page's rows, stored 128 lanes wide
+                return self._pool[at].reshape((1,) + self.shape[2:])
+            return _dense_pages(
+                self._pool[None],
+                None if self._scales is None else self._scales[None],
+                at, self.dtype).reshape((1,) + self.shape[2:])
+
+
 @part("cache.view")
 def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
-                    slot: jax.Array):
+                    slot: jax.Array, by_layer: bool = False):
     """One slot's pages gathered into `models/decode.py` layout:
     (k [L, 1, R, H, D], v [L, 1, R, H, D], length scalar), R =
     pages_per_slot * page_size, dequantized to `compute_dtype` on an
     int8 pool. `table_row` ([pages_per_slot] int32) and `slot` are
     traced — one compiled program covers every slot and every page
     mapping. A grouped cache takes one table row a group and gives one
-    view a group (a ring group's view is its ring)."""
+    view a group (a ring group's view is its ring). `by_layer`: a
+    `LayerwiseSlotView` in every stacked view's place, and nothing is
+    gathered until a family asks for a layer."""
     if isinstance(cache, GroupedPagedCache):
-        views = [paged_slot_view(g, row, slot)
+        views = [paged_slot_view(g, row, slot, by_layer)
                  for g, row in zip(cache.groups, table_row)]
         return (tuple(v[0] for v in views), tuple(v[1] for v in views),
                 cache.lengths[slot])
     L, _, H, ps, D = cache.k.shape
     P = cache.pages_per_slot
-    ks, vs = _both(
-        lambda kv: _dense_pages(*kv, table_row, cache.compute_dtype).reshape(
-            L, 1, P * ps, H, D),
-        (cache.k, cache.k_scale), None if cache.latent
-        else (cache.v, cache.v_scale))
+    if by_layer:
+        def view(kv):
+            return LayerwiseSlotView(cache, *kv, table_row, H, D)
+    else:
+        def view(kv):
+            return _dense_pages(*kv, table_row, cache.compute_dtype).reshape(
+                L, 1, P * ps, H, D)
+    ks, vs = _both(view, (cache.k, cache.k_scale), None if cache.latent
+                   else (cache.v, cache.v_scale))
     if cache.side is not None:
-        ks = WithSide(ks, _side_view(cache, table_row, 1))
+        ks = WithSide(ks, LayerwiseSlotView(
+            cache, cache.side, None, table_row, 1, cache.side_width,
+            side=True) if by_layer else _side_view(cache, table_row, 1))
     return ks, vs, cache.lengths[slot]
+
+
+@part("cache.write")
+def paged_write_chunk(cache: PagedKVCache, table_row: jax.Array,
+                      slot: jax.Array, rows_k, rows_v,
+                      advance: jax.Array) -> PagedKVCache:
+    """Write the rows a prefill chunk produced ([L, 1, chunk, H, D]: view
+    rows [length, length + chunk), padding included) to the pool and
+    advance the slot's length by `advance` REAL tokens. `_scatter_rows`
+    rewrites the `chunk // page_size + 1` pages they straddle: per-chunk
+    write traffic is O(chunk), not O(max_len). Every written row is at or
+    past `length`, hence in a PRIVATE page by the allocator's invariant:
+    shared copy-on-write pages are never touched, and the rows of a
+    private page below `length` are put back as the bytes they were
+    (selected, not re-encoded: an int8 round-trip is NOT idempotent, so
+    re-quantizing "the same values" would drift them)."""
+    if isinstance(cache, GroupedPagedCache):
+        return cache.map_groups(
+            lambda g, row, rk, rv: paged_write_chunk(g, row, slot, rk, rv,
+                                                     advance),
+            table_row, rows_k, rows_v)
+    length = cache.lengths[slot]
+    chunk = jax.tree.leaves(rows_k)[0].shape[2]
+    return _scatter_rows(cache, table_row[None], length[None],
+                         jnp.full((1,), chunk, jnp.int32), rows_k, rows_v,
+                         cache.lengths.at[slot].set(length + advance))
 
 
 @part("cache.write")
 def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
                      slot: jax.Array, new_k: jax.Array, new_v: jax.Array,
                      advance: jax.Array, chunk: int) -> PagedKVCache:
-    """Write the rows a prefill chunk produced back to the pool and
-    advance the slot's length by `advance` REAL tokens. The chunk only
-    changes view rows [length, length + chunk), so exactly those `chunk`
-    rows (padding included) are handed to `_scatter_rows`, which rewrites
-    the `chunk // page_size + 1` pages they straddle — per-chunk write
-    traffic is O(chunk), not O(max_len). `chunk` must be a static python
-    int. Every written row is at or past `length`, hence in a PRIVATE
-    page by the allocator's invariant — shared copy-on-write pages are
-    never touched, and the rows of a private page below `length` are
-    put back as the bytes they were (selected, not re-encoded: an int8
-    round-trip is NOT idempotent, so re-quantizing "the same values"
-    would drift them)."""
+    """`paged_write_chunk` for a family that returns whole updated views
+    ([L, 1, R, H, D]): the chunk only changes view rows [length, length +
+    chunk), so exactly those `chunk` rows (a ring's modulo R) are taken
+    out of the views and written. `chunk` must be a static python int."""
     if isinstance(cache, GroupedPagedCache):
         return cache.map_groups(
             lambda g, row, nk, nv: paged_write_slot(g, row, slot, nk, nv,
@@ -545,10 +615,9 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
             table_row, new_k, new_v)
     L, _, H, ps, D = cache.k.shape
     R = cache.rows
-    length = cache.lengths[slot]
     # rows never spill past the view: length <= max_len and pad_slack
     # covers the chunk padding (module docstring)
-    rows = length + jnp.arange(chunk, dtype=jnp.int32)
+    rows = cache.lengths[slot] + jnp.arange(chunk, dtype=jnp.int32)
     if cache.ring:
         rows = rows % R
     new_k, new_side = _split_side(cache, new_k)
@@ -558,9 +627,7 @@ def paged_write_slot(cache: PagedKVCache, table_row: jax.Array,
     if new_side is not None:
         win_k = WithSide(win_k, jnp.take(new_side.reshape(
             L, R, 1, cache.side_width), rows, axis=1)[:, None])
-    return _scatter_rows(cache, table_row[None], length[None],
-                         jnp.full((1,), chunk, jnp.int32), win_k, win_v,
-                         cache.lengths.at[slot].set(length + advance))
+    return paged_write_chunk(cache, table_row, slot, win_k, win_v, advance)
 
 
 @part("cache.write")

@@ -113,6 +113,7 @@ from .cache import (
     paged_append_window,
     paged_batch_view,
     paged_slot_view,
+    paged_write_chunk,
     paged_write_slot,
     slot_caches,
     write_slot,
@@ -766,6 +767,11 @@ class Engine:
             one_row = False
         fold_stats = getattr(self._family, "accumulate_serving_stats", None)
         grouped = self._cache_groups is not None
+        # a family that loops over its layers says that a prefill chunk may
+        # be handed its slot's views a layer at a time and gives the
+        # chunk's rows back; every other one takes the stacked views and
+        # returns them updated
+        layerwise = getattr(self._family, "takes_layerwise_views", False)
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
@@ -849,7 +855,8 @@ class Engine:
         @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
         def prefill(params, cache, tokens, slot_keys, temps, slot,
                     table_row, ids, real_len):
-            ks, vs, length = paged_slot_view(cache, table_row, slot)
+            ks, vs, length = paged_slot_view(cache, table_row, slot,
+                                             by_layer=layerwise)
             positions = (length + jnp.arange(chunk, dtype=jnp.int32))[None, :]
             logits, (nk, nv, _), cache = serving_forward(
                 "prefill", params, cache, ids[None, :], positions,
@@ -862,8 +869,12 @@ class Engine:
                     last = jax.lax.dynamic_index_in_dim(
                         logits[0].astype(jnp.float32), real_len - 1,
                         keepdims=False)
-            cache = paged_write_slot(cache, table_row, slot, nk, nv, real_len,
-                                     chunk)
+            if layerwise:   # the chunk's rows came back
+                cache = paged_write_chunk(cache, table_row, slot, nk, nv,
+                                          real_len)
+            else:           # the rows, out of the updated views
+                cache = paged_write_slot(cache, table_row, slot, nk, nv,
+                                         real_len, chunk)
             with part("sample"):
                 new_len = length + real_len
                 tok, lp = sample_slot(last, slot_keys[slot], new_len,

@@ -550,10 +550,13 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
         pool lies as [6, 21505, 8, 128] bf16, whole (8, 128)(2, 1) tiles:
         its argument takes its logical bytes, not twice them;
     (d) a chunk's logits are one row; temporaries: `decode` under 300 MB
-        (90 MB read), `prefill` under 1.7 GB (1.57 GB read: the slot's
-        gathered K and V views, 267 MB each, their updated stacks, and a
-        layer's [512, 43520] scores, their ordered image and the mask),
-        beside 8.75 GB of weights and 4.49 GB of pool."""
+        (90 MB read), `prefill` under 1.2 GB (a layer's gathered K and
+        V views, 45 MB each, of the layers the scheduler holds at once,
+        and a layer's [512, 43520] scores, their ordered image and the
+        mask; 1.57 GB until PR 39, with all six layers' views stacked in
+        and stacked out), beside 8.75 GB of weights and 4.49 GB of pool;
+    (e) `prefill` holds no array of the stacked views' shape
+        [6, 1, 43520, ...]: a layer's view is gathered alone."""
     from accelerate_tpu.models import keye
     from accelerate_tpu.ops import sparse_paged_attention as sparse
     from accelerate_tpu.serving import Engine, EngineConfig, PagedKVCache
@@ -596,7 +599,7 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
             arg((slots,), jnp.bool_), arg((slots, 2720), jnp.int32)), 300e6),
         "prefill": (engine._prefill_p, state + (
             arg((), jnp.int32), arg((2720,), jnp.int32),
-            arg((chunk,), jnp.int32), arg((), jnp.int32)), 1.7e9),
+            arg((chunk,), jnp.int32), arg((), jnp.int32)), 1.2e9),
     }
     for name, (program, args, temp_limit) in programs.items():
         compiled = program.lower(*args).compile()
@@ -629,6 +632,7 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
         assert memory.temp_size_in_bytes < temp_limit, (
             name, memory.temp_size_in_bytes)
         assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        assert not re.search(r"\[6,1,43520,", text), name
         _assert_host_output_is_its_own(
             program, args, text, (slots,) if name == "decode" else ())
         print(name, "temp", memory.temp_size_in_bytes, "args",

@@ -35,6 +35,8 @@ The collective-permute pins of the `jax.shard_map` programs live in ONE
 table — `analysis.contracts._SHARD_MAP_TABLE` — resolved by `contract_for`.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -433,3 +435,58 @@ class TestFp8StepStability:
             for a, b in zip(jax.tree_util.tree_leaves(ts.fp8_state), fresh)
         ]
         assert any(moved), "fp8 metas never updated across steps" 
+
+
+class TestPrefillViewStructure:
+    """A prefill chunk of a family that loops over its layers holds its
+    slot's view ONE LAYER at a time and returns its own rows: the traced
+    program and the compiled one hold no array of the stacked views'
+    shape `[L, 1, R, ...]`, in or out (PERF.md, PR 39: the stacks around
+    every chunk were a fifth of the keye cell's chunk). The llama family's
+    layers are one scan over the stacked views, which it keeps."""
+
+    @staticmethod
+    def _prefill(family, cfg, **engine):
+        from accelerate_tpu.serving import Engine, EngineConfig
+
+        eng = Engine(family, cfg, family.init_params(cfg, jax.random.key(0)),
+                     EngineConfig(num_slots=2, max_len=48, prefill_chunk=8,
+                                  page_size=8, cache_dtype=jnp.float32,
+                                  paged_attention=False, **engine))
+        chunk = eng.engine_config.prefill_chunk
+        lowered = eng._prefill_p.lower(
+            eng.params, eng.cache, eng._tokens, eng._slot_keys, eng._temps,
+            jnp.int32(0), eng._tables(0), np.zeros((chunk,), np.int32),
+            jnp.int32(chunk))
+        groups = getattr(eng.cache, "groups", (eng.cache,))
+        return lowered, [(g.num_layers, g.rows) for g in groups]
+
+    @staticmethod
+    def _stacked(lowered, layers, rows):
+        """The arrays `[layers, 1, rows, ...]` of the traced program (MLIR
+        types) and of the compiled one (HLO shapes)."""
+        return (re.findall(rf"tensor<{layers}x1x{rows}x[0-9x]*f32>",
+                           lowered.as_text())
+                + re.findall(rf"f32\[{layers},1,{rows},[0-9,]*\]",
+                             lowered.compile().as_text()))
+
+    @pytest.mark.parametrize("name", ["keye", "deepseek", "mellum"])
+    def test_looping_families_hold_no_stacked_view(self, name):
+        from accelerate_tpu.models import deepseek, keye, mellum
+
+        family, cfg, engine = {
+            "keye": (keye, keye.KeyeConfig.tiny(), {}),
+            "deepseek": (deepseek, deepseek.DeepseekConfig.tiny(), {}),
+            "mellum": (mellum, mellum.MellumConfig.tiny(num_hidden_layers=4),
+                       dict(prefix_cache=False)),
+        }[name]
+        lowered, groups = self._prefill(family, cfg, **engine)
+        assert len(groups) == (2 if name == "mellum" else 1)
+        for layers, rows in groups:
+            assert rows == 56 or (name == "mellum" and rows == 48)
+            assert self._stacked(lowered, layers, rows) == []
+
+    def test_llama_takes_the_stacked_views(self):
+        cfg = llama.LlamaConfig.tiny()
+        lowered, [(layers, rows)] = self._prefill(llama, cfg)
+        assert len(self._stacked(lowered, layers, rows)) >= 4

@@ -764,3 +764,55 @@ def test_page_granular_writes_match_the_row_by_row_pool(replay, kv_dtype):
     re-encoded; shared full pages are never touched; dead lanes change
     the trash page only (the one page the comparison leaves out)."""
     replay(kv_dtype)
+
+
+@pytest.mark.parametrize("kv_dtype,window", [
+    (None, None), ("int8", None), (None, 5)],
+    ids=["bf16", "int8", "bf16-ring"])
+def test_the_rows_taking_write_is_the_whole_view_write(kv_dtype, window):
+    """`paged_write_chunk` takes the chunk's rows; `paged_write_slot` takes
+    them out of whole updated views and calls it. Handed the same rows
+    (a ring's: the view's rows modulo R) both leave the same pool, bit
+    for bit, codes and scales: over a start inside a page behind a shared
+    one, chunks that straddle three pages, a chunk whose padding runs
+    past the slot's pages, and a ring that wraps inside the chunk."""
+    from accelerate_tpu.serving.cache import (
+        paged_write_chunk,
+        paged_write_slot,
+    )
+
+    chunk = 6
+    cache, rng = _random_pool(kv_dtype, 3, 30, chunk, [0, 4, 0], seed=39)
+    if window is not None:
+        ring = PagedKVCache.create(
+            num_layers=2, num_slots=3, max_len=30, num_kv_heads=2,
+            head_dim=8, page_size=4, pad_slack=chunk, dtype=jnp.bfloat16,
+            window=window)
+        assert ring.pages_per_slot == 4 and ring.rows == 16
+        cache = dataclasses.replace(
+            ring, k=cache.k[:, :ring.k.shape[1]],
+            v=cache.v[:, :ring.v.shape[1]], lengths=cache.lengths)
+    T, P, R = cache.trash_page, cache.pages_per_slot, cache.rows
+    own = {1: [0, 1, 2, 3, 4, 5, 6, 7, 8], 2: [0, 9, 10], 0: [11, 12, 13, 14]}
+    tables = {slot: np.asarray((pages + [T] * P)[:P], np.int32)
+              for slot, pages in own.items()}
+    whole = jax.jit(paged_write_slot, static_argnames="chunk")
+    by_rows = jax.jit(paged_write_chunk)
+    # (slot, length before, real tokens); a ring wraps at 16 rows
+    plan = [(1, 4, 5), (1, 9, 6), (1, 15, 6), (1, 27, 3), (2, 4, 6),
+            (2, 10, 1), (0, 0, 6), (0, 13, 2)]
+    a = b = cache
+    for slot, length, advance in plan:
+        a, b = (dataclasses.replace(
+            c, lengths=c.lengths.at[slot].set(length)) for c in (a, b))
+        new_k, new_v = (jnp.asarray(rng.normal(size=(2, 1, R, 2, 8)),
+                                    jnp.bfloat16) for _ in range(2))
+        rows = np.arange(length, length + chunk)
+        rows = rows % R if window is not None else rows
+        a = whole(a, jnp.asarray(tables[slot]), jnp.int32(slot), new_k,
+                  new_v, jnp.int32(advance), chunk=chunk)
+        b = by_rows(b, jnp.asarray(tables[slot]), jnp.int32(slot),
+                    new_k[:, :, rows], new_v[:, :, rows], jnp.int32(advance))
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert not np.array_equal(np.asarray(a.k), np.asarray(cache.k))
