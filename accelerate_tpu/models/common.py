@@ -560,5 +560,20 @@ def _fused_head_loss_bwd(tied, chunk, residuals, g):
 fused_head_loss.defvjp(_fused_head_loss_fwd, _fused_head_loss_bwd)
 
 
+_WIDE = 30  # a wide counter is (units of 2**30, the rest below 2**30)
+
+
+def wide_count(pair) -> int:
+    """A wide device counter (`Engine.device_counters()`) as a Python int."""
+    return (int(pair[0]) << _WIDE) + int(pair[1])
+
+
+def add_wide(total, x):
+    """`total` (int32 [2], see `_WIDE`) plus `x` (int32, below 2**30): what
+    a serving window counts (keys seen, tokens folded) passes 2**31."""
+    low = total[1] + x
+    return jnp.stack([total[0] + (low >> _WIDE), low & ((1 << _WIDE) - 1)])
+
+
 def count_params(params: Any) -> int:
     return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params))

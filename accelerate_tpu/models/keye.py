@@ -72,6 +72,7 @@ from ..ops.sparse_paged_attention import (
     sparse_paged_decode_attention,
 )
 from .common import (
+    add_wide,
     apply_mrope,
     apply_rope,
     blocked_attention,
@@ -83,6 +84,7 @@ from .common import (
     rms_norm,
     rope_frequencies,
     softmax_moe_layer,
+    wide_count,  # noqa: F401  (who reads the counters takes it from here)
     write_view,
 )
 from .decode import build_generate, layer_view, rope_table_len
@@ -495,21 +497,6 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
 # counters
 # ---------------------------------------------------------------------------
 
-_WIDE = 30  # a wide counter is (units of 2**30, the rest below 2**30)
-
-
-def wide_count(pair) -> int:
-    """A wide counter of `Engine.device_counters()` as a Python int."""
-    return (int(pair[0]) << _WIDE) + int(pair[1])
-
-
-def _add_wide(total, x):
-    """`total` (int32 [2], see `_WIDE`) plus `x` (int32, below 2**30): the
-    keys a serving window sees pass 2**31."""
-    low = total[1] + x
-    return jnp.stack([total[0] + (low >> _WIDE), low & ((1 << _WIDE) - 1)])
-
-
 def init_serving_stats(config: KeyeConfig) -> dict:
     """The device counters one engine program accumulates, all zero: the
     expert layer's (`models/deepseek.py`), and the keys the program's
@@ -528,10 +515,10 @@ def accumulate_serving_stats(total: dict, call: dict) -> dict:
 
     with part("attn.select"):
         keys = dict(
-            keys_visible=_add_wide(total["keys_visible"],
-                                   call["keys_visible"]),
-            keys_selected=_add_wide(total["keys_selected"],
-                                    call["keys_selected"]))
+            keys_visible=add_wide(total["keys_visible"],
+                                  call["keys_visible"]),
+            keys_selected=add_wide(total["keys_selected"],
+                                   call["keys_selected"]))
     return dict(experts(total, call), **keys)
 
 

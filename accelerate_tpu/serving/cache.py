@@ -204,6 +204,15 @@ class CacheSpec:
     the context, and the one the allocator's books, the prefix index and
     the engine's page gauges mean.
 
+    `kind="state"`: NO row a token. What a layer keeps of a sequence is
+    one STATE of fixed size, whatever the sequence's length: `heads`
+    matrices of `state_rows` x `width` and as many vectors of `state_rows`,
+    in `state_dtype` (`StateCache`; `ops/power_retention.py` says what the
+    rows are). It is not addressed by position, so nothing of it can be
+    shared, published or cut at a page: the pool's unit, where the
+    allocator and the gauges say "page", is an ENTRY, one sequence's
+    whole state in every layer.
+
     `side_width` > 0: a third per-token row of that many lanes, in the
     pool's dtype, that lives in the SAME pages as K and V (`PagedKVCache`,
     SIDE ROW): what a family keeps for a second scorer of its keys (a
@@ -217,6 +226,8 @@ class CacheSpec:
     window: int | None = None
     layers: tuple | None = None
     side_width: int = 0
+    state_rows: int = 0
+    state_dtype: Any = jnp.float32
 
     @property
     def label(self) -> str:
@@ -813,6 +824,21 @@ def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,
         cache, lengths=cache.lengths.at[slot].set(reused_len))
 
 
+@part("cache.write")
+def state_admit_slot(cache: StateCache, slot: jax.Array,
+                     entry: jax.Array) -> StateCache:
+    """Admit a request into `slot` of a state pool: length zero, and the
+    state at `entry` ZEROED in every layer (a state is read whole from the
+    first token on; nothing masks what the entry's last tenant left)."""
+    def zero(pool):
+        blank = jnp.zeros(pool.shape[:1] + (1,) + pool.shape[2:], pool.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(pool, blank, entry, axis=1)
+
+    return dataclasses.replace(
+        cache, s=zero(cache.s), z=zero(cache.z),
+        lengths=cache.lengths.at[slot].set(0))
+
+
 def _flatten_paged(cache: PagedKVCache):
     return (cache.k, cache.v, cache.lengths, cache.k_scale, cache.v_scale,
             cache.stats, cache.side), (
@@ -833,6 +859,135 @@ def _unflatten_paged(aux, children):
 
 jax.tree_util.register_pytree_node(PagedKVCache, _flatten_paged,
                                    _unflatten_paged)
+
+
+@dataclasses.dataclass(frozen=True)
+class StateCache:
+    """The pool of a family that keeps a STATE a sequence and no rows
+    (`CacheSpec.kind == "state"`).
+
+    s: [num_layers, entries + 1, heads, state_rows, width], z:
+    [num_layers, entries + 1, heads, state_rows / width rounded up to a
+    multiple of 8, width], both in
+    the spec's `state_dtype`; lengths: [num_slots] int32. ONE entry is one
+    sequence's whole state in every layer. A slot is given its entry at
+    admission, where it is zeroed (`state_admit_slot`: unlike K/V rows,
+    which a position mask hides, a state left by the last tenant would be
+    read), and gives it back at release. The last entry is the SPARE, the
+    trash page's like: it is never allocated, an idle lane's table row
+    names it, and the decode step sends there the write of every lane that
+    is not live, so a live sequence's state is never touched by another
+    lane.
+
+    To the host's books an entry IS a page: `num_pages` entries, a table
+    row of `pages_per_slot` = 1 page whose `page_size` is every position a
+    slot may hold, `page_nbytes` an entry's bytes. The allocator, the
+    scheduler, the sanitizer and the page gauges therefore work unchanged
+    and say true things (a request needs one page; admission is bounded by
+    free entries). What a state cannot do follows from the same fact: no
+    part of an entry is a prefix of another sequence, so prefix reuse,
+    forks, the host tier and page shipments need a SNAPSHOT of a state,
+    which is not implemented (`serving/engine.py` `_UNPORTED["state"]`).
+
+    The programs never gather a view of it: a family forward is handed the
+    whole pool (`pool()`, an `ops.power_retention.StatePool`) and hands it
+    back updated, each layer's op writing the entries it read, in place
+    under donation (`commit`)."""
+
+    s: jax.Array
+    z: jax.Array
+    lengths: jax.Array
+    max_len: int
+    pad_slack: int
+    compute_dtype: Any = jnp.bfloat16
+    stats: Any = None
+
+    # what the engine asks of any pool and this one has none of
+    quantized = latent = ring = False
+    side = k_scale = v_scale = window = None
+    side_width = side_page_nbytes = 0
+    pages_per_slot = 1
+
+    @classmethod
+    def create(cls, spec: CacheSpec, num_slots: int, max_len: int,
+               dtype: Any = jnp.bfloat16, pad_slack: int = 0,
+               num_entries: int | None = None,
+               stats: Any = None) -> "StateCache":
+        if spec.state_rows < 1 or spec.state_rows % spec.width:
+            raise ValueError(
+                "a state is `state_rows` rows of `width` lanes a head, a "
+                f"whole number of `width`; got {spec.state_rows} and "
+                f"{spec.width}")
+        entries = num_slots if num_entries is None else num_entries
+        if entries < 1:
+            raise ValueError(f"a state pool of {entries} entries")
+        lead = (spec.num_layers, entries + 1, spec.heads)
+        # (the vectors lie as rows of `width` lanes, whole 8-row tiles:
+        # `ops.power_retention.normaliser_rows` says why)
+        z_rows = -(-spec.state_rows // spec.width // 8) * 8
+        return cls(
+            s=jnp.zeros(lead + (spec.state_rows, spec.width),
+                        spec.state_dtype),
+            z=jnp.zeros(lead + (z_rows, spec.width), spec.state_dtype),
+            lengths=jnp.zeros((num_slots,), jnp.int32),
+            max_len=max_len, pad_slack=pad_slack, compute_dtype=dtype,
+            stats=stats)
+
+    def with_stats(self, stats) -> "StateCache":
+        return dataclasses.replace(self, stats=stats)
+
+    def pool(self, kernel: bool = False):
+        """The pool as a family forward takes it."""
+        from ..ops.power_retention import StatePool
+
+        return StatePool(self.s, self.z, kernel)
+
+    def commit(self, pool, lengths: jax.Array) -> "StateCache":
+        """The cache after a program: the pool a forward handed back, and
+        the slots' new lengths."""
+        return dataclasses.replace(self, s=pool.s, z=pool.z, lengths=lengths)
+
+    @property
+    def num_layers(self) -> int:
+        return self.s.shape[0]
+
+    @property
+    def num_pages(self) -> int:
+        """Entries a sequence can be given (the spare is excluded)."""
+        return self.s.shape[1] - 1
+
+    @property
+    def trash_page(self) -> int:
+        """The spare entry."""
+        return self.s.shape[1] - 1
+
+    @property
+    def num_slots(self) -> int:
+        return self.lengths.shape[0]
+
+    @property
+    def page_size(self) -> int:
+        """Positions an entry stands for: all a slot may hold."""
+        return self.max_len + self.pad_slack
+
+    rows = page_size
+
+    @property
+    def page_nbytes(self) -> int:
+        """HBM bytes of one entry: a sequence's state in every layer."""
+        return self.nbytes() // self.s.shape[1]
+
+    def nbytes(self) -> int:
+        return (self.s.size + self.z.size) * self.s.dtype.itemsize
+
+
+jax.tree_util.register_pytree_node(
+    StateCache,
+    lambda c: ((c.s, c.z, c.lengths, c.stats),
+               (c.max_len, c.pad_slack, c.compute_dtype)),
+    lambda aux, ch: StateCache(s=ch[0], z=ch[1], lengths=ch[2], stats=ch[3],
+                               max_len=aux[0], pad_slack=aux[1],
+                               compute_dtype=aux[2]))
 
 
 def ring_positions(rows: int, last):
@@ -1252,7 +1407,11 @@ class PagedAllocator:
         on_evict: Callable[[int], None] | None = None,
         on_unmap: Callable[[int], None] | None = None,
         rings: tuple = (),
+        state_entries: bool = False,
     ):
+        # a state pool's page is an ENTRY, one sequence's whole state
+        # (`StateCache`): the span says so beside the page count
+        self.state_entries = state_entries
         self.page_size = page_size
         self.pad_slack = pad_slack
         self.prefix_cache = prefix_cache
@@ -1333,6 +1492,8 @@ class PagedAllocator:
             sp.set(pages=len(alloc.pages) if alloc else 0,
                    reused_len=alloc.reused_len if alloc else 0,
                    evicted=self.evictions - evictions)
+            if self.state_entries:
+                sp.set(state_entries=len(alloc.pages) if alloc else 0)
             if self.ring_pools:
                 sp.set(full_pages=len(alloc.pages) if alloc else 0,
                        window_pages=sum(map(len, alloc.rings)) if alloc

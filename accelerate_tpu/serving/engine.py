@@ -106,6 +106,7 @@ from .cache import (
     PagedAllocator,
     PagedKVCache,
     SlotKVCache,
+    StateCache,
     WithSide,
     paged_admit_slot,
     paged_append_batch,
@@ -116,6 +117,7 @@ from .cache import (
     paged_write_chunk,
     paged_write_slot,
     slot_caches,
+    state_admit_slot,
     write_slot,
 )
 from .metrics import ServingMetrics
@@ -368,7 +370,7 @@ def _cache_spec(config, family=None):
 # The engine options a pool OTHER than one K/V stack does not implement
 # yet, by the trait of the cache a family declares (`cache_spec`): what the
 # error calls the trait's fallback, then for each option what porting it
-# would take. ROADMAP M3 (latent), M2 (grouped), M8 (side).
+# would take. ROADMAP M3 (latent), M2 (grouped), M8 (side), M4 (state).
 _OPTIONS = {
     "prefix_cache=True": lambda ec: ec.prefix_cache,
     "kv_dtype='int8'": lambda ec: ec.kv_dtype is not None,
@@ -402,6 +404,23 @@ _UNPORTED = {
                                "no side row",
         "mesh": "a sharded index pool, and the selection across shards",
         "speculative": "the verify step's multi-token selection"}),
+    # one state a sequence and no rows (CacheSpec.kind='state'): each of
+    # these needs a SNAPSHOT of a state, a copy of an entry taken at a
+    # position, which nothing makes yet
+    "state": ("K/V rows", {
+        "prefix_cache=True": "a state is not addressed by position, so no "
+                             "part of it is another prompt's prefix: a hit "
+                             "needs the state as it stood after the shared "
+                             "tokens, a snapshot published at a boundary",
+        "kv_dtype='int8'": "int8 codes of a state and the kernels that "
+                           "decay them",
+        "host_tier_bytes > 0": "the host tier ships pages of rows; an "
+                               "evicted state would be a snapshot of an entry",
+        "mesh": "a state pool sharded over KV heads and its kernels under "
+                "GSPMD",
+        "speculative": "a rejected draft token cannot be cut off a state: "
+                       "the verify step needs the state before its window "
+                       "to roll back to"}),
 }
 
 
@@ -416,6 +435,9 @@ def _refuse_unported(ec: "EngineConfig", spec: CacheSpec, groups) -> None:
         traits.append(("grouped", "this family's layers differ in kind "
                        f"(cache_spec gives {len(groups)} groups: "
                        f"{[g.label for g in groups]})"))
+    if spec.kind == "state":
+        traits.append(("state", "this family keeps one recurrent state a "
+                       "sequence and no K/V rows (CacheSpec.kind='state')"))
     if spec.side_width:
         traits.append(("side", "this family caches a side row a token "
                        f"beside K and V (CacheSpec.side_width="
@@ -599,6 +621,13 @@ class Engine:
                 dtype=ec.cache_dtype, page_size=ec.page_size,
                 pad_slack=self._pad_slack, num_pages=ec.num_pages,
                 stats=stats)
+        elif spec.kind == "state":
+            # `num_pages` counts the pool's entries, one a sequence (a
+            # spare besides); `page_size` means nothing to it
+            self.cache = StateCache.create(
+                spec, ec.num_slots, ec.max_len, dtype=ec.cache_dtype,
+                pad_slack=self._pad_slack, num_entries=ec.num_pages,
+                stats=stats)
         else:
             self.cache = PagedKVCache.create(
                 spec.num_layers, ec.num_slots, ec.max_len, spec.heads,
@@ -654,7 +683,7 @@ class Engine:
         # lambdas read self.metrics at call time, so reset_metrics()'s
         # replacement instance keeps receiving events.
         self.allocator = PagedAllocator(
-            page_size=ec.page_size,
+            page_size=self.cache.page_size,
             num_pages=self.cache.num_pages,
             pad_slack=self._pad_slack,
             prefix_cache=ec.prefix_cache,
@@ -662,6 +691,7 @@ class Engine:
             on_unmap=self._unmap_slot,
             rings=tuple((g.pages_per_slot, g.num_pages)
                         for g in self._ring_groups()),
+            state_entries=spec.kind == "state",
         )
         # COW forking: parent_id -> parent handle, consulted by the
         # admission hold below (entries drop as parents reach a terminal
@@ -772,6 +802,13 @@ class Engine:
         # chunk's rows back; every other one takes the stacked views and
         # returns them updated
         layerwise = getattr(self._family, "takes_layerwise_views", False)
+        # a family that keeps a state a sequence is handed the whole pool
+        # and hands it back; `kernel` says which form its ops take
+        state = self._cache_spec.kind == "state"
+        kernel = self._use_paged_kernel
+        if state:
+            from ..ops.power_retention import StateMeta
+        count_zeroed = getattr(self._family, "count_state_zeroed", None)
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
@@ -839,6 +876,18 @@ class Engine:
                 temps = temps.at[slot].set(temp)
                 dlengths = dlengths.at[slot].set(0)
                 return cache, slot_keys, temps, dlengths
+        elif state:
+            @partial(jax.jit, donate_argnums=don_admit)
+            def admit(cache, slot_keys, temps, slot, key_raw, temp, entry):
+                # the slot's entry is zeroed: a state is read whole
+                cache = state_admit_slot(cache, slot, entry)
+                if cache.stats is not None and count_zeroed is not None:
+                    cache = cache.with_stats(dict(
+                        cache.stats, prefill=count_zeroed(
+                            cache.stats["prefill"])))
+                slot_keys = slot_keys.at[slot].set(key_raw)
+                temps = temps.at[slot].set(temp)
+                return cache, slot_keys, temps
         else:
             @partial(jax.jit, donate_argnums=don_admit,
                      out_shardings=admit_out)
@@ -855,12 +904,18 @@ class Engine:
         @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
         def prefill(params, cache, tokens, slot_keys, temps, slot,
                     table_row, ids, real_len):
-            ks, vs, length = paged_slot_view(cache, table_row, slot,
-                                             by_layer=layerwise)
+            if state:
+                length = cache.lengths[slot]
+                kvc = (cache.pool(kernel), None,
+                       StateMeta(table_row[:1], real_len[None]))
+            else:
+                ks, vs, length = paged_slot_view(cache, table_row, slot,
+                                                 by_layer=layerwise)
+                kvc = (ks, vs, length)
             positions = (length + jnp.arange(chunk, dtype=jnp.int32))[None, :]
             logits, (nk, nv, _), cache = serving_forward(
                 "prefill", params, cache, ids[None, :], positions,
-                (ks, vs, length), (real_len - 1)[None],
+                kvc, (real_len - 1)[None],
                 (jnp.arange(chunk) < real_len)[None, :])
             with part("sample"):
                 if one_row:  # the one row that is read, not the chunk's
@@ -869,7 +924,10 @@ class Engine:
                     last = jax.lax.dynamic_index_in_dim(
                         logits[0].astype(jnp.float32), real_len - 1,
                         keepdims=False)
-            if layerwise:   # the chunk's rows came back
+            if state:       # the pool came back, the slot's entry advanced
+                cache = cache.commit(
+                    nk, cache.lengths.at[slot].set(length + real_len))
+            elif layerwise:   # the chunk's rows came back
                 cache = paged_write_chunk(cache, table_row, slot, nk, nv,
                                           real_len)
             else:           # the rows, out of the updated views
@@ -887,6 +945,29 @@ class Engine:
         decode = None
         if self._spec:
             pass  # draft/verify replace the one-token decode below
+        elif state:
+            @partial(jax.jit, donate_argnums=don)
+            def decode(params, cache, tokens, slot_keys, temps, live, table):
+                # one batched forward; every layer's op reads each live
+                # lane's state once and writes it back where it lay. A lane
+                # that is not live (idle, mid-prefill, finished) has no
+                # real row: the op sends it to the SPARE entry and its own
+                # state stays as it is
+                lengths = cache.lengths
+                meta = StateMeta(table[:, 0], live.astype(jnp.int32))
+                logits, (pool, _, _), cache = serving_forward(
+                    "decode", params, cache, tokens[:, None],
+                    lengths[:, None], (cache.pool(kernel), None, meta),
+                    jnp.zeros_like(lengths), live[:, None])
+                with part("sample"):
+                    last = logits[:, 0].astype(jnp.float32)
+                    next_tok, lps = jax.vmap(sample_slot)(
+                        last, slot_keys, lengths + 1, temps)
+                    tokens = jnp.where(live, next_tok, tokens)
+                with part("cache.write"):
+                    cache = cache.commit(
+                        pool, lengths + live.astype(jnp.int32))
+                return cache, tokens, (next_tok, lps)
         elif self._use_paged_kernel:
             from ..ops.paged_attention import PagedDecodeMeta, PagedKV
 
@@ -1281,6 +1362,13 @@ class Engine:
         streams (None derives a distinct key from the fork's request
         id). With `prefix_cache=False` the fork still runs, it just
         re-prefills — sharing needs the radix tree."""
+        if self._cache_spec.kind == "state":
+            raise ValueError(
+                "fork: this family keeps one recurrent state a sequence and "
+                "no K/V rows (CacheSpec.kind='state'); a fork shares its "
+                "parent's prompt PAGES, and a state has none: it needs a "
+                "snapshot of the parent's state after the prompt, which is "
+                "not implemented. Submit the prompt again.")
         parent.share_prompt = True
         if not parent.done:
             self._fork_parents[parent.request_id] = parent
@@ -1760,6 +1848,9 @@ class Engine:
         self.metrics.set_page_gauges(
             alloc.pages_in_use, alloc.pages_free,
             alloc.pages_in_use * self.cache.page_nbytes)
+        if self._cache_spec.kind == "state":
+            self.metrics.set_state_bytes_gauge(
+                alloc.pages_in_use * self.cache.page_nbytes)
         if self.cache.side is not None:
             self.metrics.set_side_bytes_gauge(
                 alloc.pages_in_use * self.cache.side_page_nbytes)
@@ -1839,8 +1930,12 @@ class Engine:
             record_span("serving.queue_wait", req.submitted_at,
                         req.admitted_at, trace=req.trace_id,
                         parent=req.span_id, tenant=req.tenant)
-        tail = (jnp.int32(slot.index), key_raw,
-                jnp.float32(req.temperature), jnp.int32(alloc.reused_len))
+        # (a state pool's admit takes the slot's entry in the reused
+        # length's place: there is no prefix to reuse, and the entry is
+        # what it zeroes)
+        tail = (jnp.int32(slot.index), key_raw, jnp.float32(req.temperature),
+                jnp.int32(alloc.pages[0] if self._cache_spec.kind == "state"
+                          else alloc.reused_len))
         if self._spec:
             slot.draft_done = 0
             args = (self.cache, self._slot_keys, self._temps,

@@ -105,6 +105,9 @@ class ServingMetrics:
         # keeps beside K and V (an indexer's keys: CacheSpec.side_width);
         # created at the first observation, as above
         self._g_side_bytes = None
+        # the bytes of a state pool's entries that live sequences hold (a
+        # family that keeps one recurrent state a sequence: `StateCache`)
+        self._g_state_bytes = None
         # the engine reads a program's results one step late: a read that
         # found another program already dispatched (the chip worked while
         # the host waited) against one that found none (the chip waited)
@@ -288,6 +291,13 @@ class ServingMetrics:
                 "serving_kv_side_bytes_in_use")
         self._g_side_bytes.set(bytes_in_use)
 
+    def set_state_bytes_gauge(self, bytes_in_use: int) -> None:
+        """Bytes of a state pool's entries held by live sequences."""
+        if self._g_state_bytes is None:
+            self._g_state_bytes = self.registry.gauge(
+                "serving_state_bytes_in_use")
+        self._g_state_bytes.set(bytes_in_use)
+
     def set_group_page_gauges(self, in_use: dict) -> None:
         """`in_use`: pages held a cache group, by the group's label."""
         for group, pages in in_use.items():
@@ -384,6 +394,8 @@ class ServingMetrics:
             out[f"pages_in_use.{group}"] = float(gauge.value)
         if self._g_side_bytes is not None:
             out["kv_side_bytes_in_use"] = float(self._g_side_bytes.value)
+        if self._g_state_bytes is not None:
+            out["state_bytes_in_use"] = float(self._g_state_bytes.value)
         if self.decode_steps:
             out["tokens_per_decode_step"] = (
                 self.tokens_out / self.decode_steps)

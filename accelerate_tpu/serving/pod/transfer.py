@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..cache import StateCache
+
 __all__ = ["KVPageShipment", "PageTransport", "place_shipment"]
 
 
@@ -110,6 +112,12 @@ class PageTransport:
                 "page shipments of a cache with one group a layer kind are "
                 "not implemented: a KVPageShipment carries ONE pool's "
                 "pages, and a window group's ring is no prefix (ROADMAP M2)")
+        if isinstance(engine.cache, StateCache):
+            raise ValueError(
+                "page shipments of a state pool are not implemented: a "
+                "KVPageShipment carries pages of K and V rows, and this "
+                "family keeps one recurrent state a sequence: what would "
+                "ship is a snapshot of an entry (ROADMAP M4)")
         if engine.cache.latent:
             raise ValueError(
                 "page shipments of a latent pool are not implemented: a "

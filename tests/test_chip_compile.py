@@ -639,6 +639,140 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
               memory.argument_size_in_bytes)
 
 
+@pytest.mark.parametrize("degree,state_dtype", [
+    (2, "float32"), (1, "float32"), (2, "bfloat16")],
+    ids=["served", "degree-1", "bf16-state"])
+def test_retention_kernels_compile_for_v5e(one_chip, chip_compile, degree,
+                                           state_dtype):
+    """The decode kernel and the chunk kernel of power retention alone, at
+    the cell's widths (40 query heads over 8 KV heads of 128, 16 lanes, a
+    chunk of 512 rows, a pool of 8 layers x 17 entries), as served and as
+    the controls and the what-if run them: each takes the WHOLE pool and
+    hands it back aliased; nothing of the pool's size is a temporary."""
+    from accelerate_tpu.ops import power_retention as pr
+
+    L, E, B, C, H, G, d = 8, 17, 16, 512, 40, 8, 128
+    D, dt = pr.state_rows(d, degree), jnp.dtype(state_dtype)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = arg((L, E, G, D, d), dt)
+    z = arg((L, E, G, pr.normaliser_rows(d, degree), d), dt)
+    pool_bytes = (s.size + z.size) * dt.itemsize
+
+    def decode(q, k, v, gamma, s, z, entries, rows):
+        o, pool = pr.retention_decode_step(
+            q, k, v, gamma, pr.StatePool(s, z, True), 3,
+            pr.StateMeta(entries, rows), degree=degree)
+        return o, pool.s, pool.z
+
+    def chunk(q, k, v, gamma, s, z, entries):
+        o, pool = pr.retention_chunk(
+            q, k, v, gamma, pr.StatePool(s, z, True), 3, entries,
+            degree=degree)
+        return o, pool.s, pool.z
+
+    bf = jnp.bfloat16
+    for fn, args, name in (
+            (decode, (arg((B, H, d), bf), arg((B, G, d), bf),
+                      arg((B, G, d), bf), arg((B, G), jnp.float32), s, z,
+                      arg((B,), jnp.int32), arg((B,), jnp.int32)),
+             pr.DECODE_KERNEL),
+            (chunk, (arg((1, C, H, d), bf), arg((1, C, G, d), bf),
+                     arg((1, C, G, d), bf), arg((1, C, G), jnp.float32), s,
+                     z, arg((1,), jnp.int32)), pr.CHUNK_KERNEL)):
+        compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(*args).compile()
+        text = compiled.as_text()
+        assert len(re.findall("%" + name + r"(?:\.\d+)? = ", text)) == 1, name
+        assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+        # no copy, slice or re-laid image of the pool around the kernel
+        for shape in (s.shape, s.shape[1:], s.shape[2:]):
+            assert _ops_of_shape(text, "f32" if dt == jnp.float32 else "bf16",
+                                 shape) == {}, (name, shape)
+
+
+def test_retention_engine_programs_compile_for_v5e(one_chip, chip_compile):
+    """`decode` and `prefill` of `serve-brumby-8k-in-1k-out-closed`
+    (Brumby-14B-Base widths, 8 layers, 16 slots x 26624, chunk 512, a state
+    pool of 16 entries and the spare), abstract weights and pool, compiled
+    for the chip:
+
+    (a) `decode` holds the retention decode kernel once a layer and
+        `prefill` the chunk kernel once a layer, each under its own name;
+    (b) the pool (4.67 GB) is aliased to its arguments in both programs and
+        NOTHING of a pool array's shape, of a layer's slice of it or of an
+        entry's is produced around the kernels: no copy of the state;
+    (c) a chunk's logits are one row; temporaries: `decode` under 200 MB,
+        `prefill` under 700 MB (the chunk's [40, 512, 512] scores and
+        weights, a layer's MLP activations), beside 8.40 GB of weights."""
+    from accelerate_tpu.models import brumby
+    from accelerate_tpu.ops import power_retention as pr
+    from accelerate_tpu.serving import Engine, EngineConfig
+    from accelerate_tpu.serving.cache import StateCache
+
+    slots, max_len, chunk, entries = 16, 26624, 512, 16
+    cfg = brumby.BrumbyConfig(num_hidden_layers=8)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: brumby.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * 4_198_652_992
+    engine = Engine(brumby, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk, num_pages=1,
+        prefix_cache=False, paged_attention=True))
+    small = engine.cache
+    cache = on_chip(jax.eval_shape(lambda: StateCache.create(
+        brumby.cache_spec(cfg), slots, max_len, pad_slack=small.pad_slack,
+        num_entries=entries, stats=small.stats)))
+    assert cache.s.shape == (8, 17, 8, 8320, 128)
+    assert cache.z.shape == (8, 17, 8, 72, 128)
+    assert (cache.num_pages, cache.trash_page, cache.pages_per_slot) == (
+        16, 16, 1)
+    assert cache.page_nbytes == 274_989_056
+    pool_bytes = 17 * cache.page_nbytes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_), arg((slots, 1), jnp.int32)),
+            pr.DECODE_KERNEL, 200e6),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32), arg((1,), jnp.int32),
+            arg((chunk,), jnp.int32), arg((), jnp.int32)),
+            pr.CHUNK_KERNEL, 700e6),
+    }
+    for name, (program, args, kernel, temp_limit) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        assert len(re.findall("%" + kernel + r"(?:\.\d+)? = ", text)) == 8, name
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        for shape in (cache.s.shape, cache.s.shape[1:], cache.s.shape[2:],
+                      cache.z.shape[1:]):
+            assert _ops_of_shape(text, "f32", shape) == {}, (name, shape)
+        # (a chunk's normaliser, 37 KB a head, is XLA's: a slice read and
+        # an in-place slice update a layer)
+        assert set(_ops_of_shape(text, "f32", cache.z.shape)) <= {
+            "dynamic-update-slice"}, name
+        assert memory.temp_size_in_bytes < temp_limit, (
+            name, memory.temp_size_in_bytes)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
+        print(name, "temp", memory.temp_size_in_bytes, "args",
+              memory.argument_size_in_bytes)
+
+
 def test_qwen_decode_holds_the_one_kernel_name_on_v5e(one_chip,
                                                       chip_compile):
     """The Qwen cells' `decode` still calls `paged_decode_attention` alone:

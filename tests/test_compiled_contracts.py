@@ -490,3 +490,75 @@ class TestPrefillViewStructure:
         cfg = llama.LlamaConfig.tiny()
         lowered, [(layers, rows)] = self._prefill(llama, cfg)
         assert len(self._stacked(lowered, layers, rows)) >= 4
+
+
+class TestStatePoolInPlace:
+    """The programs of a family that keeps one recurrent state a sequence
+    (`CacheSpec.kind == "state"`) are handed the WHOLE pool and hand it
+    back: both pool arrays are aliased, argument to result, in `admit`,
+    `prefill` and `decode`, and the compiled programs hold no copy of
+    either (4.67 GB in the benchmark's cell; the chip's compiler is held
+    to the same in `tests/test_chip_compile.py`)."""
+
+    @staticmethod
+    def _programs(kernel):
+        from accelerate_tpu.models import brumby
+        from accelerate_tpu.serving import Engine, EngineConfig
+
+        cfg = brumby.BrumbyConfig.tiny(head_dim=16)
+        eng = Engine(brumby, cfg, brumby.init_params(cfg, jax.random.key(0)),
+                     EngineConfig(num_slots=2, max_len=48, prefill_chunk=8,
+                                  num_pages=2, cache_dtype=jnp.float32,
+                                  prefix_cache=False, paged_attention=kernel))
+        state = (eng.params, eng.cache, eng._tokens, eng._slot_keys,
+                 eng._temps)
+        return eng, {
+            "admit": (eng._admit_p, (
+                eng.cache, eng._slot_keys, eng._temps, jnp.int32(0),
+                eng._slot_keys[0], jnp.float32(0.0), jnp.int32(1))),
+            "prefill": (eng._prefill_p, state + (
+                jnp.int32(0), eng._tables(0), np.zeros((8,), np.int32),
+                jnp.int32(8))),
+            "decode": (eng._decode_p, state + (
+                np.ones((2,), bool), eng._tables())),
+        }
+
+    @pytest.mark.parametrize("program", ["admit", "prefill", "decode"])
+    def test_the_pool_is_aliased_and_never_copied(self, program):
+        eng, programs = self._programs(kernel=False)
+        fn, args = programs[program]
+        pool = (eng.cache.s, eng.cache.z)
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        aliases = re.search(r"input_output_alias=\{[^\n]*?\}, entry",
+                            text).group(0)
+        flat = jax.tree.leaves(args)
+        for array in pool:
+            at = next(i for i, leaf in enumerate(flat) if leaf is array)
+            assert re.search(rf"\({at}, \{{\}}", aliases), (program, at)
+            shape = ",".join(map(str, array.shape))
+            # (the CPU's compiler copies the pool once around a chunk's
+            # slice-then-update in the plain `jax.numpy` form; the chip's,
+            # with the kernels, does not: tests/test_chip_compile.py)
+            assert program == "prefill" or not re.search(
+                rf"= f32\[{shape}\]\S* copy\(", text), program
+
+    def test_the_kernels_take_the_pool_aliased(self):
+        """Traced with the kernels (interpreted on the CPU): each layer's
+        `pallas_call` of `decode` and of `prefill` names the pool's `s` as
+        an aliased operand."""
+        _, programs = self._programs(kernel=True)
+        for name, aliased in (("decode", 2), ("prefill", 1)):
+            fn, args = programs[name]
+            calls = [eqn for eqn in _all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                     if eqn.primitive.name == "pallas_call"]
+            assert len(calls) == 2, name                  # one a layer
+            for eqn in calls:
+                assert len(eqn.params["input_output_aliases"]) == aliased
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(inner)

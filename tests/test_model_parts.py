@@ -14,7 +14,14 @@ import numpy as np
 import optax
 import pytest
 
-from accelerate_tpu.models import common, deepseek, keye, llama, mellum
+from accelerate_tpu.models import (
+    brumby,
+    common,
+    deepseek,
+    keye,
+    llama,
+    mellum,
+)
 from accelerate_tpu.serving import Engine, EngineConfig
 from chipbench.harness import trace_scopes
 
@@ -36,6 +43,9 @@ FAMILIES = {
         prefix_cache=False)),
     "keye": (keye, keye.KeyeConfig.tiny, dict(
         num_slots=2, max_len=64, prefill_chunk=16, page_size=16)),
+    "brumby": (brumby, lambda: brumby.BrumbyConfig.tiny(head_dim=16), dict(
+        num_slots=2, max_len=64, prefill_chunk=16, num_pages=2,
+        prefix_cache=False)),
 }
 
 
@@ -154,7 +164,8 @@ def test_every_heavy_operation_of_an_engine_program_has_a_part(
     assert _unbilled(heavy) == []
     parts = {trace_scopes.part_of(n) for _, n in heavy}
     assert {"attn.project", "attn.attend", "attn.output", "head"} <= parts
-    assert ({"mlp"} if family == "llama" else {"moe.experts"}) <= parts
+    assert ({"mlp"} if family in ("llama", "brumby")
+            else {"moe.experts"}) <= parts
     if family == "keye":
         assert {"attn.indexer", "attn.select"} <= parts
 
