@@ -1150,7 +1150,23 @@ class PrefixIndex:
     refcount-0 LEAVES oldest-first, which keeps every cached path
     contiguous from the root (an interior node is unevictable while any
     descendant survives, and a mapped page — refcount > 0 — is never
-    evicted)."""
+    evicted).
+
+    The eviction candidates STAND between evictions: `_lru` is a
+    min-heap of `(last_used, page, serial, node)` that holds, for every
+    node `_evictable` says yes to, at least one entry under the node's
+    current stamp and page. Nothing is removed in place. Whatever makes
+    a node a candidate, or re-stamps one, pushes an entry (`_offer`);
+    whatever unmakes one (an `acquire`, a child cached below it, its
+    eviction) leaves its entries behind, and `evict_lru` discards an
+    entry that no longer describes its node when it surfaces. So an
+    eviction costs the pages it frees and the stale entries above them,
+    never a walk of the tree."""
+
+    # the heap may hold this many entries a cached page (plus a floor
+    # for a small tree) before `_offer` rebuilds it from its live ones
+    LRU_SLACK = 2
+    LRU_FLOOR = 64
 
     def __init__(self, page_size: int):
         self.page_size = page_size
@@ -1164,10 +1180,62 @@ class PrefixIndex:
         # fresh insert (adoption) or its naming path is destructively
         # evicted. None when no host tier is attached.
         self.drop_host: Callable[[Any], None] | None = None
+        self._lru: list[tuple] = []
+        # entries ever pushed: the third field of an entry, so that two
+        # entries of one node under one stamp never compare the nodes
+        self._lru_serial = 0
+        self.lru_stale = 0      # entries discarded so far (running total)
 
     def _touch(self, node: _RadixNode) -> None:
         self._tick += 1
         node.last_used = self._tick
+
+    def _evictable(self, node: _RadixNode) -> bool:
+        """Would an eviction take `node` next, were it the oldest: an
+        attached, unmapped HBM node with no HBM child (host-resident
+        children don't pin their parent). Never the root."""
+        if node.refcount or node.residency != "hbm" or node.parent is None:
+            return False
+        for child in node.children.values():
+            if child.residency == "hbm":
+                return False
+        return True
+
+    def _offer(self, node: _RadixNode) -> None:
+        """Enter `node` among the candidates if it is one now. Called
+        wherever a node may have BECOME one or a candidate's stamp or
+        page changed; an entry it makes stale is not looked for."""
+        if not self._evictable(node):
+            return
+        self._lru_serial += 1
+        heapq.heappush(self._lru, (node.last_used, node.page,
+                                   self._lru_serial, node))
+        self._bound_lru()
+
+    def _live(self, entry: tuple) -> bool:
+        stamp, page, _, node = entry
+        return (node.last_used == stamp and node.page == page
+                and self._evictable(node))
+
+    def lru_bound(self) -> int:
+        """The most entries the heap holds once a call has returned."""
+        return self.LRU_SLACK * self.cached_pages + self.LRU_FLOOR
+
+    def _bound_lru(self) -> None:
+        """Keep the heap within `lru_bound()`: a history that never
+        evicts (a pool that never fills) pushes an entry a touch and
+        pops none. The rebuild walks the HEAP, keeps one live entry a
+        node, and leaves at most `cached_pages` of them, so it is paid
+        once in `cached_pages` pushes."""
+        if len(self._lru) <= self.lru_bound():
+            return
+        kept: dict[int, tuple] = {}
+        for entry in self._lru:
+            if self._live(entry):
+                kept.setdefault(id(entry[3]), entry)
+        self.lru_stale += len(self._lru) - len(kept)
+        self._lru[:] = kept.values()
+        heapq.heapify(self._lru)
 
     def _chunk(self, prompt: np.ndarray, i: int) -> bytes:
         ps = self.page_size
@@ -1189,7 +1257,18 @@ class PrefixIndex:
             node = child
         for n in path:
             self._touch(n)
+        self._offer_path_end(path)
         return path
+
+    def _offer_path_end(self, path: list[_RadixNode]) -> None:
+        """Offer the one node of a root path that can be a candidate:
+        every node above the path's last HBM node has an HBM child, the
+        next one (residency along a path is an HBM prefix, then a host
+        suffix)."""
+        for node in reversed(path):
+            if node.residency == "hbm":
+                self._offer(node)
+                return
 
     def acquire(self, nodes: list[_RadixNode]) -> None:
         for n in nodes:
@@ -1198,10 +1277,15 @@ class PrefixIndex:
                 self.mapped_pages += 1
 
     def release(self, nodes: list[_RadixNode]) -> None:
+        """Unmap `nodes`, a path in root-to-leaf order (what `match`
+        and `extend_path` return, which is all that is ever acquired:
+        refcounts are downward-closed). Only its end can have become an
+        eviction candidate."""
         for n in nodes:
             n.refcount -= 1
             if n.refcount == 0:
                 self.mapped_pages -= 1
+        self._offer_path_end(nodes)
 
     def insert(self, prompt: np.ndarray, pages: list[int],
                upto_pages: int) -> list[int]:
@@ -1226,11 +1310,15 @@ class PrefixIndex:
                 spare.append(pages[i])
             self._touch(child)
             node = child
+        # every node walked is HBM now and all but the last has the next
+        # one below it: the walk's end is its one possible candidate
+        self._offer(node)
         return spare
 
     def _adopt_host(self, node: _RadixNode, page: int) -> None:
         """Re-home a host-resident node in HBM at `page` (whose bytes
-        must already hold the chunk's K/V) and drop the host mirror."""
+        must already hold the chunk's K/V) and drop the host mirror.
+        The caller stamps the node and offers its walk's end."""
         node.page = page
         node.residency = "hbm"
         self.host_pages -= 1
@@ -1271,7 +1359,36 @@ class PrefixIndex:
             self._touch(child)
             out.append(child)
             node = child
+        self._offer_path_end(out)
         return out
+
+    def swap_in(self, nodes: list[_RadixNode], pages: list[int]) -> list:
+        """Re-home the host-resident end of a matched path in HBM, one
+        reserved page a node, and map it: bookkeeping only, the caller
+        installs the bytes. Returns the (node, page) pairs. The nodes go
+        from host-resident to mapped, so none is ever a candidate."""
+        swap_ins = list(zip(nodes, pages))
+        for node, page in swap_ins:
+            node.page = page
+            node.residency = "hbm"
+            self.host_pages -= 1
+            self.cached_pages += 1
+        self.acquire(nodes)
+        return swap_ins
+
+    def undo_swap_in(self, swap_ins: list) -> None:
+        """Turn released `swap_in` pairs back to host residency (their
+        bytes never left the host tier). The node ABOVE them is the end
+        of the path's HBM part again, and no `release` saw it so: it had
+        an HBM child then."""
+        for node, _ in swap_ins:
+            node.page = -1
+            node.residency = "host"
+            self.host_pages += 1
+            self.cached_pages -= 1
+        if swap_ins:
+            self._offer(swap_ins[0][0].parent)
+            self._bound_lru()
 
     def evict_lru(self, n: int,
                   swap_out: "Callable[[Any], bool] | None" = None
@@ -1301,26 +1418,22 @@ class PrefixIndex:
         node can never have a mapped descendant, and every refcount-0
         subtree drains leaf-first (host-resident nodes are refcount-0 by
         construction and hold no HBM page, so they count in neither
-        term). The sufficient case pays one DFS plus a min-heap of
-        candidate leaves: O(tree + n log tree), once per actual eviction
-        burst, never per blocked step."""
+        term). That is also why the standing heap cannot run dry below:
+        while an unmapped HBM page is left, the deepest one on its path
+        is a candidate, and every candidate has a live entry.
+
+        The victims come off the standing heap (see the class): each pop
+        is either a victim or a stale entry that leaves for good, so a
+        call costs O((n + stale) log heap), whatever the tree's size."""
         if n <= 0 or self.cached_pages - self.mapped_pages < n:
             return []
-        heap = []
-        stack = [c for c in self.root.children.values()
-                 if c.residency == "hbm"]
-        while stack:
-            node = stack.pop()
-            hbm_children = [c for c in node.children.values()
-                            if c.residency == "hbm"]
-            if hbm_children:
-                stack.extend(hbm_children)
-            elif node.refcount == 0:
-                heap.append((node.last_used, node.page, node))
-        heapq.heapify(heap)
         freed: list[int] = []
         while len(freed) < n:
-            _, _, victim = heapq.heappop(heap)
+            entry = heapq.heappop(self._lru)
+            if not self._live(entry):
+                self.lru_stale += 1
+                continue
+            victim = entry[3]
             parent = victim.parent
             freed.append(victim.page)
             self.cached_pages -= 1
@@ -1340,11 +1453,8 @@ class PrefixIndex:
                     self.host_pages -= 1
                     if self.drop_host is not None:
                         self.drop_host(orphan)
-            if parent is not self.root and parent.refcount == 0 \
-                    and parent.residency == "hbm" \
-                    and not any(c.residency == "hbm"
-                                for c in parent.children.values()):
-                heapq.heappush(heap, (parent.last_used, parent.page, parent))
+            self._offer(parent)
+        self._bound_lru()
         return freed
 
     def residency_probe(self, prompt: np.ndarray) -> tuple[int, int]:
@@ -1487,11 +1597,13 @@ class PagedAllocator:
         in that case NOTHING was evicted (evict_lru is all-or-nothing),
         so a too-big queue head can't strip the cache while it waits."""
         with span("serving.kv.allocate") as sp:
-            evictions = self.evictions
+            evictions, stale = self.evictions, self.index.lru_stale
             alloc = self._allocate(request)
             sp.set(pages=len(alloc.pages) if alloc else 0,
                    reused_len=alloc.reused_len if alloc else 0,
-                   evicted=self.evictions - evictions)
+                   evicted=self.evictions - evictions,
+                   lru_stale=self.index.lru_stale - stale,
+                   lru_heap=len(self.index._lru))
             if self.state_entries:
                 sp.set(state_entries=len(alloc.pages) if alloc else 0)
             if self.ring_pools:
@@ -1549,14 +1661,7 @@ class PagedAllocator:
             # re-home the host suffix: each node takes a reserved page
             # NOW (bookkeeping only — the caller installs the bytes
             # before the slot's first device program reads them)
-            swap_ins = []
-            for node, page in zip(host_nodes, extra):
-                node.page = page
-                node.residency = "hbm"
-                self.index.host_pages -= 1
-                self.index.cached_pages += 1
-                swap_ins.append((node, page))
-            self.index.acquire(host_nodes)
+            swap_ins = self.index.swap_in(host_nodes, extra)
         except BaseException:
             # on_evict / swap_stall are caller-supplied callbacks: if
             # one raises mid-allocate the matched nodes' refcounts must
@@ -1594,12 +1699,9 @@ class PagedAllocator:
         self.pool.release(alloc.pages[len(alloc.nodes):])
         for pool, ring in zip(self.ring_pools, alloc.rings):
             pool.release(ring)
-        for node, page in (alloc.swap_ins or ()):
-            node.page = -1
-            node.residency = "host"
-            self.index.host_pages += 1
-            self.index.cached_pages -= 1
-            self.pool.release([page])
+        swap_ins = alloc.swap_ins or []
+        self.index.undo_swap_in(swap_ins)
+        self.pool.release([page for _, page in swap_ins])
         self.lookups -= 1
         if alloc.nodes:
             self.hits -= 1

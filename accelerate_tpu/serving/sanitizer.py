@@ -20,6 +20,11 @@ validates exactly those joins after every engine step:
   along root paths (a refcount-0 node never has a mapped descendant —
   the invariant `evict_lru`'s O(1) bail relies on), and the
   `cached_pages`/`mapped_pages` running counters match the tree;
+- **eviction candidates**: the prefix index's standing heap holds a live
+  entry (the node's current stamp and page) for exactly the tree's
+  refcount-0 effective leaves, so `evict_lru` neither runs dry with
+  `cached - mapped` pages left nor takes a newer page before an older
+  one, and the heap is within its bound;
 - **table discipline**: a slot's device page-table row is exactly its
   allocation followed by trash padding; idle lanes are all-trash (a
   stale row is how a retired lane's masked writes corrupt a reallocated
@@ -56,16 +61,17 @@ import numpy as np
 from .scheduler import RequestStatus, SlotState
 
 __all__ = ["SanitizerViolation", "resolve_sanitize", "check_engine",
-           "check_pod_worker", "check_distributed_router"]
+           "check_eviction_candidates", "check_pod_worker",
+           "check_distributed_router"]
 
 SANITIZE_ENV = "ACCELERATE_TPU_SANITIZE"
 
 
 class SanitizerViolation(RuntimeError):
     """One broken cross-structure invariant. `check` is the stable
-    invariant name (page-conservation, refcount, table, lengths,
-    scheduler-books, worker-books, droute-books); `details` is a JSON-safe dict that
-    lands in the incident bundle."""
+    invariant name (page-conservation, refcount, eviction-candidates,
+    table, lengths, scheduler-books, worker-books, droute-books);
+    `details` is a JSON-safe dict that lands in the incident bundle."""
 
     def __init__(self, check: str, message: str,
                  details: dict | None = None):
@@ -102,6 +108,40 @@ def _walk_tree(index) -> list:
         out.append((node, parent))
         stack.extend((c, node) for c in node.children.values())
     return out
+
+
+def check_eviction_candidates(index, tree_nodes=None) -> None:
+    """The prefix index's standing heap against its tree (model-free: a
+    `PrefixIndex` alone will do). `tree_nodes` is `_walk_tree(index)`
+    where the caller has it."""
+    if tree_nodes is None:
+        tree_nodes = _walk_tree(index)
+    leaves = {
+        id(node): node for node, _ in tree_nodes
+        if node.refcount == 0
+        and getattr(node, "residency", "hbm") == "hbm"
+        and not any(getattr(c, "residency", "hbm") == "hbm"
+                    for c in node.children.values())}
+    queued = {id(entry[3]): entry[3] for entry in index._lru
+              if index._live(entry)}
+    lost = [n.page for key, n in leaves.items() if key not in queued]
+    if lost:
+        _fail("eviction-candidates",
+              "evictable leaves of the radix tree have no live entry in "
+              "the standing heap (a transition did not reach the index: "
+              "evict_lru would pass them over, or run dry with "
+              "cached - mapped pages left)", pages=sorted(lost))
+    stray = [n.page for key, n in queued.items() if key not in leaves]
+    if stray:
+        _fail("eviction-candidates",
+              "the standing heap holds live entries for nodes that are "
+              "not evictable leaves of the tree (a detached node passes "
+              "for attached)", pages=sorted(stray))
+    if len(index._lru) > index.lru_bound():
+        _fail("eviction-candidates",
+              "the standing heap outgrew its bound (stale entries are "
+              "not being rebuilt away)", entries=len(index._lru),
+              bound=index.lru_bound(), cached_pages=index.cached_pages)
 
 
 def check_engine(engine) -> None:
@@ -258,6 +298,8 @@ def check_engine(engine) -> None:
     if index.mapped_pages != mapped:
         _fail("refcount", "mapped_pages counter disagrees with the tree",
               counter=index.mapped_pages, tree=mapped)
+
+    check_eviction_candidates(index, tree_nodes)
 
     # -- device page tables --------------------------------------------------
     table = engine._table

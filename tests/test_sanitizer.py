@@ -138,6 +138,36 @@ def test_fires_on_refcount_corruption(gpt2_setup):
     assert ei.value.details["page"] == node.page
 
 
+@pytest.mark.parametrize("fault", ["lost", "stray", "unbounded"])
+def test_fires_on_eviction_candidates_out_of_step(gpt2_setup, fault):
+    """The prefix index keeps its eviction candidates standing in a heap
+    (PR 41): a transition that does not reach it is silent until some
+    later admission evicts the wrong page or finds the heap dry."""
+    cfg, params = gpt2_setup
+    eng = _engine(cfg, params)
+    _serve_one(eng, cfg, n=17)    # retirement caches 2 full prompt pages
+    index = eng.allocator.index
+    leaf = next(iter(index.root.children.values()))
+    leaf = next(iter(leaf.children.values()))
+    assert index._evictable(leaf) and [e[3] for e in index._lru] == [leaf]
+    if fault == "lost":
+        index._lru.clear()        # the leaf's release never reached it
+        words = "no live entry"
+    elif fault == "stray":
+        # a detached page that still passes for attached
+        ghost = type(leaf)(b"ghost", 0, leaf)
+        index._lru.append((ghost.last_used, ghost.page, 0, ghost))
+        words = "not evictable leaves"
+    else:
+        index._lru.extend([(0, -1, -i, leaf)
+                           for i in range(index.lru_bound())])
+        words = "outgrew its bound"
+    with pytest.raises(SanitizerViolation) as ei:
+        eng.step()
+    assert ei.value.check == "eviction-candidates"
+    assert words in str(ei.value)
+
+
 def test_fires_on_lost_page(gpt2_setup):
     """A page missing from free+tree+slots entirely (the classic leak
     end-state) breaks conservation."""
