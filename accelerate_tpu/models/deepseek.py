@@ -315,17 +315,23 @@ def _absorbed_attention(config, a, q_nope, q_pe, view, positions):
     return _unabsorb_output(c, a, o_lat)
 
 
-def _attention(config, a, x, cos, sin, positions, cache, layer_index,
-               rows_back: bool = False):
-    """-> (attention output [B, S, h], this layer's new cache entry: the
-    updated dense view, or with `rows_back` this call's own rows [B, S, 1,
-    W] as the view holds them; a paged step's one row)."""
+def mla_project(config, a, x, cos, sin, positions, rescale: bool = False):
+    """The two low-rank paths of latent attention over x [B, S, h] -> (c_q
+    [B, S, q_lora_rank], q_nope [B, S, H, nope], q_pe [B, S, H, rope]
+    rotated, the cache row [B, S, W] = [c_kv | k_pe rotated | 0]).
+    `config` is anything with this block's widths (`num_attention_heads`,
+    the two ranks, the three head widths, `latent_width`,
+    `latent_row_width`, `rms_norm_eps`): another family's layer kind hands
+    its own. `rescale`: both normed latents times `sqrt(hidden / rank)`
+    (a family whose published config says so)."""
     c = config
-    B, S, _ = x.shape
+    B, S, h = x.shape
     H = c.num_attention_heads
     with part("attn.project"):
         c_q = rms_norm(dense(x, a["q_a_proj"]["kernel"]),
                        a["q_a_layernorm"]["scale"], c.rms_norm_eps)
+        if rescale:
+            c_q = c_q * math.sqrt(h / c.q_lora_rank)
         q = dense(c_q, a["q_b_proj"]["kernel"]).reshape(B, S, H,
                                                         c.qk_head_dim)
         q_nope = q[..., :c.qk_nope_head_dim]
@@ -334,12 +340,26 @@ def _attention(config, a, x, cos, sin, positions, cache, layer_index,
         kv_a = dense(x, a["kv_a_proj"]["kernel"])
         c_kv = rms_norm(kv_a[..., :c.kv_lora_rank],
                         a["kv_a_layernorm"]["scale"], c.rms_norm_eps)
+        if rescale:
+            c_kv = c_kv * math.sqrt(h / c.kv_lora_rank)
         k_pe = _rope_interleaved(kv_a[..., None, c.kv_lora_rank:], cos, sin,
                                  positions)[:, :, 0]
         row = jnp.concatenate(
             [c_kv, k_pe, jnp.zeros(
                 (B, S, c.latent_row_width - c.latent_width), x.dtype)],
             axis=-1)                                        # [B, S, W]
+    return c_q, q_nope, q_pe, row
+
+
+def _attention(config, a, x, cos, sin, positions, cache, layer_index,
+               rows_back: bool = False):
+    """-> (attention output [B, S, h], this layer's new cache entry: the
+    updated dense view, or with `rows_back` this call's own rows [B, S, 1,
+    W] as the view holds them; a paged step's one row)."""
+    c = config
+    B, S, _ = x.shape
+    H = c.num_attention_heads
+    _, q_nope, q_pe, row = mla_project(c, a, x, cos, sin, positions)
 
     if cache is None:
         with part("attn.attend"):
@@ -396,9 +416,10 @@ def _swiglu(m, x):
     return dense(act, m["down_proj"]["kernel"])
 
 
-def moe_layer(config: DeepseekConfig, m: dict, x, token_mask=None):
+def moe_layer(config, m: dict, x, token_mask=None):
     """The expert layer over x [B, S, h] -> (y, assignments per expert [E]
-    of the tokens `token_mask` [B, S] keeps; all of them without a mask).
+    of the tokens `token_mask` [B, S] keeps; all of them without a mask;
+    E is every expert the router chooses among, held here or not).
     The mask only says which tokens the counters count: padding and dead
     lanes are routed and computed like any row (shapes are static)."""
     c = config
@@ -408,8 +429,11 @@ def moe_layer(config: DeepseekConfig, m: dict, x, token_mask=None):
         flat, m["router"]["kernel"], m["router"]["e_score_correction_bias"],
         c.num_experts_per_tok, c.routed_scaling_factor, c.norm_topk_prob)
     e = m["experts"]
+    # a config that holds a share of its experts says which
+    # (`experts_held`); the counters below stay over all the router's
     y = grouped_swiglu_experts(flat, experts, weights, e["gate_proj"],
-                               e["up_proj"], e["down_proj"])
+                               e["up_proj"], e["down_proj"],
+                               experts_held=getattr(c, "experts_held", None))
     with part("moe.shared"):
         y = (y + _swiglu(m["shared"], flat).astype(jnp.float32)).astype(
             x.dtype)

@@ -258,7 +258,7 @@ def init_params(config: KeyeConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _view_scores(config, qI, wts, side_view, q_pos, key_pos):
+def view_index_scores(config, qI, wts, side_view, q_pos, key_pos):
     """float32 index scores [B, S, R] of queries qI [B, S, J, w] (weights
     wts [B, S, J]) at positions `q_pos` [B, S] over the index keys
     `side_view` [B, R, w] at positions `key_pos` [B, R] (negative: nothing
@@ -357,7 +357,7 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
                 lo = jnp.zeros((), jnp.int32)
                 hi = jnp.minimum(jnp.max(at) // blk + 1, -(-R // blk))
             with part("attn.indexer"):
-                scores = _view_scores(c, qI.astype(view_i.dtype), wts,
+                scores = view_index_scores(c, qI.astype(view_i.dtype), wts,
                                       view_i[:, :, 0], at, key_pos)
             with part("attn.select"):
                 select = exact_topk_mask(scores, c.topk)        # [B, S, R]

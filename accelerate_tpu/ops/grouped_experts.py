@@ -28,6 +28,12 @@ rows has two kernels, chosen from the static shapes by ONE rule
   its tile and spends a decode step's time on masked rows (`PERF.md`
   section 6, PR 32); the two get their own code, the sort, the route
   and the combine around them stay shared.
+
+A layer that holds a SHARE of its experts (`experts_held`) and has many
+rows an expert keeps few of its sorted rows (the others belong to absent
+experts): those go through the rows kernel too, a window of them a pass,
+an expert's matrix in blocks of lanes (`held_rows_in_windows`,
+`_held_swiglu_in_windows`).
 """
 
 from __future__ import annotations
@@ -44,7 +50,7 @@ from ..models.common import part
 
 __all__ = ["sigmoid_topk_route", "softmax_topk_route", "expert_counts",
            "grouped_swiglu_experts", "grouped_rows_matmul",
-           "few_rows_an_expert", "ROWS_KERNEL_NAME"]
+           "few_rows_an_expert", "held_rows_in_windows", "ROWS_KERNEL_NAME"]
 
 
 def sigmoid_topk_route(x, router_kernel, correction_bias, top_k: int,
@@ -101,6 +107,7 @@ def expert_counts(experts, num_experts: int, token_mask=None):
 
 ROWS_KERNEL_NAME = "ragged-dot-rows"
 _ROW_ALIGN = 16   # a bf16 sublane tile: where a window of rows may start
+_LANES = 128
 # Rows a product: 16, 32 and 64 read the same on the chip (`PERF.md`
 # section 6, PR 32); at 32 one window holds any group of up to 17 rows
 # wherever it starts.
@@ -108,14 +115,15 @@ ROW_TILE = 32
 
 
 def _rows_kernel(ids_ref, offsets_ref, x_ref, w_ref, o_ref, *, row_tile,
-                 rows):
+                 rows, walk_axis=0):
     """One grid step = one expert THAT HAS ROWS: its matrix `w_ref`
-    [K, N] is fetched once, by the pipeline, while the step before
-    multiplies. All rows and the whole result stay in VMEM; the expert's
-    rows are covered by windows of `row_tile` rows that start on a
-    sublane tile, and what a window holds of other experts' rows is
-    masked out of the sums."""
-    g = pl.program_id(0)
+    [K, N] (or, under `lane_tile`, the [K, lane_tile] block of it that
+    the grid's first axis names) is fetched once, by the pipeline, while
+    the step before multiplies. All rows and the result's block stay in
+    VMEM; the expert's rows are covered by windows of `row_tile` rows that
+    start on a sublane tile, and what a window holds of other experts'
+    rows is masked out of the sums."""
+    g = pl.program_id(walk_axis)
 
     @pl.when(g == 0)
     def _zero():
@@ -160,16 +168,22 @@ def _group_walk(sizes):
 
 
 def grouped_rows_matmul(x, w, sizes, *, row_tile: int = ROW_TILE,
+                        lane_tile: int | None = None,
                         interpret: bool | None = None):
     """`jax.lax.ragged_dot(x, w, sizes, preferred_element_type=float32)`
     for FEW rows a group: x [M, K] sorted by group, w [E, K, N] as it is
-    held (never copied), sizes [E] int32 summing to M -> float32 [M, N].
+    held (never copied), sizes [E] int32 summing to M OR LESS (rows behind
+    the last group belong to none and come back 0, where any group has
+    rows) -> float32 [M, N].
 
     The grid walks the groups that HAVE rows (`_group_walk(sizes)`,
     scalar-prefetched; a group without rows costs no step and no read):
     every weight byte is read once, a whole matrix a step, double-
-    buffered behind the products. Operands keep x's dtype, accumulation
-    is float32."""
+    buffered behind the products. Under `lane_tile` (whole 128-lane
+    tiles, a divisor of N) a step holds a [K, lane_tile] block of the
+    matrix instead, and the groups are walked once a block of N: a matrix
+    too large for VMEM is still read once. Operands keep x's dtype,
+    accumulation is float32."""
     M, K = x.shape
     N = w.shape[2]
     if row_tile % _ROW_ALIGN:
@@ -180,22 +194,39 @@ def grouped_rows_matmul(x, w, sizes, *, row_tile: int = ROW_TILE,
     x = jnp.pad(x, ((0, rows - M), (0, 0)))
     ids, offsets, live = _group_walk(sizes)
     item = jnp.dtype(x.dtype).itemsize
-    buffers = 2 * (rows * K * item + K * N * item + rows * N * 4)
+    if lane_tile is None:
+        n = N
+        grid, walk_axis, semantics = (live,), 0, ("arbitrary",)
+        in_specs = [
+            pl.BlockSpec((rows, K), lambda g, ids, offsets: (0, 0)),
+            pl.BlockSpec((None, K, N),
+                         lambda g, ids, offsets: (ids[g], 0, 0)),
+        ]
+        out_specs = pl.BlockSpec((rows, N), lambda g, ids, offsets: (0, 0))
+    else:
+        n = lane_tile
+        if n % _LANES or N % n:
+            raise ValueError(f"a block of {n} lanes is not whole "
+                             f"{_LANES}-lane tiles that divide {N}")
+        grid, walk_axis = (N // n, live), 1
+        semantics = ("arbitrary", "arbitrary")
+        in_specs = [
+            pl.BlockSpec((rows, K), lambda j, g, ids, offsets: (0, 0)),
+            pl.BlockSpec((None, K, n),
+                         lambda j, g, ids, offsets: (ids[g], 0, j)),
+        ]
+        out_specs = pl.BlockSpec((rows, n),
+                                 lambda j, g, ids, offsets: (0, j))
+    buffers = 2 * (rows * K * item + K * n * item + rows * n * 4)
     out = pl.pallas_call(
-        functools.partial(_rows_kernel, row_tile=row_tile, rows=rows),
+        functools.partial(_rows_kernel, row_tile=row_tile, rows=rows,
+                          walk_axis=walk_axis),
         out_shape=jax.ShapeDtypeStruct((rows, N), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(live,),
-            in_specs=[
-                pl.BlockSpec((rows, K), lambda g, ids, offsets: (0, 0)),
-                pl.BlockSpec((None, K, N),
-                             lambda g, ids, offsets: (ids[g], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((rows, N), lambda g, ids, offsets: (0, 0)),
-        ),
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=semantics,
             vmem_limit_bytes=buffers + (16 << 20)),
         name=ROWS_KERNEL_NAME,
         interpret=interpret,
@@ -213,7 +244,6 @@ ROWS_KERNEL_BELOW = 8
 # A step holds an expert's whole matrix twice (this step's and the
 # next's): the largest one the kernel takes.
 _MATRIX_BYTES = 8 << 20
-_LANES = 128
 
 
 def few_rows_an_expert(rows: int, num_experts: int, k: int, n: int,
@@ -226,31 +256,126 @@ def few_rows_an_expert(rows: int, num_experts: int, k: int, n: int,
             and k * n * jnp.dtype(dtype).itemsize <= _MATRIX_BYTES)
 
 
-def grouped_swiglu_experts(x, experts, weights, gate, up, down):
+# A SHARE's chunk (`experts_held`, many rows an expert): most of the
+# `tokens * top_k` sorted rows belong to absent experts and to no group, so
+# the held rows are few (a chunk of 512 tokens x 8 over 32 of 256 experts:
+# ~512 of 4,096) and the rows kernel takes them, this many a pass, its
+# matrices in blocks of `_MATRIX_BYTES`. `ragged_dot` there pays ~55 us for
+# every group it touches (a 15.7 MB matrix is read in 19), 5.4 ms a layer
+# where this reads 2.7, and a chunk's time followed how many of the held
+# experts the seed's router touched (`PERF.md` section 6, PR 43).
+HELD_ROWS_WINDOW = 1024
+
+
+def _lane_tile(k: int, n: int, dtype) -> int:
+    """The widest block of whole lane tiles that divides `n` and whose
+    [k, block] slice of a matrix fits the rows kernel's VMEM block."""
+    fits = _MATRIX_BYTES // (k * jnp.dtype(dtype).itemsize)
+    return max((t for t in range(_LANES, n + 1, _LANES)
+                if n % t == 0 and t <= fits), default=_LANES)
+
+
+def held_rows_in_windows(rows: int, num_experts: int, k: int, n: int,
+                         dtype=jnp.bfloat16) -> bool:
+    """The rule for a SHARE's products, from static shapes alone: many
+    rows an expert (few take the rule above) over [k, n] (and [n, k])
+    matrices of whole lane tiles, of any size."""
+    return (rows // num_experts >= ROWS_KERNEL_BELOW
+            and k % _LANES == 0 and n % _LANES == 0)
+
+
+def _held_swiglu_in_windows(x, order, top_k: int, sizes, gate, up, down):
+    """The three products over the HELD rows alone, `HELD_ROWS_WINDOW`
+    sorted rows a pass and as many passes as the held rows need (one, at
+    the cell's share; all `tokens * top_k` rows if every assignment lands
+    here: dropless at any imbalance, and an expert's matrix is read once
+    a pass that holds rows of it). x [T, h], order [T * k] (the sort),
+    sizes [E] of the held experts -> float32 [T * k, h], rows behind the
+    last group 0."""
+    M, (_, h, f) = order.shape[0], gate.shape
+    window = min(HELD_ROWS_WINDOW, M)
+    passes = -(-M // window)
+    order = jnp.pad(order, (0, passes * window - M))
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                               jnp.cumsum(sizes, dtype=jnp.int32)])
+    tiles = _lane_tile(h, f, x.dtype), _lane_tile(f, h, x.dtype)
+
+    def one_pass(i, out):
+        lo = i * window
+        with part("moe.sort"):
+            rows = x[jax.lax.dynamic_slice(order, (lo,), (window,)) // top_k]
+        inside = jnp.clip(offsets, lo, lo + window)
+        product = functools.partial(grouped_rows_matmul,
+                                    sizes=inside[1:] - inside[:-1])
+        act = (jax.nn.silu(product(rows, gate, lane_tile=tiles[0]))
+               * product(rows, up, lane_tile=tiles[0])).astype(x.dtype)
+        return jax.lax.dynamic_update_slice(
+            out, product(act, down, lane_tile=tiles[1]), (lo, 0))
+
+    out = jax.lax.fori_loop(
+        0, -(-offsets[-1] // window), one_pass,
+        jnp.zeros((passes * window, h), jnp.float32))
+    return out[:M]
+
+
+def grouped_swiglu_experts(x, experts, weights, gate, up, down,
+                           experts_held=None):
     """`y[t] = sum_k weights[t, k] * E_{experts[t, k]}(x[t])` with every
     expert `W_down(silu(W_gate x) * W_up x)`. x [T, h]; experts, weights
     [T, k]; gate, up [E, h, f]; down [E, f, h]. Products take x's dtype
-    with float32 accumulation; returns float32 [T, h]."""
+    with float32 accumulation; returns float32 [T, h].
+
+    `experts_held = (first, count)`: this layer HOLDS the experts `first
+    .. first + count - 1` of those the router chose among (an expert-
+    parallel layer's share: gate, up, down are `[count, ...]`). The
+    router's choice and its weights stay as they are, over all experts;
+    an assignment to an expert that is not held is DROPPED before the
+    grouped products (it sorts behind the held ones and belongs to no
+    group: no product, no weight byte), and the sum is over the held
+    assignments alone: the part of `y` this share gives, which the other
+    shares' parts complete. A token none of whose experts is held gets 0.
+    Absent (None): every expert is held, the layer whole."""
     T, k = experts.shape
     E, h, f = gate.shape
+    held = None
+    if experts_held is not None:
+        first, count = experts_held
+        if count != E:
+            raise ValueError(
+                f"experts_held={experts_held} names {count} experts; gate, "
+                f"up and down hold {E}")
+        with part("moe.sort"):
+            experts = experts - first
+            held = (experts >= 0) & (experts < E)
+            # an absent expert's id is E: behind every group, counted in none
+            experts = jnp.where(held, experts, E)
     with part("moe.sort"):
         flat = experts.reshape(T * k)
         order = jnp.argsort(flat, stable=True)
         sizes = expert_counts(experts, E)
-        rows = x[order // k]                                # [T * k, h]
-    with part("moe.experts"):
-        if few_rows_an_expert(T * k, E, h, f, x.dtype):
-            product = functools.partial(grouped_rows_matmul, sizes=sizes)
-        else:
-            def product(a, w):
-                return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes,
-                                          preferred_element_type=jnp.float32)
+    if held is not None and held_rows_in_windows(T * k, E, h, f, x.dtype):
+        with part("moe.experts"):
+            out = _held_swiglu_in_windows(x, order, k, sizes, gate, up, down)
+    else:
+        with part("moe.sort"):
+            rows = x[order // k]                            # [T * k, h]
+        with part("moe.experts"):
+            if few_rows_an_expert(T * k, E, h, f, x.dtype):
+                product = functools.partial(grouped_rows_matmul, sizes=sizes)
+            else:
+                def product(a, w):
+                    return jax.lax.ragged_dot(
+                        a, w.astype(a.dtype), sizes,
+                        preferred_element_type=jnp.float32)
 
-        act = (jax.nn.silu(product(rows, gate))
-               * product(rows, up)).astype(x.dtype)
-        out = product(act, down)                            # [T * k, h] f32
+            act = (jax.nn.silu(product(rows, gate))
+                   * product(rows, up)).astype(x.dtype)
+            out = product(act, down)                        # [T * k, h] f32
     with part("moe.combine"):
         back = jnp.zeros((T * k,), order.dtype).at[order].set(
             jnp.arange(T * k, dtype=order.dtype))
-        return jnp.sum(out[back].reshape(T, k, -1)
-                       * weights[:, :, None].astype(jnp.float32), axis=1)
+        out = out[back].reshape(T, k, -1)
+        if held is not None:
+            # rows past the last group are whatever the product left there
+            out = jnp.where(held[:, :, None], out, 0.0)
+        return jnp.sum(out * weights[:, :, None].astype(jnp.float32), axis=1)

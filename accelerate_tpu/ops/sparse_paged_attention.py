@@ -47,6 +47,14 @@ section 6, PR 33 has the reading).
 
 While `length + 1 <= top_k` every position is selected and the result is
 `paged_decode_attention`'s.
+
+- `sparse_latent_paged_decode_attention` (Pallas,
+  `LATENT_ATTENTION_KERNEL_NAME`): the same walk over a LATENT pool
+  (`ops/latent_paged_attention.py`: one row a token that is key and value
+  at once, H absorbed query heads over it). The index keys are then the
+  latent pool's side row; `indexer_paged_scores` and `exact_topk_mask` are
+  the ones above. A copied page is read ONCE for all heads and as key and
+  value both, and the same reasoning holds for its granularity.
 """
 
 from __future__ import annotations
@@ -71,6 +79,9 @@ from .paged_attention import (
 SCORES_KERNEL_NAME = "indexer_paged_scores"
 ATTENTION_KERNEL_NAME = "sparse_paged_decode_attention"
 SELECT_NAME = "sparse_topk_select"
+LATENT_ATTENTION_KERNEL_NAME = "sparse_latent_paged_decode_attention"
+# latent pages copied and attended at a time (a page is 20 KB at 640 lanes)
+LATENT_PAGES_PER_GROUP = 32
 # index-pool pages copied and scored at a time (a page is 2 KB at the
 # published shape: 64 of them are one [512, 128] bf16 operand)
 SCORE_PAGES_PER_GROUP = 64
@@ -82,6 +93,8 @@ __all__ = [
     "exact_topk_mask",
     "sparse_paged_decode_attention",
     "sparse_paged_decode_reference",
+    "sparse_latent_paged_decode_attention",
+    "sparse_latent_paged_decode_reference",
 ]
 
 
@@ -410,6 +423,25 @@ def _compact_selection(selected, meta: PagedDecodeMeta, page_size: int):
     return table, bits, jnp.sum(bits != 0, axis=-1, dtype=jnp.int32), own
 
 
+def _compacted_walk(selected, meta: PagedDecodeMeta, page_size: int,
+                    pages_per_group: int):
+    """What a sparse kernel prefetches and adds to its scores:
+    `_compact_selection`'s table, count and self flag, and the additive
+    bias [S, groups, pages_per_group x page_size] in the table's order (0
+    at a selected cached position, NEG_INF elsewhere)."""
+    S, P = meta.table.shape
+    ps, G = page_size, pages_per_group
+    n_groups = -(-P // G)
+    rows = G * ps
+    table, bits, count, own = _compact_selection(selected, meta, ps)
+    lane = jnp.arange(ps, dtype=jnp.int32)
+    bias = jnp.where((bits[:, :, None] >> lane) & 1 == 1, 0.0,
+                     NEG_INF).astype(jnp.float32).reshape(S, P * ps)
+    bias = jnp.pad(bias, ((0, 0), (0, n_groups * rows - P * ps)),
+                   constant_values=NEG_INF).reshape(S, n_groups, rows)
+    return table, count, own, bias
+
+
 def sparse_paged_decode_attention(q, k_new, v_new, pk: PagedKV, pv: PagedKV,
                                   meta: PagedDecodeMeta, selected,
                                   interpret: bool | None = None):
@@ -438,12 +470,7 @@ def sparse_paged_decode_attention(q, k_new, v_new, pk: PagedKV, pv: PagedKV,
     rows = G * ps
     # the pages that hold a selected position first: the kernel's walk
     # ends where they end
-    table, bits, count, own = _compact_selection(selected, meta, ps)
-    lane = jnp.arange(ps, dtype=jnp.int32)
-    bias = jnp.where((bits[:, :, None] >> lane) & 1 == 1, 0.0,
-                     NEG_INF).astype(jnp.float32).reshape(S, P * ps)
-    bias = jnp.pad(bias, ((0, 0), (0, n_groups * rows - P * ps)),
-                   constant_values=NEG_INF).reshape(S, n_groups, rows)
+    table, count, own, bias = _compacted_walk(selected, meta, ps, G)
     group = H // Hkv
     row_dtype = pk.row_dtype
     k_row, v_row = k_new.astype(row_dtype), v_new.astype(row_dtype)
@@ -508,3 +535,157 @@ def sparse_paged_decode_reference(q, k_new, v_new, pk: PagedKV, pv: PagedKV,
     s = jnp.where(keep[:, None, None, :], s, NEG_INF)
     out = jnp.einsum("shgr,srhd->shgd", jax.nn.softmax(s, axis=-1), v_all)
     return out.reshape(S, 1, H, D).astype(q.dtype), (k_row, v_row)
+
+
+# ---------------------------------------------------------------------------
+# sparse_latent_paged_decode_attention
+# ---------------------------------------------------------------------------
+
+
+def _sparse_latent_kernel(table_ref, count_ref, layer_ref, self_ref, q_ref,
+                          new_ref, bias_ref, pool_hbm, o_ref, buf, sem, *,
+                          sm_scale: float, page_size: int,
+                          pages_per_slot: int, pages_per_group: int,
+                          value_width: int):
+    """Grid [slots]; `_sparse_kernel`'s walk of a COMPACTED table over a
+    latent pool `pool_hbm` [L, N + 1, ps, W]: q_ref [1, H, W] absorbed
+    queries, new_ref [1, 1, W] the new token's row, `bias_ref` [1, groups,
+    G * ps] 0 at a selected position and NEG_INF elsewhere, in the table's
+    order. A copied row serves every head, as key (all W lanes) and as
+    value (its first `value_width`)."""
+    s = pl.program_id(0)
+    layer = layer_ref[0]
+    G, ps, P = pages_per_group, page_size, pages_per_slot
+    n_groups = (count_ref[s] + G - 1) // G
+
+    def start(g, slot):
+        for j in range(G):
+            # entries past the table's end re-read its last page: masked
+            page = table_ref[s * P + jnp.minimum(g * G + j, P - 1)]
+            pltpu.make_async_copy(pool_hbm.at[layer, page],
+                                  buf.at[slot, pl.ds(j * ps, ps)],
+                                  sem.at[slot]).start()
+
+    @pl.when(n_groups > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[0]                                            # [H, W]
+    H = q.shape[0]
+
+    def fold(carry, s_blk, pv):
+        m, l, acc = carry
+        m_new = jnp.maximum(m, jnp.max(s_blk, axis=-1, keepdims=True))
+        p = jnp.where(s_blk <= NEG_INF / 2, 0.0, jnp.exp(s_blk - m_new))
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + pv(p))
+
+    def body(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            start(g + 1, 1 - slot)
+
+        # one wait a buffer: the semaphore counts bytes, and a group's
+        # copies fill exactly this buffer
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sem.at[slot]).wait()
+        kv = buf[slot]                                      # [G * ps, W]
+        s_blk = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s_blk = s_blk + bias_ref[0, pl.ds(g, 1), :]
+        return fold(carry, s_blk, lambda p: jnp.dot(
+            p.astype(kv.dtype), kv[:, :value_width],
+            preferred_element_type=jnp.float32))
+
+    carry = (jnp.full((H, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, value_width), jnp.float32))
+    carry = jax.lax.fori_loop(0, n_groups, body, carry)
+    # the new token's own row, iff the token selected itself; on the VPU
+    new = new_ref[0].astype(jnp.float32)                    # [1, W]
+    s_new = (jnp.sum(q.astype(jnp.float32) * new, axis=-1, keepdims=True)
+             * sm_scale + jnp.where(self_ref[s] > 0, 0.0, NEG_INF))
+    _, l, acc = fold(carry, s_new, lambda p: p * new[:, :value_width])
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def sparse_latent_paged_decode_attention(q, new_row, pool, layer,
+                                         meta: PagedDecodeMeta, selected, *,
+                                         value_width: int, sm_scale: float,
+                                         interpret: bool | None = None):
+    """One decode step of absorbed latent attention over SELECTED rows for
+    every slot. q [S, H, W]: each head's absorbed query, laid out like a
+    pool row; new_row [S, W]: this step's latent row (in the pool's dtype;
+    what the engine appends afterwards); pool [L, pages + 1, page_size,
+    W], the whole stacked latent pool; layer: int32 scalar; `selected`
+    [S, R] bool over a slot's positions (R = pages_per_slot x page_size),
+    the query's own position included: a cached position counts iff it is
+    below the slot's length. Returns o_lat [S, H, value_width] in q's
+    dtype, as `latent_paged_decode_attention` does."""
+    S, H, W = q.shape
+    L, _, ps, Wp = pool.shape
+    if Wp != W or new_row.shape != (S, W) or W % 128 or value_width % 128 \
+            or value_width > W or ps > 31:
+        raise ValueError(
+            "sparse latent decode attention is one absorbed query a head "
+            "and slot over the whole stacked latent pool [L, pages + 1, "
+            "page_size, W] of whole 128-lane tiles; got q "
+            f"{q.shape}, new_row {new_row.shape}, pool {pool.shape}, "
+            f"value_width {value_width}")
+    interpret = kernel_mode.resolve_interpret(LATENT_ATTENTION_KERNEL_NAME,
+                                              interpret)
+    P = meta.table.shape[1]
+    G = max(1, min(LATENT_PAGES_PER_GROUP, P))
+    n_groups = -(-P // G)
+    rows = G * ps
+    table, count, own, bias = _compacted_walk(selected, meta, ps, G)
+    kernel = functools.partial(
+        _sparse_latent_kernel, sm_scale=float(sm_scale), page_size=ps,
+        pages_per_slot=P, pages_per_group=G, value_width=value_width)
+    per_slot = lambda s, *_: (s, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((S, H, value_width), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, H, W), per_slot),
+                      pl.BlockSpec((1, 1, W), per_slot),
+                      pl.BlockSpec((1, n_groups, rows), per_slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, value_width), per_slot),
+            scratch_shapes=[pltpu.VMEM((2, rows, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=LATENT_ATTENTION_KERNEL_NAME,
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(table.reshape(-1), count, jnp.asarray(layer, jnp.int32).reshape(1),
+      own.astype(jnp.int32), q.astype(pool.dtype), new_row[:, None, :], bias,
+      pool)
+
+
+def sparse_latent_paged_decode_reference(q, new_row, pool, layer,
+                                         meta: PagedDecodeMeta, selected, *,
+                                         value_width: int, sm_scale: float):
+    """The same by a plain gather of every table page: the new token's row
+    as one more key at position == length, a float32 softmax over the
+    selected positions alone."""
+    S, H, W = q.shape
+    R = meta.table.shape[1] * pool.shape[2]
+    rows = pool[layer][meta.table].reshape(S, R, W).astype(jnp.float32)
+    pos = jnp.arange(R, dtype=jnp.int32)[None, :]
+    at_self = pos == meta.lengths[:, None]
+    rows = jnp.where(at_self[:, :, None],
+                     new_row.astype(jnp.float32)[:, None, :], rows)
+    keep = selected & (pos <= meta.lengths[:, None])
+    s = jnp.einsum("shw,srw->shr", q.astype(pool.dtype).astype(jnp.float32),
+                   rows) * sm_scale
+    s = jnp.where(keep[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("shr,srw->shw", p,
+                      rows[:, :, :value_width]).astype(q.dtype)

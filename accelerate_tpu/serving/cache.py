@@ -301,9 +301,9 @@ class PagedKVCache:
     chip, twice its bytes, while this one is a whole (8, 128)(2, 1) tile
     of bf16 and a page is still indexed outside the tile. So w divides 128
     and page_size x w is a whole number of such rows, or `create` raises.
-    Views carry the side rows as one head, [L, B, R, 1, w], beside K's in a
-    `WithSide`; int8 codes, a latent pool and a ring are not implemented
-    with it.
+    Views carry the side rows as one head, [L, B, R, 1, w], beside K's (or
+    the latent rows) in a `WithSide`; int8 codes and a ring are not
+    implemented with it.
 
     `stats`: a family's own device counters (`family.init_serving_stats`),
     or None. They ride here because the cache is what both engine
@@ -355,16 +355,16 @@ class PagedKVCache:
                 "an int8 latent pool is not implemented: kv_dtype='int8' "
                 "quantizes K and V rows per head, and a latent row's key "
                 "and value parts would need scales of their own")
-        if window is not None and (latent or quantized or window < 1):
+        if window is not None and (quantized or window < 1):
             raise ValueError(
-                "a ring of pages (window=) holds K/V rows in `dtype`: a "
-                "latent or int8 ring is not implemented, and a window is "
-                f"at least 1; got window={window}")
-        if side_width and (latent or quantized or window is not None):
+                "a ring of pages (window=) holds rows in `dtype`: an int8 "
+                "ring is not implemented, and a window is at least 1; got "
+                f"window={window}")
+        if side_width and (quantized or window is not None):
             raise ValueError(
-                "a side row (side_width=) lives beside K/V rows in `dtype` "
-                "under every position: with a latent pool, int8 codes or a "
-                "ring it is not implemented")
+                "a side row (side_width=) lives beside rows in `dtype` "
+                "under every position: with int8 codes or inside a ring it "
+                "is not implemented")
         if side_width and (128 % side_width or page_size * side_width % 128):
             raise ValueError(
                 "a page's side rows are stored as whole 128-lane rows: "
@@ -1004,9 +1004,11 @@ def ring_positions(rows: int, last):
 class GroupedPagedCache:
     """The cache of a family whose layers differ in kind: one
     `PagedKVCache` a GROUP (`CacheSpec`), each with its own stacked pool
-    pair, its own page shape and its retention rule. `groups[0]` keeps
-    every position (`window` None); a further group keeps a window, as a
-    ring of pages a slot. `layers[g]` are the model's layers group g
+    pair (or its one latent pool: the groups are all K/V or all latent,
+    each of its own row width), its own page shape and its retention
+    rule. `groups[0]` keeps every position (`window` None) and may carry a
+    side row; a further group keeps a window, as a ring of pages a slot.
+    `layers[g]` are the model's layers group g
     holds, in order. Every group carries the slots' lengths (they advance
     together through the one `_scatter_rows`); the family's counters ride
     the first. What reads ONE pool's books (`num_pages`, `page_nbytes`,
@@ -1029,16 +1031,21 @@ class GroupedPagedCache:
                 "the first group of a grouped cache keeps every position "
                 "and every further one a window; got windows "
                 f"{[s.window for s in specs]}")
-        if any(s.kind != "kv" or s.layers is None for s in specs):
+        kinds = {s.kind for s in specs}
+        if (len(kinds) != 1 or not kinds <= {"kv", "latent"}
+                or any(s.layers is None for s in specs)):
             raise ValueError(
-                "every group of a grouped cache holds K/V rows "
-                "(kind='kv') and names its layers")
+                "the groups of a grouped cache are of ONE kind, K/V rows "
+                "(kind='kv') or latent rows (kind='latent'; each group its "
+                "own width), and name their layers; got kinds "
+                f"{sorted(kinds)}")
         return cls(
             groups=tuple(PagedKVCache.create(
                 s.num_layers, num_slots, max_len, s.heads, s.width,
                 dtype=dtype, page_size=page_size, pad_slack=pad_slack,
                 num_pages=num_pages if g == 0 else None,
-                stats=stats if g == 0 else None, window=s.window)
+                stats=stats if g == 0 else None, window=s.window,
+                latent=s.kind == "latent", side_width=s.side_width)
                 for g, s in enumerate(specs)),
             layers=tuple(tuple(s.layers) for s in specs))
 

@@ -371,6 +371,8 @@ def _cache_spec(config, family=None):
 # yet, by the trait of the cache a family declares (`cache_spec`): what the
 # error calls the trait's fallback, then for each option what porting it
 # would take. ROADMAP M3 (latent), M2 (grouped), M8 (side), M4 (state).
+# A cache with several traits (latent groups, the first with a side row)
+# is refused by each trait in turn: the traits compose, the options do not.
 _OPTIONS = {
     "prefix_cache=True": lambda ec: ec.prefix_cache,
     "kv_dtype='int8'": lambda ec: ec.kv_dtype is not None,
@@ -438,18 +440,20 @@ def _refuse_unported(ec: "EngineConfig", spec: CacheSpec, groups) -> None:
     if spec.kind == "state":
         traits.append(("state", "this family keeps one recurrent state a "
                        "sequence and no K/V rows (CacheSpec.kind='state')"))
-    if spec.side_width:
+    sides = [g.side_width for g in (groups or (spec,)) if g.side_width]
+    if sides:
         traits.append(("side", "this family caches a side row a token "
-                       f"beside K and V (CacheSpec.side_width="
-                       f"{spec.side_width}: an indexer's key, which chooses "
+                       f"beside its rows (CacheSpec.side_width="
+                       f"{sides[0]}: an indexer's key, which chooses "
                        "the keys attention reads)"))
     for trait, said in traits:
         instead, options = _UNPORTED[trait]
         unported = [f"{option} ({takes})" for option, takes in options.items()
                     if _OPTIONS[option](ec)]
-        if trait == "side" and groups is not None:
-            unported.append("layers that differ in kind (a side row in a "
-                            "ring of pages)")
+        if trait == "side" and any(g.side_width for g in (groups or ())[1:]):
+            unported.append("layers that differ in kind with a side row "
+                            "INSIDE a ring group (a side row in a ring of "
+                            "pages)")
         if unported:
             raise ValueError(
                 f"{said}, which is not implemented together with: "
@@ -998,9 +1002,10 @@ class Engine:
                 # the K/V kernel's work follows the lengths it is given:
                 # a retired or mid-prefill lane (a stale length; its
                 # result is discarded below) walks no page at length 0.
-                # The latent kernel's program stays as it was (PR 26)
+                # The one-pool latent kernel's program stays as it was
+                # (PR 26); latent GROUPS' kernels honour `live`
                 with part("cache.view"):
-                    walked = (lengths if latent
+                    walked = (lengths if latent and not grouped
                               else jnp.where(live, lengths, 0))
                 kvc = (pools(cache, "k"),
                        None if latent else pools(cache, "v"),

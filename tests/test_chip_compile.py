@@ -33,6 +33,7 @@ in `parametrize` or in `conftest.py`; the compiles run in the test's own
 process; and all of them live in this one file.
 """
 
+import functools
 import os
 import re
 
@@ -296,26 +297,34 @@ def test_decode_does_not_know_the_pool_size_on_v5e(one_chip, chip_compile):
     assert seen[4096][0] < 8 << 20, seen
 
 
-@pytest.mark.parametrize("rows,experts,k,n", [
-    (384, 64, 2304, 896), (384, 64, 896, 2304),
-    (128, 256, 2048, 768), (128, 256, 768, 2048),
-], ids=["mellum-gate", "mellum-down", "joyai-gate", "joyai-down"])
+@pytest.mark.parametrize("rows,experts,k,n,lane_tile", [
+    (384, 64, 2304, 896, None), (384, 64, 896, 2304, None),
+    (128, 256, 2048, 768, None), (128, 256, 768, 2048, None),
+    (1024, 32, 5120, 1536, 768), (1024, 32, 1536, 5120, 2560),
+], ids=["mellum-gate", "mellum-down", "joyai-gate", "joyai-down",
+        "share-chunk-gate", "share-chunk-down"])
 def test_rows_kernel_compiles_for_v5e(one_chip, chip_compile, rows, experts,
-                                      k, n):
+                                      k, n, lane_tile):
     """The grouped product for few rows an expert at the decode shapes of
-    both expert cells: it compiles as ONE kernel under its own name (what
-    a device trace shows of it, and what `%ragged-dot` still finds), reads
-    the stacked `[E, K, N]` matrices as they are (nothing of their shape
-    is produced) and holds no temporary beyond its scalars."""
+    both expert cells, and for a pass of a share's held rows at the dots3
+    cell's chunk (15.7 MB matrices in blocks of lanes): it compiles as ONE
+    kernel under its own name (what a device trace shows of it, and what
+    `%ragged-dot` still finds), reads the stacked `[E, K, N]` matrices as
+    they are (nothing of their shape is produced) and holds no temporary
+    beyond its scalars."""
     from accelerate_tpu.ops.grouped_experts import (
         ROWS_KERNEL_NAME,
+        _lane_tile,
         grouped_rows_matmul,
     )
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(grouped_rows_matmul).lower(
+    if lane_tile is not None:
+        assert _lane_tile(k, n, jnp.bfloat16) == lane_tile
+    compiled = jax.jit(
+        functools.partial(grouped_rows_matmul, lane_tile=lane_tile)).lower(
         sds((rows, k), jnp.bfloat16), sds((experts, k, n), jnp.bfloat16),
         sds((experts,), jnp.int32)).compile()
     text = compiled.as_text()
@@ -633,6 +642,116 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
             name, memory.temp_size_in_bytes)
         assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
         assert not re.search(r"\[6,1,43520,", text), name
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
+        print(name, "temp", memory.temp_size_in_bytes, "args",
+              memory.argument_size_in_bytes)
+
+
+def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
+    """`decode` and `prefill` of `serve-dots3-note-16k-in-512-out-closed`
+    (dots3-note-prev widths, 5 layers, 32 of 256 experts and 19,008
+    vocabulary rows held, 16 slots x 43008, page 16, chunk 512, 43,008
+    pages of 640-lane latent rows with a 128-lane index key a token, and a
+    ring of 66 pages of 1,152-lane rows a slot), abstract weights and
+    pools, compiled for the chip:
+
+    (a) `decode` holds, a FULL layer, the indexer-score kernel and the
+        sparse latent attention kernel, and a SLIDING layer the ring mode
+        of the latent kernel, each under its own name, and the selection's
+        loops; `prefill` holds none of the kernels and the same selection;
+    (b) each kernel takes its whole stacked pool: `decode` holds no copy
+        of either pool, nor of a layer's slice of one;
+    (c) both latent pools and the index pool are aliased to their
+        arguments; arguments + temporaries stay under 15.5 GB;
+    (d) a chunk's logits are one row, and `prefill` holds no array of the
+        stacked views' shape: a layer's view is gathered alone;
+    (e) the held experts' products: `ragged-dot-none` in `decode`, the
+        rows kernel over the held rows in `prefill`."""
+    import json
+
+    from accelerate_tpu.models import dots3
+    from accelerate_tpu.ops import latent_paged_attention as latent
+    from accelerate_tpu.ops import sparse_paged_attention as sparse
+    from accelerate_tpu.serving import Engine, EngineConfig
+    from accelerate_tpu.serving.cache import GroupedPagedCache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "dots3-note-prev-d5-ep8.json")) as f:
+        file = json.load(f)
+    cfg = dots3.Dots3Config(**{k: file[k] for k in file["program"]["copy"]},
+                            **file["program"]["extra"])
+    slots, max_len, page, chunk, num_pages = 16, 43008, 16, 512, 43008
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    # (the router's float32 bias: 4 x 256 x 2 bytes over bf16)
+    assert weights == 2 * file["parameters"] + 4 * 256 * 2
+    engine = Engine(dots3, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=(max_len + chunk) // page,
+        prefix_cache=False, paged_attention=True))
+    small = engine.cache
+    cache = on_chip(jax.eval_shape(lambda: GroupedPagedCache.create(
+        dots3.cache_spec(cfg), slots, max_len, page_size=page,
+        pad_slack=small.pad_slack, num_pages=num_pages, stats=small.stats)))
+    full, ring = cache.groups
+    assert full.k.shape == (2, 43009, 1, 16, 640) and full.v is None
+    assert full.side.shape == (2, 43009, 16, 128)
+    assert ring.k.shape == (3, 16 * 66 + 1, 1, 16, 1152) and ring.v is None
+    assert (full.pages_per_slot, ring.pages_per_slot) == (2720, 66)
+    assert cache.page_nbytes == 16 * 2 * (1280 + 256)
+    pool_bytes = 2 * (full.k.size + full.side.size + ring.k.size)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (params, cache, arg((slots,), jnp.int32),
+             arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+             arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, state + (
+            arg((slots,), jnp.bool_),
+            (arg((slots, 2720), jnp.int32), arg((slots, 66), jnp.int32)))),
+        "prefill": (engine._prefill_p, state + (
+            arg((), jnp.int32),
+            (arg((2720,), jnp.int32), arg((66,), jnp.int32)),
+            arg((chunk,), jnp.int32), arg((), jnp.int32))),
+    }
+    for name, (program, args) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
+                 for n in (sparse.SCORES_KERNEL_NAME,
+                           sparse.LATENT_ATTENTION_KERNEL_NAME,
+                           latent.WINDOW_KERNEL_NAME)]
+        assert calls == ([2, 2, 3] if name == "decode" else [0, 0, 0]), (
+            name, calls)
+        # (e) the four expert layers' products: a decode step's few rows
+        # over 15.7 MB matrices are XLA's; a chunk's HELD rows go through
+        # the rows kernel in blocks of lanes, and XLA's kernel is gone
+        assert _grouped_products(text) == (
+            (12, 0) if name == "decode" else (0, 12)), name
+        assert len(re.findall(r" conditional\(", text)) == 2, name
+        # no copy of a pool, nor of a layer's slice of one
+        for pool in (full.k, full.side, ring.k):
+            for shape in (pool.shape, pool.shape[1:]):
+                found = _ops_of_shape(text, "bf16", shape)
+                assert set(found) <= {"scatter", "fusion"} and (
+                    len(shape) == pool.ndim or not found), (name, shape, found)
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes)
+        assert total < 15.5e9, (name, total)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        assert not re.search(r"\[[23],1,43520,", text), name
+        assert not re.search(r"\[3,1,1056,", text), name
         _assert_host_output_is_its_own(
             program, args, text, (slots,) if name == "decode" else ())
         print(name, "temp", memory.temp_size_in_bytes, "args",

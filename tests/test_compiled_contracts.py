@@ -470,26 +470,98 @@ class TestPrefillViewStructure:
                 + re.findall(rf"f32\[{layers},1,{rows},[0-9,]*\]",
                              lowered.compile().as_text()))
 
-    @pytest.mark.parametrize("name", ["keye", "deepseek", "mellum"])
+    @pytest.mark.parametrize("name", ["keye", "deepseek", "mellum", "dots3"])
     def test_looping_families_hold_no_stacked_view(self, name):
-        from accelerate_tpu.models import deepseek, keye, mellum
+        from accelerate_tpu.models import deepseek, dots3, keye, mellum
 
         family, cfg, engine = {
             "keye": (keye, keye.KeyeConfig.tiny(), {}),
             "deepseek": (deepseek, deepseek.DeepseekConfig.tiny(), {}),
             "mellum": (mellum, mellum.MellumConfig.tiny(num_hidden_layers=4),
                        dict(prefix_cache=False)),
+            "dots3": (dots3, dots3.Dots3Config.tiny(),
+                      dict(prefix_cache=False)),
         }[name]
         lowered, groups = self._prefill(family, cfg, **engine)
-        assert len(groups) == (2 if name == "mellum" else 1)
+        assert len(groups) == (2 if name in ("mellum", "dots3") else 1)
         for layers, rows in groups:
-            assert rows == 56 or (name == "mellum" and rows == 48)
+            assert rows == 56 or (name == "mellum" and rows == 48) or (
+                name == "dots3" and rows == 32)
             assert self._stacked(lowered, layers, rows) == []
 
     def test_llama_takes_the_stacked_views(self):
         cfg = llama.LlamaConfig.tiny()
         lowered, [(layers, rows)] = self._prefill(llama, cfg)
         assert len(self._stacked(lowered, layers, rows)) >= 4
+
+
+class TestLatentGroupsInPlace:
+    """The programs of a family whose cache is latent GROUPS (the full
+    layers' pool with its index keys beside it, the sliding layers' ring:
+    `models/dots3.py`) update all three pool arrays in place: each is
+    aliased, argument to result, in `prefill` and `decode`; and under the
+    kernels every Pallas call of `decode` is handed its WHOLE stacked pool
+    (2.2 GB in the benchmark's cell: nothing slices a layer out; that the
+    chip's compiler then copies neither pool is held in
+    `tests/test_chip_compile.py`)."""
+
+    @staticmethod
+    def _programs(kernel):
+        from accelerate_tpu.models import dots3
+        from accelerate_tpu.serving import Engine, EngineConfig
+
+        cfg = dots3.Dots3Config.tiny(experts_held=(0, 4))
+        eng = Engine(dots3, cfg, dots3.init_params(cfg, jax.random.key(0)),
+                     EngineConfig(num_slots=2, max_len=48, prefill_chunk=16,
+                                  page_size=16, cache_dtype=jnp.float32,
+                                  prefix_cache=False, paged_attention=kernel))
+        state = (eng.params, eng.cache, eng._tokens, eng._slot_keys,
+                 eng._temps)
+        return eng, {
+            "prefill": (eng._prefill_p, state + (
+                jnp.int32(0), eng._tables(0), np.zeros((16,), np.int32),
+                jnp.int32(16))),
+            "decode": (eng._decode_p, state + (
+                np.ones((2,), bool), eng._tables())),
+        }
+
+    @pytest.mark.parametrize("program", ["prefill", "decode"])
+    def test_both_pools_and_the_index_keys_are_aliased(self, program):
+        eng, programs = self._programs(kernel=False)
+        fn, args = programs[program]
+        full, ring = eng.cache.groups
+        assert full.v is None and ring.v is None
+        text = fn.lower(*args).compile().as_text()
+        aliases = re.search(r"input_output_alias=\{[^\n]*?\}, entry",
+                            text).group(0)
+        flat = jax.tree.leaves(args)
+        for array in (full.k, full.side, ring.k):
+            at = next(i for i, leaf in enumerate(flat) if leaf is array)
+            assert re.search(rf"\({at}, \{{\}}", aliases), (program, at)
+
+    def test_the_kernels_take_their_whole_stacked_pools(self):
+        eng, programs = self._programs(kernel=True)
+        fn, args = programs["decode"]
+        full, ring = eng.cache.groups
+        calls = [eqn for eqn in _all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                 if eqn.primitive.name == "pallas_call"]
+        # a full layer: the scores and the sparse latent kernel; a sliding
+        # layer: the ring kernel
+        assert len(calls) == 2 * 2 + 2
+        took = [tuple(v.aval.shape for v in eqn.invars) for eqn in calls]
+
+        def folded(pool):   # the unit head axis folds away
+            return tuple(d for i, d in enumerate(pool.shape) if i != 2)
+
+        assert sum(folded(full.k) in t for t in took) == 2
+        assert sum(full.side.shape in t for t in took) == 2
+        assert sum(folded(ring.k) in t for t in took) == 2
+        # and nothing of ONE layer's slice of a pool is made around them
+        shapes = {v.aval.shape for eqn in _all_eqns(
+            jax.make_jaxpr(fn)(*args).jaxpr) for v in eqn.outvars}
+        for pool in (full.k, full.side, ring.k):
+            assert folded(pool)[1:] not in shapes
+            assert pool.shape[1:] not in shapes
 
 
 class TestStatePoolInPlace:

@@ -88,6 +88,27 @@ def test_rows_kernel_windows(row_tile):
     np.testing.assert_allclose(got, _loop(x, w, sizes), atol=1e-4, rtol=1e-5)
 
 
+@pytest.mark.parametrize("lane_tile", [128, 256])
+def test_rows_kernel_in_blocks_of_lanes(lane_tile):
+    """Under `lane_tile` a step holds a [K, lane_tile] block of an
+    expert's matrix and the groups are walked once a block: the same
+    product, and rows behind the last group come back 0."""
+    x, w, sizes = _operands([5, 0, 9, 1, 22, 0, 40], jnp.bfloat16, n=256)
+    x = jnp.concatenate([x, jnp.ones((19, x.shape[1]), x.dtype)])
+    got = np.asarray(grouped_rows_matmul(x, w, sizes, row_tile=32,
+                                         lane_tile=lane_tile, interpret=True))
+    np.testing.assert_allclose(got[:77], _loop(x[:77], w, sizes), atol=1e-4,
+                               rtol=1e-5)
+    assert np.abs(got[77:]).max() == 0.0
+
+
+def test_rows_kernel_refuses_a_block_off_the_lane_tile():
+    x, w, sizes = _operands([4, 4], jnp.float32, n=256)
+    for bad in (64, 384):
+        with pytest.raises(ValueError, match="lane"):
+            grouped_rows_matmul(x, w, sizes, lane_tile=bad, interpret=True)
+
+
 def test_rows_kernel_masks_other_groups_rows():
     """A window holds rows of the groups beside it; they must not reach
     the sums even where they are not finite."""

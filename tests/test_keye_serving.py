@@ -205,7 +205,7 @@ def test_the_side_row_lies_in_whole_lane_rows():
                 dict(page_size=1, side_width=64)):   # half a row a page
         with pytest.raises(ValueError, match="whole 128-lane rows"):
             PagedKVCache.create(2, 2, 16, 2, 8, num_pages=8, **odd)
-    for bad in (dict(kv_dtype="int8"), dict(latent=True), dict(window=8)):
+    for bad in (dict(kv_dtype="int8"), dict(window=8)):
         with pytest.raises(ValueError, match="side row"):
             PagedKVCache.create(2, 2, 16, 2, 8, page_size=16, side_width=8,
                                 **bad)

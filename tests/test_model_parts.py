@@ -18,6 +18,7 @@ from accelerate_tpu.models import (
     brumby,
     common,
     deepseek,
+    dots3,
     keye,
     llama,
     mellum,
@@ -46,6 +47,9 @@ FAMILIES = {
     "brumby": (brumby, lambda: brumby.BrumbyConfig.tiny(head_dim=16), dict(
         num_slots=2, max_len=64, prefill_chunk=16, num_pages=2,
         prefix_cache=False)),
+    "dots3": (dots3, lambda: dots3.Dots3Config.tiny(experts_held=(2, 4)),
+              dict(num_slots=2, max_len=64, prefill_chunk=16, page_size=16,
+                   prefix_cache=False)),
 }
 
 
@@ -166,8 +170,10 @@ def test_every_heavy_operation_of_an_engine_program_has_a_part(
     assert {"attn.project", "attn.attend", "attn.output", "head"} <= parts
     assert ({"mlp"} if family in ("llama", "brumby")
             else {"moe.experts"}) <= parts
-    if family == "keye":
+    if family in ("keye", "dots3"):
         assert {"attn.indexer", "attn.select"} <= parts
+    if family == "dots3":   # a dense first layer AND expert layers
+        assert {"mlp", "moe.shared"} <= parts
 
 
 def _train_step():
