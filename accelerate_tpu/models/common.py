@@ -306,51 +306,36 @@ def dot_product_attention(
 
 
 def blocked_attention(q, q_pos, k_view, v_view, key_pos, window, block,
-                      lo=None, hi=None, select=None, expand=None):
+                      lo=None, hi=None, select=None):
     """Causal attention of q [B, S, H, D] at positions `q_pos` [B, S] over
     keys `k_view` / `v_view` [B, R, Hkv, D] at positions `key_pos` [B, R]
     (negative: nothing there), `block` rows at a time in an online
     softmax; a `window` drops keys with `q - key >= window`, and `select`
     [B, S, R] bool (a learned selection) every key a query did not
     choose. Only blocks [lo, hi) are visited (all of them by default): the
-    `[H, S, R]` scores never exist whole. Returns [B, S, H, D].
-
-    `expand`: the view holds COMPRESSED rows (`k_view` [B, R, 1, W];
-    `v_view` None) and `expand(rows [B, block, 1, W]) -> (k [B, block,
-    Hkv, D], v [B, block, Hkv, Dv])` gives a block's keys and values where
-    the block is attended, and nowhere else (latent attention,
-    decompressed a block at a time). Returns [B, S, H, Dv]."""
+    `[H, S, R]` scores never exist whole. Returns [B, S, H, D]. (A view of
+    LATENT rows is attended by `ops/latent_chunk_attention.py`, which
+    decompresses a block where it is attended, in one kernel.)"""
     B, S, H, D = q.shape
     R = k_view.shape[1]
     blk = min(block, R)
     if R % blk:
         pad = blk - R % blk
-        k_view, v_view = (
-            None if a is None
-            else jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            for a in (k_view, v_view))
+        k_view, v_view = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for a in (k_view, v_view))
         key_pos = jnp.pad(key_pos, ((0, 0), (0, pad)), constant_values=-1)
         if select is not None:
             select = jnp.pad(select, ((0, 0), (0, 0), (0, pad)))
     n_blocks = k_view.shape[1] // blk
-    if expand is None:
-        Hkv, Dv = k_view.shape[2], D
-    else:
-        Hkv, Dv = jax.eval_shape(
-            expand, jax.ShapeDtypeStruct(
-                (B, blk) + k_view.shape[2:], q.dtype))[1].shape[2:]
+    Hkv = k_view.shape[2]
     q5 = q.reshape(B, S, Hkv, H // Hkv, D)
     scale = 1.0 / math.sqrt(D)
     at = q_pos[:, None, None, :, None]
 
     def body(i, carry):
         m, l, acc = carry
-        if expand is None:
-            kb, vb = (jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis=1)
-                      .astype(q.dtype) for a in (k_view, v_view))
-        else:
-            kb, vb = expand(jax.lax.dynamic_slice_in_dim(
-                k_view, i * blk, blk, axis=1).astype(q.dtype))
+        kb, vb = (jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis=1)
+                  .astype(q.dtype) for a in (k_view, v_view))
         pb = jax.lax.dynamic_slice_in_dim(
             key_pos, i * blk, blk, axis=1)[:, None, None, None, :]
         s = jnp.einsum("bskgd,brkd->bkgsr", q5, kb,
@@ -373,12 +358,12 @@ def blocked_attention(q, q_pos, k_view, v_view, key_pos, window, block,
     shape = (B, Hkv, H // Hkv, S)
     carry = (jnp.full(shape + (1,), NEG_INF, jnp.float32),
              jnp.zeros(shape + (1,), jnp.float32),
-             jnp.zeros(shape + (Dv,), jnp.float32))
+             jnp.zeros(shape + (D,), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0 if lo is None else lo,
                                   n_blocks if hi is None else hi,
                                   body, carry)
-    out = acc / jnp.maximum(l, 1e-30)                   # [B, Hkv, G, S, Dv]
-    return jnp.moveaxis(out, 3, 1).reshape(B, S, H, Dv).astype(q.dtype)
+    out = acc / jnp.maximum(l, 1e-30)                   # [B, Hkv, G, S, D]
+    return jnp.moveaxis(out, 3, 1).reshape(B, S, H, D).astype(q.dtype)
 
 
 @part("cache.write")

@@ -12,11 +12,13 @@ What is cached for a token is ONE row `[c_kv | k_pe | 0]` (`kv_lora_rank +
 qk_rope_head_dim` numbers, zero-padded to whole 128-lane tiles), never a
 decompressed K or V. Two forms of the same mathematics read it:
 
-- decompressed, blocked over the cached rows (prefill chunks, the
-  cache-free forward): a block of rows is expanded through `W_kvb`,
-  scored, folded into an online softmax, dropped. The `[H, chunk, rows]`
-  scores never exist whole, and blocks past the last query are not
-  visited;
+- decompressed (prefill chunks, the cache-free forward): ONE Pallas kernel
+  a layer, `latent_chunk_attention` (`ops/latent_chunk_attention.py`;
+  interpreted where there is no TPU): a tile of rows is expanded through
+  `W_kvb`, scored and folded into an online softmax in vector memory,
+  dropped. The `[H, chunk, rows]` scores never exist, not even a tile's
+  outside the kernel, and tiles past the last query are neither read nor
+  computed;
 - absorbed (decode): `q_lat_h = q_nope_h W_UK_h^T`, `score = q_lat_h .
   c_kv + q_pe_h . k_pe`, `o_h = (P c_kv) W_UV_h`: H query heads over one
   shared key row whose first `kv_lora_rank` lanes are also the value. On
@@ -54,6 +56,7 @@ from ..ops.grouped_experts import (
     grouped_swiglu_experts,
     sigmoid_topk_route,
 )
+from ..ops.latent_chunk_attention import latent_chunk_attention
 from .common import dense, normal_init, part, rms_norm, rope_frequencies
 from .decode import build_generate, layer_view, rope_table_len
 
@@ -88,8 +91,6 @@ class DeepseekConfig:
     rope_theta: float = 32e6
     rope_interleave: bool = True
     rms_norm_eps: float = 1e-6
-    # cached rows expanded at a time by the decompressed form
-    kv_block: int = 1024
 
     def __post_init__(self):
         if self.scoring_func != "sigmoid" or self.topk_method != "noaux_tc":
@@ -129,7 +130,7 @@ class DeepseekConfig:
             num_attention_heads=4, q_lora_rank=48, kv_lora_rank=128,
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             n_routed_experts=8, num_experts_per_tok=2,
-            max_position_embeddings=128, kv_block=16)
+            max_position_embeddings=128)
         defaults.update(overrides)
         return cls(**defaults)
 
@@ -232,48 +233,14 @@ def _kv_b(config, a, dtype):
 def _decompressed_attention(config, a, q_nope, q_pe, view, positions):
     """Causal attention of q [B, S, H, *] at `positions` [B, S] over the
     latent rows `view` [B, R, W] (row r is position r), K and V expanded
-    from the rows a block at a time; returns [B, S, H, v]."""
-    c = config
-    B, S, H, _ = q_nope.shape
-    R = view.shape[1]
-    blk = min(c.kv_block, R)
-    if R % blk:
-        view = jnp.pad(view, ((0, 0), (0, blk - R % blk), (0, 0)))
-    kv_b = _kv_b(c, a, q_nope.dtype)
-    scale = 1.0 / math.sqrt(c.qk_head_dim)
-    # blocks that hold a position some query may see
-    n_blocks = jnp.max(positions) // blk + 1
-
-    def body(i, carry):
-        m, l, acc = carry
-        rows = jax.lax.dynamic_slice_in_dim(view, i * blk, blk, axis=1)
-        rows = rows.astype(q_nope.dtype)
-        kv = jnp.einsum("brc,chd->brhd", rows[..., :c.kv_lora_rank], kv_b,
-                        preferred_element_type=jnp.float32
-                        ).astype(q_nope.dtype)
-        k_pe = rows[..., c.kv_lora_rank:c.latent_width]
-        s = (jnp.einsum("bshd,brhd->bhsr", q_nope,
-                        kv[..., :c.qk_nope_head_dim],
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bshd,brd->bhsr", q_pe, k_pe,
-                          preferred_element_type=jnp.float32)) * scale
-        key_pos = i * blk + jnp.arange(blk, dtype=jnp.int32)
-        see = key_pos[None, None, None, :] <= positions[:, None, :, None]
-        s = jnp.where(see, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(see, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        pv = jnp.einsum("bhsr,brhd->bhsd", p.astype(q_nope.dtype),
-                        kv[..., c.qk_nope_head_dim:],
-                        preferred_element_type=jnp.float32)
-        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
-                acc * alpha + pv)
-
-    carry = (jnp.full((B, H, S, 1), NEG_INF, jnp.float32),
-             jnp.zeros((B, H, S, 1), jnp.float32),
-             jnp.zeros((B, H, S, c.v_head_dim), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, carry)
-    return jnp.swapaxes(acc / l, 1, 2).astype(q_nope.dtype)
+    from the rows a tile at a time where they are attended, in ONE kernel
+    (`ops/latent_chunk_attention.py`) over the tiles up to the last
+    query's; returns [B, S, H, v]."""
+    key_pos = jnp.broadcast_to(
+        jnp.arange(view.shape[1], dtype=jnp.int32)[None], view.shape[:2])
+    return latent_chunk_attention(
+        q_nope, q_pe, positions, view, key_pos,
+        _kv_b(config, a, q_nope.dtype), live=(0, jnp.max(positions) + 1))
 
 
 def _absorb_query(config, a, q_nope, q_pe):

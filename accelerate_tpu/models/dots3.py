@@ -47,12 +47,16 @@ forms of the same mathematics:
 - no cache: every query over the sequence's own rows;
 - views (`kv_caches = (rows a group, (None, None), cache_len)`, the first
   group's in a `WithSide` with its index keys: the engine's prefill chunks
-  and its dense decode, and `generate`): this call's rows written, then
-  `common.blocked_attention` over the view, a block of rows DECOMPRESSED
-  through `W_kvb` where it is attended (at a chunk's 512 queries the
-  absorbed form costs 2.2 times the operations and read 24% slower on the
-  chip: `PERF.md` section 6, PR 43), masked by the selection on a full
-  layer and by position on a sliding one;
+  and its dense decode, and `generate`): this call's rows written, then the
+  view attended in the EXPANDED form (at a chunk's 512 queries the absorbed
+  form costs 2.2 times the operations and read 24% slower on the chip:
+  `PERF.md` section 6, PR 43), masked by the selection on a full layer and
+  by position on a sliding one.
+  Both of these are ONE Pallas kernel a layer, `latent_chunk_attention`
+  (`ops/latent_chunk_attention.py`; interpreted where there is no TPU): a
+  tile of rows is decompressed through `W_kvb`, scored, masked and folded
+  into an online softmax in vector memory, over the tiles that may hold a
+  visible key and no others;
 - the paged pools (`PagedKV` a group, `PagedDecodeMeta` with a table a
   group): one token a slot in ABSORBED form, a full layer through
   `indexer_paged_scores`, `exact_topk_mask` and
@@ -75,6 +79,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..ops.latent_chunk_attention import latent_chunk_attention
 from ..ops.sparse_paged_attention import (
     exact_topk_mask,
     indexer_paged_scores,
@@ -82,7 +87,6 @@ from ..ops.sparse_paged_attention import (
 )
 from .common import (
     add_wide,
-    blocked_attention,
     dense,
     layer_norm,
     normal_init,
@@ -162,7 +166,7 @@ class Dots3Config:
     tie_word_embeddings: bool = False
     max_position_embeddings: int = 524288
     rms_norm_eps: float = 1e-5
-    # view rows attended, and scored, at a time
+    # view rows the indexer scores at a time (`keye.view_index_scores`)
     kv_block: int = 1024
 
     def __post_init__(self):
@@ -393,29 +397,16 @@ def _index_inputs(config, ix, x, c_q, cos, sin, positions):
     return qI, kI, wts
 
 
-def _attend_view(config, m, a, q_nope, q_pe, positions, view, key_pos, window,
-                 lo, hi, select):
+def _attend_view(m, a, q_nope, q_pe, positions, view, key_pos, window, live,
+                 select):
     """The queries over a view of latent rows [B, R, 1, W] at positions
-    `key_pos` [B, R] -> [B, S, H, v], a block of rows at a time, each
-    decompressed through `W_kvb` where it is attended
-    (`common.blocked_attention`)."""
-    nope, kvr = m.qk_nope_head_dim, m.kv_lora_rank
-    kv_b = _kv_b(m, a, q_nope.dtype)
-    H = m.num_attention_heads
-
-    def expand(rows):
-        kv = jnp.einsum("brc,chd->brhd", rows[:, :, 0, :kvr], kv_b,
-                        preferred_element_type=jnp.float32).astype(rows.dtype)
-        k_pe = jnp.broadcast_to(
-            rows[:, :, :, kvr:m.latent_width],
-            rows.shape[:2] + (H, m.qk_rope_head_dim))
-        return (jnp.concatenate([kv[..., :nope], k_pe], axis=-1),
-                kv[..., nope:])
-
-    return blocked_attention(
-        jnp.concatenate([q_nope, q_pe], axis=-1), positions, view, None,
-        key_pos, window, config.kv_block, lo, hi, select=select,
-        expand=expand)
+    `key_pos` [B, R] -> [B, S, H, v]: ONE kernel a layer
+    (`ops/latent_chunk_attention.py`), a tile of rows decompressed through
+    `W_kvb`, scored, masked and folded where it is attended, over the
+    tiles that hold a row of `live` alone."""
+    return latent_chunk_attention(
+        q_nope, q_pe, positions, view[:, :, 0], key_pos,
+        _kv_b(m, a, q_nope.dtype), select=select, window=window, live=live)
 
 
 def _attention(config, kind, a, x, rope, positions, cache, token_mask,
@@ -491,7 +482,7 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
             out = _unabsorb_output(m, a, o_lat[:, None].astype(x.dtype))
         new = (new_row[:, None, None, :], new_i)
     else:
-        lo = hi = None
+        live = None
         if cache is None:
             view, view_i, key_pos = row[:, :, None, :], kI if full else None, \
                 positions
@@ -509,17 +500,17 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
                        kI.astype(view_i.dtype) if full else None)
             last = start + S - 1
             key_pos = ring_positions(R, last)
-            blk = min(c.kv_block, R)
-            n_blocks = -(-R // blk)
-            lo = jnp.zeros((), jnp.int32)
-            hi = jnp.minimum(jnp.max(positions) // blk + 1, n_blocks)
+            # the rows that may hold a visible key: rows are positions
+            # until a ring wraps; from then on every row of the (short)
+            # ring may
+            first = jnp.zeros((), jnp.int32)
+            end = jnp.minimum(jnp.max(positions) + 1, R)
             if wraps:
-                # rows are positions until the ring wraps; from then on
-                # every block of the (short) ring may hold a visible key
                 wrapped = jnp.max(last) >= R
-                lo = jnp.where(wrapped, 0, jnp.maximum(
-                    jnp.min(positions) - window + 1, 0) // blk)
-                hi = jnp.where(wrapped, n_blocks, hi)
+                first = jnp.where(wrapped, 0, jnp.maximum(
+                    jnp.min(positions) - window + 1, 0))
+                end = jnp.where(wrapped, R, end)
+            live = (first, end)
         if full:
             with part("attn.indexer"):
                 scores = view_index_scores(c, qI.astype(view_i.dtype), wts,
@@ -528,8 +519,8 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
             with part("attn.select"):
                 select = exact_topk_mask(scores, c.index_topk)  # [B, S, R]
         with part("attn.attend"):
-            out = _attend_view(c, m, a, q_nope, q_pe, positions, view,
-                               key_pos, window, lo, hi, select)
+            out = _attend_view(m, a, q_nope, q_pe, positions, view, key_pos,
+                               window, live, select)
     if full:
         with part("attn.select"):
             counted = (jnp.ones((B, S), bool) if token_mask is None

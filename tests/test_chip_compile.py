@@ -353,7 +353,8 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
 
     (a) the latent paged-attention kernel is in `decode`, once a layer,
         under its own name, and takes the WHOLE stacked pool (nothing of
-        a layer's slice shape is produced around it);
+        a layer's slice shape is produced around it); `prefill` holds the
+        chunk kernel (`latent_chunk_attention`), once a layer;
     (b) the expert products, 3 an expert layer, are `ragged-dot` kernels
         in `prefill` and the rows kernel (`ragged-dot-rows`, which reads
         the stacked matrices as they are held) in `decode`, where no
@@ -417,6 +418,8 @@ def test_latent_engine_programs_compile_for_v5e(one_chip, chip_compile):
         kernels = len(re.findall(
             r"%latent_paged_decode_attention[.\d]* = ", text))
         assert kernels == (5 if name == "decode" else 0), name
+        chunks = len(re.findall(r"%latent_chunk_attention[.\d]* = ", text))
+        assert chunks == (0 if name == "decode" else 5), name
         assert _grouped_products(text) == (
             (0, 12) if name == "decode" else (12, 0)), name
         assert " conditional(" not in text, name
@@ -659,7 +662,9 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
     (a) `decode` holds, a FULL layer, the indexer-score kernel and the
         sparse latent attention kernel, and a SLIDING layer the ring mode
         of the latent kernel, each under its own name, and the selection's
-        loops; `prefill` holds none of the kernels and the same selection;
+        loops; `prefill` holds none of those, the same selection, and ONE
+        chunk kernel a layer (`latent_chunk_attention`, five), with no
+        block's float32 scores `[.., 512, 1024]` beside them;
     (b) each kernel takes its whole stacked pool: `decode` holds no copy
         of either pool, nor of a layer's slice of one;
     (c) both latent pools and the index pool are aliased to their
@@ -671,6 +676,7 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
     import json
 
     from accelerate_tpu.models import dots3
+    from accelerate_tpu.ops import latent_chunk_attention as chunk_kernel
     from accelerate_tpu.ops import latent_paged_attention as latent
     from accelerate_tpu.ops import sparse_paged_attention as sparse
     from accelerate_tpu.serving import Engine, EngineConfig
@@ -731,9 +737,11 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
         calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
                  for n in (sparse.SCORES_KERNEL_NAME,
                            sparse.LATENT_ATTENTION_KERNEL_NAME,
-                           latent.WINDOW_KERNEL_NAME)]
-        assert calls == ([2, 2, 3] if name == "decode" else [0, 0, 0]), (
-            name, calls)
+                           latent.WINDOW_KERNEL_NAME,
+                           chunk_kernel.KERNEL_NAME)]
+        assert calls == ([2, 2, 3, 0] if name == "decode"
+                         else [0, 0, 0, 5]), (name, calls)
+        assert not re.search(r"\[(?:1,)?128,(?:1,)?512,1024\]", text), name
         # (e) the four expert layers' products: a decode step's few rows
         # over 15.7 MB matrices are XLA's; a chunk's HELD rows go through
         # the rows kernel in blocks of lanes, and XLA's kernel is gone
@@ -756,6 +764,48 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
             program, args, text, (slots,) if name == "decode" else ())
         print(name, "temp", memory.temp_size_in_bytes, "args",
               memory.argument_size_in_bytes)
+
+
+@pytest.mark.parametrize("S,heads,nope,rank,width,rows,selected,window", [
+    (512, 128, 128, 512, 640, 43520, True, None),   # dots3, a full layer
+    (512, 64, 192, 1024, 1152, 1056, False, 513),   # dots3, a sliding ring
+    (512, 32, 128, 512, 640, 18432, False, None),   # joyai
+    (1, 128, 128, 512, 640, 43520, True, None),     # a dense decode's token
+], ids=["dots3-full", "dots3-sliding", "joyai", "one-token"])
+def test_latent_chunk_kernel_compiles_for_v5e(one_chip, chip_compile, S,
+                                              heads, nope, rank, width, rows,
+                                              selected, window):
+    """`latent_chunk_attention` alone at the cells' shapes (bf16, a chunk
+    of 512 queries; the last case ONE query, padded to a sublane tile):
+    the chip's compiler takes the tiles `_tiles` computes, the program
+    holds the kernel once, no loop, and no float32 array over a tile of
+    rows (its scores) around it."""
+    from accelerate_tpu.ops import latent_chunk_attention as lca
+
+    bf = jnp.bfloat16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [arg((1, S, heads, nope), bf), arg((1, S, heads, 64), bf),
+            arg((1, S), jnp.int32), arg((1, rows, width), bf),
+            arg((1, rows), jnp.int32), arg((rank, heads, nope + 128), bf),
+            arg((), jnp.int32), arg((), jnp.int32)]
+    if selected:
+        args.append(arg((1, S, rows), jnp.bool_))
+
+    def attend(q_nope, q_pe, q_pos, view, key_pos, w_kvb, first, end,
+               select=None):
+        return lca.latent_chunk_attention(
+            q_nope, q_pe, q_pos, view, key_pos, w_kvb, select=select,
+            window=window, live=(first, end))
+
+    compiled = jax.jit(attend).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall("%" + lca.KERNEL_NAME + r"(?:\.\d+)? = ",
+                          text)) == 1
+    assert not re.search(r"f32\[(?:\d+,){2,}1\d\d\d\]", text)
+    assert " while(" not in text
 
 
 @pytest.mark.parametrize("degree,state_dtype", [

@@ -167,6 +167,22 @@ def test_engine_serves_through_the_latent_paged_cache(params, ids, kernel):
     assert eng.compile_stats() == {"admit": 1, "prefill": 1, "decode": 1}
 
 
+def test_a_chunk_attends_every_layer_in_one_chunk_kernel(params, ids):
+    """The cache-free forward and a chunk over a dense view are one
+    `latent_chunk_attention` call a layer (a single token over a view
+    stays absorbed), interpreted here."""
+    from accelerate_tpu.ops import kernel_mode
+
+    caches = deepseek.init_kv_caches(CFG, 2, 64, jnp.float32)
+    for kv_caches, tokens, calls in ((None, 16, CFG.num_hidden_layers),
+                                     (caches, 16, CFG.num_hidden_layers),
+                                     (caches, 1, 0)):
+        text = str(jax.make_jaxpr(lambda p, i: deepseek.forward(
+            CFG, p, i, kv_caches=kv_caches))(params, ids[:, :tokens]))
+        assert text.count("name=latent_chunk_attention") == calls
+    assert kernel_mode.kernel_report()["latent_chunk_attention"] == "interpret"
+
+
 def test_prefix_cache_reuses_latent_pages(params, ids):
     """A second request over the same 32-token document maps its pages
     instead of prefilling them, and serves what a cold engine serves."""

@@ -173,6 +173,19 @@ def test_chunked_prefill_then_decode_through_views(params, ids, ref_logits):
                   - ref_logits[:, :104]).max() < TOL
 
 
+@pytest.mark.parametrize("form", ["no cache", "views"])
+def test_every_layer_attends_in_one_chunk_kernel(params, ids, form):
+    """Both forms that attend latent ROWS (the paged step reads pages) are
+    one `latent_chunk_attention` call a layer, full and sliding alike, and
+    what every test above compared was that kernel, interpreted."""
+    caches = (None if form == "no cache"
+              else dots3.init_kv_caches(CFG, 2, 128, jnp.float32))
+    text = str(jax.make_jaxpr(lambda p, i: dots3.forward(
+        CFG, p, i, kv_caches=caches))(params, ids[:, :16]))
+    assert text.count("name=latent_chunk_attention") == CFG.num_hidden_layers
+    assert kernel_mode.kernel_report()["latent_chunk_attention"] == "interpret"
+
+
 # ---------------------------------------------------------------------------
 # the two decode kernels, interpreted, against jax.numpy
 # ---------------------------------------------------------------------------

@@ -40,7 +40,8 @@ FAMILIES = {
 
 # name -> (max_len, the slot's length before the chunk, the chunk's real
 # tokens). R = max_len + CHUNK rows in whole pages; a tiny config walks a
-# view in blocks of 16 rows (`kv_block`)
+# view in blocks of 16 rows (`kv_block`; deepseek's chunk kernel takes so
+# short a view as one tile)
 CASES = {
     # R 48, three whole blocks
     "start 0": (40, 0, CHUNK),
