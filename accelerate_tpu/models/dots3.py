@@ -51,7 +51,11 @@ forms of the same mathematics:
   view attended in the EXPANDED form (at a chunk's 512 queries the absorbed
   form costs 2.2 times the operations and read 24% slower on the chip:
   `PERF.md` section 6, PR 43), masked by the selection on a full layer and
-  by position on a sliding one.
+  by position on a sliding one. A full layer's selection is
+  `exact_topk_mask_rows` (`ops/sparse_paged_attention.py`): 32 or more
+  query rows (a prefill chunk) take the rows kernel, which reads only the
+  columns below the chunk's last position and keeps a tile's keys in
+  vector memory through its passes; fewer keep XLA's loop.
   Both of these are ONE Pallas kernel a layer, `latent_chunk_attention`
   (`ops/latent_chunk_attention.py`; interpreted where there is no TPU): a
   tile of rows is decompressed through `W_kvb`, scored, masked and folded
@@ -82,8 +86,10 @@ import jax.numpy as jnp
 from ..ops.latent_chunk_attention import latent_chunk_attention
 from ..ops.sparse_paged_attention import (
     exact_topk_mask,
+    exact_topk_mask_rows,
     indexer_paged_scores,
     indexer_scores,
+    selection_columns,
 )
 from .common import (
     add_wide,
@@ -107,7 +113,13 @@ from .deepseek import (
     moe_layer,
 )
 from .deepseek import accumulate_serving_stats as _accumulate_experts
-from .keye import view_index_scores
+from .keye import (  # noqa: F401 - the chunk counters are the engine's
+    CHUNK_COUNTERS,
+    SELECTION_COUNTERS,
+    accumulate_chunk_stats,
+    init_chunk_stats,
+    view_index_score_blocks,
+)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 _LANES = 128
@@ -166,7 +178,7 @@ class Dots3Config:
     tie_word_embeddings: bool = False
     max_position_embeddings: int = 524288
     rms_norm_eps: float = 1e-5
-    # view rows the indexer scores at a time (`keye.view_index_scores`)
+    # view rows the indexer scores at a time (`keye.view_index_score_blocks`)
     kv_block: int = 1024
 
     def __post_init__(self):
@@ -440,6 +452,9 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
     visible = chosen = jnp.zeros((), jnp.int32)
     select = new_i = None
     paged = cache is not None and cache[0] == "paged"
+    # a forward over views also counts the columns its selection scanned
+    # and held (`keye.CHUNK_COUNTERS`)
+    columns = () if paged else (visible, visible)
     if paged:
         from ..ops.latent_paged_attention import latent_paged_decode_attention
         from ..ops.sparse_paged_attention import (
@@ -513,11 +528,17 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
             live = (first, end)
         if full:
             with part("attn.indexer"):
-                scores = view_index_scores(c, qI.astype(view_i.dtype), wts,
-                                           view_i[:, :, 0], positions,
-                                           key_pos)
+                scores = view_index_score_blocks(
+                    c, qI.astype(view_i.dtype), wts, view_i[:, :, 0],
+                    positions, key_pos)
             with part("attn.select"):
-                select = exact_topk_mask(scores, c.index_topk)  # [B, S, R]
+                # the columns that may hold a visible key: rows are
+                # positions in a full layer's view
+                end = None if live is None else live[1]
+                R = view_i.shape[1]
+                select = exact_topk_mask_rows(scores, c.index_topk, end,
+                                              columns=R)
+                columns = selection_columns((B, S, R), end)
         with part("attn.attend"):
             out = _attend_view(m, a, q_nope, q_pe, positions, view, key_pos,
                                window, live, select)
@@ -532,7 +553,7 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
         out = (out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
         out = dense(out.reshape(B, S, H * m.v_head_dim),
                     a["o_proj"]["kernel"])
-    return out, new, (visible, chosen)
+    return out, new, (visible, chosen) + columns
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +585,9 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
     [expert layers, n_routed_experts], "assignments_routed",
     "assignments_held": [expert layers] (the real tokens' assignments, and
     those of them to an expert held here), "keys_visible", "keys_selected":
-    int32 scalars, summed over the real tokens and the full layers}`."""
+    int32 scalars, summed over the real tokens and the full layers; over
+    views also "select_columns_scanned", "select_columns_total"
+    (`keye.CHUNK_COUNTERS`)}`."""
     from ..serving.cache import WithSide
 
     c = config
@@ -612,7 +635,7 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
         x = params["embed_tokens"]["embedding"][input_ids]
     new_rows = [[] for _ in groups]
     new_side, counts = [], []
-    visible = chosen = jnp.zeros((), jnp.int32)
+    tallies = (jnp.zeros((), jnp.int32),) * (2 if paged else 4)
     for i, layer in enumerate(params["layers"]):
         g, j = place[i]
         kind = groups[g][0]
@@ -630,7 +653,7 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
         with part("attn.project"):
             y = rms_norm(x, layer["input_layernorm"]["scale"],
                          c.rms_norm_eps)
-        attn, new, (n_vis, n_sel) = _attention(
+        attn, new, n = _attention(
             c, kind, layer["attn"], y, rope[kind], positions, cache,
             token_mask, rows_back=layerwise)
         if new is not None:
@@ -638,7 +661,7 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
             if new[1] is not None:
                 new_side.append(new[1])
         with part("attn.select"):
-            visible, chosen = visible + n_vis, chosen + n_sel
+            tallies = tuple(t + more for t, more in zip(tallies, n))
         with part("attn.output"):
             x = x + attn
         if "moe" in layer:
@@ -682,8 +705,9 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
                                               dtype=jnp.int32),
                 "assignments_held": jnp.sum(counts[:, first:first + held],
                                             axis=-1, dtype=jnp.int32)}
-        out = out + (dict(shares, expert_counts=counts, keys_visible=visible,
-                          keys_selected=chosen),)
+        out = out + (dict(shares, expert_counts=counts,
+                          **dict(zip(SELECTION_COUNTERS + CHUNK_COUNTERS,
+                                     tallies))),)
     return out[0] if len(out) == 1 else out
 
 

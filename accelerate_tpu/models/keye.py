@@ -46,11 +46,16 @@ same mathematics:
 - views (`kv_caches = (WithSide(k, kI), v, cache_len)`, all `[L, B, R, *,
   *]`: the engine's prefill chunks and its dense decode, and `generate`):
   this call's rows written at `cache_len`, every query's index scores
-  over the view in blocks, `exact_topk_mask`, and `common.blocked_attention`
-  masked by the selection as well as by position (a chunk's every query
-  has its own set);
+  over the view in blocks, the exact selection, and
+  `common.blocked_attention` masked by the selection as well as by
+  position (a chunk's every query has its own set). The selection is
+  `exact_topk_mask_rows`: 32 or more query rows (a prefill chunk) take
+  the rows kernel, which reads only the columns below the chunk's last
+  position and keeps a tile's keys in vector memory through its passes;
+  fewer (a dense decode's one row, a tiny chunk) XLA's loop;
 - the paged pools (`WithSide(PagedKV k, PagedKV kI)`, `PagedKV v`,
-  `PagedDecodeMeta`): one token a slot through `ops/sparse_paged_attention.py`.
+  `PagedDecodeMeta`): one token a slot through `ops/sparse_paged_attention.py`
+  (the selection there is `exact_topk_mask`, XLA's loop over `[slots, R]`).
 
 The serving engine's contract: `forward(config, params, ids, positions=,
 kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
@@ -67,8 +72,10 @@ import jax.numpy as jnp
 
 from ..ops.sparse_paged_attention import (
     exact_topk_mask,
+    exact_topk_mask_rows,
     indexer_paged_scores,
     indexer_scores,
+    selection_columns,
     sparse_paged_decode_attention,
 )
 from .common import (
@@ -88,6 +95,15 @@ from .common import (
     write_view,
 )
 from .decode import build_generate, layer_view, rope_table_len
+
+
+# the selection's wide device counters, in the order `_attention` tallies
+# them: every forward the keys its queries could see and those they
+# selected, a forward over views also the columns its selection scanned
+# and the columns the views held, times the query rows. The engine keeps
+# the last two for its prefill chunks alone (`init_chunk_stats`)
+SELECTION_COUNTERS = ("keys_visible", "keys_selected")
+CHUNK_COUNTERS = ("select_columns_scanned", "select_columns_total")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,12 +274,15 @@ def init_params(config: KeyeConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def view_index_scores(config, qI, wts, side_view, q_pos, key_pos):
-    """float32 index scores [B, S, R] of queries qI [B, S, J, w] (weights
-    wts [B, S, J]) at positions `q_pos` [B, S] over the index keys
-    `side_view` [B, R, w] at positions `key_pos` [B, R] (negative: nothing
-    there); `-inf` where the query does not see the key. In blocks of
-    `kv_block` keys: the `[S, J, R]` products never exist whole."""
+def view_index_score_blocks(config, qI, wts, side_view, q_pos, key_pos):
+    """float32 index scores of queries qI [B, S, J, w] (weights wts [B, S,
+    J]) at positions `q_pos` [B, S] over the index keys `side_view` [B, R,
+    w] at positions `key_pos` [B, R] (negative: nothing there); `-inf`
+    where the query does not see the key. In blocks of `kv_block` keys,
+    and left in them: [n, B, S, block], column r of the view at `[r //
+    block, ..., r % block]`, the columns past R `-inf`. The `[S, J, R]`
+    products never exist whole, and no `[B, S, R]` array is laid out: the
+    selection reads the blocks where they lie (`exact_topk_mask_rows`)."""
     B, R, w = side_view.shape
     blk = min(config.kv_block, R)
     pad = -R % blk
@@ -277,16 +296,17 @@ def view_index_scores(config, qI, wts, side_view, q_pos, key_pos):
         see = (pos[:, None, :] >= 0) & (pos[:, None, :] <= q_pos[:, :, None])
         return jnp.where(see, s, -jnp.inf)
 
-    out = jax.lax.map(block, (
+    return jax.lax.map(block, (
         jnp.moveaxis(side_view.reshape(B, n, blk, w), 1, 0),
         jnp.moveaxis(key_pos.reshape(B, n, blk), 1, 0)))      # [n, B, S, blk]
-    return jnp.moveaxis(out, 0, 2).reshape(B, -1, n * blk)[:, :, :R]
 
 
 def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
                rows_back: bool = False):
     """-> (attention output [B, S, h], this layer's new cache entry, (keys
-    visible, keys selected) of the tokens `token_mask` keeps). `cache`:
+    visible, keys selected) of the tokens `token_mask` keeps, and over
+    views or no cache (columns scanned, columns held) by the selection:
+    `SELECTION_COUNTERS`, `CHUNK_COUNTERS`). `cache`:
     None; ("view", k [B, R, Hkv, D], v, kI [B, R, 1, w], start [B]); or
     ("paged", PagedKV k at its layer, PagedKV v, PagedKV kI,
     PagedDecodeMeta). `positions` [3, B, S]. The new entry of a view is
@@ -337,10 +357,11 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
                 q, k, v, pk, pv, meta, select)
             new = (k_row, v_row, kI)
             select = select[:, None]
+            columns = ()
         else:
             if cache is None:
                 view_k, view_v, view_i, key_pos = k, v, kI, at
-                lo = hi = None
+                lo = hi = live = None
             else:
                 _, view_k, view_v, view_i, start = cache
                 R = view_k.shape[1]
@@ -356,11 +377,17 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
                 blk = min(c.kv_block, R)
                 lo = jnp.zeros((), jnp.int32)
                 hi = jnp.minimum(jnp.max(at) // blk + 1, -(-R // blk))
+                # the columns that may hold a visible key
+                live = jnp.minimum(jnp.max(at) + 1, R)
             with part("attn.indexer"):
-                scores = view_index_scores(c, qI.astype(view_i.dtype), wts,
-                                      view_i[:, :, 0], at, key_pos)
+                scores = view_index_score_blocks(
+                    c, qI.astype(view_i.dtype), wts, view_i[:, :, 0], at,
+                    key_pos)
             with part("attn.select"):
-                select = exact_topk_mask(scores, c.topk)        # [B, S, R]
+                R = view_i.shape[1]
+                select = exact_topk_mask_rows(scores, c.topk, live,
+                                              columns=R)        # [B, S, R]
+                columns = selection_columns((B, S, R), live)
             out = blocked_attention(q, at, view_k, view_v, key_pos, None,
                                     c.kv_block, lo, hi, select=select)
         with part("attn.select"):
@@ -371,7 +398,7 @@ def _attention(config, a, x, rope, rope_i, positions, cache, token_mask,
             chosen = jnp.sum(select & counted[:, :, None], dtype=jnp.int32)
     with part("attn.output"):
         out = dense(out.reshape(B, S, H * D), a["o_proj"]["kernel"])
-    return out, new, (visible, chosen)
+    return out, new, (visible, chosen) + columns
 
 
 # ---------------------------------------------------------------------------
@@ -401,7 +428,9 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     `token_mask` [B, S]: which tokens are real, for the counters.
     `return_stats`: a third result `{"expert_counts": [layers, E],
     "keys_visible", "keys_selected": int32 scalars, summed over the real
-    tokens and the layers}`."""
+    tokens and the layers; over views also "select_columns_scanned",
+    "select_columns_total": the columns the selection read and the columns
+    of the view, times the query rows, summed over the layers}`."""
     from ..serving.cache import WithSide
 
     c = config
@@ -435,7 +464,9 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     with part("embed"):
         x = params["embed_tokens"]["embedding"][input_ids]
     new_k, new_v, new_i, counts = [], [], [], []
-    visible = chosen = jnp.zeros((), jnp.int32)
+    # a decode step over the paged pools counts the keys; a chunk over
+    # views also the columns its selection scanned and held
+    tallies = (jnp.zeros((), jnp.int32),) * (2 if paged else 4)
     for i, layer in enumerate(params["layers"]):
         cache = None
         if paged:
@@ -450,7 +481,7 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
         with part("attn.project"):
             y = rms_norm(x, layer["input_layernorm"]["scale"],
                          c.rms_norm_eps)
-        attn, new, (n_vis, n_sel) = _attention(
+        attn, new, n = _attention(
             c, layer["attn"], y, rope, rope_i, positions, cache, token_mask,
             rows_back=layerwise)
         if new is not None:
@@ -458,7 +489,7 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
             new_v.append(new[1])
             new_i.append(new[2])
         with part("attn.select"):
-            visible, chosen = visible + n_vis, chosen + n_sel
+            tallies = tuple(t + more for t, more in zip(tallies, n))
         with part("attn.output"):
             x = x + attn
         with part("moe.route"):
@@ -489,7 +520,8 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
         with part("moe.route"):
             counts = jnp.stack(counts)
         out = out + ({"expert_counts": counts,
-                      "keys_visible": visible, "keys_selected": chosen},)
+                      **dict(zip(SELECTION_COUNTERS + CHUNK_COUNTERS,
+                                 tallies))},)
     return out[0] if len(out) == 1 else out
 
 
@@ -520,6 +552,18 @@ def accumulate_serving_stats(total: dict, call: dict) -> dict:
             keys_selected=add_wide(total["keys_selected"],
                                    call["keys_selected"]))
     return dict(experts(total, call), **keys)
+
+
+def init_chunk_stats(config) -> dict:
+    """The device counters that the engine's `prefill` alone accumulates
+    (`CHUNK_COUNTERS`, wide, all zero): they are no argument of `decode`."""
+    return {name: jnp.zeros((2,), jnp.int32) for name in CHUNK_COUNTERS}
+
+
+def accumulate_chunk_stats(total: dict, call: dict) -> dict:
+    with part("attn.select"):
+        return {name: add_wide(total[name], call[name])
+                for name in CHUNK_COUNTERS}
 
 
 def init_kv_caches(config: KeyeConfig, batch: int, max_len: int,

@@ -2,7 +2,7 @@
 scores every cached position of a slot, the `top_k` largest are selected
 EXACTLY, and attention is a softmax over the selected positions alone
 (DeepSeek-Sparse-Attention's lightning indexer, as `models/keye.py`
-serves it). Three pieces, each with a name of its own in a device trace:
+serves it). Three pieces, each with names of its own in a device trace:
 
 - `indexer_paged_scores` (Pallas, `SCORES_KERNEL_NAME`): a slot's LIVE
   pages of the index-key pool (`serving/cache.py`, SIDE ROW: one key of w
@@ -16,14 +16,25 @@ serves it). Three pieces, each with a name of its own in a device trace:
   BLOCK-DIAGONAL query (`128 / w` copies of q, each over its own lanes):
   every token's score comes out of one MXU product with no relayout of
   the page, in `128 / w` planes that the wrapper interleaves.
-- `exact_topk_mask` (XLA, `SELECT_NAME`; a `while` in the trace): which
+- `exact_topk_mask` (XLA, `SELECT_NAME`; a `while` in the trace) and
+  `exact_topk_mask_rows` (Pallas, `ROWS_SELECT_NAME`): which
   positions are the k largest of each row, ties to the LOWER position. No
   sort: the k-th largest value is found bit by bit over the scores'
   order-preserving uint32 image (32 counting passes), then the ties at
   that value are cut at the position that fills k (a second bisection,
   over position bits, run only if some row must leave a tie out). Exact
   for any input; `approx_max_k`, or any selection that can miss a key, is
-  a different model.
+  a different model. Two forms of the one algorithm, taken by the static
+  shape and the call site: a DECODE step's one row a slot (`[16, 43008]`,
+  2.75 MB of keys) runs XLA's loop as written; a prefill CHUNK's hundreds
+  of rows over a slot's view (`[1, 512, 43520]`, 89 MB of keys, which
+  XLA streams from HBM in every pass unless it happens to find room for
+  them) runs `exact_topk_mask_rows` (Pallas, `ROWS_SELECT_NAME`): a tile
+  of 32 query rows is read once, only the columns below the slot's live
+  length, its keys stay in vector memory through the 32 passes, the cut
+  of ties runs in the kernel for the tiles that need it, and the mask is
+  written once. Bit for bit the same mask; fewer rows than one tile keep
+  XLA's loop.
 - `sparse_paged_decode_attention` (Pallas, `ATTENTION_KERNEL_NAME`): the
   live-pages walk of `ops/paged_attention.py` over a COMPACTED table. A
   page none of whose positions was selected is NOT COPIED: the wrapper
@@ -79,6 +90,7 @@ from .paged_attention import (
 SCORES_KERNEL_NAME = "indexer_paged_scores"
 ATTENTION_KERNEL_NAME = "sparse_paged_decode_attention"
 SELECT_NAME = "sparse_topk_select"
+ROWS_SELECT_NAME = "sparse_topk_select_rows"
 LATENT_ATTENTION_KERNEL_NAME = "sparse_latent_paged_decode_attention"
 # latent pages copied and attended at a time (a page is 20 KB at 640 lanes)
 LATENT_PAGES_PER_GROUP = 32
@@ -91,6 +103,8 @@ __all__ = [
     "indexer_paged_scores",
     "indexer_paged_scores_reference",
     "exact_topk_mask",
+    "exact_topk_mask_rows",
+    "selection_columns",
     "sparse_paged_decode_attention",
     "sparse_paged_decode_reference",
     "sparse_latent_paged_decode_attention",
@@ -311,6 +325,265 @@ def exact_topk_mask(scores, k: int):
         last = jax.lax.cond(jnp.all(count(tie) <= need),
                             lambda _: jnp.full_like(need, N), cut, None)
         return above | (tie & (pos <= last))
+
+
+_INT_MIN = -(1 << 31)
+# the signed key of -inf: a key is visible iff it is above this
+_KEY_NEG_INF = (0xFF800000 ^ 0x7FFFFFFF) - (1 << 32)
+
+
+def _signed_keys(x):
+    """float32 -> int32, monotone in SIGNED order: `_ordered_bits` with its
+    top bit flipped, which is the order the chip's vector compares know."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    b = jnp.where(b == _INT_MIN, 0, b)                  # -0.0 is +0.0
+    return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _select_rows_kernel(live_ref, x_ref, o_ref, keys, *, k: int,
+                        position_bits: int):
+    """Grid [B, row tiles]. `x_ref` [n, rows, block] float32: a tile of
+    query rows' scores in the n blocks of columns they were scored in,
+    `o_ref` [rows, N] int8, `keys` [steps, rows, step] int32: the row
+    tile's `_signed_keys`, one counting step of columns an entry, on
+    which all the counting passes run; only the entries below
+    `live_ref[b]` columns are written and read (a pass walks them
+    `_SELECT_UNROLL` a turn: the last turn's entries past the live ones
+    are filled with the least key, which no pass counts). Whatever walks
+    the counting steps (the keys' fill, the passes, the mask's write) is
+    a LOOP over the live ones, never a Python loop over the view's: the
+    body's jaxpr and its Mosaic text, which every process traces and
+    lowers before any compile cache is asked, do not grow with the view."""
+    b = pl.program_id(0)
+    _, rows, block = x_ref.shape
+    step = keys.shape[2]
+    per_block = block // step
+    live = live_ref[b]
+    n_live = (live + step - 1) // step      # counting steps with a live column
+    unroll = _SELECT_UNROLL
+    n_loops = (n_live + unroll - 1) // unroll
+
+    def lanes(c):
+        """The `step` lanes of counting step `c` in an array of whole
+        steps."""
+        return pl.ds(pl.multiple_of(c * step, step), step)
+
+    def over(first, end, body):
+        """`body(c)` for the counting steps [first, end): ONE traced body
+        however many steps the view has."""
+        def turn(c, carry):
+            body(c)
+            return carry
+        jax.lax.fori_loop(first, end, turn, 0)
+
+    @functools.partial(over, 0, n_live)
+    def _keys(c):
+        keys[c] = _signed_keys(
+            x_ref[c // per_block, :, lanes(c % per_block)])
+
+    @functools.partial(over, n_live, n_loops * unroll)
+    def _fill(c):
+        keys[c] = jnp.full((rows, step), _INT_MIN, jnp.int32)
+
+    def count(pred):
+        """[rows, 1] int32: how many live keys of a row `pred` holds
+        for."""
+        def body(t, acc):
+            for u in range(unroll):
+                c = t * unroll + u
+                acc = acc + pred(c, keys[c]).astype(jnp.int32)
+            return acc
+        acc = jax.lax.fori_loop(0, n_loops, body,
+                                jnp.zeros((rows, step), jnp.int32))
+        return jnp.sum(acc, axis=-1, keepdims=True)
+
+    def value_bit(i, carry):
+        # `prefix` holds the bits of the UNSIGNED image; a candidate is
+        # compared in the signed one
+        prefix, reached = carry
+        cand = prefix | (jnp.int32(1) << (31 - i))
+        at_least = cand ^ _INT_MIN
+        n = count(lambda c, key: key >= at_least)
+        take = n >= k
+        return (jnp.where(take, cand, prefix),
+                jnp.where(take, n, reached))
+
+    # the k-th largest key (the least key where a row has fewer than k)
+    # and how many keys reach it
+    zero = jnp.zeros((rows, 1), jnp.int32)
+    kth, reached = jax.lax.fori_loop(0, 32, value_bit, (zero, zero))
+    kth = kth ^ _INT_MIN
+    # visible keys at or above the k-th
+    floor = jnp.maximum(kth, _KEY_NEG_INF + 1)
+    # a row must leave a tie out iff more than k visible keys reach a
+    # visible k-th
+    cuts = (reached > k) & (kth > _KEY_NEG_INF)
+
+    @functools.partial(over, n_live, o_ref.shape[1] // step)
+    def _nothing_past_the_live_steps(c):
+        o_ref[:, lanes(c)] = jnp.zeros((rows, step), jnp.int8)
+
+    def write(mask):
+        @functools.partial(over, 0, n_live)
+        def _step(c):
+            o_ref[:, lanes(c)] = mask(c, keys[c]).astype(jnp.int8)
+
+    any_cut = jnp.max(cuts.astype(jnp.int32)) > 0
+
+    @pl.when(jnp.logical_not(any_cut))
+    def _all_ties():
+        write(lambda c, key: key >= floor)
+
+    @pl.when(any_cut)
+    def _cut_ties():
+        def position(c):
+            return c * step + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, step), 1)
+
+        need = k - count(lambda c, key: key > floor)
+
+        def position_bit(i, prefix):
+            cand = prefix | (jnp.int32(1) << (position_bits - 1 - i))
+            n = count(lambda c, key: (key == floor)
+                      & (position(c) <= cand))
+            return jnp.where(n <= need, cand, prefix)
+
+        # the largest position p with `ties at or below p <= need`
+        # (need >= 1 in a row that cuts: position 0 is always taken)
+        last = jax.lax.fori_loop(0, position_bits, position_bit, zero)
+        last = jnp.where(cuts, last, jnp.int32((1 << 31) - 1))
+        write(lambda c, key: (key > floor) | (
+            (key == floor) & (position(c) <= last)))
+
+
+# the rows kernel's tiles: query rows a tile (an int8 mask tile's 32
+# sublanes), columns a counting step (its partial counts are 16 vregs)
+# and steps a turn of a pass's loop. Alone on the chip at [1, 512, 43520],
+# all columns live
+# (`PERF.md` section 6, PR 46): 64 rows or 256 / 1,024 columns a step
+# read 10-25% slower than 32 x 512; 1 / 2 / 4 steps a turn 0.86 / 0.75 /
+# 0.70 ms
+_SELECT_ROWS = 32
+_SELECT_STEP = 512
+_SELECT_UNROLL = 4
+
+
+def _live_columns(shape, live):
+    """`live` as `exact_topk_mask_rows` takes it -> int32 [B], one a
+    flattened leading index, inside [0, N]."""
+    *lead, _, N = shape
+    live = jnp.clip(jnp.asarray(N if live is None else live, jnp.int32), 0, N)
+    return jnp.broadcast_to(live, tuple(lead)).reshape(-1)
+
+
+def selection_columns(shape, live=None):
+    """(columns `exact_topk_mask_rows` scans for scores of `shape` under
+    `live`, columns they hold), each summed over the query rows: int32
+    scalars for a device counter. The kernel scans whole counting steps
+    below `live`; XLA's loop (fewer rows than a tile) every column."""
+    *_, S, N = shape
+    live = _live_columns(shape, live)
+    scanned = jnp.full_like(live, N)
+    if S >= _SELECT_ROWS:
+        step = _SELECT_STEP
+        scanned = jnp.minimum((live + step - 1) // step * step, N)
+    return (S * jnp.sum(scanned, dtype=jnp.int32),
+            jnp.int32(S * N * live.shape[0]))
+
+
+def exact_topk_mask_rows(scores, k: int, live=None, columns=None,
+                         interpret: bool | None = None):
+    """`exact_topk_mask(scores, k)` for MANY query rows over one long view
+    (a prefill chunk), bit for bit, as one Pallas kernel
+    (`ROWS_SELECT_NAME`). `scores`: float32 [..., S, N], or, with
+    `columns`, the same scores in the BLOCKS of columns a blocked scorer
+    made them in, [n, ..., S, block] (`models/keye.py`
+    `view_index_score_blocks`: the kernel reads them where they lie, and
+    no `[S, N]` array is laid out for it), of whose n x block columns the
+    first `columns` are the view's. `live`: int32, a scalar or one a
+    leading index `[...]`: only the first `live` columns may hold a
+    visible key (default all; the caller's contract: the columns at or
+    past it hold `-inf`), and the passes do not go past them.
+    -> bool [..., S, N].
+
+    A tile of `_SELECT_ROWS` query rows is read from HBM once, turned into
+    order-preserving int32 keys in vector memory, and the 32 counting
+    passes, the tie cut (only in a tile where some row must leave a tie
+    out) and the mask run on that resident tile; XLA's loop streams the
+    `[S, N]` key array from HBM in every pass unless the compiler happens
+    to find room for it. Every tile is whole: a view that the counting
+    step does not divide is padded with `-inf` first (a copy). Fewer rows
+    than one tile (a decode step's one row a slot, a tiny chunk) keep
+    XLA's loop: padding them would count 32 rows for one."""
+    if k < 1:
+        raise ValueError(f"a selection takes at least one key; got k = {k}")
+    rows, step = _SELECT_ROWS, _SELECT_STEP
+    blocked = columns is not None
+    if blocked and (scores.shape[-2] < rows or scores.shape[-1] % step):
+        # blocks the kernel cannot read where they lie: side by side
+        scores = jnp.moveaxis(scores, 0, -2)
+        scores = scores.reshape(scores.shape[:-2] + (-1,))[..., :columns]
+        blocked = False
+    *lead, S, N = scores.shape[1:] if blocked else scores.shape
+    shape = (*lead, S, columns if blocked else N)
+    if S < rows:
+        return exact_topk_mask(scores, k)
+    interpret = kernel_mode.resolve_interpret(ROWS_SELECT_NAME, interpret)
+    return _select_rows(scores, _live_columns(shape, live), k=k,
+                        columns=columns if blocked else None,
+                        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "columns", "interpret"))
+def _select_rows(scores, live, *, k: int, columns, interpret: bool):
+    """`exact_topk_mask_rows` past its choices: scores of `_SELECT_ROWS` or
+    more query rows, `[..., S, N]` or (`columns`) `[n, ..., S, block]` with
+    blocks of whole counting steps; `live` int32 [B]. A program of its
+    own: the layers of one model hand it the same shapes, so an engine
+    program traces and lowers ONE kernel body and calls it a layer."""
+    rows, step = _SELECT_ROWS, _SELECT_STEP
+    blocked = columns is not None
+    *lead, S, N = scores.shape[1:] if blocked else scores.shape
+    B = math.prod(lead)
+    Sp = -(-S // rows) * rows
+    if blocked:
+        n, block, N = scores.shape[0], N, columns
+        x = scores.astype(jnp.float32).reshape(n, B, S, block)
+        if Sp != S:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, Sp - S), (0, 0)),
+                        constant_values=-jnp.inf)
+    else:
+        n, block = 1, -(-N // step) * step
+        x = scores.astype(jnp.float32).reshape(1, B, S, N)
+        if (Sp, block) != (S, N):
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, Sp - S), (0, block - N)),
+                        constant_values=-jnp.inf)
+    # whole counting steps of the view's own columns
+    Np = -(-N // step) * step
+    held = -(-Np // step // _SELECT_UNROLL) * _SELECT_UNROLL
+    vmem = rows * (2 * n * block * 4 + held * step * 4 + 2 * Np) \
+        + 8 * rows * step * 4
+    with jax.named_scope(ROWS_SELECT_NAME):
+        mask = pl.pallas_call(
+            functools.partial(_select_rows_kernel, k=k,
+                              position_bits=max(1, (N - 1).bit_length())),
+            out_shape=jax.ShapeDtypeStruct((B, Sp, Np), jnp.int8),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, Sp // rows),
+                in_specs=[pl.BlockSpec((n, None, rows, block),
+                                       lambda b, i, live: (0, b, i, 0))],
+                out_specs=pl.BlockSpec((None, rows, Np),
+                                       lambda b, i, live: (b, i, 0)),
+                scratch_shapes=[pltpu.VMEM((held, rows, step),
+                                           jnp.int32)]),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=min(vmem + (8 << 20), 100 << 20)),
+            name=ROWS_SELECT_NAME,
+            interpret=interpret,
+        )(live, x)
+        return (mask[:, :S, :N] != 0).reshape(*lead, S, N)
 
 
 # ---------------------------------------------------------------------------

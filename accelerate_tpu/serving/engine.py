@@ -619,6 +619,11 @@ class Engine:
         # experts apart from the chunks'
         stats = None if stats is None else {
             "prefill": stats(config), "decode": stats(config)}
+        # what a family counts in its prefill chunks ALONE is kept out of
+        # the cache: `decode` is not handed it
+        chunk_stats = getattr(family, "init_chunk_stats", None)
+        self._chunk_stats = (None if chunk_stats is None
+                             else chunk_stats(config))
         if self._cache_groups is not None:
             self.cache = GroupedPagedCache.create(
                 self._cache_groups, ec.num_slots, ec.max_len,
@@ -800,6 +805,7 @@ class Engine:
         except (TypeError, ValueError):
             one_row = False
         fold_stats = getattr(self._family, "accumulate_serving_stats", None)
+        fold_chunk = getattr(self._family, "accumulate_chunk_stats", None)
         grouped = self._cache_groups is not None
         # a family that loops over its layers says that a prefill chunk may
         # be handed its slot's views a layer at a time and gives the
@@ -816,9 +822,10 @@ class Engine:
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
-            """-> (logits, new caches, cache): `forward`, with the head for
-            `logit_rows` only ([B, 1, V]) where it takes them, and the
-            family's counters, where it has any, folded into the cache's."""
+            """-> (logits, new caches, cache, this call's counts): `forward`,
+            with the head for `logit_rows` only ([B, 1, V]) where it takes
+            them, and the family's counters, where it has any, folded into
+            the cache's."""
             extra = {"logit_rows": logit_rows} if one_row else {}
             if cache.stats is not None:
                 extra.update(token_mask=token_mask, return_stats=True)
@@ -828,7 +835,8 @@ class Engine:
                 cache = cache.with_stats(dict(
                     cache.stats, **{program: fold_stats(
                         cache.stats[program], out[2])}))
-            return out[0], out[1], cache
+            return out[0], out[1], cache, (
+                None if cache.stats is None else out[2])
 
         # donation lets the (large) cache be updated in place; that it IS,
         # on the chip, takes the page-granular write of
@@ -841,12 +849,13 @@ class Engine:
         # meshed engines pin output shardings to the input layout so the
         # jit cache key reaches its fixed point on the FIRST compile
         # (inputs are placed to exactly these shardings in __init__)
-        admit_out = step_out = None
+        admit_out = step_out = prefill_out = None
         if self._mesh_shardings is not None:
             cache_sh, rep = self._mesh_shardings
             admit_out = (cache_sh, rep, rep)
             # cache, the token register, the host's (tokens, logprobs)
             step_out = (cache_sh, rep, (rep, rep))
+            prefill_out = step_out + (rep,)     # and the chunks' counters
 
         @part("sample")
         def sample_slot(logits, key_raw, position, temp):
@@ -905,9 +914,10 @@ class Engine:
                 temps = temps.at[slot].set(temp)
                 return cache, slot_keys, temps
 
-        @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
+        @partial(jax.jit, donate_argnums=don + ((9,) if don else ()),
+                 out_shardings=prefill_out)
         def prefill(params, cache, tokens, slot_keys, temps, slot,
-                    table_row, ids, real_len):
+                    table_row, ids, real_len, chunk_stats=None):
             if state:
                 length = cache.lengths[slot]
                 kvc = (cache.pool(kernel), None,
@@ -917,10 +927,12 @@ class Engine:
                                                  by_layer=layerwise)
                 kvc = (ks, vs, length)
             positions = (length + jnp.arange(chunk, dtype=jnp.int32))[None, :]
-            logits, (nk, nv, _), cache = serving_forward(
+            logits, (nk, nv, _), cache, counted = serving_forward(
                 "prefill", params, cache, ids[None, :], positions,
                 kvc, (real_len - 1)[None],
                 (jnp.arange(chunk) < real_len)[None, :])
+            if chunk_stats is not None:
+                chunk_stats = fold_chunk(chunk_stats, counted)
             with part("sample"):
                 if one_row:  # the one row that is read, not the chunk's
                     last = logits[0, 0].astype(jnp.float32)
@@ -944,7 +956,7 @@ class Engine:
                 tokens = tokens.at[slot].set(tok)
             # (tok, lp) is the host's: `tokens` is donated to the next
             # program, which may be dispatched before the host reads
-            return cache, tokens, (tok, lp)
+            return cache, tokens, (tok, lp), chunk_stats
 
         decode = None
         if self._spec:
@@ -959,7 +971,7 @@ class Engine:
                 # state stays as it is
                 lengths = cache.lengths
                 meta = StateMeta(table[:, 0], live.astype(jnp.int32))
-                logits, (pool, _, _), cache = serving_forward(
+                logits, (pool, _, _), cache, _ = serving_forward(
                     "decode", params, cache, tokens[:, None],
                     lengths[:, None], (cache.pool(kernel), None, meta),
                     jnp.zeros_like(lengths), live[:, None])
@@ -1010,7 +1022,7 @@ class Engine:
                 kvc = (pools(cache, "k"),
                        None if latent else pools(cache, "v"),
                        PagedDecodeMeta(table, walked, rows=rows))
-                logits, (row_k, row_v, _), cache = serving_forward(
+                logits, (row_k, row_v, _), cache, _ = serving_forward(
                     "decode", params, cache, tokens[:, None],
                     lengths[:, None], kvc, jnp.zeros_like(lengths),
                     live[:, None])
@@ -1050,7 +1062,7 @@ class Engine:
                     # under the kernel; a family with groups is handed
                     # one view a group)
                     lengths = cache.lengths
-                    logits, (nk, nv, _), cache = serving_forward(
+                    logits, (nk, nv, _), cache, _ = serving_forward(
                         "decode", params, cache, tokens[:, None],
                         lengths[:, None], (k_all, v_all, lengths),
                         jnp.zeros_like(lengths), live[:, None])
@@ -1234,13 +1246,17 @@ class Engine:
     def device_counters(self) -> dict:
         """The family's own counters (`family.init_serving_stats`), one
         set a program ("prefill", "decode"), as NumPy arrays; {} for a
-        family that declares none. They accumulate on the device inside
-        the two programs and cross to the host HERE, on demand: nothing
-        on a step's path reads them."""
+        family that declares none. "prefill" also holds what the family
+        counts in its chunks alone (`family.init_chunk_stats`). They
+        accumulate on the device inside the two programs and cross to the
+        host HERE, on demand: nothing on a step's path reads them."""
         if self.cache is None or self.cache.stats is None:
             return {}
         self._settle()  # counts and committed tokens of the same programs
-        return jax.tree_util.tree_map(np.asarray, self.cache.stats)
+        stats = dict(self.cache.stats)
+        if self._chunk_stats is not None:
+            stats["prefill"] = dict(stats["prefill"], **self._chunk_stats)
+        return jax.tree_util.tree_map(np.asarray, stats)
 
     def compile_stats(self) -> dict[str, int]:
         """Compiled-program counts per engine program — the recompile
@@ -2012,7 +2028,8 @@ class Engine:
             ids[:real] = req.prompt[start:start + real]
             args = (self.params, self.cache, self._tokens, self._slot_keys,
                     self._temps, jnp.int32(slot.index),
-                    self._tables(slot.index), ids, jnp.int32(real))
+                    self._tables(slot.index), ids, jnp.int32(real),
+                    self._chunk_stats)
             self._strict_audit("prefill", self._prefill_p, args)
             self._ensure_cost("prefill", self._prefill_p, args)
         with self.cost.maybe_sample(
@@ -2020,7 +2037,8 @@ class Engine:
             with self._request_span("serving.prefill", req, slot=slot.index,
                                     chunk_start=start, chunk_tokens=real), \
                     self.timer.dispatch():
-                self.cache, self._tokens, out = self._prefill_p(*args)
+                self.cache, self._tokens, out, self._chunk_stats = \
+                    self._prefill_p(*args)
             sample(self.cache)
         if self._spec:
             # joint chunk: the draft processes the same window, so both

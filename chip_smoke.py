@@ -71,6 +71,12 @@ class Sizes:
     op_slots: int = 8
     op_pages_per_slot: int = 24
     op_num_pages: int = 256
+    # the selection check: a prefill chunk's scores at the sparse cells'
+    # shape (512 queries over a view of 43,520 rows, 2,048 keys a query)
+    select_rows: int = 512
+    select_columns: int = 43520
+    select_block: int = 1024
+    select_k: int = 2048
     # --multichip
     multi_layers: int = 4
     multi_batch: int = 8
@@ -85,7 +91,8 @@ TINY = Sizes(
     max_position_embeddings=256, train_layers=2, train_batch=2,
     train_seq=32, train_steps=4, serve_slots=2, serve_max_len=64,
     serve_prefill_chunk=8, serve_prompt_lens=(3, 9, 17), serve_new_tokens=4,
-    op_slots=2, op_pages_per_slot=3, op_num_pages=8, multi_layers=2,
+    op_slots=2, op_pages_per_slot=3, op_num_pages=8, select_rows=40,
+    select_columns=1300, select_block=512, select_k=50, multi_layers=2,
     multi_batch=8, multi_steps=3)
 
 # --- tolerances, each with its reason ---------------------------------------
@@ -372,6 +379,52 @@ def kernel_op_check(s: Sizes, seed: int = 0) -> None:
             raise AssertionError(f"kernel disagrees with reference: {err}")
 
 
+def selection_op_check(s: Sizes, seed: int = 0) -> None:
+    """`exact_topk_mask_rows` (the rows kernel of a prefill chunk's
+    selection) against `exact_topk_mask` (XLA's loop), bit for bit, on
+    seeded scores: distinct values under live bounds inside and at the
+    view's end, and values quantized until the k-th is a tie that must be
+    cut; each as one row-major array and in the blocks of columns an
+    indexer's loop leaves its scores in."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.sparse_paged_attention import (
+        exact_topk_mask,
+        exact_topk_mask_rows,
+    )
+
+    S, N, k = s.select_rows, s.select_columns, s.select_k
+    block = s.select_block
+    n = -(-N // block)
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(1, S, N)).astype(np.float32)
+    col = np.arange(N)
+    kernel = jax.jit(exact_topk_mask_rows, static_argnums=(1, 3))
+    reference = jax.jit(exact_topk_mask, static_argnums=1)
+    for name, scores, live in (
+            ("distinct scores, three quarters live", x, 3 * N // 4 + 1),
+            ("distinct scores, all live", x, N),
+            ("scores in steps of 1/4, half live", np.round(x * 4) / 4,
+             N // 2)):
+        scores = jnp.asarray(np.where(col < live, scores, -np.inf),
+                             jnp.float32)
+        blocks = jnp.moveaxis(jnp.pad(
+            scores, ((0, 0), (0, 0), (0, n * block - N)),
+            constant_values=-jnp.inf).reshape(1, S, n, block), 2, 0)
+        got = np.asarray(kernel(scores, k, jnp.int32(live), None))
+        want = np.asarray(reference(scores, k))
+        wrong = int((got != want).sum()) + int((np.asarray(kernel(
+            blocks, k, jnp.int32(live), N)) != want).sum())
+        log(f"serve: exact_topk_mask_rows vs exact_topk_mask ([{S}, {N}], "
+            f"k={k}, {name}): {int(got.sum())} selected, {wrong} differ")
+        if wrong or int(got.sum()) != S * min(k, live):
+            raise AssertionError(
+                f"the rows selection kernel disagrees with XLA's loop: "
+                f"{wrong} positions ({name})")
+
+
 def _prompts(s: Sizes, seed: int):
     import numpy as np
 
@@ -473,6 +526,7 @@ def serve_phase(s: Sizes, expect_chip: bool, seed: int = 0):
     # workers do (a no-op when the train phase already configured it)
     log(f"serve: compile cache dir = {configure_compilation_cache()}")
     kernel_op_check(s, seed)
+    selection_op_check(s, seed)
 
     cfg = model_config(s, s.full_layers)
     params = llama.init_params(cfg, jax.random.key(seed), dtype=jnp.bfloat16)

@@ -21,6 +21,8 @@ guard every later PR at no chip time:
   rows, the rows kernel over a decode step's), the one-array pool is
   updated in place, no expert matrix is copied;
 - the rows kernel alone at both expert cells' decode shapes;
+- the rows kernel of a prefill chunk's exact selection at the sparse
+  cells' chunk shape;
 - the train step's head and loss as one op (`fused_head_loss`) at the
   train cell's shape: three vocabulary-wide products, the logits once,
   one block of float32 logits, the head's gradient summed in float32.
@@ -148,17 +150,21 @@ def _ops_of_shape(text, dtype, shape):
 
 
 def _assert_host_output_is_its_own(program, args, text, shape):
-    """What the host reads of a step is the program's LAST output, the
+    """What the host reads of a step is the program's THIRD output, the
     sampled tokens and their logprobs, of `shape`. The engine dispatches the
-    next program, which donates the pool and the token register, before it
-    reads them: every aliased output has to lie before the two."""
+    next program, which donates the pool, the token register and (a
+    prefill) the chunks' counters, before it reads them: no aliased output
+    is one of the two."""
     out = jax.eval_shape(program, *args)
     assert [(x.shape, x.dtype) for x in out[2]] == [
         (shape, jnp.int32), (shape, jnp.float32)]
     aliased = {int(i) for i in re.findall(
         r"\{(\d+)\}: \(\d+, \{\}", re.search(
             r"input_output_alias=\{[^\n]*?\}, entry", text).group(0))}
-    assert aliased and max(aliased) < len(jax.tree.leaves(out)) - 2, aliased
+    host = len(jax.tree.leaves(out[:2]))
+    assert aliased and not aliased & {host, host + 1}, aliased
+    assert host + 2 + len(jax.tree.leaves(out[3:])) == len(
+        jax.tree.leaves(out))
 
 
 def _chat_cell_programs(one_chip, kv_dtype, num_pages=4096):
@@ -554,7 +560,8 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
         `conditional`); each kernel takes its whole stacked pool (nothing
         of a layer's slice shape is produced around it); `prefill` holds
         neither kernel (a chunk scores and attends the slot's gathered
-        views) and the same selection;
+        views) and the selection as the rows kernel, once a layer, with
+        no 32-bit image of a layer's scores beside it;
     (b) the expert products, 3 a layer, are `ragged-dot` kernels in
         `prefill` and the rows kernel in `decode`; nothing of an expert
         matrix's shape is copied in either;
@@ -564,8 +571,8 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
     (d) a chunk's logits are one row; temporaries: `decode` under 300 MB
         (90 MB read), `prefill` under 1.2 GB (a layer's gathered K and
         V views, 45 MB each, of the layers the scheduler holds at once,
-        and a layer's [512, 43520] scores, their ordered image and the
-        mask; 1.57 GB until PR 39, with all six layers' views stacked in
+        and a layer's [512, 43520] scores and the mask; 1.57 GB until
+        PR 39, with all six layers' views stacked in
         and stacked out), beside 8.75 GB of weights and 4.49 GB of pool;
     (e) `prefill` holds no array of the stacked views' shape
         [6, 1, 43520, ...]: a layer's view is gathered alone."""
@@ -611,7 +618,8 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
             arg((slots,), jnp.bool_), arg((slots, 2720), jnp.int32)), 300e6),
         "prefill": (engine._prefill_p, state + (
             arg((), jnp.int32), arg((2720,), jnp.int32),
-            arg((chunk,), jnp.int32), arg((), jnp.int32)), 1.2e9),
+            arg((chunk,), jnp.int32), arg((), jnp.int32),
+            on_chip(engine._chunk_stats)), 1.2e9),
     }
     for name, (program, args, temp_limit) in programs.items():
         compiled = program.lower(*args).compile()
@@ -619,16 +627,20 @@ def test_sparse_select_engine_programs_compile_for_v5e(one_chip, chip_compile):
         memory = compiled.memory_analysis()
         calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
                  for n in (sparse.SCORES_KERNEL_NAME,
-                           sparse.ATTENTION_KERNEL_NAME)]
-        assert calls == ([6, 6] if name == "decode" else [0, 0]), (
+                           sparse.ATTENTION_KERNEL_NAME,
+                           sparse.ROWS_SELECT_NAME)]
+        assert calls == ([6, 6, 0] if name == "decode" else [0, 0, 6]), (
             name, calls)
-        # the selection: a loop over the value's bits a layer, and the
-        # ties' cut (a second loop) under a conditional; in `decode` no
-        # other loop (a trace names the selection `%while` there), in
-        # `prefill` also the blocks of the scores and of the attention
-        assert len(re.findall(r" while\(", text)) == (
-            12 if name == "decode" else 24), name
-        assert len(re.findall(r" conditional\(", text)) == 6, name
+        # the selection in `decode`: a loop over the value's bits a
+        # layer, and the ties' cut (a second loop) under a conditional,
+        # and no other loop (a trace names the selection `%while`
+        # there); in `prefill` it is the rows kernel, and the loops are
+        # the blocks of the scores and of the attention
+        assert len(re.findall(r" while\(", text)) == 12, name
+        assert len(re.findall(r" conditional\(", text)) == (
+            6 if name == "decode" else 0), name
+        assert not re.search(r"[su]32\[1,512,43520\].* (?:while|copy)\(",
+                             text), name
         assert _grouped_products(text) == (
             (0, 18) if name == "decode" else (18, 0)), name
         assert _ops_of_shape(text, "bf16", (128, 2048, 768)) == {}, name
@@ -662,9 +674,11 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
     (a) `decode` holds, a FULL layer, the indexer-score kernel and the
         sparse latent attention kernel, and a SLIDING layer the ring mode
         of the latent kernel, each under its own name, and the selection's
-        loops; `prefill` holds none of those, the same selection, and ONE
-        chunk kernel a layer (`latent_chunk_attention`, five), with no
-        block's float32 scores `[.., 512, 1024]` beside them;
+        loops; `prefill` holds none of those, the selection as the rows
+        kernel (a full layer, two), and ONE chunk kernel a layer
+        (`latent_chunk_attention`, five), with no block's float32 scores
+        `[.., 512, 1024]` and no 32-bit image of a layer's index scores
+        beside them;
     (b) each kernel takes its whole stacked pool: `decode` holds no copy
         of either pool, nor of a layer's slice of one;
     (c) both latent pools and the index pool are aliased to their
@@ -728,7 +742,8 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
         "prefill": (engine._prefill_p, state + (
             arg((), jnp.int32),
             (arg((2720,), jnp.int32), arg((66,), jnp.int32)),
-            arg((chunk,), jnp.int32), arg((), jnp.int32))),
+            arg((chunk,), jnp.int32), arg((), jnp.int32),
+            on_chip(engine._chunk_stats))),
     }
     for name, (program, args) in programs.items():
         compiled = program.lower(*args).compile()
@@ -738,16 +753,22 @@ def test_latent_groups_engine_programs_compile_for_v5e(one_chip, chip_compile):
                  for n in (sparse.SCORES_KERNEL_NAME,
                            sparse.LATENT_ATTENTION_KERNEL_NAME,
                            latent.WINDOW_KERNEL_NAME,
-                           chunk_kernel.KERNEL_NAME)]
-        assert calls == ([2, 2, 3, 0] if name == "decode"
-                         else [0, 0, 0, 5]), (name, calls)
+                           chunk_kernel.KERNEL_NAME,
+                           sparse.ROWS_SELECT_NAME)]
+        assert calls == ([2, 2, 3, 0, 0] if name == "decode"
+                         else [0, 0, 0, 5, 2]), (name, calls)
         assert not re.search(r"\[(?:1,)?128,(?:1,)?512,1024\]", text), name
         # (e) the four expert layers' products: a decode step's few rows
         # over 15.7 MB matrices are XLA's; a chunk's HELD rows go through
         # the rows kernel in blocks of lanes, and XLA's kernel is gone
         assert _grouped_products(text) == (
             (12, 0) if name == "decode" else (0, 12)), name
-        assert len(re.findall(r" conditional\(", text)) == 2, name
+        # the selection's cut of ties: a conditional of XLA's in a decode
+        # step, inside the rows kernel in a chunk
+        assert len(re.findall(r" conditional\(", text)) == (
+            2 if name == "decode" else 0), name
+        assert not re.search(r"[su]32\[1,512,43520\].* (?:while|copy)\(",
+                             text), name
         # no copy of a pool, nor of a layer's slice of one
         for pool in (full.k, full.side, ring.k):
             for shape in (pool.shape, pool.shape[1:]):
@@ -806,6 +827,38 @@ def test_latent_chunk_kernel_compiles_for_v5e(one_chip, chip_compile, S,
                           text)) == 1
     assert not re.search(r"f32\[(?:\d+,){2,}1\d\d\d\]", text)
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("blocked", [True, False],
+                         ids=["blocks-as-scored", "row-major"])
+def test_rows_select_kernel_compiles_for_v5e(one_chip, chip_compile, blocked):
+    """`exact_topk_mask_rows` alone at the sparse cells' chunk shape (512
+    queries over a view of 43,520 rows, 2,048 keys a query, a traced live
+    bound), the scores in the 43 blocks of 1,024 columns the indexer's
+    loop leaves them in, and as one row-major array: the chip's compiler
+    takes the tile the wrapper computes (32 rows x 43,520 keys resident),
+    the program holds the kernel once, no loop of XLA's, no 32-bit image
+    of the scores beside them, and the blocks go into the kernel as they
+    are: no copy of them."""
+    from accelerate_tpu.ops import sparse_paged_attention as sparse
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scores = arg((43, 1, 512, 1024) if blocked else (1, 512, 43520),
+                 jnp.float32)
+    compiled = jax.jit(
+        lambda scores, live: sparse.exact_topk_mask_rows(
+            scores, 2048, live, columns=43520 if blocked else None)
+    ).lower(scores, arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall("%" + sparse.ROWS_SELECT_NAME + r"(?:\.\d+)? = ",
+                          text)) == 1
+    assert " while(" not in text
+    assert not re.search(r"[su]32\[1,512,43520\]", text)
+    assert re.search(r"s8\[1,512,43520\]", text)
+    assert not re.search(r"f32\[\S* (?:copy|fusion)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("degree,state_dtype", [

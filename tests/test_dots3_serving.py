@@ -348,6 +348,70 @@ def test_engine_serves_chunks_then_decode_through_the_paged_groups(
         assert stats["assignments"].sum(-1).tolist() == routed
 
 
+def test_a_chunk_of_whole_row_tiles_selects_in_the_rows_kernel(params, ids):
+    """A chunk of 32 query rows is one tile of `sparse_topk_select_rows`
+    (interpreted here) and a full layer's view of 1,056 rows three of its
+    counting steps: the engine serves the reference's logits with the
+    kernel on the path of both full layers, and the prefill program's
+    counters say what the live bound let it skip (every chunk ends below
+    position 512: ONE step scanned of a view of 1,056)."""
+    with jax.default_matmul_precision("highest"):
+        eng = _engine(params, max_len=1024, prefill_chunk=32)
+        prompts = [ids[0, :75], ids[1, 3:43]]
+        reqs = [eng.submit(p, max_new_tokens=3, temperature=0.0)
+                for p in prompts]
+        eng.run_until_idle()
+    for prompt, req in zip(prompts, reqs):
+        assert req.status.value == "finished"
+        _agrees_with_the_reference(params, prompt, req)
+    assert eng.compile_stats() == {"admit": 1, "prefill": 1, "decode": 1}
+    got = eng.device_counters()
+    chunks, view = eng.metrics.prefill_chunks, 1024 + 32
+    assert chunks == 3 + 2
+    stats = got["prefill"]
+    assert (dots3.wide_count(stats["select_columns_scanned"]),
+            dots3.wide_count(stats["select_columns_total"])) == (
+                chunks * 2 * 32 * 512, chunks * 2 * 32 * view)
+    # `decode` is not handed them: its program is what it was without
+    assert eng.metrics.decode_steps > 0
+    assert not set(dots3.CHUNK_COUNTERS) & set(got["decode"])
+    assert set(dots3.SELECTION_COUNTERS) <= set(got["decode"])
+
+
+def test_a_program_holds_the_rows_kernel_once_and_decode_none_of_it(
+        monkeypatch):
+    """The engine's programs lowered for the chip, the indexer's scores in
+    blocks of whole counting steps: `prefill` holds the rows kernel's body
+    ONCE, in a function of its own that both full layers call (beside the
+    four layers' chunk kernels); `decode` holds nothing of it, and none
+    of the chunks' counters is among its arguments."""
+    monkeypatch.setattr(kernel_mode, "resolve_interpret",
+                        lambda name, interpret=None: False)
+    cfg = dataclasses.replace(CFG, kv_block=512)
+    abstract = jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.key(0), jnp.float32))
+    eng = _engine(abstract, cfg, max_len=1024, prefill_chunk=32,
+                  paged_attention=True)
+    state = (eng.params, eng.cache, eng._tokens, eng._slot_keys, eng._temps)
+    prefill = eng._prefill_p.trace(
+        *state, jnp.int32(0), eng._tables(0), np.zeros((32,), np.int32),
+        jnp.int32(32), eng._chunk_stats).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert prefill.count("func.func private @_select_rows(") == 1
+    assert prefill.count("call @_select_rows(") == 2
+    assert prefill.count("tpu_custom_call") == 4 + 1
+    decode = eng._decode_p.trace(
+        *state, np.ones((3,), bool), eng._tables()).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert sparse.ROWS_SELECT_NAME not in decode
+    assert "_select_rows" not in decode
+    handed = {path[-1].key for path, _ in jax.tree_util.tree_leaves_with_path(
+        eng.cache.stats) if hasattr(path[-1], "key")}
+    assert {"keys_visible", "keys_selected"} <= handed
+    assert not set(dots3.CHUNK_COUNTERS) & handed
+    assert set(dots3.CHUNK_COUNTERS) == set(eng._chunk_stats)
+
+
 def test_a_reused_slot_serves_the_logits_of_a_cold_request(params, ids):
     """ONE slot: the second request takes the first's slot, ring and (by
     the free list's order) pages, with the first's rows and index keys

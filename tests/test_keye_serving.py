@@ -16,6 +16,7 @@ caches under `jax.default_matmul_precision("highest")`; program and
 reference sum in different orders, which reads 4e-7 on logits of order
 0.5."""
 
+import dataclasses
 import importlib.util
 import os
 
@@ -211,12 +212,12 @@ def test_the_side_row_lies_in_whole_lane_rows():
                                 **bad)
 
 
-def _engine(params, **kw):
+def _engine(params, config=CFG, **kw):
     args = dict(num_slots=3, max_len=128, prefill_chunk=16, page_size=16,
                 cache_dtype=jnp.float32, prefix_cache=True,
                 paged_attention=False)
     args.update(kw)
-    return Engine(keye, CFG, params, EngineConfig(**args))
+    return Engine(keye, config, params, EngineConfig(**args))
 
 
 def _teacher_forced(params, prompt, tokens):
@@ -276,6 +277,84 @@ def test_engine_serves_chunks_then_decode_through_the_paged_cache(
         assert (keye.wide_count(got[program]["keys_visible"]),
                 keye.wide_count(got[program]["keys_selected"])) == keys(
                     positions), program
+
+
+@pytest.mark.parametrize("kv_block", [16, 512],
+                         ids=["scores-laid-out", "blocks-as-scored"])
+def test_a_chunk_of_whole_row_tiles_selects_in_the_rows_kernel(
+        params, ids, kv_block):
+    """A chunk of 32 query rows is one tile of `sparse_topk_select_rows`
+    (interpreted here), and a view of 1,056 rows is three of its counting
+    steps: the engine serves the reference's logits with the kernel on
+    the path, and the prefill program's counters say what the live bound
+    let it skip: every chunk ends below position 512, so the selection
+    scanned ONE step of a view it would else scan whole. The decode steps
+    (one row a slot over the same views) keep XLA's loop, and their
+    program neither counts columns nor is handed the chunks' counters.
+    The indexer's blocks of 16 columns are laid side by side for the
+    kernel; blocks of whole counting steps (as the cells score them) it
+    reads where they lie."""
+    with jax.default_matmul_precision("highest"):
+        eng = _engine(params, dataclasses.replace(CFG, kv_block=kv_block),
+                      max_len=1024, prefill_chunk=32, prefix_cache=False)
+        prompts = [ids[0, :75], ids[1, 3:43]]
+        reqs = [eng.submit(p, max_new_tokens=3, temperature=0.0)
+                for p in prompts]
+        eng.run_until_idle()
+    for prompt, req in zip(prompts, reqs):
+        assert req.status.value == "finished"
+        _agrees_with_the_reference(params, prompt, req)
+    assert eng.compile_stats() == {"admit": 1, "prefill": 1, "decode": 1}
+    got = eng.device_counters()
+    layers, view = CFG.num_hidden_layers, 1024 + 32
+    chunks, steps = eng.metrics.prefill_chunks, eng.metrics.decode_steps
+    assert chunks == 3 + 2
+    prefill = {name: keye.wide_count(got["prefill"][name])
+               for name in keye.CHUNK_COUNTERS}
+    assert prefill == {"select_columns_scanned": chunks * layers * 32 * 512,
+                       "select_columns_total": chunks * layers * 32 * view}
+    # `decode` is not handed them: its program is what it was without
+    assert steps > 0 and not set(keye.CHUNK_COUNTERS) & set(got["decode"])
+    assert set(keye.SELECTION_COUNTERS) <= set(got["decode"])
+
+
+def test_a_program_holds_the_rows_kernel_once_and_decode_none_of_it(
+        monkeypatch):
+    """Six layers, the engine's programs lowered for the chip: `prefill`
+    holds the rows kernel's body ONCE (one Mosaic payload, in a function
+    of its own that the six layers call), so what a process pays to trace
+    and lower it does not grow with the model's depth; `decode` (one row a
+    slot: XLA's loop) holds nothing of it, and none of the chunks'
+    counters is among its arguments."""
+    from accelerate_tpu.ops import kernel_mode
+    from accelerate_tpu.ops.sparse_paged_attention import ROWS_SELECT_NAME
+
+    monkeypatch.setattr(kernel_mode, "resolve_interpret",
+                        lambda name, interpret=None: False)
+    cfg = keye.KeyeConfig.tiny(num_hidden_layers=6, kv_block=512)
+    abstract = jax.eval_shape(
+        lambda: keye.init_params(cfg, jax.random.key(0), jnp.float32))
+    eng = _engine(abstract, cfg, max_len=1024, prefill_chunk=32,
+                  paged_attention=True)
+    state = (eng.params, eng.cache, eng._tokens, eng._slot_keys, eng._temps)
+    prefill = eng._prefill_p.trace(
+        *state, jnp.int32(0), eng._tables(0), np.zeros((32,), np.int32),
+        jnp.int32(32), eng._chunk_stats).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert prefill.count("tpu_custom_call") == 1
+    assert prefill.count("func.func private @_select_rows(") == 1
+    assert prefill.count("call @_select_rows(") == 6
+    decode = eng._decode_p.trace(
+        *state, np.ones((3,), bool), eng._tables()).lower(
+            lowering_platforms=("tpu",))
+    assert decode.as_text().count("tpu_custom_call") == 2 * 6
+    assert ROWS_SELECT_NAME not in decode.as_text()
+    assert "_select_rows" not in decode.as_text()
+    handed = {path[-1].key for path, _ in jax.tree_util.tree_leaves_with_path(
+        eng.cache.stats) if hasattr(path[-1], "key")}
+    assert {"keys_visible", "keys_selected"} <= handed
+    assert not set(keye.CHUNK_COUNTERS) & handed
+    assert set(keye.CHUNK_COUNTERS) == set(eng._chunk_stats)
 
 
 def test_a_prefix_hit_serves_the_logits_of_a_cold_request(params, ids):

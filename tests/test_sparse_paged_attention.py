@@ -1,13 +1,17 @@
 """`ops/sparse_paged_attention.py` on the CPU (kernels interpreted): the
-exact selection against a sort, ties and `-inf` included; the indexer-score
+exact selection against a sort, ties and `-inf` included; the rows kernel
+of the same selection (a prefill chunk's) bit for bit against it, under
+every live bound; the indexer-score
 kernel and the sparse attention kernel against plain gathers; the sparse
 kernel equal to the dense live-pages kernel while nothing is left out; and
 a page that holds no selected key is never copied."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from accelerate_tpu.ops import kernel_mode
 from accelerate_tpu.ops import sparse_paged_attention as sparse
 from accelerate_tpu.ops.paged_attention import (
     PagedDecodeMeta,
@@ -60,6 +64,151 @@ def test_exact_topk_mask_takes_leading_axes_and_the_lower_of_equal_zeros():
     row = np.array([[0.0, -0.0, -1.0, -0.0]], np.float32)
     got = np.asarray(sparse.exact_topk_mask(jnp.asarray(row), 2))
     np.testing.assert_array_equal(got, [[True, True, False, False]])
+
+
+def _chunk_scores(case: str):
+    """-> (float32 scores, k, live or None) for one case of the rows
+    kernel: several row tiles and counting steps of tiny scores, the
+    columns at or past `live` at `-inf` as the kernel's callers hold
+    them."""
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(2, 40, 1300)).astype(np.float32)
+    live, k = None, 50
+    if case == "k-equals-visible":
+        live = k = 200
+    elif case == "k-above-visible":
+        live, k = 200, 900
+    elif case == "k-above-every-column":
+        k = 5000
+    elif case == "ties-that-must-be-cut":
+        x = np.round(x * 2) / 2
+    elif case == "every-score-equal":
+        x[:], k = 1.0, 77
+    elif case == "both-zeros-are-one-value":
+        x = np.where(x > 0.3, 1.0, np.where(x > 0, 0.0, np.where(
+            x > -0.3, -0.0, -1.0))).astype(np.float32)
+        k = 700
+    elif case == "rows-of-all-minus-inf":
+        x[:, ::3] = -np.inf
+        x[1, 1, 5] = 3.0
+    elif case == "live-bound-0":
+        live = 0
+    elif case == "live-bound-inside-a-step":
+        live = 700
+    elif case == "live-bound-equals-n":
+        live = 1300
+    elif case == "whole-tiles-no-padding":
+        x = np.round(rng.normal(size=(1, 64, 1024)) * 8).astype(np.float32)
+        live = 1024
+    elif case == "leading-axes-a-bound-each":
+        x = np.round(rng.normal(size=(2, 3, 33, 600)) * 4).astype(np.float32)
+        live, k = np.array([[600, 0, 17], [512, 513, 300]], np.int32), 24
+    elif case == "a-bound-a-batch-row":
+        live = np.array([700, 1300], np.int32)
+    elif case == "fewer-rows-than-a-tile":
+        x, live = x[:, :8], 900
+    elif case in ("blocks-as-they-were-scored", "blocks-under-a-live-bound"):
+        # 1,300 columns scored in three blocks of 512: 236 past the view
+        x = np.round(rng.normal(size=(2, 40, 1536)) * 2).astype(np.float32) / 2
+        x[:, :, 1300:] = -np.inf
+        live, k = (None, 300) if case.endswith("scored") else (700, 50)
+    elif case == "blocks-no-step-divides":
+        x = rng.normal(size=(2, 40, 1344)).astype(np.float32)  # 21 x 64
+        x[:, :, 1300:] = -np.inf
+    else:
+        assert case == "k-below-visible", case
+    if live is not None:
+        col = np.arange(x.shape[-1])
+        x = np.where(col >= np.asarray(live)[..., None, None], -np.inf,
+                     x).astype(np.float32)
+    return x, k, live
+
+
+@pytest.mark.parametrize("case", [
+    "k-below-visible", "k-equals-visible", "k-above-visible",
+    "k-above-every-column", "ties-that-must-be-cut", "every-score-equal",
+    "both-zeros-are-one-value", "rows-of-all-minus-inf", "live-bound-0",
+    "live-bound-inside-a-step", "live-bound-equals-n",
+    "whole-tiles-no-padding", "leading-axes-a-bound-each",
+    "a-bound-a-batch-row", "fewer-rows-than-a-tile",
+    "blocks-as-they-were-scored", "blocks-under-a-live-bound",
+    "blocks-no-step-divides"])
+def test_rows_kernel_selects_what_exact_topk_mask_selects(case):
+    """`exact_topk_mask_rows` (interpreted) against `exact_topk_mask`, bit
+    for bit: k below, at and above the visible count, ties at the k-th
+    value that must be cut (the lower positions win), `-0.0 == +0.0`,
+    rows that see nothing, every kind of live bound, a view the counting
+    step does not divide, leading axes, and scores handed over in the
+    blocks of columns they were scored in (read where they lie when a
+    block is whole counting steps, laid side by side first when not)."""
+    x, k, live = _chunk_scores(case)
+    select = jax.jit(sparse.exact_topk_mask_rows, static_argnums=(1, 3))
+    bound = x.shape[-1] if live is None else live
+    if case.startswith("blocks"):
+        block = 64 if case == "blocks-no-step-divides" else 512
+        blocks = np.moveaxis(x.reshape(2, 40, -1, block), 2, 0)
+        x = x[:, :, :1300]
+        got = np.asarray(select(blocks, k, bound, 1300))
+    else:
+        # jitted: the cases of one shape and k share a compile
+        got = np.asarray(select(x, k, bound, None))
+    # the reference is handed +0.0 for -0.0: jitted on the CPU XLA folds
+    # its `x + 0.0` away and it would order the two zeros
+    want = np.asarray(jax.jit(sparse.exact_topk_mask, static_argnums=1)(
+        x + np.float32(0.0), k))
+    assert got.dtype == want.dtype == np.bool_ and got.shape == x.shape
+    np.testing.assert_array_equal(got, want)
+    visible = (x > -np.inf).sum(-1)
+    np.testing.assert_array_equal(got.sum(-1), np.minimum(visible, k))
+    if case == "ties-that-must-be-cut":
+        np.testing.assert_array_equal(got[1], _oracle(x[1], k))
+        assert (got.sum(-1) < (x >= np.where(got, x, np.inf).min(
+            -1, keepdims=True)).sum(-1)).any()      # some tie was left out
+    scanned, total = sparse.selection_columns(x.shape, live)
+    assert int(total) == x.size
+    if case == "live-bound-inside-a-step":
+        assert int(scanned) == 2 * 40 * 1024        # two whole steps a row
+    elif case == "leading-axes-a-bound-each":
+        assert int(scanned) == 33 * (600 + 0 + 512 + 512 + 600 + 512)
+    elif case == "fewer-rows-than-a-tile" or live is None:
+        assert int(scanned) == int(total)           # XLA's loop, or no bound
+
+
+def _equations(jaxpr):
+    """Equations of a traced program, those of what it calls, loops over
+    and hands a kernel included."""
+    return sum(1 + sum(_equations(inner)
+                       for inner in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("blocked", [False, True],
+                         ids=["row-major", "blocks-as-scored"])
+def test_what_the_rows_kernel_costs_to_lower_does_not_grow_with_the_view(
+        monkeypatch, blocked):
+    """Tracing and lowering run in every process before any compile cache
+    is asked, so the kernel's counting steps are LOOPS in its body, not
+    ladders of conditionals a step: lowered for the chip over a view of
+    4,096 columns (8 counting steps) and of 43,520 (85), the program has
+    the same number of equations and a text of the same size (the ladders
+    read 25 KB against 109 KB)."""
+    monkeypatch.setattr(kernel_mode, "resolve_interpret",
+                        lambda name, interpret=None: False)
+    sizes = []
+    for columns in (4096, 43520):
+        shape = (-(-columns // 1024), 1, 512, 1024) if blocked else (
+            1, 512, columns)
+        traced = jax.jit(
+            lambda x, live, columns=columns: sparse.exact_topk_mask_rows(
+                x, 2048, live, columns=columns if blocked else None)
+        ).trace(jax.ShapeDtypeStruct(shape, jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 1
+        sizes.append((_equations(traced.jaxpr.jaxpr), len(text)))
+    (few, small), (many, large) = sizes
+    assert few == many and few > 100, sizes
+    assert large < 1.5 * small and large < 40_000, sizes
 
 
 def _index_pool(rng, layers, pages, page_size, w):
