@@ -35,10 +35,7 @@ controls (a model that is NOT this one must fail the cell's check);
 `state_dtype` bfloat16 is the reported what-if of a state kept in half the
 bytes.
 
-The serving engine's contract: `forward(config, params, ids, positions=,
-kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
-`init_serving_stats` / `accumulate_serving_stats` / `count_state_zeroed`,
-`generate`.
+`SERVING`, at the foot: docs/serving.md, "What a served family declares".
 """
 
 from __future__ import annotations
@@ -59,6 +56,7 @@ from ..ops.power_retention import (
     state_rows,
 )
 from .common import add_wide, dense, normal_init, part, rms_norm
+from .contract import CacheSpec, ServingContract
 from .decode import build_generate
 
 
@@ -111,8 +109,6 @@ class BrumbyConfig:
 def cache_spec(config: BrumbyConfig):
     """One state a sequence and layer: G matrices of `state_rows` x d and
     as many vectors of `state_rows`, float32 as served."""
-    from ..serving.cache import CacheSpec
-
     return CacheSpec(
         num_layers=config.num_hidden_layers,
         heads=config.num_key_value_heads, width=config.head_dim,
@@ -346,3 +342,8 @@ def init_kv_caches(config: BrumbyConfig, batch: int, max_len: int,
 
 
 generate = build_generate(forward, init_kv_caches)
+
+SERVING = ServingContract(
+    forward=forward, cache_spec=cache_spec, logit_rows=True,
+    init_stats=init_serving_stats, fold_stats=accumulate_serving_stats,
+    count_state_zeroed=count_state_zeroed)

@@ -36,11 +36,7 @@ pool then reach their kernels as whole arrays. Inside a `lax.scan` the
 compiler copies each layer's slice out of a stacked array around every
 kernel call (PERF.md, section 7).
 
-The serving engine's contract (`forward(config, params, ids, positions=,
-kv_caches=) -> (logits, new_caches)`), plus what a family that declares
-`cache_spec` is handed besides (serving/engine.py): `logit_rows`,
-`token_mask`, `return_stats`, and a dense cache whose `cache_len` may be
-one length a row of the batch.
+`SERVING`, at the foot: docs/serving.md, "What a served family declares".
 """
 
 from __future__ import annotations
@@ -58,6 +54,7 @@ from ..ops.grouped_experts import (
 )
 from ..ops.latent_chunk_attention import latent_chunk_attention
 from .common import dense, normal_init, part, rms_norm, rope_frequencies
+from .contract import CacheSpec, ServingContract
 from .decode import build_generate, layer_view, rope_table_len
 
 NEG_INF = -1e30
@@ -138,15 +135,8 @@ class DeepseekConfig:
 def cache_spec(config: DeepseekConfig):
     """What the serving engine's pool holds for this family: one latent
     row a token a layer, no V twin."""
-    from ..serving.cache import CacheSpec
-
     return CacheSpec(num_layers=config.num_hidden_layers, heads=1,
                      width=config.latent_row_width, kind="latent")
-
-
-# prefill may hand `forward` one slot's view a layer at a time
-# (`serving.cache.LayerwiseSlotView`) and takes the chunk's rows back
-takes_layerwise_views = True
 
 
 def init_params(config: DeepseekConfig, key: jax.Array,
@@ -541,3 +531,8 @@ def init_kv_caches(config: DeepseekConfig, batch: int, max_len: int,
 
 
 generate = build_generate(forward, init_kv_caches)
+
+SERVING = ServingContract(
+    forward=forward, cache_spec=cache_spec, logit_rows=True,
+    layerwise_views=True,
+    init_stats=init_serving_stats, fold_stats=accumulate_serving_stats)

@@ -68,9 +68,7 @@ forms of the same mathematics:
   a sliding layer through the ring mode of `latent_paged_decode_attention`
   (`ops/latent_paged_attention.py`).
 
-The serving engine's contract: `forward(config, params, ids, positions=,
-kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
-`init_serving_stats` / `accumulate_serving_stats`, `generate`.
+`SERVING`, at the foot: docs/serving.md, "What a served family declares".
 """
 
 from __future__ import annotations
@@ -102,6 +100,7 @@ from .common import (
     wide_count,  # noqa: F401  (who reads the counters takes it from here)
     write_view,
 )
+from .contract import CacheSpec, ServingContract, WithSide, ring_positions
 from .decode import build_generate, layer_view, rope_table_len
 from .deepseek import (
     _absorb_query,
@@ -113,7 +112,7 @@ from .deepseek import (
     moe_layer,
 )
 from .deepseek import accumulate_serving_stats as _accumulate_experts
-from .keye import (  # noqa: F401 - the chunk counters are the engine's
+from .keye import (
     CHUNK_COUNTERS,
     SELECTION_COUNTERS,
     accumulate_chunk_stats,
@@ -294,8 +293,6 @@ def cache_spec(config: Dots3Config):
     """One latent group a layer kind: every position, and the index key
     beside the row, for the full layers; the last `sliding_window_size`
     positions, in rows of their own width, for the sliding ones."""
-    from ..serving.cache import CacheSpec
-
     groups = _groups(config)
     if groups[0][0] != FULL:
         raise ValueError(
@@ -307,12 +304,6 @@ def cache_spec(config: Dots3Config):
         window=window, layers=layers,
         side_width=config.index_head_dim if kind == FULL else 0)
         for kind, window, layers in groups)
-
-
-# prefill may hand `forward` one slot's views a layer at a time
-# (`serving.cache.LayerwiseSlotView`, one a group) and takes the chunk's
-# rows back
-takes_layerwise_views = True
 
 
 def init_params(config: Dots3Config, key: jax.Array,
@@ -430,8 +421,6 @@ def _attention(config, kind, a, x, rope, positions, cache, token_mask,
     kI or None, this group's PagedDecodeMeta). The new entry is (rows, kI
     or None): the updated views, with `rows_back` this call's own rows, a
     paged step's one row."""
-    from ..serving.cache import ring_positions
-
     c = config
     m = c.mla(kind)
     full = kind == FULL
@@ -588,8 +577,6 @@ def forward(config: Dots3Config, params: dict, input_ids: jax.Array,
     int32 scalars, summed over the real tokens and the full layers; over
     views also "select_columns_scanned", "select_columns_total"
     (`keye.CHUNK_COUNTERS`)}`."""
-    from ..serving.cache import WithSide
-
     c = config
     B, S = input_ids.shape
     groups = _groups(c)
@@ -751,8 +738,6 @@ def init_kv_caches(config: Dots3Config, batch: int, max_len: int,
                    dtype=jnp.bfloat16):
     """Views for `generate`: every group keeps `max_len` rows (a prompt is
     one call here, so a sliding group's view never wraps)."""
-    from ..serving.cache import WithSide
-
     views = []
     for kind, _, layers in _groups(config):
         rows = jnp.zeros((len(layers), batch, max_len, 1,
@@ -766,3 +751,9 @@ def init_kv_caches(config: Dots3Config, batch: int, max_len: int,
 
 
 generate = build_generate(forward, init_kv_caches)
+
+SERVING = ServingContract(
+    forward=forward, cache_spec=cache_spec, logit_rows=True,
+    layerwise_views=True,
+    init_stats=init_serving_stats, fold_stats=accumulate_serving_stats,
+    init_chunk_stats=init_chunk_stats, fold_chunk_stats=accumulate_chunk_stats)

@@ -57,9 +57,7 @@ same mathematics:
   `PagedDecodeMeta`): one token a slot through `ops/sparse_paged_attention.py`
   (the selection there is `exact_topk_mask`, XLA's loop over `[slots, R]`).
 
-The serving engine's contract: `forward(config, params, ids, positions=,
-kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
-`init_serving_stats` / `accumulate_serving_stats`, `generate`.
+`SERVING`, at the foot: docs/serving.md, "What a served family declares".
 """
 
 from __future__ import annotations
@@ -94,7 +92,9 @@ from .common import (
     wide_count,  # noqa: F401  (who reads the counters takes it from here)
     write_view,
 )
+from .contract import CacheSpec, ServingContract, WithSide
 from .decode import build_generate, layer_view, rope_table_len
+from .deepseek import accumulate_serving_stats as _accumulate_experts
 
 
 # the selection's wide device counters, in the order `_attention` tallies
@@ -210,17 +210,10 @@ class KeyeConfig:
 def cache_spec(config: KeyeConfig):
     """K and V rows of every position, and the index key beside them in the
     same pages."""
-    from ..serving.cache import CacheSpec
-
     return CacheSpec(
         num_layers=config.num_hidden_layers,
         heads=config.num_key_value_heads, width=config.head_dim,
         side_width=config.indexer["indexer_head_dim"])
-
-
-# prefill may hand `forward` one slot's views a layer at a time
-# (`serving.cache.LayerwiseSlotView`) and takes the chunk's rows back
-takes_layerwise_views = True
 
 
 def init_params(config: KeyeConfig, key: jax.Array,
@@ -431,8 +424,6 @@ def forward(config: KeyeConfig, params: dict, input_ids: jax.Array,
     tokens and the layers; over views also "select_columns_scanned",
     "select_columns_total": the columns the selection read and the columns
     of the view, times the query rows, summed over the layers}`."""
-    from ..serving.cache import WithSide
-
     c = config
     B, S = input_ids.shape
     paged = kv_caches is not None and getattr(
@@ -543,15 +534,13 @@ def init_serving_stats(config: KeyeConfig) -> dict:
 
 
 def accumulate_serving_stats(total: dict, call: dict) -> dict:
-    from .deepseek import accumulate_serving_stats as experts
-
     with part("attn.select"):
         keys = dict(
             keys_visible=add_wide(total["keys_visible"],
                                   call["keys_visible"]),
             keys_selected=add_wide(total["keys_selected"],
                                    call["keys_selected"]))
-    return dict(experts(total, call), **keys)
+    return dict(_accumulate_experts(total, call), **keys)
 
 
 def init_chunk_stats(config) -> dict:
@@ -569,8 +558,6 @@ def accumulate_chunk_stats(total: dict, call: dict) -> dict:
 def init_kv_caches(config: KeyeConfig, batch: int, max_len: int,
                    dtype=jnp.bfloat16):
     """Views for `generate`."""
-    from ..serving.cache import WithSide
-
     L = config.num_hidden_layers
     kv = jnp.zeros((L, batch, max_len, config.num_key_value_heads,
                     config.head_dim), dtype)
@@ -580,3 +567,9 @@ def init_kv_caches(config: KeyeConfig, batch: int, max_len: int,
 
 
 generate = build_generate(forward, init_kv_caches)
+
+SERVING = ServingContract(
+    forward=forward, cache_spec=cache_spec, logit_rows=True,
+    layerwise_views=True,
+    init_stats=init_serving_stats, fold_stats=accumulate_serving_stats,
+    init_chunk_stats=init_chunk_stats, fold_chunk_stats=accumulate_chunk_stats)

@@ -43,9 +43,7 @@ Layers are a LIST of per-layer dicts walked by a Python loop, as in
 at the published widths) and the pools reach their kernels as whole
 arrays.
 
-The serving engine's contract: `forward(config, params, ids, positions=,
-kv_caches=, logit_rows=, token_mask=, return_stats=)`, `cache_spec`,
-`init_serving_stats` / `accumulate_serving_stats`, `generate`.
+`SERVING`, at the foot: docs/serving.md, "What a served family declares".
 """
 
 from __future__ import annotations
@@ -68,8 +66,9 @@ from .common import (
     softmax_moe_layer,
     write_view,
 )
+from .contract import CacheSpec, ServingContract, ring_positions
 from .decode import build_generate, layer_view, rope_table_len
-from .deepseek import accumulate_serving_stats  # noqa: F401 - the contract
+from .deepseek import accumulate_serving_stats as _accumulate_experts
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -190,8 +189,6 @@ def _groups(config: MellumConfig):
 def cache_spec(config: MellumConfig):
     """One group a layer kind: every position for the full layers, the
     last `sliding_window` for the sliding ones."""
-    from ..serving.cache import CacheSpec
-
     groups = _groups(config)
     if groups[0][0] != FULL:
         raise ValueError(
@@ -201,12 +198,6 @@ def cache_spec(config: MellumConfig):
         num_layers=len(layers), heads=config.num_key_value_heads,
         width=config.head_dim, window=window, layers=layers)
         for _, window, layers in groups)
-
-
-# prefill may hand `forward` one slot's views a layer at a time
-# (`serving.cache.LayerwiseSlotView`, one a group) and takes the chunk's
-# rows back
-takes_layerwise_views = True
 
 
 def init_params(config: MellumConfig, key: jax.Array,
@@ -260,8 +251,6 @@ def _attend_view(config, q, k, v, positions, view_k, view_v, start, window,
     the queries attended over it -> (out, new view k, new view v), or with
     `rows_back` (out, this call's rows k, v [B, S, Hkv, D] as the views
     hold them)."""
-    from ..serving.cache import ring_positions
-
     S, R = q.shape[1], view_k.shape[1]
     wraps = window is not None
     view_k = write_view(view_k, k, start, wraps)
@@ -450,7 +439,7 @@ def forward(config: MellumConfig, params: dict, input_ids: jax.Array,
 
 def init_serving_stats(config: MellumConfig) -> dict:
     """The device counters one engine program accumulates
-    (`accumulate_serving_stats`, shared with `models/deepseek.py`), all
+    (folded by `models/deepseek.py`'s `accumulate_serving_stats`), all
     zero: assignments per expert per layer, the distinct experts a call
     touched in each layer summed over calls, and the calls."""
     n = config.num_hidden_layers
@@ -470,3 +459,8 @@ def init_kv_caches(config: MellumConfig, batch: int, max_len: int,
 
 
 generate = build_generate(forward, init_kv_caches)
+
+SERVING = ServingContract(
+    forward=forward, cache_spec=cache_spec, logit_rows=True,
+    layerwise_views=True,
+    init_stats=init_serving_stats, fold_stats=_accumulate_experts)

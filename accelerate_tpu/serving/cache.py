@@ -66,13 +66,14 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..models.common import part
+from ..models.contract import CacheSpec, WithSide, ring_positions  # noqa: F401
 from ..telemetry.trace import span
 
 
@@ -180,68 +181,6 @@ jax.tree_util.register_pytree_node(SlotKVCache, _flatten, _unflatten)
 # ---------------------------------------------------------------------------
 # paged pool (device side)
 # ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class CacheSpec:
-    """What a family caches for one token in one layer, as the family
-    declares it (`family.cache_spec(config)`; families that declare
-    nothing are GQA/MHA stacks and get `kind="kv"` from their config).
-
-    `kind="kv"`: a K row and a V row of `heads` x `width`, two pools.
-    `kind="latent"`: ONE row of `heads` x `width` (heads = 1 for MLA)
-    that is key and value at once; the pool is one array and
-    `PagedKVCache.v` is None. A page is `page_size` token positions of
-    either kind, so the allocator, the prefix index, admission and the
-    scheduler do not know the kind.
-
-    A family whose layers differ in KIND returns a tuple of these, one
-    GROUP a layer kind (`GroupedPagedCache`): `layers` are the model's
-    layers the group holds, in order, and `window` is the group's
-    retention rule: None keeps every position of a request, W keeps the
-    last W (a ring of pages a slot, whatever the request's length). The
-    first group keeps every position: it is the one whose pages grow with
-    the context, and the one the allocator's books, the prefix index and
-    the engine's page gauges mean.
-
-    `kind="state"`: NO row a token. What a layer keeps of a sequence is
-    one STATE of fixed size, whatever the sequence's length: `heads`
-    matrices of `state_rows` x `width` and as many vectors of `state_rows`,
-    in `state_dtype` (`StateCache`; `ops/power_retention.py` says what the
-    rows are). It is not addressed by position, so nothing of it can be
-    shared, published or cut at a page: the pool's unit, where the
-    allocator and the gauges say "page", is an ENTRY, one sequence's
-    whole state in every layer.
-
-    `side_width` > 0: a third per-token row of that many lanes, in the
-    pool's dtype, that lives in the SAME pages as K and V (`PagedKVCache`,
-    SIDE ROW): what a family keeps for a second scorer of its keys (a
-    learned indexer's key). Like K and V it depends only on the tokens
-    before it, so it is cached, shared and released with its page."""
-
-    num_layers: int
-    heads: int
-    width: int
-    kind: str = "kv"
-    window: int | None = None
-    layers: tuple | None = None
-    side_width: int = 0
-    state_rows: int = 0
-    state_dtype: Any = jnp.float32
-
-    @property
-    def label(self) -> str:
-        """The group's name in gauges and debug output."""
-        return "full" if self.window is None else f"window{self.window}"
-
-
-class WithSide(NamedTuple):
-    """What stands in K's place wherever a cache with a side row hands K
-    to a family or takes it back: K's rows, views or pool, and the side
-    row's beside them (the same leading axes, one head)."""
-
-    rows: Any
-    side: Any
 
 
 def _split_side(cache, k):
@@ -583,6 +522,24 @@ def paged_slot_view(cache: PagedKVCache, table_row: jax.Array,
             cache, cache.side, None, table_row, 1, cache.side_width,
             side=True) if by_layer else _side_view(cache, table_row, 1))
     return ks, vs, cache.lengths[slot]
+
+
+def paged_decode_operands(cache: PagedKVCache):
+    """(K, V) as a family forward takes the WHOLE pools under the paged
+    kernels (`ops.paged_attention.PagedKV`: nothing is gathered, the kernel
+    walks the page table): one pool a group under a grouped cache, a
+    `WithSide` in K's place where there is a side row, None in V's for
+    latent rows. The decode-side twin of `paged_slot_view`."""
+    from ..ops.paged_attention import PagedKV
+
+    if isinstance(cache, GroupedPagedCache):
+        ks, vs = zip(*(paged_decode_operands(g) for g in cache.groups))
+        return ks, None if cache.latent else vs
+    k = PagedKV(cache.k, cache.k_scale, cache.compute_dtype)
+    if cache.side is not None:
+        k = WithSide(k, PagedKV(cache.side, None, cache.compute_dtype))
+    return k, None if cache.latent else PagedKV(
+        cache.v, cache.v_scale, cache.compute_dtype)
 
 
 @part("cache.write")
@@ -990,16 +947,6 @@ jax.tree_util.register_pytree_node(
                                compute_dtype=aux[2]))
 
 
-def ring_positions(rows: int, last):
-    """The position each of a ring view's `rows` rows holds once
-    positions 0..`last` are written (`last` [...] int32 -> [..., rows]):
-    row r holds the newest position that is r modulo `rows`; a negative
-    position means that nothing of this request is there. A view that
-    never wraps (`last < rows`) is the same rule."""
-    last = jnp.asarray(last, jnp.int32)[..., None]
-    return last - (last - jnp.arange(rows, dtype=jnp.int32)) % rows
-
-
 @dataclasses.dataclass(frozen=True)
 class GroupedPagedCache:
     """The cache of a family whose layers differ in kind: one
@@ -1079,6 +1026,29 @@ jax.tree_util.register_pytree_node(
     GroupedPagedCache,
     lambda c: (c.groups, c.layers),
     lambda layers, groups: GroupedPagedCache(tuple(groups), layers))
+
+
+def create_cache(spec, engine_config, pad_slack: int, stats: Any = None):
+    """The cache a family's declared `spec` gets, at an `EngineConfig`'s
+    sizes: a tuple of specs a `GroupedPagedCache`, `kind="state"` a
+    `StateCache`, rows of either other kind a `PagedKVCache`."""
+    ec = engine_config
+    if isinstance(spec, tuple):
+        return GroupedPagedCache.create(
+            spec, ec.num_slots, ec.max_len, dtype=ec.cache_dtype,
+            page_size=ec.page_size, pad_slack=pad_slack,
+            num_pages=ec.num_pages, stats=stats)
+    if spec.kind == "state":
+        # `num_pages` counts ENTRIES (one a sequence, a spare besides)
+        return StateCache.create(
+            spec, ec.num_slots, ec.max_len, dtype=ec.cache_dtype,
+            pad_slack=pad_slack, num_entries=ec.num_pages, stats=stats)
+    return PagedKVCache.create(
+        spec.num_layers, ec.num_slots, ec.max_len, spec.heads, spec.width,
+        dtype=ec.cache_dtype, page_size=ec.page_size, pad_slack=pad_slack,
+        num_pages=ec.num_pages, kv_dtype=ec.kv_dtype,
+        latent=spec.kind == "latent", stats=stats,
+        side_width=spec.side_width)
 
 
 # ---------------------------------------------------------------------------

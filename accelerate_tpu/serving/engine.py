@@ -44,7 +44,7 @@ Token delivery is one small device->host read a program: `prefill` and
 logprobs, apart from the donated [S] token register), and the engine reads
 it ONE STEP LATE: `step()` dispatches the next program first and only then
 fetches and commits the last one's results, so the chip works through the
-read and the host pass instead of waiting for them (`_Unread`, `_settle`).
+read and the host pass instead of waiting for them (`_Unread`, `settle`).
 A token reaches `request.tokens` in the `step()` after the one that
 computed it; `stream()`/`astream()`/`run_until_idle()` drive until every
 token is committed.
@@ -76,8 +76,8 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
-import inspect
 import time
+from contextlib import nullcontext
 from functools import partial
 from typing import Any, AsyncIterator, Iterator, NamedTuple
 
@@ -85,7 +85,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.common import part
+from ..models.common import count_params, part
+from ..models.contract import CacheSpec, ServingContract
 from ..models.decode import sample_token
 from ..profiler import StepTimer, causal_lm_infer_flops
 from ..telemetry.cost import CostTable, resolve_sample_every
@@ -101,18 +102,15 @@ from ..telemetry.trace import (
 )
 from ..telemetry.watchdog import StallWatchdog, resolve_stall_timeout
 from .cache import (
-    CacheSpec,
-    GroupedPagedCache,
     PagedAllocator,
-    PagedKVCache,
     SlotKVCache,
-    StateCache,
-    WithSide,
+    create_cache,
     paged_admit_slot,
     paged_append_batch,
     paged_append_rows,
     paged_append_window,
     paged_batch_view,
+    paged_decode_operands,
     paged_slot_view,
     paged_write_chunk,
     paged_write_slot,
@@ -351,22 +349,6 @@ class EngineConfig:
     mesh: Any = None
 
 
-def _cache_spec(config, family=None):
-    """What the pool holds for a token in a layer. A family that declares
-    `cache_spec(config)` says so itself: a latent pool, or a TUPLE of
-    specs, one group a layer kind, for layers that differ in kind. Every
-    other family is a K/V stack read off its config: GQA families carry
-    num_key_value_heads, MHA families fall back to num_attention_heads."""
-    declared = getattr(family, "cache_spec", None)
-    if declared is not None:
-        spec = declared(config)
-        return tuple(spec) if isinstance(spec, (tuple, list)) else spec
-    kv = getattr(config, "num_key_value_heads", None)
-    if kv is None:
-        kv = config.num_attention_heads
-    return CacheSpec(config.num_hidden_layers, kv, config.head_dim)
-
-
 # The engine options a pool OTHER than one K/V stack does not implement
 # yet, by the trait of the cache a family declares (`cache_spec`): what the
 # error calls the trait's fallback, then for each option what porting it
@@ -512,8 +494,9 @@ class Engine:
 
     `family` is any model-zoo module following the uniform decode contract
     (`forward(config, params, ids, positions=..., kv_caches=...) ->
-    (logits, new_caches)` — see models/decode.py), or that forward callable
-    directly.
+    (logits, new_caches)` — see models/decode.py), that forward callable
+    directly, or a `models.contract.ServingContract`; what a module asks
+    for beyond a K/V stack is its `SERVING`.
     """
 
     def __init__(
@@ -538,18 +521,13 @@ class Engine:
             # tripping the meshed strict audit, which demands sharded
             # args and TP reductions that can never exist on one chip
             self.engine_config = ec = dataclasses.replace(ec, mesh=None)
-        self._forward = family if callable(family) else family.forward
-        # the page shape is the family's to declare (`cache_spec`); what
-        # its forward is handed beyond the uniform decode contract follows
-        # from what it takes (`_build_programs`)
-        self._cache_spec = _cache_spec(config, family)
+        # what the family declares is read HERE and nowhere else
+        self._serving = serving = ServingContract.of(family)
+        spec = serving.cache_spec(config)
         # one group a layer kind (serving/cache.py GroupedPagedCache), or
         # None: the one pool every layer shares
-        self._cache_groups = None
-        if isinstance(self._cache_spec, tuple):
-            self._cache_groups = self._cache_spec
-            self._cache_spec = self._cache_groups[0]
-        self._family = family
+        self._cache_groups = spec if isinstance(spec, tuple) else None
+        self._cache_spec = spec[0] if self._cache_groups else spec
         self._tracker = tracker
         self._log_every = log_every
         self._last_logged = 0
@@ -583,7 +561,7 @@ class Engine:
                     f"draft vocab_size ({getattr(dcfg, 'vocab_size', None)})"
                     f" must match the target's ({config.vocab_size}): "
                     "drafted tokens are verified by id")
-            self._draft_forward = dfam if callable(dfam) else dfam.forward
+            self._draft_serving = ServingContract.of(dfam)
             self._draft_config = dcfg
             self._draft_params = dparams
         self._use_paged_kernel = _resolve_paged_attention(
@@ -606,8 +584,6 @@ class Engine:
         self._audited: dict = {}
         self._sanitize = resolve_sanitize(ec.sanitize)
 
-        spec = self._cache_spec
-        stats = getattr(family, "init_serving_stats", None)
         # pad_slack covers BOTH overshoot sources: chunk padding can spill
         # chunk-1 rows past max_len, and a speculative verify can write up
         # to draft_k candidate rows past the last budgeted token (the slot
@@ -617,36 +593,17 @@ class Engine:
                               ec.draft_k if self._spec else 0)
         # one set of counters a program: a reader wants the decode steps'
         # experts apart from the chunks'
-        stats = None if stats is None else {
-            "prefill": stats(config), "decode": stats(config)}
+        stats = None if serving.init_stats is None else {
+            "prefill": serving.init_stats(config),
+            "decode": serving.init_stats(config)}
         # what a family counts in its prefill chunks ALONE is kept out of
         # the cache: `decode` is not handed it
-        chunk_stats = getattr(family, "init_chunk_stats", None)
-        self._chunk_stats = (None if chunk_stats is None
-                             else chunk_stats(config))
-        if self._cache_groups is not None:
-            self.cache = GroupedPagedCache.create(
-                self._cache_groups, ec.num_slots, ec.max_len,
-                dtype=ec.cache_dtype, page_size=ec.page_size,
-                pad_slack=self._pad_slack, num_pages=ec.num_pages,
-                stats=stats)
-        elif spec.kind == "state":
-            # `num_pages` counts the pool's entries, one a sequence (a
-            # spare besides); `page_size` means nothing to it
-            self.cache = StateCache.create(
-                spec, ec.num_slots, ec.max_len, dtype=ec.cache_dtype,
-                pad_slack=self._pad_slack, num_entries=ec.num_pages,
-                stats=stats)
-        else:
-            self.cache = PagedKVCache.create(
-                spec.num_layers, ec.num_slots, ec.max_len, spec.heads,
-                spec.width, dtype=ec.cache_dtype, page_size=ec.page_size,
-                pad_slack=self._pad_slack, num_pages=ec.num_pages,
-                kv_dtype=ec.kv_dtype, latent=spec.kind == "latent",
-                stats=stats, side_width=spec.side_width)
+        self._chunk_stats = (None if serving.init_chunk_stats is None
+                             else serving.init_chunk_stats(config))
+        self.cache = create_cache(spec, ec, self._pad_slack, stats)
         if self._spec:
-            draft = _cache_spec(self._draft_config, dfam)
-            if draft.kind != "kv":
+            draft = self._draft_serving.cache_spec(self._draft_config)
+            if isinstance(draft, tuple) or draft.kind != "kv":
                 raise ValueError(
                     "a draft model with a latent cache is not implemented")
             dl, dkv, dhd = draft.num_layers, draft.heads, draft.width
@@ -700,7 +657,7 @@ class Engine:
             on_unmap=self._unmap_slot,
             rings=tuple((g.pages_per_slot, g.num_pages)
                         for g in self._ring_groups()),
-            state_entries=spec.kind == "state",
+            state_entries=self._cache_spec.kind == "state",
         )
         # COW forking: parent_id -> parent handle, consulted by the
         # admission hold below (entries drop as parents reach a terminal
@@ -792,33 +749,23 @@ class Engine:
     # -- compiled programs ---------------------------------------------------
 
     def _build_programs(self) -> None:
-        forward, config = self._forward, self.config
+        forward, config = self._serving.forward, self.config
         chunk = self.engine_config.prefill_chunk
         # what a family is handed beyond the uniform decode contract
-        # follows from what it takes, one thing at a time: a forward with a
-        # `logit_rows` keyword computes the head for the one row that is
-        # read; a family with `accumulate_serving_stats` counts on the
-        # device (`token_mask`, `return_stats`) and sees all slots' tokens
-        # in ONE dense-decode forward, as under the kernel
-        try:
-            one_row = "logit_rows" in inspect.signature(forward).parameters
-        except (TypeError, ValueError):
-            one_row = False
-        fold_stats = getattr(self._family, "accumulate_serving_stats", None)
-        fold_chunk = getattr(self._family, "accumulate_chunk_stats", None)
+        # follows from what it DECLARES, one field a thing (`ServingContract`
+        # says what each means); a family that counts sees all slots'
+        # tokens in ONE dense-decode forward, as under the kernel
+        serving = self._serving
+        one_row, layerwise = serving.logit_rows, serving.layerwise_views
+        fold_stats, fold_chunk = serving.fold_stats, serving.fold_chunk_stats
+        count_zeroed = serving.count_state_zeroed
         grouped = self._cache_groups is not None
-        # a family that loops over its layers says that a prefill chunk may
-        # be handed its slot's views a layer at a time and gives the
-        # chunk's rows back; every other one takes the stacked views and
-        # returns them updated
-        layerwise = getattr(self._family, "takes_layerwise_views", False)
         # a family that keeps a state a sequence is handed the whole pool
         # and hands it back; `kernel` says which form its ops take
         state = self._cache_spec.kind == "state"
         kernel = self._use_paged_kernel
         if state:
             from ..ops.power_retention import StateMeta
-        count_zeroed = getattr(self._family, "count_state_zeroed", None)
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
@@ -985,23 +932,10 @@ class Engine:
                         pool, lengths + live.astype(jnp.int32))
                 return cache, tokens, (next_tok, lps)
         elif self._use_paged_kernel:
-            from ..ops.paged_attention import PagedDecodeMeta, PagedKV
+            from ..ops.paged_attention import PagedDecodeMeta
 
             rows = self.cache.rows
             latent = self._cache_spec.kind == "latent"
-
-            def pools(cache, which):
-                """The K (or V) pool as a family forward takes it: one a
-                group under a grouped cache."""
-                if isinstance(cache, GroupedPagedCache):
-                    return tuple(pools(g, which) for g in cache.groups)
-                data, scales = ((cache.k, cache.k_scale) if which == "k"
-                                else (cache.v, cache.v_scale))
-                pool = PagedKV(data, scales, cache.compute_dtype)
-                if which == "k" and cache.side is not None:
-                    return WithSide(pool, PagedKV(cache.side, None,
-                                                  cache.compute_dtype))
-                return pool
 
             @partial(jax.jit, donate_argnums=don, out_shardings=step_out)
             def decode(params, cache, tokens, slot_keys, temps, live, table):
@@ -1019,8 +953,7 @@ class Engine:
                 with part("cache.view"):
                     walked = (lengths if latent and not grouped
                               else jnp.where(live, lengths, 0))
-                kvc = (pools(cache, "k"),
-                       None if latent else pools(cache, "v"),
+                kvc = (*paged_decode_operands(cache),
                        PagedDecodeMeta(table, walked, rows=rows))
                 logits, (row_k, row_v, _), cache, _ = serving_forward(
                     "decode", params, cache, tokens[:, None],
@@ -1106,8 +1039,8 @@ class Engine:
         tag) with distinct tags — still slot-decorrelated and
         schedule-independent, and independent of each other, which is
         what the rejection-sampling correctness argument requires."""
-        forward, config = self._forward, self.config
-        dforward, dcfg = self._draft_forward, self._draft_config
+        forward, config = self._serving.forward, self.config
+        dforward, dcfg = self._draft_serving.forward, self._draft_config
         chunk = self.engine_config.prefill_chunk
         K = self.engine_config.draft_k
         S = self.engine_config.num_slots
@@ -1244,15 +1177,15 @@ class Engine:
         self._verify_p = verify
 
     def device_counters(self) -> dict:
-        """The family's own counters (`family.init_serving_stats`), one
+        """The family's own counters (`ServingContract.init_stats`), one
         set a program ("prefill", "decode"), as NumPy arrays; {} for a
         family that declares none. "prefill" also holds what the family
-        counts in its chunks alone (`family.init_chunk_stats`). They
+        counts in its chunks alone (`init_chunk_stats`). They
         accumulate on the device inside the two programs and cross to the
         host HERE, on demand: nothing on a step's path reads them."""
         if self.cache is None or self.cache.stats is None:
             return {}
-        self._settle()  # counts and committed tokens of the same programs
+        self.settle()  # counts and committed tokens of the same programs
         stats = dict(self.cache.stats)
         if self._chunk_stats is not None:
             stats["prefill"] = dict(stats["prefill"], **self._chunk_stats)
@@ -1416,7 +1349,7 @@ class Engine:
         )
 
     def cancel(self, request: Request) -> bool:
-        self._settle()  # a token on its way may finish the request first
+        self.settle()  # a token on its way may finish the request first
         if self.scheduler.cancel(request):
             self._finalize_request(request)
             return True
@@ -1426,7 +1359,7 @@ class Engine:
         """Retire a running request as FINISHED before its budget (e.g.
         a server-side stop sequence matched): counts in the finished/
         latency metrics, prompt pages cached for reuse."""
-        self._settle()
+        self.settle()
         if self.scheduler.finish_early(request):
             self._finalize_request(request)
             return True
@@ -1500,7 +1433,7 @@ class Engine:
         if self._spec:
             # the speculative step's own books (draft progress, accepted
             # counts) decide its next inputs: it keeps the synchronous order
-            self._settle()
+            self.settle()
         with _phase("serving.bookkeeping"):
             self.metrics.stopped_at = self._clock()
             # the EMA behind the scheduler's SLO / Retry-After estimates —
@@ -1556,7 +1489,7 @@ class Engine:
     def _leave_unread(self, program: str, out, lanes: list) -> None:
         """The program just dispatched owes `lanes` a token each: start
         its results' copy to the host and leave the read to the next
-        `step()` (or to `_settle`)."""
+        `step()` (or to `settle`)."""
         for leaf in out:
             leaf.copy_to_host_async()
         for slot, _, _ in lanes:
@@ -1587,7 +1520,7 @@ class Engine:
                     finished += 1
             sp.set(finished=finished)
 
-    def _settle(self) -> None:
+    def settle(self) -> None:
         """Commit the unread program's results now. Whatever acts on a
         request's books from outside `step()` (cancel, finish, a counter
         read, a metrics reset) calls this first, so that it never sees a
@@ -1713,6 +1646,25 @@ class Engine:
         self.cost.register(name, src,
                            fallback=lambda: self._analytic_cost(name))
 
+    def _dispatch(self, name: str, program, args: tuple, fence_in, opened,
+                  timed: bool = True, unread: list | None = None):
+        """The ONE way a program of the engine's own is dispatched -> its
+        outputs: strict audit and cost sheet (each at a program's first
+        dispatch), the sampled fence pair from `fence_in` to the outputs,
+        and inside it `opened`, the caller's span, around the call (`timed`:
+        under `timer.dispatch()`; all but `admit`). `unread`: the lanes owed
+        a token each, left unread INSIDE the span (`decode`'s)."""
+        self._strict_audit(name, program, args)
+        self._ensure_cost(name, program, args)
+        with self.cost.maybe_sample(name, fence_in=fence_in) as sample:
+            with opened:
+                with self.timer.dispatch() if timed else nullcontext():
+                    out = program(*args)
+                if unread is not None:
+                    self._leave_unread(name, out[2], unread)
+            sample(out)
+        return out
+
     def _analytic_cost(self, name: str) -> tuple[float, float]:
         """Analytic fallback (flops, bytes) per program call when the
         backend reports nothing: ~2 FLOPs/param/token + the attention-
@@ -1720,11 +1672,9 @@ class Engine:
         full weight read + the KV rows touched. The mid-stream context
         length is unknown statically; max_len/2 is the documented
         approximation."""
-        cfg, ec = self.config, self.engine_config
-        from ..models.common import count_params
-
+        cfg, ec, serving = self.config, self.engine_config, self._serving
         if name in ("draft", "draft_prefill"):
-            cfg = self._draft_config
+            cfg, serving = self._draft_config, self._draft_serving
             if getattr(self, "_n_draft_params", None) is None:
                 self._n_draft_params = count_params(self._draft_params)
             n = self._n_draft_params
@@ -1732,8 +1682,7 @@ class Engine:
             if self._n_params is None:
                 self._n_params = count_params(self.params)
             n = self._n_params
-        # a draft model is always a K/V stack (checked at construction)
-        spec = _cache_spec(cfg, self._family if cfg is self.config else None)
+        spec = serving.cache_spec(cfg)
         if isinstance(spec, tuple):
             # groups: every layer counted as keeping its rows whole (an
             # upper bound for the window groups)
@@ -1963,20 +1912,14 @@ class Engine:
                     self._draft_cache.lengths) + tail
         else:
             args = (self.cache, self._slot_keys, self._temps) + tail
-        self._strict_audit("admit", self._admit_p, args)
-        self._ensure_cost("admit", self._admit_p, args)
-        with self.cost.maybe_sample("admit", fence_in=self.cache) as sample:
-            with self._request_span("serving.admit", req, slot=slot.index,
-                                    reused_len=alloc.reused_len):
-                if self._spec:
-                    (self.cache, self._slot_keys, self._temps,
-                     dlengths) = self._admit_p(*args)
-                    self._draft_cache = dataclasses.replace(
-                        self._draft_cache, lengths=dlengths)
-                else:
-                    self.cache, self._slot_keys, self._temps = \
-                        self._admit_p(*args)
-            sample(self.cache)
+        out = self._dispatch(
+            "admit", self._admit_p, args, self.cache,
+            self._request_span("serving.admit", req, slot=slot.index,
+                               reused_len=alloc.reused_len), timed=False)
+        self.cache, self._slot_keys, self._temps = out[:3]
+        if self._spec:
+            self._draft_cache = dataclasses.replace(
+                self._draft_cache, lengths=out[3])
         if self.on_admit is not None:
             self.on_admit(slot, req)
 
@@ -1994,16 +1937,10 @@ class Engine:
             ids[:real] = req.prompt[start:start + real]
             args = (self._draft_params, self._draft_cache,
                     jnp.int32(slot.index), ids, jnp.int32(real))
-            self._strict_audit("draft_prefill", self._draft_prefill_p, args)
-            self._ensure_cost("draft_prefill", self._draft_prefill_p, args)
-        with self.cost.maybe_sample(
-                "draft_prefill", fence_in=self._draft_cache) as sample:
-            with self._request_span("serving.draft_prefill", req,
-                                    slot=slot.index, chunk_start=start,
-                                    chunk_tokens=real), \
-                    self.timer.dispatch():
-                self._draft_cache = self._draft_prefill_p(*args)
-            sample(self._draft_cache)
+        self._draft_cache = self._dispatch(
+            "draft_prefill", self._draft_prefill_p, args, self._draft_cache,
+            self._request_span("serving.draft_prefill", req, slot=slot.index,
+                               chunk_start=start, chunk_tokens=real))
         slot.draft_done += real
 
     def _run_prefill_chunk(self, slot: Slot) -> None:
@@ -2030,16 +1967,10 @@ class Engine:
                     self._temps, jnp.int32(slot.index),
                     self._tables(slot.index), ids, jnp.int32(real),
                     self._chunk_stats)
-            self._strict_audit("prefill", self._prefill_p, args)
-            self._ensure_cost("prefill", self._prefill_p, args)
-        with self.cost.maybe_sample(
-                "prefill", fence_in=(self.cache, self._tokens)) as sample:
-            with self._request_span("serving.prefill", req, slot=slot.index,
-                                    chunk_start=start, chunk_tokens=real), \
-                    self.timer.dispatch():
-                self.cache, self._tokens, out, self._chunk_stats = \
-                    self._prefill_p(*args)
-            sample(self.cache)
+        self.cache, self._tokens, out, self._chunk_stats = self._dispatch(
+            "prefill", self._prefill_p, args, (self.cache, self._tokens),
+            self._request_span("serving.prefill", req, slot=slot.index,
+                               chunk_start=start, chunk_tokens=real))
         if self._spec:
             # joint chunk: the draft processes the same window, so both
             # prompts complete on the same engine step
@@ -2070,19 +2001,13 @@ class Engine:
                 live[s.index] = True
             args = (self.params, self.cache, self._tokens, self._slot_keys,
                     self._temps, live, self._tables())
-            self._strict_audit("decode", self._decode_p, args)
             links = self._step_links(slots)
-            self._ensure_cost("decode", self._decode_p, args)
-        with self.cost.maybe_sample(
-                "decode", fence_in=(self.cache, self._tokens)) as sample:
-            with span("serving.decode", links=links):
-                with self.timer.dispatch():
-                    self.cache, self._tokens, out = self._decode_p(*args)
-                self.metrics.note_decode_step(
-                    "kernel" if self._use_paged_kernel else "dense")
-                self._leave_unread("decode", out,
-                                   [(s, s.request, s.index) for s in slots])
-            sample(self.cache)
+        self.cache, self._tokens, _ = self._dispatch(
+            "decode", self._decode_p, args, (self.cache, self._tokens),
+            span("serving.decode", links=links),
+            unread=[(s, s.request, s.index) for s in slots])
+        self.metrics.note_decode_step(
+            "kernel" if self._use_paged_kernel else "dense")
 
     def _run_spec_decode(self, slots: list[Slot]) -> None:
         """One speculative step for every decoding slot: draft K
@@ -2101,27 +2026,17 @@ class Engine:
             links = self._step_links(slots)
             dargs = (self._draft_params, self._draft_cache, self._tokens,
                      self._slot_keys, self._temps)
-            self._strict_audit("draft", self._draft_p, dargs)
-            self._ensure_cost("draft", self._draft_p, dargs)
-        with self.cost.maybe_sample(
-                "draft", fence_in=self._draft_cache) as sample:
-            with span("serving.draft", links=links), \
-                    self.timer.dispatch():
-                d_toks, d_logits, new_dcache = self._draft_p(*dargs)
-            sample(new_dcache)
+        d_toks, d_logits, new_dcache = self._dispatch(
+            "draft", self._draft_p, dargs, self._draft_cache,
+            span("serving.draft", links=links))
         with _phase("serving.stage_inputs",
                     h2d_bytes=self._table.nbytes + num_slots):
             vargs = (self.params, self.cache, self._tokens, self._slot_keys,
                      self._temps, live, self._table, d_toks, d_logits)
-            self._strict_audit("verify", self._verify_p, vargs)
-            self._ensure_cost("verify", self._verify_p, vargs)
-        with self.cost.maybe_sample(
-                "verify", fence_in=(self.cache, self._tokens)) as sample:
-            with span("serving.verify", links=links), \
-                    self.timer.dispatch():
-                (self.cache, self._tokens, committed, counts, n_acc,
-                 lps) = self._verify_p(*vargs)
-            sample(self.cache)
+        (self.cache, self._tokens, committed, counts, n_acc,
+         lps) = self._dispatch(
+            "verify", self._verify_p, vargs, (self.cache, self._tokens),
+            span("serving.verify", links=links))
         # the draft cache's valid rows now equal the target's: adopt the
         # committed lengths (rejected proposals' draft rows fall past the
         # length, masked exactly like the target's rejected rows) — but
@@ -2375,7 +2290,7 @@ class Engine:
         programs, slot state, and in-flight requests are untouched. The
         registry's series objects survive (zeroed in place), so the
         Prometheus endpoint and any cached metric handles stay live."""
-        self._settle()  # a read belongs to the window of its dispatch
+        self.settle()  # a read belongs to the window of its dispatch
         self.registry.reset()
         self.metrics = ServingMetrics(registry=self.registry)
         # static program costs survive a metrics reset (the compiled

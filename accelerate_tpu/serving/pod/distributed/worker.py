@@ -528,7 +528,7 @@ class WorkerServer:
                 # the engine reads a step's results one step late; a
                 # prefill job's first token ships the moment the chip has
                 # it, and its slot retires before it rides a decode step
-                self.engine._settle()
+                self.engine.settle()
             self._last_step_s = self._clock() - t0
             worked = True
         self._harvest_prefill()

@@ -1,6 +1,6 @@
 """A prefill chunk over its slot's views a layer at a time
 (`serving.cache.LayerwiseSlotView`, `paged_write_chunk`: what the engine
-hands a family that declares `takes_layerwise_views`) against the same
+hands a family that declares `layerwise_views`) against the same
 chunk over the whole stacked views (`paged_slot_view`, `paged_write_slot`:
 what every other family is handed): the chunk's logits and every byte of
 the pools afterwards are EQUAL, bit for bit, in the three families that
@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.models import deepseek, keye, llama, mellum
+from accelerate_tpu.models.contract import ServingContract
 from accelerate_tpu.serving import Engine, EngineConfig
 from accelerate_tpu.serving.cache import (
     GroupedPagedCache,
@@ -26,7 +27,6 @@ from accelerate_tpu.serving.cache import (
     paged_write_chunk,
     paged_write_slot,
 )
-from accelerate_tpu.serving.engine import _cache_spec
 
 PAGE, CHUNK, SLOT = 8, 8, 1
 
@@ -73,7 +73,7 @@ def _setup(name, max_len, length):
     family, tiny = FAMILIES[name]
     cfg = tiny()
     params = family.init_params(cfg, jax.random.key(1), jnp.float32)
-    spec = _cache_spec(cfg, family)
+    spec = ServingContract.of(family).cache_spec(cfg)
     shape = dict(num_slots=2, max_len=max_len, dtype=jnp.float32,
                  page_size=PAGE, pad_slack=CHUNK)
     if isinstance(spec, tuple):
@@ -207,7 +207,7 @@ def test_the_engine_asks_the_family(family, layerwise, monkeypatch):
             mellum: lambda: mellum.MellumConfig.tiny(num_hidden_layers=4),
             llama: llama.LlamaConfig.tiny}[family]
     cfg = tiny()
-    assert getattr(family, "takes_layerwise_views", False) == layerwise
+    assert ServingContract.of(family).layerwise_views == layerwise
     eng = Engine(family, cfg, family.init_params(cfg, jax.random.key(0)),
                  EngineConfig(num_slots=2, max_len=32, prefill_chunk=8,
                               page_size=8, cache_dtype=jnp.float32,

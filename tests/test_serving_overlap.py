@@ -1,9 +1,9 @@
 """The engine reads a step's results one step late (serving/engine.py
-`_Unread`, `_settle`): `step()` dispatches the next program before it
+`_Unread`, `settle`): `step()` dispatches the next program before it
 fetches and commits the last one's tokens.
 
 CPU contracts: what is served is bit-equal to the synchronous order (the
-same engine with `_settle()` after every `step()`) and to the family's
+same engine with `settle()` after every `step()`) and to the family's
 cache-free `generate`; an EOS finish, which the host cannot count ahead,
 costs one dead lane and corrupts nothing; whatever acts on a request from
 outside `step()` sees settled books; a program in flight keeps the page
@@ -75,7 +75,7 @@ def _reference(cfg, params, prompt, n, family=gpt2):
 def _drive(eng, synchronous=False, after_step=None):
     while eng.step():
         if synchronous:
-            eng._settle()
+            eng.settle()
         if after_step is not None:
             after_step()
 
@@ -116,7 +116,7 @@ def _serve(eng, trace, synchronous):
         for _ in range(steps):
             eng.step()
             if synchronous:
-                eng._settle()
+                eng.settle()
         reqs.append(eng.submit(prompt, max_new_tokens=n, temperature=temp,
                                key=jax.random.key(100 + i)))
     _drive(eng, synchronous)
