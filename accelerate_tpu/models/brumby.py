@@ -108,12 +108,14 @@ class BrumbyConfig:
 
 def cache_spec(config: BrumbyConfig):
     """One state a sequence and layer: G matrices of `state_rows` x d and
-    as many vectors of `state_rows`, float32 as served."""
+    as many vectors of `state_rows` (the second block: `normaliser_rows`
+    rows of d lanes), float32 as served."""
     return CacheSpec(
         num_layers=config.num_hidden_layers,
         heads=config.num_key_value_heads, width=config.head_dim,
         kind="state",
         state_rows=state_rows(config.head_dim, config.retention_degree),
+        aux_rows=normaliser_rows(config.head_dim, config.retention_degree),
         state_dtype=jnp.dtype(config.state_dtype))
 
 

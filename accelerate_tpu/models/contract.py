@@ -10,6 +10,7 @@ import dataclasses
 import inspect
 from typing import Any, Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,13 +37,27 @@ class CacheSpec:
     the engine's page gauges mean.
 
     `kind="state"`: NO row a token. What a layer keeps of a sequence is
-    one STATE of fixed size, whatever the sequence's length: `heads`
-    matrices of `state_rows` x `width` and as many vectors of `state_rows`,
-    in `state_dtype` (`StateCache`; `ops/power_retention.py` says what the
-    rows are). It is not addressed by position, so nothing of it can be
-    shared, published or cut at a page: the pool's unit, where the
-    allocator and the gauges say "page", is an ENTRY, one sequence's
-    whole state in every layer.
+    one STATE of fixed size, whatever the sequence's length, and its shape
+    is the FAMILY's: `heads` blocks of `state_rows` x `width` and, beside
+    each, a second block of `aux_rows` x `width` (0: none), in
+    `state_dtype` (`StateCache`, which lays them out as `StatePool.s` and
+    `.z` and knows nothing of what the rows mean: power retention's
+    matrix and its normaliser, `models/brumby.py`; a state-space layer's
+    state and its convolution window, `models/jamba.py`).
+    `aux_entry_minor`: the second block lies `[layers, heads * aux_rows,
+    entries + 1, width]`, the ENTRIES in the tile's sublanes, which is how
+    rows want to lie that plain XLA reads for every lane at once (a
+    window's row j of all lanes is then one dense `[lanes, width]` slice;
+    entry-major, the TPU's compiler re-lays the whole pool out to get
+    it); False: `[layers, entries + 1, heads, aux_rows, width]`, an entry's
+    rows one block, as a kernel that walks entries takes them. It is not
+    addressed by position, so nothing of it can be shared, published or
+    cut at a page: the pool's unit, where the allocator and the gauges say
+    "page", is an ENTRY, one sequence's whole state in every layer.
+    Alone it is the whole cache; as the LAST of a tuple, after one group of
+    K/V rows that keeps every position, it is a group of entries BESIDE a
+    group of pages (`GroupedPagedCache.state`): slot i's entry is entry i,
+    so the one allocation a request makes is its pages.
 
     `side_width` > 0: a third per-token row of that many lanes, in the
     pool's dtype, that lives in the SAME pages as K and V (`PagedKVCache`,
@@ -58,11 +73,15 @@ class CacheSpec:
     layers: tuple | None = None
     side_width: int = 0
     state_rows: int = 0
+    aux_rows: int = 0
+    aux_entry_minor: bool = False
     state_dtype: Any = jnp.float32
 
     @property
     def label(self) -> str:
         """The group's name in gauges and debug output."""
+        if self.kind == "state":
+            return "state"
         return "full" if self.window is None else f"window{self.window}"
 
 
@@ -73,6 +92,43 @@ class WithSide(NamedTuple):
 
     rows: Any
     side: Any
+
+
+@dataclasses.dataclass(frozen=True)
+class StatePool:
+    """The state of every sequence in every layer of a `kind="state"` spec,
+    as a family forward is handed it and hands it back: `s` [layers,
+    entries + 1, heads, state_rows, width] and `z` [layers, entries + 1,
+    heads, aux_rows, width], or with the spec's `aux_entry_minor` [layers,
+    heads * aux_rows, entries + 1, width] (None without a second block);
+    the last entry is the SPARE. `kernel`: the family's ops take their
+    Pallas kernels (static)."""
+
+    s: jax.Array
+    z: jax.Array | None
+    kernel: bool = False
+
+    is_state_pool = True
+
+    @property
+    def spare(self) -> int:
+        """The entry that takes the writes of lanes that leave no trace."""
+        return self.s.shape[1] - 1
+
+
+jax.tree_util.register_pytree_node(
+    StatePool, lambda p: ((p.s, p.z), p.kernel),
+    lambda kernel, sz: StatePool(sz[0], sz[1], kernel))
+
+
+class StateMeta(NamedTuple):
+    """`entries` [B] int32: each lane's pool entry (None: lane b's is entry
+    b). `rows` [B] int32: how many of the lane's rows in this call are real
+    (of a chunk, its leading rows; of a decode step, 1 or 0: a lane with 0
+    leaves its entry as it is)."""
+
+    entries: Any
+    rows: Any
 
 
 def ring_positions(rows: int, last):

@@ -28,8 +28,8 @@ d/2). So `phi(a) . phi(b) = (a . b) ** 2` exactly, over `(d / 2 + 1) d`
 entries: 8,320 for d = 128, 64 more than the `d (d + 1) / 2` = 8,256 the
 mathematics needs (0.8%), every row a whole 128-lane tile.
 
-THE POOL (`StatePool`; `serving/cache.py` `StateCache` owns it): `s [L,
-entries + 1, G, D, dv]` and `z [L, entries + 1, G, normaliser_rows(d, p),
+THE POOL (`models.contract.StatePool`; `serving/cache.py` `StateCache` owns
+it): `s [L, entries + 1, G, D, dv]` and `z [L, entries + 1, G, normaliser_rows(d, p),
 d]`, `D = state_rows(d, p)`. ONE entry is one sequence's whole state in every
 layer; the last entry is a SPARE that takes the writes of lanes that must
 leave no trace. Both ops below take the whole pool and a layer index and
@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.common import part
+from ..models.contract import StateMeta, StatePool
 from . import kernel_mode
 
 DECODE_KERNEL = "retention_decode_step"
@@ -114,39 +114,6 @@ def phi(x: jax.Array, degree: int = 2) -> jax.Array:
     return jnp.concatenate([
         c * x * jnp.roll(x, o, axis=-1)
         for o, c in enumerate(_coefficients(d, degree))], axis=-1)
-
-
-@dataclasses.dataclass(frozen=True)
-class StatePool:
-    """The state of every sequence in every layer (module docstring), as a
-    family forward is handed it and hands it back. `kernel`: the ops take
-    their Pallas kernels (static)."""
-
-    s: jax.Array
-    z: jax.Array
-    kernel: bool = False
-
-    is_state_pool = True
-
-    @property
-    def spare(self) -> int:
-        """The entry that takes the writes of lanes that leave no trace."""
-        return self.s.shape[1] - 1
-
-
-jax.tree_util.register_pytree_node(
-    StatePool, lambda p: ((p.s, p.z), p.kernel),
-    lambda kernel, sz: StatePool(sz[0], sz[1], kernel))
-
-
-class StateMeta(NamedTuple):
-    """`entries` [B] int32: each lane's pool entry (the spare for a lane
-    whose state must stay as it is). `rows` [B] int32: how many of the
-    lane's rows in this call are real (of a chunk, its leading rows; of a
-    decode step, 1 or 0)."""
-
-    entries: jax.Array
-    rows: jax.Array
 
 
 @part("cache.view")

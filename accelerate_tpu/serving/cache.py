@@ -73,7 +73,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.common import part
-from ..models.contract import CacheSpec, WithSide, ring_positions  # noqa: F401
+from ..models.contract import (  # noqa: F401
+    CacheSpec,
+    StatePool,
+    WithSide,
+    ring_positions,
+)
 from ..telemetry.trace import span
 
 
@@ -782,17 +787,26 @@ def paged_admit_slot(cache: PagedKVCache, slot: jax.Array,
 
 
 @part("cache.write")
-def state_admit_slot(cache: StateCache, slot: jax.Array,
-                     entry: jax.Array) -> StateCache:
+def state_admit_slot(cache, slot: jax.Array, entry: jax.Array):
     """Admit a request into `slot` of a state pool: length zero, and the
-    state at `entry` ZEROED in every layer (a state is read whole from the
-    first token on; nothing masks what the entry's last tenant left)."""
-    def zero(pool):
-        blank = jnp.zeros(pool.shape[:1] + (1,) + pool.shape[2:], pool.dtype)
-        return jax.lax.dynamic_update_slice_in_dim(pool, blank, entry, axis=1)
+    state at `entry` ZEROED in every layer, both blocks (a state is read
+    whole from the first token on; nothing masks what the entry's last
+    tenant left). Of a grouped cache: its state group's entry (the page
+    groups' lengths are `paged_admit_slot`'s)."""
+    if isinstance(cache, GroupedPagedCache):
+        return dataclasses.replace(
+            cache, state=state_admit_slot(cache.state, slot, entry))
+
+    def zero(pool, axis=1):
+        if pool is None:
+            return None
+        blank = jnp.zeros(pool.shape[:axis] + (1,) + pool.shape[axis + 1:],
+                          pool.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(pool, blank, entry,
+                                                   axis=axis)
 
     return dataclasses.replace(
-        cache, s=zero(cache.s), z=zero(cache.z),
+        cache, s=zero(cache.s), z=zero(cache.z, cache.z_entry_axis),
         lengths=cache.lengths.at[slot].set(0))
 
 
@@ -824,9 +838,13 @@ class StateCache:
     (`CacheSpec.kind == "state"`).
 
     s: [num_layers, entries + 1, heads, state_rows, width], z:
-    [num_layers, entries + 1, heads, state_rows / width rounded up to a
-    multiple of 8, width], both in
-    the spec's `state_dtype`; lengths: [num_slots] int32. ONE entry is one
+    [num_layers, entries + 1, heads, aux_rows, width] (None where the spec
+    has no second block; [num_layers, heads * aux_rows, entries + 1, width]
+    where it asks for `aux_entry_minor`: `z_entry_axis` says which axis
+    counts entries), both in the spec's `state_dtype`; lengths:
+    [num_slots] int32. What the rows MEAN is the family's (`CacheSpec`,
+    `kind="state"`): this class zeroes, hands over and takes back two
+    blocks of rows an entry and layer. ONE entry is one
     sequence's whole state in every layer. A slot is given its entry at
     admission, where it is zeroed (`state_admit_slot`: unlike K/V rows,
     which a position mask hides, a state left by the last tenant would be
@@ -847,9 +865,13 @@ class StateCache:
     which is not implemented (`serving/engine.py` `_UNPORTED["state"]`).
 
     The programs never gather a view of it: a family forward is handed the
-    whole pool (`pool()`, an `ops.power_retention.StatePool`) and hands it
+    whole pool (`pool()`, a `models.contract.StatePool`) and hands it
     back updated, each layer's op writing the entries it read, in place
-    under donation (`commit`)."""
+    under donation (`commit`).
+
+    As the state group of a `GroupedPagedCache` (`.state`) it has one entry
+    a slot, slot i's is entry i, and the host keeps no books of it: the
+    pages beside it are what a request allocates."""
 
     s: jax.Array
     z: jax.Array
@@ -858,6 +880,7 @@ class StateCache:
     pad_slack: int
     compute_dtype: Any = jnp.bfloat16
     stats: Any = None
+    z_entry_axis: int = 1
 
     # what the engine asks of any pool and this one has none of
     quantized = latent = ring = False
@@ -870,33 +893,34 @@ class StateCache:
                dtype: Any = jnp.bfloat16, pad_slack: int = 0,
                num_entries: int | None = None,
                stats: Any = None) -> "StateCache":
-        if spec.state_rows < 1 or spec.state_rows % spec.width:
+        if spec.state_rows < 1 or spec.aux_rows < 0 or spec.heads < 1:
             raise ValueError(
-                "a state is `state_rows` rows of `width` lanes a head, a "
-                f"whole number of `width`; got {spec.state_rows} and "
-                f"{spec.width}")
+                "a state is `heads` blocks of `state_rows` x `width` and of "
+                f"`aux_rows` x `width`; got heads {spec.heads}, state_rows "
+                f"{spec.state_rows}, aux_rows {spec.aux_rows}")
         entries = num_slots if num_entries is None else num_entries
         if entries < 1:
             raise ValueError(f"a state pool of {entries} entries")
         lead = (spec.num_layers, entries + 1, spec.heads)
-        # (the vectors lie as rows of `width` lanes, whole 8-row tiles:
-        # `ops.power_retention.normaliser_rows` says why)
-        z_rows = -(-spec.state_rows // spec.width // 8) * 8
+        # (no axis of one heads: the TPU's default layout of an array with
+        # an axis of 1 before its tile is not the plain one, and a program
+        # would re-lay the pool out on the way in and on the way out)
+        z_shape = ((spec.num_layers, spec.heads * spec.aux_rows, entries + 1,
+                    spec.width) if spec.aux_entry_minor
+                   else lead + (spec.aux_rows, spec.width))
         return cls(
             s=jnp.zeros(lead + (spec.state_rows, spec.width),
                         spec.state_dtype),
-            z=jnp.zeros(lead + (z_rows, spec.width), spec.state_dtype),
+            z=jnp.zeros(z_shape, spec.state_dtype) if spec.aux_rows else None,
             lengths=jnp.zeros((num_slots,), jnp.int32),
             max_len=max_len, pad_slack=pad_slack, compute_dtype=dtype,
-            stats=stats)
+            stats=stats, z_entry_axis=2 if spec.aux_entry_minor else 1)
 
     def with_stats(self, stats) -> "StateCache":
         return dataclasses.replace(self, stats=stats)
 
     def pool(self, kernel: bool = False):
         """The pool as a family forward takes it."""
-        from ..ops.power_retention import StatePool
-
         return StatePool(self.s, self.z, kernel)
 
     def commit(self, pool, lengths: jax.Array) -> "StateCache":
@@ -935,35 +959,48 @@ class StateCache:
         return self.nbytes() // self.s.shape[1]
 
     def nbytes(self) -> int:
-        return (self.s.size + self.z.size) * self.s.dtype.itemsize
+        return (self.s.size + (0 if self.z is None else self.z.size)
+                ) * self.s.dtype.itemsize
 
 
 jax.tree_util.register_pytree_node(
     StateCache,
     lambda c: ((c.s, c.z, c.lengths, c.stats),
-               (c.max_len, c.pad_slack, c.compute_dtype)),
+               (c.max_len, c.pad_slack, c.compute_dtype, c.z_entry_axis)),
     lambda aux, ch: StateCache(s=ch[0], z=ch[1], lengths=ch[2], stats=ch[3],
                                max_len=aux[0], pad_slack=aux[1],
-                               compute_dtype=aux[2]))
+                               compute_dtype=aux[2], z_entry_axis=aux[3]))
 
 
 @dataclasses.dataclass(frozen=True)
 class GroupedPagedCache:
     """The cache of a family whose layers differ in kind: one
     `PagedKVCache` a GROUP (`CacheSpec`), each with its own stacked pool
-    pair (or its one latent pool: the groups are all K/V or all latent,
-    each of its own row width), its own page shape and its retention
-    rule. `groups[0]` keeps every position (`window` None) and may carry a
-    side row; a further group keeps a window, as a ring of pages a slot.
-    `layers[g]` are the model's layers group g
+    pair (or its one latent pool: the page groups are all K/V or all
+    latent, each of its own row width), its own page shape and its
+    retention rule. `groups[0]` keeps every position (`window` None) and
+    may carry a side row; a further group keeps a window, as a ring of
+    pages a slot. `layers[g]` are the model's layers group g
     holds, in order. Every group carries the slots' lengths (they advance
     together through the one `_scatter_rows`); the family's counters ride
     the first. What reads ONE pool's books (`num_pages`, `page_nbytes`,
     `pages_per_slot`, ...) reads the first group's: the one that grows
-    with context."""
+    with context.
+
+    A group of ENTRIES beside the pages (`state`, `state_layers`; a spec of
+    `kind="state"`, the last of the tuple): the layers that keep one state
+    a sequence and no rows. It is a `StateCache` of one entry a slot, slot
+    i's entry is entry i and the spare is the last, as a ring group's rings
+    follow from the slots: a request's ONE allocation is its pages, and the
+    host keeps no second free list. The functions that map the page groups
+    (`map_groups`, views, writes) do not see it; the engine's programs hand
+    its pool to the family beside the page groups' operands and take it
+    back (`with_state`), and `state_admit_slot` zeroes a slot's entry."""
 
     groups: tuple
     layers: tuple
+    state: Any = None
+    state_layers: tuple = ()
 
     @classmethod
     def create(cls, specs, num_slots: int, max_len: int, dtype=jnp.bfloat16,
@@ -971,8 +1008,25 @@ class GroupedPagedCache:
                num_pages: int | None = None,
                stats: Any = None) -> "GroupedPagedCache":
         """`num_pages` sizes the first group's pool; a ring group's pool
-        follows from the slots (`num_slots` rings)."""
-        if specs[0].window is not None or any(
+        and a state group's follow from the slots (`num_slots` rings,
+        `num_slots` entries and the spare)."""
+        states = [s for s in specs if s.kind == "state"]
+        if states and specs[-1].kind != "state":
+            raise ValueError(
+                "the first group of a grouped cache keeps every position "
+                "(its pages are what a request allocates): a group of state "
+                "entries comes LAST")
+        specs = [s for s in specs if s.kind != "state"]
+        if states and (len(states) > 1 or len(specs) != 1
+                       or specs[0].kind != "kv" or specs[0].side_width
+                       or states[0].layers is None):
+            raise ValueError(
+                "a group of state entries stands beside ONE group of K/V "
+                "pages that keeps every position, and names its layers: a "
+                "state beside latent rows, a side row, a ring or a second "
+                "state group is not implemented; got kinds "
+                f"{[s.kind for s in specs] + ['state'] * len(states)}")
+        if not specs or specs[0].window is not None or any(
                 s.window is None for s in specs[1:]):
             raise ValueError(
                 "the first group of a grouped cache keeps every position "
@@ -982,9 +1036,9 @@ class GroupedPagedCache:
         if (len(kinds) != 1 or not kinds <= {"kv", "latent"}
                 or any(s.layers is None for s in specs)):
             raise ValueError(
-                "the groups of a grouped cache are of ONE kind, K/V rows "
-                "(kind='kv') or latent rows (kind='latent'; each group its "
-                "own width), and name their layers; got kinds "
+                "the page groups of a grouped cache are of ONE kind, K/V "
+                "rows (kind='kv') or latent rows (kind='latent'; each group "
+                "its own width), and name their layers; got kinds "
                 f"{sorted(kinds)}")
         return cls(
             groups=tuple(PagedKVCache.create(
@@ -994,10 +1048,15 @@ class GroupedPagedCache:
                 stats=stats if g == 0 else None, window=s.window,
                 latent=s.kind == "latent", side_width=s.side_width)
                 for g, s in enumerate(specs)),
-            layers=tuple(tuple(s.layers) for s in specs))
+            layers=tuple(tuple(s.layers) for s in specs),
+            state=StateCache.create(
+                states[0], num_slots, max_len, dtype=dtype,
+                pad_slack=pad_slack) if states else None,
+            state_layers=tuple(states[0].layers) if states else ())
 
     def map_groups(self, f, *per_group) -> "GroupedPagedCache":
-        """`f(group, *the g-th of every argument)` in every group's place."""
+        """`f(group, *the g-th of every argument)` in every PAGE group's
+        place."""
         return dataclasses.replace(self, groups=tuple(
             f(g, *args) for g, *args in zip(self.groups, *per_group)))
 
@@ -1006,8 +1065,16 @@ class GroupedPagedCache:
         return dataclasses.replace(
             self, groups=(first.with_stats(stats), *rest))
 
+    def with_state(self, pool) -> "GroupedPagedCache":
+        """The cache with the state group's pool as a forward handed it
+        back. (The state group's own lengths stay zero: the slots' lengths
+        are the page groups'.)"""
+        return dataclasses.replace(
+            self, state=self.state.commit(pool, self.state.lengths))
+
     def nbytes(self) -> int:
-        return sum(g.nbytes() for g in self.groups)
+        return sum(g.nbytes() for g in self.groups) + (
+            0 if self.state is None else self.state.nbytes())
 
     # one pool's books, the lengths and the counters: the first group's
     _OF_THE_FIRST_GROUP = (
@@ -1022,10 +1089,16 @@ class GroupedPagedCache:
         raise AttributeError(name)
 
 
+# (the page groups are the children, as they were before a state group
+# could stand beside them: a cache without one flattens to the same paths,
+# so its programs' text is the same; a state group is one child more)
 jax.tree_util.register_pytree_node(
     GroupedPagedCache,
-    lambda c: (c.groups, c.layers),
-    lambda layers, groups: GroupedPagedCache(tuple(groups), layers))
+    lambda c: ((c.groups, c.layers) if c.state is None else (
+        (*c.groups, c.state), ("state", c.layers, c.state_layers))),
+    lambda aux, ch: (
+        GroupedPagedCache(tuple(ch[:-1]), aux[1], ch[-1], aux[2])
+        if aux[0] == "state" else GroupedPagedCache(tuple(ch), aux)))
 
 
 def create_cache(spec, engine_config, pad_slack: int, stats: Any = None):
@@ -1495,10 +1568,16 @@ class PagedAllocator:
         on_unmap: Callable[[int], None] | None = None,
         rings: tuple = (),
         state_entries: bool = False,
+        entries_beside: bool = False,
     ):
         # a state pool's page is an ENTRY, one sequence's whole state
         # (`StateCache`): the span says so beside the page count
         self.state_entries = state_entries
+        # a group of entries BESIDE the pages (`GroupedPagedCache.state`):
+        # every allocation holds its slot's one entry, which needs no books
+        # of its own; the span and `allocations_live` say how many are held
+        self.entries_beside = entries_beside
+        self.allocations_live = 0
         self.page_size = page_size
         self.pad_slack = pad_slack
         self.prefix_cache = prefix_cache
@@ -1587,6 +1666,11 @@ class PagedAllocator:
                 sp.set(full_pages=len(alloc.pages) if alloc else 0,
                        window_pages=sum(map(len, alloc.rings)) if alloc
                        else 0)
+            if self.entries_beside:
+                sp.set(full_pages=len(alloc.pages) if alloc else 0,
+                       state_entries=1 if alloc else 0)
+            if alloc is not None:
+                self.allocations_live += 1
         return alloc
 
     def _allocate(self, request) -> PageAllocation | None:
@@ -1672,6 +1756,7 @@ class PagedAllocator:
         Pending swap-ins revert to host residency — their bytes were
         never installed, so the reserved pages return to the pool and the
         host tier keeps the mirror."""
+        self.allocations_live -= 1
         self.index.release(alloc.nodes)
         self.pool.release(alloc.pages[len(alloc.nodes):])
         for pool, ring in zip(self.ring_pools, alloc.rings):
@@ -1734,6 +1819,7 @@ class PagedAllocator:
         building the ATP2xx/sanitizer audit and pinned model-free in
         test_paged_cache."""
         alloc, req = slot.alloc, slot.request
+        self.allocations_live -= 1
         with span("serving.kv.release") as sp:
             self.index.release(alloc.nodes)
             n_cached = len(alloc.nodes)

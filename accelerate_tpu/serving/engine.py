@@ -86,7 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.common import count_params, part
-from ..models.contract import CacheSpec, ServingContract
+from ..models.contract import CacheSpec, ServingContract, StateMeta
 from ..models.decode import sample_token
 from ..profiler import StepTimer, causal_lm_infer_flops
 from ..telemetry.cost import CostTable, resolve_sample_every
@@ -354,7 +354,8 @@ class EngineConfig:
 # error calls the trait's fallback, then for each option what porting it
 # would take. ROADMAP M3 (latent), M2 (grouped), M8 (side), M4 (state).
 # A cache with several traits (latent groups, the first with a side row)
-# is refused by each trait in turn: the traits compose, the options do not.
+# is refused by each of its traits, all named in the one error: the traits
+# compose, the options do not.
 _OPTIONS = {
     "prefix_cache=True": lambda ec: ec.prefix_cache,
     "kv_dtype='int8'": lambda ec: ec.kv_dtype is not None,
@@ -422,12 +423,17 @@ def _refuse_unported(ec: "EngineConfig", spec: CacheSpec, groups) -> None:
     if spec.kind == "state":
         traits.append(("state", "this family keeps one recurrent state a "
                        "sequence and no K/V rows (CacheSpec.kind='state')"))
+    elif any(g.kind == "state" for g in groups or ()):
+        traits.append(("state", "layers of this family keep one recurrent "
+                       "state a sequence BESIDE the other layers' K/V rows "
+                       "(a group of CacheSpec.kind='state')"))
     sides = [g.side_width for g in (groups or (spec,)) if g.side_width]
     if sides:
         traits.append(("side", "this family caches a side row a token "
                        f"beside its rows (CacheSpec.side_width="
                        f"{sides[0]}: an indexer's key, which chooses "
                        "the keys attention reads)"))
+    refused = []
     for trait, said in traits:
         instead, options = _UNPORTED[trait]
         unported = [f"{option} ({takes})" for option, takes in options.items()
@@ -437,9 +443,11 @@ def _refuse_unported(ec: "EngineConfig", spec: CacheSpec, groups) -> None:
                             "INSIDE a ring group (a side row in a ring of "
                             "pages)")
         if unported:
-            raise ValueError(
+            refused.append(
                 f"{said}, which is not implemented together with: "
                 + "; ".join(unported) + f". Nothing falls back to {instead}.")
+    if refused:     # every trait that refuses says so, in one error
+        raise ValueError(" ".join(refused))
 
 
 def _resolve_paged_attention(setting, mesh, speculative=None) -> bool:
@@ -528,6 +536,12 @@ class Engine:
         # None: the one pool every layer shares
         self._cache_groups = spec if isinstance(spec, tuple) else None
         self._cache_spec = spec[0] if self._cache_groups else spec
+        # the spec of the layers that keep a state a sequence, whether it
+        # is the whole cache or a group of entries beside the pages; None
+        # for a family that keeps rows only
+        self._state_spec = next(
+            (s for s in self._cache_groups or (spec,) if s.kind == "state"),
+            None)
         self._tracker = tracker
         self._log_every = log_every
         self._last_logged = 0
@@ -657,7 +671,8 @@ class Engine:
             on_unmap=self._unmap_slot,
             rings=tuple((g.pages_per_slot, g.num_pages)
                         for g in self._ring_groups()),
-            state_entries=self._cache_spec.kind == "state",
+            state_entries=self._cache_spec is self._state_spec,
+            entries_beside=self._state_beside,
         )
         # COW forking: parent_id -> parent handle, consulted by the
         # admission hold below (entries drop as parents reach a terminal
@@ -731,6 +746,13 @@ class Engine:
         self.on_admit: Any = None
         self._build_programs()
 
+    @property
+    def _state_beside(self) -> bool:
+        """A group of state entries stands beside the page groups (alone,
+        the state's spec IS the cache's)."""
+        return (self._state_spec is not None
+                and self._state_spec is not self._cache_spec)
+
     def _ring_groups(self) -> tuple:
         """The cache's window groups (none without a grouped cache)."""
         return self.cache.groups[1:] if self._cache_groups else ()
@@ -762,10 +784,26 @@ class Engine:
         grouped = self._cache_groups is not None
         # a family that keeps a state a sequence is handed the whole pool
         # and hands it back; `kernel` says which form its ops take
-        state = self._cache_spec.kind == "state"
+        state = self._cache_spec is self._state_spec
         kernel = self._use_paged_kernel
-        if state:
-            from ..ops.power_retention import StateMeta
+        # ... or, beside page groups, its state group's pool after the page
+        # groups' operands and takes it back in the same place
+        beside = self._state_beside
+
+        def with_state(cache, ks, vs, entries, rows, kernel=kernel):
+            """The page groups' operands with the state group's after
+            them: its pool in K's place, who the lanes are in V's."""
+            if not beside:
+                return ks, vs
+            return ((*ks, cache.state.pool(kernel)),
+                    (*vs, StateMeta(entries, rows)))
+
+        def take_state(cache, nk, nv):
+            """-> (the cache with the pool a forward handed back in its
+            state group, the page groups' new rows or views)."""
+            if not beside:
+                return cache, nk, nv
+            return cache.with_state(nk[-1]), nk[:-1], nv[:-1]
 
         def serving_forward(program, params, cache, ids, positions,
                             kv_caches, logit_rows, token_mask):
@@ -822,6 +860,12 @@ class Engine:
             lp = jax.nn.log_softmax(logits)[tok]
             return tok, lp
 
+        def count_zeroed_state(cache):
+            if cache.stats is None or count_zeroed is None:
+                return cache
+            return cache.with_stats(dict(
+                cache.stats, prefill=count_zeroed(cache.stats["prefill"])))
+
         if self._spec:
             @partial(jax.jit, donate_argnums=don_admit + ((3,) if don_admit
                                                           else ()),
@@ -840,11 +884,8 @@ class Engine:
             @partial(jax.jit, donate_argnums=don_admit)
             def admit(cache, slot_keys, temps, slot, key_raw, temp, entry):
                 # the slot's entry is zeroed: a state is read whole
-                cache = state_admit_slot(cache, slot, entry)
-                if cache.stats is not None and count_zeroed is not None:
-                    cache = cache.with_stats(dict(
-                        cache.stats, prefill=count_zeroed(
-                            cache.stats["prefill"])))
+                cache = count_zeroed_state(
+                    state_admit_slot(cache, slot, entry))
                 slot_keys = slot_keys.at[slot].set(key_raw)
                 temps = temps.at[slot].set(temp)
                 return cache, slot_keys, temps
@@ -857,6 +898,9 @@ class Engine:
                 # prefix (those pages already hold its K/V); a miss
                 # starts at zero
                 cache = paged_admit_slot(cache, slot, reused_len)
+                if beside:      # and the slot's entry is zeroed
+                    cache = count_zeroed_state(
+                        state_admit_slot(cache, slot, slot))
                 slot_keys = slot_keys.at[slot].set(key_raw)
                 temps = temps.at[slot].set(temp)
                 return cache, slot_keys, temps
@@ -872,7 +916,8 @@ class Engine:
             else:
                 ks, vs, length = paged_slot_view(cache, table_row, slot,
                                                  by_layer=layerwise)
-                kvc = (ks, vs, length)
+                kvc = (*with_state(cache, ks, vs, slot[None],
+                                   real_len[None]), length)
             positions = (length + jnp.arange(chunk, dtype=jnp.int32))[None, :]
             logits, (nk, nv, _), cache, counted = serving_forward(
                 "prefill", params, cache, ids[None, :], positions,
@@ -880,6 +925,7 @@ class Engine:
                 (jnp.arange(chunk) < real_len)[None, :])
             if chunk_stats is not None:
                 chunk_stats = fold_chunk(chunk_stats, counted)
+            cache, nk, nv = take_state(cache, nk, nv)
             with part("sample"):
                 if one_row:  # the one row that is read, not the chunk's
                     last = logits[0, 0].astype(jnp.float32)
@@ -953,12 +999,15 @@ class Engine:
                 with part("cache.view"):
                     walked = (lengths if latent and not grouped
                               else jnp.where(live, lengths, 0))
-                kvc = (*paged_decode_operands(cache),
+                # (a state group's dead lanes go to the spare entry: `rows`)
+                kvc = (*with_state(cache, *paged_decode_operands(cache),
+                                   None, live.astype(jnp.int32)),
                        PagedDecodeMeta(table, walked, rows=rows))
                 logits, (row_k, row_v, _), cache, _ = serving_forward(
                     "decode", params, cache, tokens[:, None],
                     lengths[:, None], kvc, jnp.zeros_like(lengths),
                     live[:, None])
+                cache, row_k, row_v = take_state(cache, row_k, row_v)
                 with part("sample"):
                     last = logits[:, 0].astype(jnp.float32)
                     next_tok, lps = jax.vmap(sample_slot)(
@@ -997,8 +1046,12 @@ class Engine:
                     lengths = cache.lengths
                     logits, (nk, nv, _), cache, _ = serving_forward(
                         "decode", params, cache, tokens[:, None],
-                        lengths[:, None], (k_all, v_all, lengths),
+                        lengths[:, None],
+                        (*with_state(cache, k_all, v_all, None,
+                                     live.astype(jnp.int32), kernel=False),
+                         lengths),
                         jnp.zeros_like(lengths), live[:, None])
+                    cache, nk, nv = take_state(cache, nk, nv)
                     last = logits[:, 0].astype(jnp.float32)
                 else:
                     last, nk, nv = jax.vmap(
@@ -1316,13 +1369,17 @@ class Engine:
         streams (None derives a distinct key from the fork's request
         id). With `prefix_cache=False` the fork still runs, it just
         re-prefills — sharing needs the radix tree."""
-        if self._cache_spec.kind == "state":
+        if self._state_spec is not None:
             raise ValueError(
-                "fork: this family keeps one recurrent state a sequence and "
-                "no K/V rows (CacheSpec.kind='state'); a fork shares its "
-                "parent's prompt PAGES, and a state has none: it needs a "
-                "snapshot of the parent's state after the prompt, which is "
-                "not implemented. Submit the prompt again.")
+                "fork: this family keeps one recurrent state a sequence "
+                + ("in a group of entries BESIDE its groups of K/V pages "
+                   "(a grouped cache with CacheSpec.kind='state')"
+                   if self._state_beside else
+                   "and no K/V rows (CacheSpec.kind='state')")
+                + "; a fork shares its parent's prompt PAGES, and a state "
+                "has none: it needs a snapshot of the parent's state after "
+                "the prompt, which is not implemented. Submit the prompt "
+                "again.")
         parent.share_prompt = True
         if not parent.done:
             self._fork_parents[parent.request_id] = parent
@@ -1818,16 +1875,22 @@ class Engine:
         self.metrics.set_page_gauges(
             alloc.pages_in_use, alloc.pages_free,
             alloc.pages_in_use * self.cache.page_nbytes)
-        if self._cache_spec.kind == "state":
-            self.metrics.set_state_bytes_gauge(
-                alloc.pages_in_use * self.cache.page_nbytes)
+        if self._state_spec is not None:
+            # entries held: alone, the allocator's pages ARE entries;
+            # beside pages, one a live allocation
+            state = self.cache.state if self._state_beside else self.cache
+            held = (alloc.allocations_live if self._state_beside
+                    else alloc.pages_in_use)
+            self.metrics.set_state_bytes_gauge(held * state.page_nbytes)
         if self.cache.side is not None:
             self.metrics.set_side_bytes_gauge(
                 alloc.pages_in_use * self.cache.side_page_nbytes)
         if self._cache_groups:
             self.metrics.set_group_page_gauges(dict(zip(
                 (g.label for g in self._cache_groups),
-                (alloc.pages_in_use, *alloc.ring_pages_in_use))))
+                (alloc.pages_in_use, *alloc.ring_pages_in_use,
+                 *((alloc.allocations_live,) if self._state_beside
+                   else ())))))
 
     def _run_swap_in(self, slot: Slot, req: Request, alloc) -> None:
         """Install a host-resident prefix's bytes into the pages the
@@ -1904,7 +1967,8 @@ class Engine:
         # length's place: there is no prefix to reuse, and the entry is
         # what it zeroes)
         tail = (jnp.int32(slot.index), key_raw, jnp.float32(req.temperature),
-                jnp.int32(alloc.pages[0] if self._cache_spec.kind == "state"
+                jnp.int32(alloc.pages[0]
+                          if self._cache_spec is self._state_spec
                           else alloc.reused_len))
         if self._spec:
             slot.draft_done = 0

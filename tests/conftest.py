@@ -74,6 +74,19 @@ def reset_state():
     PartialState._reset_state()
     yield
     PartialState._reset_state()
+    # the tracing switch that NO test's own teardown puts back: a dozen
+    # tests turn the forwarding of span names to the profiler off
+    # (`configure_tracing(..., annotate=False)`), and a traced benchmark
+    # cell that the same worker's process runs later (tests/chipbench)
+    # then finds no span on the profiler's host plane. Which file precedes
+    # which in a worker follows the whole suite's file list, so the
+    # failure came and went with unrelated PRs.
+    from accelerate_tpu.telemetry.trace import (
+        configure_tracing,
+        tracing_enabled,
+    )
+
+    configure_tracing(enabled=tracing_enabled(), annotate=True)
 
 
 @pytest.fixture

@@ -313,10 +313,52 @@ def test_the_config_refuses_what_is_not_implemented(changed, match):
         brumby.BrumbyConfig.tiny(**changed)
 
 
-def test_a_state_pool_wants_whole_rows():
-    with pytest.raises(ValueError, match="a whole number of `width`"):
-        StateCache.create(CacheSpec(2, 2, 128, kind="state", state_rows=100),
-                          2, 64)
+def test_a_state_pool_takes_the_familys_rows():
+    """The pool asks nothing of what the rows mean (a state's shape is the
+    family's): rows that are no whole number of `width` are laid out as
+    declared; no entry at all is refused."""
+    pool = StateCache.create(
+        CacheSpec(2, 2, 128, kind="state", state_rows=100), 2, 64)
+    assert pool.s.shape == (2, 3, 2, 100, 128) and pool.z is None
     with pytest.raises(ValueError, match="0 entries"):
         StateCache.create(CacheSpec(2, 2, 128, kind="state", state_rows=128),
                           2, 64, num_entries=0)
+
+
+def test_brumby_declares_the_pool_it_had_and_compiles_to_the_programs_it_had(
+        params):
+    """Since the state pool's shape is the family's (`CacheSpec.aux_rows`),
+    brumby DECLARES the normaliser's rows the pool used to reckon for it.
+    A pool made by hand the old way (`state_rows / width` rows, rounded up
+    to whole 8-row tiles) has the declared pool's shapes and pytree, and
+    the engine's three programs lower to the same text over either."""
+    from accelerate_tpu.ops import power_retention as pr
+
+    eng = _engine(params, slots=2, entries=2)
+    spec = brumby.cache_spec(CFG)
+    assert (spec.kind, spec.state_rows, spec.aux_rows, spec.aux_entry_minor
+            ) == ("state", 9 * 16, 16, False)
+    z_rows = -(-spec.state_rows // spec.width // 8) * 8
+    lead = (spec.num_layers, 3, spec.heads)
+    by_hand = StateCache(
+        s=jnp.zeros(lead + (spec.state_rows, spec.width), jnp.float32),
+        z=jnp.zeros(lead + (z_rows, spec.width), jnp.float32),
+        lengths=jnp.zeros((2,), jnp.int32), max_len=64, pad_slack=CHUNK,
+        compute_dtype=jnp.float32, stats=eng.cache.stats)
+    assert jax.tree.structure(by_hand) == jax.tree.structure(eng.cache)
+    assert [x.shape for x in jax.tree.leaves(by_hand)] == [
+        x.shape for x in jax.tree.leaves(eng.cache)]
+    assert isinstance(eng.cache.pool(), pr.StatePool)
+    regs = (eng._tokens, eng._slot_keys, eng._temps)
+    for program, args in (
+            (eng._admit_p, lambda c: (c, *regs[1:], jnp.int32(0),
+                                      eng._slot_keys[0], jnp.float32(0.0),
+                                      jnp.int32(1))),
+            (eng._prefill_p, lambda c: (eng.params, c, *regs, jnp.int32(0),
+                                        eng._tables(0),
+                                        np.zeros((CHUNK,), np.int32),
+                                        jnp.int32(CHUNK))),
+            (eng._decode_p, lambda c: (eng.params, c, *regs,
+                                       np.ones((2,), bool), eng._tables()))):
+        assert (program.lower(*args(eng.cache)).as_text()
+                == program.lower(*args(by_hand)).as_text())

@@ -1094,3 +1094,148 @@ def test_fused_head_loss_compiles_for_v5e_with_one_projection(one_chip,
     # one block of logits + the accumulator + what the compiler keeps
     # beside them: far from the whole logits' 2.5 GB + a saved copy
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+
+
+def test_selective_scan_kernels_compile_for_v5e(one_chip, chip_compile):
+    """The decode kernel and the chunk kernel of the selective scan alone,
+    at the cell's widths (5,120 channels x 16 states, 256 lanes, a chunk of
+    512 rows, a pool of 26 layers x 257 entries): each takes the WHOLE
+    state pool and hands it back aliased; nothing of the pool's size is a
+    temporary, and no layer's or entry's slice of it is made."""
+    from accelerate_tpu.models.contract import StateMeta, StatePool
+    from accelerate_tpu.ops import selective_scan as ss
+
+    L, E, B, T, n, d = 26, 257, 256, 512, 16, 5120
+    f32 = jnp.float32
+
+    def arg(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, z = arg((L, E, 1, n, d)), arg((L, 3, E, d))
+    pool_bytes = s.size * 4
+
+    def decode(dt, x, Bm, Cm, A, s, z, rows):
+        y, pool = ss.ssm_decode_step(dt, x, Bm, Cm, A, StatePool(s, z, True),
+                                     3, StateMeta(None, rows))
+        return y, pool.s
+
+    def chunk(dt, x, Bm, Cm, A, s, z, entries, rows):
+        y, pool = ss.ssm_chunk_scan(dt, x, Bm, Cm, A, StatePool(s, z, True),
+                                    3, StateMeta(entries, rows))
+        return y, pool.s
+
+    for fn, args, name in (
+            (decode, (arg((B, d)), arg((B, d)), arg((B, n)), arg((B, n)),
+                      arg((n, d)), s, z, arg((B,), jnp.int32)),
+             ss.DECODE_KERNEL),
+            (chunk, (arg((1, T, d)), arg((1, T, d)), arg((1, T, n)),
+                     arg((1, T, n)), arg((n, d)), s, z,
+                     arg((1,), jnp.int32), arg((1,), jnp.int32)),
+             ss.CHUNK_KERNEL)):
+        compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert len(re.findall("%" + name + r"(?:\.\d+)? = ", text)) == 1, name
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        assert memory.temp_size_in_bytes < 64e6, (
+            name, memory.temp_size_in_bytes)
+        for shape in (s.shape, s.shape[1:], s.shape[2:]):
+            assert _ops_of_shape(text, "f32", shape) == {}, (name, shape)
+
+
+def test_state_beside_pages_engine_programs_compile_for_v5e(
+        one_chip, chip_compile, monkeypatch):
+    """`decode` and `prefill` of `serve-jamba2-3b-reason-256-closed`
+    (AI21-Jamba2-3B whole, 28 layers, 256 slots x 4096, page 16, chunk 512,
+    65,536 pages of two attention layers' K/V beside 257 state entries of
+    26 Mamba layers), abstract weights and pools, compiled for the chip:
+
+    (a) `decode` holds the scan's decode kernel once a Mamba layer and the
+        live-pages attention kernel once an attention layer, `prefill` the
+        chunk kernel once a Mamba layer, each under its own name;
+    (b) every pool is aliased to its argument in both programs and NO copy
+        of one is made: nothing of the state pool's shape, of a layer's or
+        an entry's slice of it is produced around the kernels; the windows
+        (entries in sublanes) are a slice read and an in-place slice
+        update a layer, never a re-laid image of the pool (entry-major they
+        were: 25 whole copies a decode step);
+    (c) arguments and temporaries fit a chip with room: under 15.5 GB."""
+    from accelerate_tpu.models import jamba
+    from accelerate_tpu.ops import paged_attention
+    from accelerate_tpu.ops import selective_scan as ss
+    from accelerate_tpu.serving import Engine, EngineConfig
+    from accelerate_tpu.serving import engine as engine_module
+
+    slots, max_len, chunk, page, pages = 256, 4096, 512, 16, 65536
+    cfg = jamba.JambaConfig()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: jamba.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 3_029_337_472
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 6.05e9 < weights < 6.07e9
+    # the pools are never allocated here: the engine is built over their
+    # shapes (3.7 GB of zeros on this machine's CPU otherwise)
+    create = engine_module.create_cache
+    monkeypatch.setattr(
+        engine_module, "create_cache",
+        lambda *a, **k: on_chip(jax.eval_shape(lambda: create(*a, **k))))
+    engine = Engine(jamba, cfg, params, EngineConfig(
+        num_slots=slots, max_len=max_len, prefill_chunk=chunk,
+        page_size=page, num_pages=pages, paged_attention=True,
+        prefix_cache=False, max_queue=512))
+    cache = engine.cache
+    full, state = cache.groups[0], cache.state
+    assert full.k.shape == (2, 65537, 1, 16, 128)
+    assert state.s.shape == (26, 257, 1, 16, 5120)
+    assert state.z.shape == (26, 3, 257, 5120)
+    assert cache.pages_per_slot == 288 and cache.page_nbytes == 16 * 1024
+    pool_bytes = 2 * full.k.size * 2 + (state.s.size + state.z.size) * 4
+    assert 3.67e9 < pool_bytes < 3.68e9
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    regs = (params, cache, arg((slots,), jnp.int32),
+            arg(engine._slot_keys.shape, engine._slot_keys.dtype),
+            arg((slots,), jnp.float32))
+    programs = {
+        "decode": (engine._decode_p, regs + (
+            arg((slots,), jnp.bool_), (arg((slots, 288), jnp.int32),))),
+        "prefill": (engine._prefill_p, regs + (
+            arg((), jnp.int32), (arg((288,), jnp.int32),),
+            arg((chunk,), jnp.int32), arg((), jnp.int32))),
+    }
+    for name, (program, args) in programs.items():
+        compiled = program.lower(*args).compile()
+        text = compiled.as_text()
+        memory = compiled.memory_analysis()
+        calls = [len(re.findall("%" + n + r"(?:\.\d+)? = ", text))
+                 for n in (ss.DECODE_KERNEL, ss.CHUNK_KERNEL,
+                           paged_attention.KERNEL_NAME)]
+        assert calls == ([26, 0, 2] if name == "decode" else [0, 26, 0]), (
+            name, calls)
+        assert memory.alias_size_in_bytes >= pool_bytes, name
+        for shape in (state.s.shape, state.s.shape[1:], state.s.shape[2:]):
+            assert _ops_of_shape(text, "f32", shape) == {}, (name, shape)
+        assert set(_ops_of_shape(text, "f32", state.z.shape)) <= {
+            "dynamic-update-slice", "fusion"}, name
+        for shape in (full.k.shape, full.k.shape[1:]):
+            assert set(_ops_of_shape(text, "bf16", shape)) <= {
+                "scatter", "fusion"}, (name, shape)
+        assert " copy(" not in "".join(
+            line for line in text.splitlines()
+            if "f32[26,3,257,5120]" in line.split(" copy(")[0][-60:]), name
+        total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        assert 9.5e9 < total < 15.5e9, (name, total)
+        assert memory.temp_size_in_bytes < 400e6, (
+            name, memory.temp_size_in_bytes)
+        assert _ops_of_shape(text, "f32", (chunk, cfg.vocab_size)) == {}
+        _assert_host_output_is_its_own(
+            program, args, text, (slots,) if name == "decode" else ())
+        print(name, "temp", memory.temp_size_in_bytes, "args",
+              memory.argument_size_in_bytes)

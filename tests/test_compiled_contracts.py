@@ -629,6 +629,83 @@ class TestStatePoolInPlace:
                 assert len(eqn.params["input_output_aliases"]) == aliased
 
 
+class TestStateBesidePagesInPlace:
+    """The programs of a family that keeps a group of state ENTRIES beside
+    a group of K/V pages (`GroupedPagedCache.state`): all four pool arrays
+    (K, V, the states, the windows) are aliased, argument to result, in
+    `admit`, `prefill` and `decode`, and `jit_decode` holds no copy of any
+    (1.07 GB + 2.6 GB in the benchmark's cell; the chip's compiler is held
+    to the same in `tests/test_chip_compile.py`)."""
+
+    @staticmethod
+    def _programs(kernel):
+        from accelerate_tpu.models import jamba
+        from accelerate_tpu.serving import Engine, EngineConfig
+
+        cfg = jamba.JambaConfig.tiny()
+        eng = Engine(jamba, cfg, jamba.init_params(cfg, jax.random.key(0)),
+                     EngineConfig(num_slots=2, max_len=48, prefill_chunk=8,
+                                  page_size=16, cache_dtype=jnp.float32,
+                                  prefix_cache=False, paged_attention=kernel))
+        regs = (eng.params, eng.cache, eng._tokens, eng._slot_keys,
+                eng._temps)
+        return eng, {
+            "admit": (eng._admit_p, (
+                eng.cache, eng._slot_keys, eng._temps, jnp.int32(0),
+                eng._slot_keys[0], jnp.float32(0.0), jnp.int32(0))),
+            "prefill": (eng._prefill_p, regs + (
+                jnp.int32(0), eng._tables(0), np.zeros((8,), np.int32),
+                jnp.int32(8))),
+            "decode": (eng._decode_p, regs + (
+                np.ones((2,), bool), eng._tables())),
+        }
+
+    @pytest.mark.parametrize("program", ["admit", "prefill", "decode"])
+    def test_every_pool_is_aliased_and_decode_copies_none(self, program):
+        eng, programs = self._programs(kernel=False)
+        fn, args = programs[program]
+        cache = eng.cache
+        pools = (cache.groups[0].k, cache.groups[0].v, cache.state.s,
+                 cache.state.z)
+        text = fn.lower(*args).compile().as_text()
+        aliases = re.search(r"input_output_alias=\{[^\n]*?\}, entry",
+                            text).group(0)
+        flat = jax.tree.leaves(args)
+        for array in pools:
+            at = next(i for i, leaf in enumerate(flat) if leaf is array)
+            assert re.search(rf"\({at}, \{{\}}", aliases), (program, at)
+            shape = ",".join(map(str, array.shape))
+            # the states: no copy. (The CPU's compiler copies a pool once
+            # around a slice-then-update in the plain `jax.numpy` form, a
+            # chunk's state, a step's windows, and the K/V pool around the
+            # dense decode's page scatter, as for every family: the
+            # chip's, held in tests/test_chip_compile.py, copies none.)
+            assert (program == "prefill" or array is not pools[2]
+                    or not re.search(rf"= f32\[{shape}\]\S* copy\(", text)
+                    ), (program, shape)
+
+    def test_the_kernels_take_their_pools_whole(self):
+        """Traced with the kernels (interpreted on the CPU): `decode` holds
+        the scan's decode kernel once a Mamba layer with the state pool
+        aliased, and the attention kernel once an attention layer;
+        `prefill` the chunk kernel once a Mamba layer."""
+        _, programs = self._programs(kernel=True)
+        for name, scans in (("decode", "ssm_decode_step"),
+                            ("prefill", "ssm_chunk_scan")):
+            fn, args = programs[name]
+            calls = [eqn for eqn in _all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                     if eqn.primitive.name == "pallas_call"]
+            by_name = {}
+            for eqn in calls:
+                by_name.setdefault(eqn.params["name"], []).append(eqn)
+            assert len(by_name[scans]) == 2, (name, sorted(by_name))
+            for eqn in by_name[scans]:
+                assert len(eqn.params["input_output_aliases"]) == 1
+            attends = [k for k in by_name if "paged_decode_attention" in k]
+            assert (len(attends) == 1 and len(by_name[attends[0]]) == 2
+                    ) == (name == "decode"), (name, sorted(by_name))
+
+
 def _all_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn

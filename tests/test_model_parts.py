@@ -19,6 +19,7 @@ from accelerate_tpu.models import (
     common,
     deepseek,
     dots3,
+    jamba,
     keye,
     llama,
     mellum,
@@ -50,6 +51,9 @@ FAMILIES = {
     "dots3": (dots3, lambda: dots3.Dots3Config.tiny(experts_held=(2, 4)),
               dict(num_slots=2, max_len=64, prefill_chunk=16, page_size=16,
                    prefix_cache=False)),
+    "jamba": (jamba, jamba.JambaConfig.tiny, dict(
+        num_slots=2, max_len=64, prefill_chunk=16, page_size=16,
+        prefix_cache=False)),
 }
 
 
@@ -168,7 +172,7 @@ def test_every_heavy_operation_of_an_engine_program_has_a_part(
     assert _unbilled(heavy) == []
     parts = {trace_scopes.part_of(n) for _, n in heavy}
     assert {"attn.project", "attn.attend", "attn.output", "head"} <= parts
-    assert ({"mlp"} if family in ("llama", "brumby")
+    assert ({"mlp"} if family in ("llama", "brumby", "jamba")
             else {"moe.experts"}) <= parts
     if family in ("keye", "dots3"):
         assert {"attn.indexer", "attn.select"} <= parts

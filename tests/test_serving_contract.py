@@ -23,6 +23,7 @@ from accelerate_tpu.models import (
     deepseek,
     dots3,
     gpt2,
+    jamba,
     keye,
     llama,
     mellum,
@@ -58,8 +59,9 @@ FAMILIES = {
     "brumby": (brumby, lambda: brumby.BrumbyConfig.tiny(head_dim=16),
                {"prefix_cache": False}),
     "dots3": (dots3, dots3.Dots3Config.tiny, {"prefix_cache": False}),
+    "jamba": (jamba, jamba.JambaConfig.tiny, {"prefix_cache": False}),
 }
-DECLARING = {"deepseek", "mellum", "keye", "brumby", "dots3"}
+DECLARING = {"deepseek", "mellum", "keye", "brumby", "dots3", "jamba"}
 each_family = pytest.mark.parametrize("name", list(FAMILIES))
 
 
@@ -88,10 +90,11 @@ def test_logit_rows_is_declared_exactly_when_forward_takes_it(name):
     # its layers in Python takes its views a layer at a time
     assert (contract.init_stats is None) == (contract.fold_stats is None)
     assert contract.layerwise_views == (
-        name in {"deepseek", "mellum", "keye", "dots3"})
+        name in {"deepseek", "mellum", "keye", "dots3", "jamba"})
     assert (contract.init_chunk_stats is not None) == (
         name in {"keye", "dots3"})
-    assert (contract.count_state_zeroed is not None) == (name == "brumby")
+    assert (contract.count_state_zeroed is not None) == (
+        name in {"brumby", "jamba"})
 
 
 @each_family
@@ -222,6 +225,33 @@ def test_nothing_under_models_or_ops_imports_the_serving_layer():
     assert serving_cache.WithSide is WithSide
 
 
+def test_the_state_pool_is_no_ops_own():
+    """`serving/cache.py` lays a state out as the family's spec says and
+    imports nothing of the op that reads it: the pool a forward is handed
+    is `models.contract.StatePool`, which power retention's module only
+    re-exports."""
+    from accelerate_tpu.models import contract
+    from accelerate_tpu.ops import power_retention
+
+    root = pathlib.Path(accelerate_tpu.__file__).parent
+    for node in ast.walk(ast.parse((root / "serving" / "cache.py").read_text())):
+        names = []
+        if isinstance(node, ast.ImportFrom):
+            names = [node.module or ""] + [a.name for a in node.names]
+        elif isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        assert not any("power_retention" in n or "selective_scan" in n
+                       for n in names), (node.lineno, names)
+    assert power_retention.StatePool is contract.StatePool
+    assert power_retention.StateMeta is contract.StateMeta
+    assert serving_cache.StatePool is contract.StatePool
+    spec = ServingContract.of(brumby).cache_spec(brumby.BrumbyConfig.tiny())
+    pool = jax.eval_shape(lambda: StateCache.create(spec, 2, 32).pool())
+    assert isinstance(pool, contract.StatePool)
+    assert pool.s.shape == (2, 3, 2, 65 * 128, 128)
+    assert pool.z.shape == (2, 3, 2, 72, 128)      # what brumby declares
+
+
 # ---------------------------------------------------------------------------
 # the two decisions that moved beside the cache classes
 # ---------------------------------------------------------------------------
@@ -235,20 +265,23 @@ def test_create_cache_picks_the_class_a_spec_gets(name):
                       cache_dtype=jnp.float32)
     cache = jax.eval_shape(lambda: create_cache(spec, ec, pad_slack=8))
     want = {"mellum": GroupedPagedCache, "dots3": GroupedPagedCache,
+            "jamba": GroupedPagedCache,
             "brumby": StateCache}.get(name, PagedKVCache)
     assert type(cache) is want
     first = spec[0] if isinstance(spec, tuple) else spec
     assert cache.latent == (first.kind == "latent")
     assert (cache.side is not None) == bool(first.side_width)
     if want is GroupedPagedCache:
+        # one pool a PAGE group; a group of state entries stands beside
         assert [g.k.shape[0] for g in cache.groups] == [
-            s.num_layers for s in spec]
+            s.num_layers for s in spec if s.kind != "state"]
+        assert (cache.state is not None) == (name == "jamba")
     else:
         assert jax.tree.leaves(cache)[0].shape[0] == spec.num_layers
 
 
 @pytest.mark.parametrize("name", ["llama", "deepseek", "mellum", "keye",
-                                  "dots3"])
+                                  "dots3", "jamba"])
 def test_paged_decode_operands_hands_the_pools_as_a_forward_takes_them(name):
     module, tiny, _ = FAMILIES[name]
     spec = ServingContract.of(module).cache_spec(tiny())
