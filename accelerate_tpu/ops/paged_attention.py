@@ -10,13 +10,36 @@ else. It takes the WHOLE stacked pool `[L, pages + 1, Hkv, page_size, D]`
 (`memory_space=pl.ANY`) and a layer index, so nothing slices a layer out
 of the pool around its call and a decode step does not know the pool's
 size. One grid step is one slot; inside it a loop with a DYNAMIC trip
-count walks the slot's live pages in groups of `PAGES_PER_GROUP`, each
-page copied (both KV heads, one copy) into one of two VMEM buffers while
+count walks the slot's live pages in groups of `PAGES_PER_GROUP`, copied
+(every KV head of a page together) into one of two VMEM buffers while
 the other is folded into the online softmax. A lane whose length the
 engine masked to 0 (retired, or mid-prefill) costs one grid step and no
-copy. The pjit/TPUv4 rule (arxiv 2204.06514) still holds: the table,
-lengths and layer are traced *data*, so one compiled program covers every
-page mapping, request mix, and eviction history.
+copy. Pages `p ... p + R - 1` of a layer are ONE block of the pool in
+HBM, as a group's pages `j ... j + R - 1` are of its buffer: the kernel
+reads a group's table entries `PAGES_PER_RUN` (`R`) at a time, from an
+index that is a multiple of `R`, and where they hold consecutive page
+ids it copies the RUN with one descriptor a pool, otherwise each page
+with its own, as it did every page before (the kernel's pace was ~20 ns
+a descriptor, not the bytes: PERF.md section 6, PRs 49-50). The same bytes
+land in the same rows either way, so the one wait a buffer, the fold and
+every output bit are what the page-at-a-time walk gives. The allocator
+hands pages out ascending (`serving/cache.py` `PagePool.alloc`), so a
+slot's table is mostly runs; what it is not (a run broken or unaligned
+in the table, the trash page or the table's clamped end, both repeated)
+is decided by what the kernel READS, a sub-group at a time, and no
+caller chooses. Over a ring (`ring=True`) the run copy is taken only
+where `pages_per_slot % R == 0`, so that no aligned sub-group wraps;
+any other ring keeps a copy a page (`_pages_per_run`: `R` is
+`gcd(PAGES_PER_RUN, group)`, a static shape's matter). The pjit/TPUv4
+rule (arxiv 2204.06514) still holds: the table, lengths and layer are
+traced *data*, so one compiled program covers every page mapping, request
+mix, and eviction history. The call itself is one inner `jax.jit` a
+variant (`_live_pages_call`; static: window, ring, group, run): a family
+that loops over its layers in Python hands every layer's call the same
+shapes and its layer index as data, so its decode program traces and
+lowers ONE kernel body a variant and `call`s it a layer; tracing and
+lowering are paid in every process before any compile cache is asked
+(PERF.md section 6, PR 50).
 
 Layout and semantics:
 
@@ -90,6 +113,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -206,6 +230,10 @@ class PagedDecodeMeta:
 # Pages copied and folded at a time: swept on the chip over 8 / 16 / 32 /
 # 64 in both Qwen serving cells, PERF.md section 6 (PR 27).
 PAGES_PER_GROUP = 32
+# Table entries tested together for a RUN (consecutive page ids), which is
+# one copy a pool: swept on the chip over 4 / 8 / 16 in docqa's and jamba's
+# shapes, PERF.md section 6 (PRs 49-50).
+PAGES_PER_RUN = 8
 # What the four group buffers (K and V, two each) may take of the chip's
 # 16 MB of scoped VMEM. A Qwen page (2 kv heads x 16 x 128 bf16) is 8 KB
 # and its buffers 1 MB; an MHA page of 32 heads is 128 KB, and 32 of them
@@ -229,18 +257,29 @@ def _pages_per_group(pages_per_slot: int, page_shape, dtype) -> int:
     return min(PAGES_PER_GROUP, pages_per_slot, fit)
 
 
+def _pages_per_run(pages_per_group: int, pages_per_slot: int,
+                   ring: bool) -> int:
+    """`PAGES_PER_RUN`, clamped to a divisor of the group; 1 (every page a
+    copy of its own) where a ring's aligned sub-group could wrap."""
+    run = math.gcd(PAGES_PER_RUN, pages_per_group)
+    return 1 if ring and pages_per_slot % run else run
+
+
 def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
                        vn_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
                        sm_scale: float, page_size: int, pages_per_slot: int,
-                       pages_per_group: int, num_kv_heads: int,
-                       window: int | None, ring: bool = False):
+                       pages_per_group: int, pages_per_run: int,
+                       num_kv_heads: int, window: int | None,
+                       ring: bool = False):
     """Grid [slots]: one step is one slot. A loop with a DYNAMIC trip
     count walks the slot's live pages in groups of `pages_per_group`:
-    each page of a group is one copy (every kv head of it) out of the
-    whole stacked pool `k_hbm`/`v_hbm` [L, N+1, Hkv, ps, D], where it
-    lies in HBM, into one of two VMEM buffers, while the other buffer is
-    folded into the online softmax. A slot of length 0 (a lane the
-    engine masked out) starts no copy at all. With `ring`, the table row
+    a group is copied (every kv head of a page) out of the whole stacked
+    pool `k_hbm`/`v_hbm` [L, N+1, Hkv, ps, D], where it lies in HBM,
+    into one of two VMEM buffers, while the other buffer is folded into
+    the online softmax: `pages_per_run` table entries that hold
+    consecutive page ids as ONE copy a pool, any others a page a copy.
+    A slot of length 0 (a lane the engine masked out) starts no copy at
+    all. With `ring`, the table row
     is a ring: the page of positions [p * ps, (p + 1) * ps) is entry `p %
     pages_per_slot`, and what an entry held `pages_per_slot` pages ago
     lies behind the window, where the mask (which goes by position) does
@@ -248,7 +287,7 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
     s = pl.program_id(0)
     length = lengths_ref[s]
     layer = layer_ref[0]
-    G, ps, P = pages_per_group, page_size, pages_per_slot
+    G, R, ps, P = pages_per_group, pages_per_run, page_size, pages_per_slot
     rows = G * ps
     n_groups = (length + rows - 1) // rows
     # under a sliding window the walk starts at the first group that
@@ -258,14 +297,54 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
 
     def start(g, slot):
         """Start the copies of every page of group `g` into buffer `slot`."""
-        for j in range(G):
+
+        # the index arithmetic binds `jax.lax` primitives on int32 scalars
+        # (all of them >= 0, so `rem` is `%`): an operator on a tracer is a
+        # jitted `jax.numpy` function, traced again in every body, `pl.when`
+        # and loop, and a body's ~600 of them were seconds of every
+        # process's set-up on the chip's host, which no compile cache saves
+        # (PERF.md section 6, PR 50: mellum's `decode` traced in 6.3-6.9 s
+        # with operators, 4.2-4.7 with these)
+        i32 = lambda x: x if isinstance(x, jax.Array) else np.int32(x)  # noqa: E731
+        row = jax.lax.mul(s, i32(P))        # the slot's table row
+        at = jax.lax.mul(i32(g), i32(G))    # the group's first entry
+
+        def entry(j):
             # entries past the table's end re-read its last page: masked
-            page = table_ref[s * P + ((g * G + j) % P if ring
-                                      else jnp.minimum(g * G + j, P - 1))]
-            pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, j],
-                                  sem.at[0, slot]).start()
-            pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, j],
-                                  sem.at[1, slot]).start()
+            e = jax.lax.add(at, i32(j))
+            e = jax.lax.rem(e, i32(P)) if ring else jax.lax.min(e, i32(P - 1))
+            return table_ref[jax.lax.add(row, e)]
+
+        def copy(pages, j):
+            # `pages` of the pool's layer -> the buffer's pages from `j`
+            for which, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                pltpu.make_async_copy(hbm.at[layer, pages], buf.at[slot, j],
+                                      sem.at[which, slot]).start()
+
+        # R entries from an index that is a multiple of R. Consecutive page
+        # ids lie side by side in the pool, as their pages do in the
+        # buffer: one copy. Whatever else the entries hold (a broken or
+        # descending run, the trash page or the table's clamped end, both
+        # repeated) goes a page at a time. Both land the same bytes in the
+        # same rows. The walk is static: rolled, its copies take their
+        # buffer offsets from the loop's index, which a table of NO runs
+        # paid 10-19% for (PERF.md section 6, PRs 49-50)
+        for j in range(0, G, R):
+            pages = [entry(j + r) for r in range(R)]
+
+            def each(j=j, pages=pages):
+                for r in range(R):
+                    copy(pages[r], j + r)
+
+            if R == 1:
+                each()
+                continue
+            run = functools.reduce(jax.lax.bitwise_and, (
+                jax.lax.eq(pages[r], jax.lax.add(pages[0], i32(r)))
+                for r in range(1, R)))
+            pl.when(run)(lambda j=j, pages=pages: copy(
+                pl.ds(pages[0], R), pl.ds(j, R)))
+            pl.when(jax.lax.bitwise_not(run))(each)
 
     @pl.when(first < n_groups)
     def _first():
@@ -354,18 +433,26 @@ def _live_pages_kernel(table_ref, lengths_ref, layer_ref, q_ref, kn_ref,
         o_ref[0, h] = (acc / l).astype(o_ref.dtype)
 
 
-def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths,
-                     window: int | None, interpret: bool, ring: bool = False):
+@functools.partial(jax.jit, static_argnames=(
+    "window", "interpret", "ring", "pages_per_group", "pages_per_run"))
+def _live_pages_call(q4, kn, vn, pool_k, pool_v, layer, table, lengths, *,
+                     window: int | None, interpret: bool, ring: bool,
+                     pages_per_group: int, pages_per_run: int):
     """q4 [S, Hkv, Gp, D], kn/vn [S, Hkv, 1, D], pools [L, N+1, Hkv, ps,
-    D], layer int32 scalar -> out [S, Hkv, Gp, D]."""
+    D], layer int32 scalar -> out [S, Hkv, Gp, D]. A program of its own:
+    the layers of one model hand it the same shapes and their layer index
+    as DATA, so a decode program that loops over its layers in Python
+    traces and lowers ONE kernel body a variant (`window`, `ring`) and
+    calls it a layer; every process pays tracing and lowering before any
+    compile cache is asked (PERF.md section 6, PRs 46 and 50)."""
     S, Hkv, Gp, D = q4.shape
     P = table.shape[1]
     ps = pool_k.shape[3]
-    G = _pages_per_group(P, pool_k.shape[2:], pool_k.dtype)
+    G = pages_per_group
     kernel = functools.partial(
         _live_pages_kernel, sm_scale=1.0 / math.sqrt(D), page_size=ps,
-        pages_per_slot=P, pages_per_group=G, num_kv_heads=Hkv, window=window,
-        ring=ring)
+        pages_per_slot=P, pages_per_group=G, pages_per_run=pages_per_run,
+        num_kv_heads=Hkv, window=window, ring=ring)
     per_slot = lambda s, *_: (s, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -649,9 +736,15 @@ def paged_decode_attention(
             layer_of(pk.scales), layer_of(pv.scales), meta.table,
             meta.lengths, window, interpret)
     else:
-        out = _live_pages_call(q4, kn, vn, pk.data, pv.data, pk.layer,
-                               meta.table, meta.lengths, window, interpret,
-                               ring)
+        # the group and the run follow from static shapes, here, so that
+        # the call below is keyed by them
+        P = meta.table.shape[1]
+        group = _pages_per_group(P, pk.data.shape[2:], pk.data.dtype)
+        out = _live_pages_call(
+            q4, kn, vn, pk.data, pv.data, pk.layer, meta.table,
+            meta.lengths, window=window, interpret=interpret, ring=ring,
+            pages_per_group=group,
+            pages_per_run=_pages_per_run(group, P, ring))
     return out[:, :, :G].reshape(S, 1, H, D), (k_row, v_row)
 
 

@@ -1129,6 +1129,24 @@ def create_cache(spec, engine_config, pad_slack: int, stats: Any = None):
 # ---------------------------------------------------------------------------
 
 
+# The aligned sub-group of table entries that the K/V paged decode kernel
+# copies with ONE descriptor a pool when their page ids are consecutive:
+# `ops/paged_attention.py` `PAGES_PER_RUN`, which imports Pallas and so is
+# not imported here (tests/test_paged_cache.py holds the two equal).
+TABLE_RUN_PAGES = 8
+
+
+def table_run_pages(pages, run: int = TABLE_RUN_PAGES) -> int:
+    """How many of a table row's `pages` lie in a sub-group of `run`
+    entries, aligned in TABLE INDEX, that holds consecutive page ids:
+    what a paged decode kernel copies `run` pages at a time. The entries
+    past the last whole sub-group are followed by the trash page and
+    count as none."""
+    whole = len(pages) // run * run
+    groups = np.asarray(pages[:whole], np.int64).reshape(-1, run)
+    return int((np.diff(groups, axis=1) == 1).all(axis=1).sum()) * run
+
+
 class PagePool:
     """Free list over the allocatable pages (the trash page never enters).
 
@@ -1149,12 +1167,19 @@ class PagePool:
         return self.num_pages - len(self._free)
 
     def alloc(self, n: int) -> list[int] | None:
-        """Pop `n` free pages, or None (and no change) if short."""
+        """Pop `n` free pages, or None (and no change) if short. They
+        come ASCENDING, whatever order they were freed in: pages a
+        retired request gave back together are neighbours in the pool,
+        and only an ascending table row shows that as the runs of
+        consecutive ids a paged decode kernel copies with one descriptor
+        (`table_run_pages`). A page is location-free, so nothing else
+        reads the order."""
         if n > len(self._free):
             return None
         taken = self._free[len(self._free) - n:]
         del self._free[len(self._free) - n:]
-        return taken[::-1]
+        taken.sort()
+        return taken
 
     def release(self, pages) -> None:
         self._free.extend(pages)
@@ -1539,13 +1564,17 @@ class PageAllocation:
     program runs, or the reused prefix serves garbage.
 
     `rings`: under a grouped cache, the pages of the slot's ring in each
-    window group (one list a group), fixed from admission to release."""
+    window group (one list a group), fixed from admission to release.
+
+    `run_pages`: how many of `pages` the K/V paged decode kernel can copy
+    a run at a time (`table_run_pages`)."""
 
     reused_len: int
     nodes: list
     pages: list[int]
     swap_ins: list | None = None
     rings: tuple = ()
+    run_pages: int = 0
 
 
 class PagedAllocator:
@@ -1656,6 +1685,7 @@ class PagedAllocator:
             evictions, stale = self.evictions, self.index.lru_stale
             alloc = self._allocate(request)
             sp.set(pages=len(alloc.pages) if alloc else 0,
+                   run_pages=alloc.run_pages if alloc else 0,
                    reused_len=alloc.reused_len if alloc else 0,
                    evicted=self.evictions - evictions,
                    lru_stale=self.index.lru_stale - stale,
@@ -1738,13 +1768,15 @@ class PagedAllocator:
             self.tokens_reused += len(path) * self.page_size
         # ownership of the acquired refcounts transfers to the returned
         # allocation here (hbm prefix + re-homed host suffix == path)
+        pages = [n.page for n in hbm_nodes + host_nodes] + private
         return PageAllocation(
             reused_len=len(path) * self.page_size,
             nodes=hbm_nodes + host_nodes,
-            pages=[n.page for n in hbm_nodes + host_nodes] + private,
+            pages=pages,
             swap_ins=swap_ins or None,
             rings=tuple(pool.alloc(n)
                         for pool, n in zip(self.ring_pools, ring_need)),
+            run_pages=table_run_pages(pages),
         )
 
     def rollback(self, alloc: PageAllocation) -> None:

@@ -1955,7 +1955,9 @@ class Engine:
             # the reused length (nothing reads the pages in between)
             self._run_swap_in(slot, req, alloc)
         self.metrics.note_admission(req.prompt_len, alloc.reused_len,
-                                    host_pages=len(alloc.swap_ins or ()))
+                                    host_pages=len(alloc.swap_ins or ()),
+                                    table_pages=len(alloc.pages),
+                                    run_pages=alloc.run_pages)
         self._set_page_gauges()
         if req.trace_sampled:
             # the queue-wait span is only known in retrospect: it closes

@@ -58,6 +58,13 @@ class ServingMetrics:
         self._c_prefix_tokens = r.counter("serving_prefix_tokens_reused_total")
         self._c_prompt_tokens = r.counter("serving_prompt_tokens_total")
         self._c_evictions = r.counter("serving_page_evictions_total")
+        # of the table entries admissions wrote, those in an aligned
+        # sub-group of consecutive page ids, which the K/V paged decode
+        # kernel copies with one descriptor (serving/cache.py
+        # `table_run_pages`); a low share is a fragmented free list
+        self._c_table_pages = r.counter("serving_kv_table_pages_total")
+        self._c_table_run_pages = r.counter(
+            "serving_kv_table_run_pages_total")
         # hierarchical KV (ISSUE 16): prefix hits split by the tier that
         # served them (an hbm hit mapped pages in place, a host hit paid
         # a swap-in), swap traffic in pages both directions, the host
@@ -177,6 +184,14 @@ class ServingMetrics:
         return int(self._c_evictions.value)
 
     @property
+    def kv_table_pages(self) -> int:
+        return int(self._c_table_pages.value)
+
+    @property
+    def kv_table_run_pages(self) -> int:
+        return int(self._c_table_run_pages.value)
+
+    @property
     def prefix_hits_hbm(self) -> int:
         return int(self._c_prefix_hits_hbm.value)
 
@@ -243,13 +258,18 @@ class ServingMetrics:
          else self._c_reads_settled).inc()
 
     def note_admission(self, prompt_len: int, reused_len: int,
-                       host_pages: int = 0) -> None:
+                       host_pages: int = 0, table_pages: int = 0,
+                       run_pages: int = 0) -> None:
         """One admitted request's prefix-cache outcome. `host_pages` is
         how many of the reused pages were swapped in from the host tier
         — any makes this a host-tier hit (the admission paid a swap-in),
-        else an HBM hit."""
+        else an HBM hit. `table_pages` is how many table entries the
+        admission wrote, `run_pages` how many of them lie in runs
+        (`PageAllocation.run_pages`)."""
         self._c_prefix_lookups.inc()
         self._c_prompt_tokens.inc(prompt_len)
+        self._c_table_pages.inc(table_pages)
+        self._c_table_run_pages.inc(run_pages)
         if reused_len > 0:
             self._c_prefix_hits.inc()
             self._c_prefix_tokens.inc(reused_len)
@@ -386,6 +406,8 @@ class ServingMetrics:
             "prefix_hits": float(self.prefix_hits),
             "prefix_tokens_reused": float(self.prefix_tokens_reused),
             "page_evictions": float(self.page_evictions),
+            "kv_table_pages": float(self.kv_table_pages),
+            "kv_table_run_pages": float(self.kv_table_run_pages),
             "pages_in_use": float(self._g_pages_in_use.value),
             "pages_free": float(self._g_pages_free.value),
             "kv_bytes_in_use": float(self._g_kv_bytes.value),

@@ -316,5 +316,6 @@ def place_shipment(engine, transport: PageTransport, shipment: KVPageShipment,
                                 logprob=shipment.first_logprob)
     engine.metrics.note_admission(
         internal.prompt_len, alloc.reused_len,
-        host_pages=len(alloc.swap_ins or ()))
+        host_pages=len(alloc.swap_ins or ()),
+        table_pages=len(alloc.pages), run_pages=alloc.run_pages)
     return internal, slot, alloc

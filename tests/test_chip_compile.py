@@ -98,13 +98,17 @@ def _assert_kernel_inside(compiled, at_least=1):
     (8, 4, 128, False),   # Llama-3-8B on a bf16 pool
     (32, 1, 128, False),  # Llama-2-7B / OPT-6.7B: MHA, a page is 128 KB
     (16, 1, 256, False),  # GPT-J-6B: MHA, 256-wide heads
+    (1, 20, 128, False),  # Jamba2-3B: MQA, a page is 4 KB
+    (4, 8, 128, False),   # Mellum2: 32 heads over 4 KV heads, a page 16 KB
 ], ids=["qwen2-bf16", "qwen2-int8", "llama3-int8", "gpt2-bf16",
-        "llama3-bf16", "llama2-mha-bf16", "gptj-bf16"])
+        "llama3-bf16", "llama2-mha-bf16", "gptj-bf16", "jamba-bf16",
+        "mellum-bf16"])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
                                               group, d, quantized):
     from accelerate_tpu.ops.paged_attention import (
         PagedDecodeMeta,
         PagedKV,
+        _pages_per_group,
         paged_decode_attention,
     )
 
@@ -124,16 +128,26 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, chip_compile, hkv,
     q = sds((slots, 1, hkv * group, d), jnp.bfloat16)
     kn = sds((slots, 1, hkv, d), jnp.bfloat16)
     for window in (None, 256):
-        compiled = jax.jit(
+        attend = jax.jit(
             lambda q, kn, vn, pk, pv, meta, window=window:
-            paged_decode_attention(q, kn, vn, pk, pv, meta, window=window)[0]
-        ).lower(q, kn, kn, pk, pk, meta).compile()
+            paged_decode_attention(q, kn, vn, pk, pv, meta, window=window)[0])
+        compiled = attend.lower(q, kn, kn, pk, pk, meta).compile()
         _assert_kernel_inside(compiled)
         # the live-pages kernel takes the pool where it lies (the older
         # one, for int8 pools and 64-wide heads, is given a layer's slice)
         if not quantized and d % 128 == 0:
             assert not _ops_of_shape(compiled.as_text(), "bf16",
                                      pool.shape[1:])
+            # ... and what the chip's compiler took holds the run copy: 8
+            # pages of the pool's layer in one descriptor, for K and for V,
+            # where a group is first started and where the next one is
+            # (a group is 32 pages, 16 of them where a page is 128 KB)
+            runs = re.findall(r"dma_start\(p0\) \w+\[\w+,(\w+):\1\+(\d+),",
+                              str(jax.make_jaxpr(attend)(q, kn, kn, pk, pk,
+                                                         meta)))
+            group = _pages_per_group(pages_per_slot, pool.shape[2:],
+                                     pool.dtype)
+            assert [int(n) for _, n in runs] == [8] * (4 * group // 8)
 
 
 def _ops_of_shape(text, dtype, shape):

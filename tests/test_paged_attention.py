@@ -21,10 +21,19 @@ from accelerate_tpu.ops import paged_attention as pa
 from accelerate_tpu.ops.paged_attention import (
     PagedDecodeMeta,
     PagedKV,
-    paged_decode_attention,
     paged_decode_reference,
 )
 from accelerate_tpu.ops.quant import kv_dequantize_rows, kv_quantize_rows
+
+
+def paged_decode_attention(*args, **kwargs):
+    """The op, WAITED for. The live-pages kernel's CPU interpreter runs jax
+    operations of its own inside the program's callbacks; a test that goes
+    on to dispatch (the reference, a second call) while the kernel is still
+    in flight can deadlock the CPU client (seen under six workers' load: a
+    worker of the suite stood still in one of the run copy's layouts, PR
+    50). The engine reads a step's results before it dispatches again."""
+    return jax.block_until_ready(pa.paged_decode_attention(*args, **kwargs))
 
 
 LIVE, OLDER = 128, 16   # head widths: which kernel a pool is given to
@@ -157,6 +166,99 @@ def test_live_pages_kernel_masked_lanes_with_stale_tables(pages_per_group):
     S, _, H, D = q.shape
     own = jnp.repeat(vn[:, 0], H // vn.shape[2], axis=1).reshape(S, 1, H, D)
     _assert_close(out[1], own[1])
+
+
+# table layouts for the run copy: groups of 8 pages of 8 rows, tested for
+# runs 4 entries at a time, in a pool of 64 pages (the trash page is 64).
+# name -> (rows of the table (padded with the trash page to `P`), lengths,
+# P, run sub-groups a slot the kernel must find, window, ring, Hkv, G)
+_ASC = list(range(16, 36))
+_RUN_LAYOUTS = {
+    "all-runs": ([_ASC, list(range(40, 60))], (157, 96), 20, (5, 5),
+                 None, False, 2, 3),
+    "shuffled": ([[9, 3, 27, 14, 40, 8, 61, 22, 5, 50, 33, 2, 19, 44, 11,
+                   30, 58, 1, 36, 25]], (155,), 20, (0,), None, False, 2, 3),
+    "broken-inside": ([[16, 17, 5, 19, 20, 21, 22, 23, 24, 25, 27, 26]],
+                      (95,), 12, (1,), None, False, 2, 3),
+    "unaligned-start": ([[50, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17]],
+                        (90,), 12, (2,), None, False, 2, 3),
+    "tail-past-length": ([_ASC[:16]], (83,), 16, (4,), None, False, 2, 3),
+    # 20 entries in groups of 8: the last group's entries 20-23 are the
+    # table's clamped end, entry 19 four times
+    "past-table": ([_ASC], (159,), 20, (5,), None, False, 2, 3),
+    # padded entries repeat the trash page; slot 1's last real page is the
+    # one below it, so [61, 62, 63, 64] is a run THROUGH the trash page
+    "padded-trash": ([[4, 5, 6, 7, 8], [60, 61, 62, 63]], (37, 30), 12,
+                     (1, 1), None, False, 2, 3),
+    "window": ([_ASC], (150,), 20, (5,), 45, False, 2, 3),
+    # a ring of 12 pages (12 % 4 == 0: no aligned sub-group wraps), walked
+    # twice round, and one of 10 (every page a copy of its own)
+    "ring-whole-runs": ([list(range(8, 20))], (203,), 12, (3,), 40, True,
+                        2, 3),
+    "ring-no-runs-taken": ([list(range(8, 18))], (171,), 10, (0,), 40, True,
+                           2, 3),
+    "mqa-20-heads": ([_ASC], (149,), 20, (5,), None, False, 1, 20),
+    "gqa-2": ([_ASC], (149,), 20, (5,), None, False, 2, 6),
+    "gqa-4": ([_ASC], (149,), 20, (5,), None, False, 4, 8),
+}
+
+
+def _run_sub_groups(table, P, G, R, ring):
+    """Sub-groups a slot that the kernel's rule takes as ONE copy, by the
+    allocator's own count (`table_run_pages`) over the entries the kernel
+    reads: whole groups, entries past the table's end clamped to its last
+    (once round a ring, whose sub-groups do not wrap or are not looked
+    for)."""
+    from accelerate_tpu.serving.cache import table_run_pages
+
+    if ring and P % R:
+        return tuple(0 for _ in table)
+    read = range(P if ring else -(-P // G) * G)
+    return tuple(
+        table_run_pages([int(row[min(i, P - 1)]) for i in read], R) // R
+        for row in np.asarray(table))
+
+
+@pytest.mark.parametrize("layout", list(_RUN_LAYOUTS))
+def test_live_pages_kernel_run_copy_is_bitwise_the_per_page_walk(
+        monkeypatch, layout):
+    """A sub-group of consecutive page ids is ONE copy a pool, anything
+    else a page at a time, and both land the same bytes: the output is
+    BITWISE that of the same call on a pool whose pages were permuted (the
+    table with them) so that no sub-group is a run, and within the
+    tolerance of the dense reference."""
+    rows, lengths, P, runs, window, ring, Hkv, G = _RUN_LAYOUTS[layout]
+    monkeypatch.setattr(pa, "PAGES_PER_GROUP", 8)
+    monkeypatch.setattr(pa, "PAGES_PER_RUN", 4)
+    N, ps, S = 64, 8, len(rows)
+    q, kn, vn, pk, pv, _ = _setup(S=S, P=P, ps=ps, Hkv=Hkv, G=G,
+                                  num_pages=N, seed=7)
+    table = np.full((S, P), N, np.int32)
+    for s, row in enumerate(rows):
+        table[s, :len(row)] = row
+    group = pa._pages_per_group(P, pk.data.shape[2:], pk.data.dtype)
+    assert group == 8
+    per_run = pa._pages_per_run(group, P, ring)
+    assert per_run == (1 if layout == "ring-no-runs-taken" else 4)
+    assert _run_sub_groups(table, P, group, 4, ring) == runs
+    meta = PagedDecodeMeta(jnp.asarray(table),
+                           jnp.asarray(lengths, jnp.int32), rows=P * ps)
+    out, _ = paged_decode_attention(q, kn, vn, pk, pv, meta, window=window,
+                                    ring=ring)
+    ref, _ = paged_decode_reference(q, kn, vn, pk, pv, meta, window=window,
+                                    ring=ring)
+    _assert_close(out, ref)
+    # the same bytes under other page ids: neighbours 37 apart, the trash
+    # page where it was
+    perm = np.append((np.arange(N) * 37 + 11) % N, N)
+    assert _run_sub_groups(perm[table], P, group, 4, ring) == (0,) * S
+    moved = [PagedKV(jnp.zeros_like(p.data).at[:, perm].set(p.data),
+                     layer=p.layer) for p in (pk, pv)]
+    walked, _ = paged_decode_attention(
+        q, kn, vn, *moved, PagedDecodeMeta(
+            jnp.asarray(perm[table]), meta.lengths, rows=meta.rows),
+        window=window, ring=ring)
+    assert jnp.array_equal(out, walked)
 
 
 @pytest.mark.parametrize("D", [LIVE, 8], ids=["live", "older"])
@@ -314,3 +416,60 @@ def test_decode_attention_dispatches_paged_vs_dense():
         decode_attention(q, kn, vn, (pk, pv, meta),
                          positions=meta.lengths[:, None],
                          mask=jnp.ones((3, 1), bool))
+
+
+def _tiny_engine_decode(family_name):
+    """(a tiny engine of the family with 128-wide heads over abstract
+    weights, the layers of it that attend by kind)."""
+    from accelerate_tpu.serving import Engine, EngineConfig
+
+    kwargs = dict(num_slots=2, max_len=64, prefill_chunk=8, page_size=8,
+                  cache_dtype=jnp.float32, paged_attention=True)
+    if family_name == "mellum":
+        from accelerate_tpu.models import mellum as family
+
+        cfg = family.MellumConfig.tiny()
+        kinds = {"full": len(cfg.layers_of(family.FULL)),
+                 "ring": len(cfg.layers_of(family.SLIDING))}
+        kwargs.update(prefix_cache=False)
+    elif family_name == "jamba":
+        from accelerate_tpu.models import jamba as family
+
+        cfg = family.JambaConfig.tiny()     # layers 1 and 3 attend
+        kinds = {"full": 2}
+        kwargs.update(prefix_cache=False, page_size=16)
+    else:
+        from accelerate_tpu.models import llama as family
+
+        cfg = family.LlamaConfig.tiny(hidden_size=512, num_attention_heads=4,
+                                      num_key_value_heads=2)
+        kinds = {"scan": 1}                 # one body inside the layer scan
+    params = jax.eval_shape(
+        lambda: family.init_params(cfg, jax.random.key(0), jnp.float32))
+    return Engine(family, cfg, params, EngineConfig(**kwargs)), kinds
+
+
+@pytest.mark.parametrize("family_name", ["mellum", "jamba", "llama"])
+def test_a_decode_program_holds_the_live_kernel_once_a_variant(
+        family_name, monkeypatch):
+    """The engine's `decode` lowered for the chip: a family that loops over
+    its layers in Python holds the live-pages kernel's body ONCE a variant
+    (full layers; a ring under a window), in a function of its own that
+    the layers call with their layer index as data, so what every process
+    pays to trace and lower it does not grow with the model's depth; under
+    the families' shared layer scan it is one body and one call."""
+    from accelerate_tpu.ops import kernel_mode
+
+    monkeypatch.setattr(kernel_mode, "resolve_interpret",
+                        lambda name, interpret=None: False)
+    eng, kinds = _tiny_engine_decode(family_name)
+    text = eng._decode_p.trace(
+        eng.params, eng.cache, eng._tokens, eng._slot_keys, eng._temps,
+        np.ones((2,), bool), eng._tables()).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("func.func private @_live_pages_call") == len(kinds)
+    assert text.count("call @_live_pages_call") == sum(kinds.values())
+    assert text.count(f'kernel_name = "{pa.KERNEL_NAME}"') == (
+        len(kinds) - ("ring" in kinds))
+    assert text.count(f'kernel_name = "{pa.WINDOW_KERNEL_NAME}"') == (
+        "ring" in kinds)

@@ -105,6 +105,96 @@ def test_page_pool_alloc_release_exact():
     assert pool.free_count == 4
 
 
+@pytest.mark.parametrize("freed_by", ["release", "release-two-lists",
+                                      "evict_lru"])
+def test_page_pool_alloc_is_ascending_whatever_order_pages_were_freed_in(
+        freed_by):
+    """A table row shows the adjacency its pages have only if they come
+    ascending: after a retirement's `release` (one list, or two requests'
+    lists out of order) and after an eviction's (oldest leaves first,
+    which is no order of ids)."""
+    if freed_by == "evict_lru":
+        al = PagedAllocator(page_size=4, num_pages=12, pad_slack=0)
+        for base in (300, 100, 200):          # three prompts of 4 pages
+            r = _req(list(range(base, base + 16)), mnt=0)
+            al.release(_slot(al.allocate(r), r), finished=True)
+        assert al.pages_free == 0 and al.index.cached_pages == 12
+        got = al.allocate(_req(list(range(900, 932)), mnt=0)).pages
+        assert al.evictions == 8
+    else:
+        pool = PagePool(24)
+        a, b, c = pool.alloc(8), pool.alloc(8), pool.alloc(8)
+        assert (a, b, c) == (list(range(8)), list(range(8, 16)),
+                             list(range(16, 24)))
+        if freed_by == "release":
+            pool.release(b[::-1])
+        else:
+            pool.release(c[4:])
+            pool.release(a[:4])
+        got = pool.alloc(8)
+        assert sorted(got) == (b if freed_by == "release" else a[:4] + c[4:])
+    assert got == sorted(got)
+
+
+def test_table_run_pages_counts_aligned_sub_groups_of_consecutive_ids():
+    from accelerate_tpu.ops import paged_attention
+    from accelerate_tpu.serving import cache
+
+    # the allocator's books and the kernel read a table the same way
+    assert cache.TABLE_RUN_PAGES == paged_attention.PAGES_PER_RUN == 8
+    count = cache.table_run_pages
+    assert count(list(range(40, 64))) == 24
+    assert count(list(range(40, 63))) == 16       # the tail is no sub-group
+    assert count(list(range(7))) == 0
+    assert count([]) == 0
+    # a run that starts unaligned loses the sub-group it starts in only
+    assert count([9] + list(range(40, 63))) == 16
+    assert count(list(range(8)) + [8, 9, 10, 12, 13, 14, 15, 16]
+                 + list(range(30, 38))) == 16     # broken inside
+    assert count(list(range(15, -1, -1))) == 0    # descending
+    assert count([1, 2, 3, 4, 9, 8], run=4) == 4
+
+
+def test_a_recycled_table_is_runs_but_for_its_boundary_sub_groups():
+    """A request admitted into the pool another just left: its pages are
+    the leaver's, ascending, so every aligned sub-group of its table row
+    is a run but the one that straddles the pages a third request still
+    holds; the allocation says so, and the engine's counters are the sums
+    of what the allocations said."""
+    from accelerate_tpu.serving.metrics import ServingMetrics
+
+    al = PagedAllocator(page_size=4, num_pages=64, prefix_cache=False)
+    metrics = ServingMetrics()
+    pages = run_pages = 0
+
+    def admit(prompt_len):
+        nonlocal pages, run_pages
+        req = _req(list(range(prompt_len)), mnt=0)
+        alloc = al.allocate(req)
+        metrics.note_admission(req.prompt_len, alloc.reused_len,
+                               table_pages=len(alloc.pages),
+                               run_pages=alloc.run_pages)
+        pages += len(alloc.pages)
+        run_pages += alloc.run_pages
+        return alloc, req
+
+    first, r1 = admit(4 * 20)                  # pages 0..19
+    kept, _ = admit(4 * 3)                     # 20, 21, 22
+    assert (first.run_pages, kept.run_pages) == (16, 0)
+    al.release(_slot(first, r1), finished=True)
+    # 30 pages: the 20 the first left, then 10 from past the kept three
+    again, _ = admit(4 * 30)
+    assert again.pages == list(range(20)) + list(range(23, 33))
+    # sub-groups [0:8] [8:16] are runs; [16:24] straddles the kept pages
+    # (16..19 | 23..26); [24:30] is no whole sub-group
+    assert again.run_pages == 16
+    assert (metrics.kv_table_pages, metrics.kv_table_run_pages) \
+        == (pages, run_pages) == (53, 32)
+    summary = metrics.summary()
+    assert summary["kv_table_run_pages"] == 32.0
+    assert summary["kv_table_pages"] == 53.0
+
+
 def test_prefix_index_match_caps_below_full_prompt():
     """Reuse never covers the whole prompt: the last token must prefill
     to produce the first output logits."""
@@ -446,7 +536,9 @@ def test_prometheus_exposition_carries_page_and_prefix_series(gpt2_setup):
         for series in ("serving_pages_in_use", "serving_pages_free",
                        "serving_prefix_hits_total",
                        "serving_prefix_tokens_reused_total",
-                       "serving_page_evictions_total"):
+                       "serving_page_evictions_total",
+                       "serving_kv_table_pages_total",
+                       "serving_kv_table_run_pages_total"):
             assert series in body, f"{series} missing from exposition"
         assert "serving_prefix_hits_total 1.0" in body
     finally:
